@@ -33,7 +33,7 @@ __all__ = [
     "capture", "restore_into", "build",
     "save_state", "load_state", "load_snapshot",
     "CheckpointStore", "StoreLeaseHeld", "StoreLockTimeout",
-    "enable_warm_start", "ProgramManifest",
+    "enable_compile_cache", "ProgramManifest",
 ]
 
 
@@ -43,7 +43,7 @@ def __getattr__(name):
         from . import store
 
         return getattr(store, name)
-    if name in ("enable_warm_start", "ProgramManifest"):
+    if name in ("enable_compile_cache", "ProgramManifest"):
         from . import warmstart
 
         return getattr(warmstart, name)

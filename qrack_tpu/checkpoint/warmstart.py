@@ -1,13 +1,17 @@
 """Persistent warm start: compile once per machine, not once per process.
 
-Two layers, both rooted in the checkpoint directory:
+Two layers:
 
-* :func:`enable_warm_start` points JAX's persistent compilation cache
-  at ``<dir>/xla_cache`` with the thresholds zeroed, so every XLA
-  executable this process compiles — fused circuit programs, vmapped
-  batch programs, gate kernels — lands on disk and a later process
-  deserializes instead of recompiling.
-* :class:`ProgramManifest` records every circuit shape the serving
+* :func:`enable_compile_cache` turns on JAX's persistent compilation
+  cache with the thresholds zeroed, so every XLA executable this
+  process compiles — fused circuit programs, vmapped batch programs,
+  gate kernels — lands on disk and a later process deserializes instead
+  of recompiling.  The directory is ``JAX_COMPILATION_CACHE_DIR`` where
+  that is set (then no directory is set in code), else the fixed
+  ``<checkout>/.xla_cache``: the path is part of the cache key, so a
+  directory that moves (a mkdtemp checkpoint dir) never hits.
+* :class:`ProgramManifest` (rooted in the checkpoint directory)
+  records every circuit shape the serving
   batcher compiles (digest-keyed by ``QCircuit.shape_key`` + batch
   size, the exact program-cache identity) together with the circuit
   itself in a container file.  A fresh process calls :meth:`prewarm`
@@ -30,21 +34,27 @@ from .. import telemetry as _tele
 from .container import CheckpointCorrupt, CheckpointError
 from .store import load_circuit, save_circuit
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 _ENABLED_DIR: Optional[str] = None
 
 
-def enable_warm_start(cache_dir: str) -> str:
-    """Point the JAX persistent compilation cache at `cache_dir` (with
-    the size/time admission thresholds disabled — serving programs are
-    many and individually small).  Idempotent; returns the directory."""
+def enable_compile_cache() -> str:
+    """Turn on the JAX persistent compilation cache (with the size/time
+    admission thresholds disabled — window and serving programs are
+    many and individually small) for chip_smoke.py, bench.py and
+    QrackService alike.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    already reads it and no directory is set here; otherwise the cache
+    is ``<checkout>/.xla_cache``.  Idempotent; returns the directory."""
     global _ENABLED_DIR
-    cache_dir = str(cache_dir)
-    if _ENABLED_DIR == cache_dir:
-        return cache_dir
-    os.makedirs(cache_dir, exist_ok=True)
+    if _ENABLED_DIR is not None:
+        return _ENABLED_DIR
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(_CHECKOUT, ".xla_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _ENABLED_DIR = cache_dir

@@ -46,7 +46,7 @@ class QHybrid:
         self._kwargs = dict(kwargs)
         self._kwargs["rng"] = rng if rng is not None else QrackRandom()
         # failover ceiling: None = healthy; "tpu" = pager died, never
-        # re-promote past single-device; "cpu" = tunnel unusable, pin
+        # re-promote past single-device; "cpu" = accelerator unusable, pin
         # to host (resilience layer, docs/RESILIENCE.md)
         self._failed_over: Optional[str] = None
         self._engine = self._make_engine(qubit_count, init_state)

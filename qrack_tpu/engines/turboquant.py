@@ -322,9 +322,10 @@ def _mk_fuse_window(ca, block, cdt, qmax, structure):
                     else:
                         dirty = dirty | hi_ok
                 else:  # gen: target < ca guaranteed by _fuse_admit
-                    pl, hi_ok = pk.tile_local_2x2(pl, lidx, cid, target, p,
-                                                  lo_cm, lo_cv,
-                                                  hi_cm, hi_cv)
+                    # XLA lowers this body: see pk.tile_partner
+                    pl, hi_ok = pk.tile_local_2x2(
+                        jax.lax.optimization_barrier(pl), lidx, cid, target,
+                        p, lo_cm, lo_cv, hi_cm, hi_cv)
                     dirty = dirty | hi_ok
             nc, ns = _comp_rows_f(_planes_to_rows(pl, block), rot,
                                   qmax, cdt)

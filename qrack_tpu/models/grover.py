@@ -23,7 +23,6 @@ import math
 import numpy as np
 
 import jax
-from ..utils.compat import shard_map as _compat_shard_map
 import jax.numpy as jnp
 
 from .. import matrices as mat
@@ -139,7 +138,7 @@ def make_sharded_grover_fn(mesh, n: int, target: int,
         return jax.lax.fori_loop(0, iters, iteration, h_all(local))
 
     fn = jax.jit(
-        _compat_shard_map(body, mesh=mesh, in_specs=P(None, "pages"),
+        jax.shard_map(body, mesh=mesh, in_specs=P(None, "pages"),
                       out_specs=P(None, "pages")),
         donate_argnums=(0,),
     )

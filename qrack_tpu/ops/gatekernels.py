@@ -5,15 +5,18 @@ src/common/qengine.cl:144-1085 apply2x2*/x/z/phase/invert/compose/
 decompose/prob*/nrmlze/applym; enumerated include/common/oclapi.hpp).
 
 Representation: **split real/imag planes** — the ket is a real array of
-shape (2, 2^n), plane 0 = Re, plane 1 = Im. TPUs have no complex ALU
-(and this environment's TPU platform rejects complex dtypes outright),
+shape (2, 2^n), plane 0 = Re, plane 1 = Im. TPUs have no complex ALU,
 so complex arithmetic is written out as plane algebra. This also makes
 bf16 amplitude storage a dtype switch rather than a redesign.
 
 Design rules (see SURVEY.md §7):
-  * A gate is reshape → einsum → reshape: the target "bit" becomes a
-    tensor axis, and the complex 2x2 becomes a real 4x4 plane-mixing
-    contraction XLA maps onto the VPU/MXU. No gathers in the hot path.
+  * A 1- or 2-qubit gate is elementwise over the FLAT (2, 2^n) planes:
+    each amplitude is mixed with its pair partner at index distance
+    2^target, fetched by a shifted read (`pair_partner`).  The planes
+    are never viewed as (…, 2, 2^target): the TPU tiles the minor
+    dimension to 128 lanes, so for target < 7 that view is padded
+    128/2^target-fold (refused outright at w28) and its einsum takes
+    minutes to compile at any target.  No gathers in the hot path.
   * Controls are dynamic (cmask, cval) scalar operands folded in with a
     `where` select, so the jit cache is keyed only on (n, target axis) —
     the reference's 8 apply2x2 kernel variants (opencl.cpp:810-1016)
@@ -21,8 +24,7 @@ Design rules (see SURVEY.md §7):
   * Every function is pure and trace-safe: usable eagerly, under
     per-gate jit, inside a whole-circuit jit, and inside shard_map.
 
-Index convention: qubit q is bit q of the flat index; axis split for
-target t is (high = 2^(n-1-t), 2, low = 2^t).
+Index convention: qubit q is bit q of the flat index.
 """
 
 from __future__ import annotations
@@ -80,10 +82,19 @@ def _mix(mp):
 def iota_for(planes):
     return jax.lax.iota(IDX_DTYPE, planes.shape[-1])
 
+def join_planes(re, im):
+    """(N,) real and imaginary parts -> (2, N) planes, as a select on
+    the plane index.  Not jnp.stack: the TPU compiler lowers that
+    concatenate as an update of the output in place, and aborts (a
+    check failure in its fusion emitter, not an error) when the parts
+    are computed from shifted reads alone, as in apply_invert."""
+    plane = jax.lax.broadcasted_iota(IDX_DTYPE, (2,) + re.shape, 0)
+    return jnp.where(plane == 0, re[None], im[None])
+
 def cmul(fre, fim, v):
     """Multiply planes v=(2,N) by a complex factor given as (re, im)
     arrays/scalars broadcastable over N."""
-    return jnp.stack([v[0] * fre - v[1] * fim, v[0] * fim + v[1] * fre])
+    return join_planes(v[0] * fre - v[1] * fim, v[0] * fim + v[1] * fre)
 
 
 # ---------------------------------------------------------------------------
@@ -96,13 +107,40 @@ def _ctrl_select(new, old, cmask, cval):
     return jnp.where(keep, new, old)
 
 
+def _shifted(v, d: int):
+    """``out[..., i] = v[..., i + d]``, zero past either end: a negative
+    edge pad, which XLA fuses into its consumer."""
+    cfg = [(0, 0, 0)] * (v.ndim - 1) + [(-d, d, 0)]
+    return jax.lax.pad(v, jnp.zeros((), v.dtype), cfg)
+
+
+def pair_partner(planes, target: int):
+    """``planes[..., i ^ (1 << target)]``: the other amplitude of each
+    target-bit pair, as two shifted reads and a select on the bit.
+    The barrier makes the producer of `planes` one pass of its own:
+    without it XLA fuses a chain of k gates by recomputing each input
+    at all three read offsets, 3^k-fold."""
+    planes = jax.lax.optimization_barrier(planes)
+    dist = 1 << target
+    bit = (iota_for(planes) & dist) != 0
+    return jnp.where(bit, _shifted(planes, -dist), _shifted(planes, dist))
+
+
 def apply_2x2(planes, mp, n: int, target: int, cmask=0, cval=0):
     """Generic (optionally controlled) single-qubit gate
-    (reference kernels apply2x2/apply2x2single/..., qengine.cl:144-244)."""
-    high = 1 << (n - 1 - target)
-    low = 1 << target
-    v = planes.reshape(2, high, 2, low)
-    out = jnp.einsum("PApa,phal->PhAl", _mix(mp), v, precision=PREC).reshape(2, -1)
+    (reference kernels apply2x2/apply2x2single/..., qengine.cl:144-244).
+    Each amplitude takes its own row of the matrix: the diagonal entry
+    times itself plus the off-diagonal entry times its partner."""
+    bit = (iota_for(planes) & (1 << target)) != 0
+    o = pair_partner(planes, target)
+    re, im = mp[0], mp[1]
+    dre = jnp.where(bit, re[1, 1], re[0, 0])
+    dim = jnp.where(bit, im[1, 1], im[0, 0])
+    ore = jnp.where(bit, re[1, 0], re[0, 1])
+    oim = jnp.where(bit, im[1, 0], im[0, 1])
+    out = join_planes(
+        planes[0] * dre - planes[1] * dim + o[0] * ore - o[1] * oim,
+        planes[0] * dim + planes[1] * dre + o[0] * oim + o[1] * ore)
     if isinstance(cmask, int) and cmask == 0:
         return out
     return _ctrl_select(out, planes, cmask, cval)
@@ -127,15 +165,10 @@ def apply_diag(planes, d0re, d0im, d1re, d1im, n: int, tmask, cmask=0, cval=0):
 def apply_invert(planes, tr_re, tr_im, bl_re, bl_im, n: int, target: int, cmask=0, cval=0):
     """Anti-diagonal gate: bit-flip + per-half phases (reference kernels
     xsingle/invertsingle, qengine.cl:247-290)."""
-    high = 1 << (n - 1 - target)
-    low = 1 << target
-    v = planes.reshape(2, high, 2, low)
-    flipped = jnp.flip(v, axis=2).reshape(2, -1)
-    idx = iota_for(planes)
-    bit = ((idx >> target) & 1) == 1
+    bit = (iota_for(planes) & (1 << target)) != 0
     fre = jnp.where(bit, bl_re, tr_re)
     fim = jnp.where(bit, bl_im, tr_im)
-    out = cmul(fre, fim, flipped)
+    out = cmul(fre, fim, pair_partner(planes, target))
     if isinstance(cmask, int) and cmask == 0:
         return out
     return _ctrl_select(out, planes, cmask, cval)
@@ -157,20 +190,30 @@ def apply_kxk(planes, mp, n: int, start: int, k: int):
 
 
 def apply_4x4(planes, mp4, n: int, q1: int, q2: int):
-    """Arbitrary two-qubit gate as one plane-mixing contraction (the
-    reference decomposes instead; natively batched here)."""
-    lo, hi = (q1, q2) if q1 < q2 else (q2, q1)
-    h = 1 << (n - 1 - hi)
-    m = 1 << (hi - lo - 1)
-    l = 1 << lo
-    v = planes.reshape(2, h, 2, m, 2, l)
-    mix = _mix(mp4)  # [2, 4, 2, 4]
-    mix = mix.reshape(2, 2, 2, 2, 2, 2)  # [P, B2, B1, p, b2, b1]
-    if q1 < q2:
-        out = jnp.einsum("PABpab,phambl->PhAmBl", mix, v, precision=PREC)
-    else:
-        out = jnp.einsum("PBApba,phambl->PhAmBl", mix, v, precision=PREC)
-    return out.reshape(2, -1)
+    """Arbitrary two-qubit gate (the reference decomposes instead).
+    Matrix index = (bit q2 << 1) | bit q1.  Each amplitude reads the
+    four members of its (q1, q2) quad — itself and its partners across
+    q1, q2 and both — weighted by its own row of the matrix."""
+    idx = iota_for(planes)
+    b1 = (idx & (1 << q1)) != 0
+    b2 = (idx & (1 << q2)) != 0
+
+    def own_row(m, x2, x1):
+        """m[row, row ^ (x2, x1)] with row = this amplitude's (b2, b1)."""
+        at = [m[r, r ^ ((x2 << 1) | x1)] for r in range(4)]
+        return jnp.where(b2, jnp.where(b1, at[3], at[2]),
+                         jnp.where(b1, at[1], at[0]))
+
+    p1 = pair_partner(planes, q1)
+    quad = ((planes, p1), (pair_partner(planes, q2), pair_partner(p1, q2)))
+    re = im = 0.0
+    for x2 in (0, 1):
+        for x1 in (0, 1):
+            v = quad[x2][x1]  # the member across (x2, x1)
+            cre, cim = own_row(mp4[0], x2, x1), own_row(mp4[1], x2, x1)
+            re = re + v[0] * cre - v[1] * cim
+            im = im + v[0] * cim + v[1] * cre
+    return join_planes(re, im)
 
 
 def uc_2x2(planes, mps, n: int, target: int, controls):
@@ -208,14 +251,17 @@ def phase_factor_apply(planes, fre, fim):
 
 
 def swap_bits(planes, n: int, q1: int, q2: int):
-    """Swap two qubits as a pure axis transpose — zero-FLOP relabel
-    (the reference pays 3 CNOT kernels)."""
+    """Swap two qubits as an index relabel — zero FLOPs (the reference
+    pays 3 CNOT kernels).  Amplitudes whose two bits differ trade
+    places with the index 2^hi - 2^lo away; the rest stay."""
     lo, hi = (q1, q2) if q1 < q2 else (q2, q1)
-    h = 1 << (n - 1 - hi)
-    m = 1 << (hi - lo - 1)
-    l = 1 << lo
-    v = planes.reshape(2, h, 2, m, 2, l)
-    return jnp.swapaxes(v, 2, 4).reshape(2, -1)
+    dist = (1 << hi) - (1 << lo)
+    idx = iota_for(planes)
+    blo = (idx & (1 << lo)) != 0
+    bhi = (idx & (1 << hi)) != 0
+    planes = jax.lax.optimization_barrier(planes)  # as in pair_partner
+    moved = jnp.where(blo, _shifted(planes, dist), _shifted(planes, -dist))
+    return jnp.where(blo == bhi, planes, moved)
 
 
 def gather(planes, src_idx):

@@ -17,8 +17,9 @@ Vocabulary (everything the fuser emits):
   splits at runtime inside the kernel into a tile-local part tested
   against the in-tile index and a high part tested against the grid
   block id, so high targets cost one scalar compare per tile.
-* inv / gen with target < block_pow — in-tile pair mix via a static
-  (2, high, 2, low) reshape; controls anywhere (runtime mask split).
+* inv / gen with target < block_pow — in-tile pair mix: each lane
+  reads its partner 2^target lanes away through two lane rotations
+  (tile_partner); controls anywhere (runtime mask split).
 * inv / gen with target >= block_pow — CROSS-TILE: the planner starts a
   new segment led by the op, and the segment's grid maps block PAIRS:
   the planes array is passed twice, the second BlockSpec index-mapping
@@ -47,13 +48,17 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # SMEM memory space: TPU lowering + honoured by the interpreter
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - non-TPU pallas builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_POW = 16
+
+# A pair-grid segment holds two input tiles and one output tile, each
+# double-buffered and padded from 2 to 8 sublanes (12 MiB at block_pow
+# 16), beside the body's temporaries: a controlled cross-tile gen with
+# five cphases behind it asked for 17.04 MiB against the compiler's
+# default 16 MiB scoped limit (v5e, w28 over 4 pages).  The v5e has
+# 128 MiB of VMEM.
+_VMEM_LIMIT_BYTES = 32 << 20
 
 # floats each op contributes to the packed scalar vector (dense layout
 # order: cphase [f.re,f.im]; diag [d0.re,d0.im,d1.re,d1.im];
@@ -109,7 +114,7 @@ def _operand_slots(structure: Tuple):
         slots.append((f, i))
         f += _NFLOATS[kind]
         i += 2 if has_ctrl else 0
-    return slots, f, i
+    return slots
 
 
 def pack_operands(structure: Tuple, operands: Sequence, dtype=jnp.float32):
@@ -178,27 +183,36 @@ def tile_diag(v, lidx, hi_id, target, L,
                       v[0] * f_im + v[1] * f_re]), hi_ok
 
 
+def tile_partner(v, lidx, target):
+    """``v[:, i ^ (1 << target)]`` on one (2, block) tile, as two lane
+    rotations and a select on the target bit.  The tile keeps its
+    (2, block) shape: Mosaic refuses the (2, high, 2, low) view for
+    low < 128 lanes, and XLA pads it 128/low-fold.
+
+    Mosaic emits the rotations as written.  Where XLA lowers this code
+    instead (the interpreter; a chunk body outside any kernel) the
+    caller puts an optimization barrier between ops, or XLA fuses a run
+    of k ops by recomputing each input at every read, 3^k-fold."""
+    dist = 1 << target
+    axis = v.ndim - 1
+    down = pltpu.roll(v, dist, axis)                   # v[i - dist]
+    up = pltpu.roll(v, v.shape[axis] - dist, axis)     # v[i + dist]
+    return jnp.where((lidx & dist) != 0, down, up)
+
+
 def tile_local_2x2(v, lidx, hi_id, target, mp, lm, lv, gm, gv):
     """Generic 2x2 with the pair inside the tile (target < tile pow);
     mp indexes like mtrx_planes (2, 2, 2) [plane, row, col] but may be
     a nested list of traced scalars."""
-    block = v.shape[-1]
-    high = block >> (target + 1)
-    low = 1 << target
-    vv = v.reshape(2, high, 2, low)
-    a0r, a1r = vv[0, :, 0, :], vv[0, :, 1, :]
-    a0i, a1i = vv[1, :, 0, :], vv[1, :, 1, :]
-    n0r = (mp[0][0][0] * a0r - mp[1][0][0] * a0i
-           + mp[0][0][1] * a1r - mp[1][0][1] * a1i)
-    n0i = (mp[0][0][0] * a0i + mp[1][0][0] * a0r
-           + mp[0][0][1] * a1i + mp[1][0][1] * a1r)
-    n1r = (mp[0][1][0] * a0r - mp[1][1][0] * a0i
-           + mp[0][1][1] * a1r - mp[1][1][1] * a1i)
-    n1i = (mp[0][1][0] * a0i + mp[1][1][0] * a0r
-           + mp[0][1][1] * a1i + mp[1][1][1] * a1r)
-    nv = jnp.stack([
-        jnp.stack([n0r, n1r], axis=1),
-        jnp.stack([n0i, n1i], axis=1)]).reshape(2, block)
+    o = tile_partner(v, lidx, target)
+    bit = (lidx & (1 << target)) != 0
+    # my own row of the matrix: (diagonal, off-diagonal) entry
+    dre = jnp.where(bit, mp[0][1][1], mp[0][0][0])
+    dim = jnp.where(bit, mp[1][1][1], mp[1][0][0])
+    ore = jnp.where(bit, mp[0][1][0], mp[0][0][1])
+    oim = jnp.where(bit, mp[1][1][0], mp[1][0][1])
+    nv = jnp.stack([dre * v[0] - dim * v[1] + ore * o[0] - oim * o[1],
+                    dre * v[1] + dim * v[0] + ore * o[1] + oim * o[0]])
     hi_ok = (hi_id & gm) == gv
     sel = ((lidx & lm) == lv) & hi_ok
     return jnp.where(sel, nv, v), hi_ok
@@ -207,19 +221,11 @@ def tile_local_2x2(v, lidx, hi_id, target, mp, lm, lv, gm, gv):
 def tile_local_invert(v, lidx, hi_id, target,
                       trre, trim, blre, blim, lm, lv, gm, gv):
     """Anti-diagonal 2x2 (X/Y-like) with the pair inside the tile."""
-    block = v.shape[-1]
-    high = block >> (target + 1)
-    low = 1 << target
-    vv = v.reshape(2, high, 2, low)
-    a0r, a1r = vv[0, :, 0, :], vv[0, :, 1, :]
-    a0i, a1i = vv[1, :, 0, :], vv[1, :, 1, :]
-    n0r = trre * a1r - trim * a1i
-    n0i = trre * a1i + trim * a1r
-    n1r = blre * a0r - blim * a0i
-    n1i = blre * a0i + blim * a0r
-    nv = jnp.stack([
-        jnp.stack([n0r, n1r], axis=1),
-        jnp.stack([n0i, n1i], axis=1)]).reshape(2, block)
+    o = tile_partner(v, lidx, target)
+    bit = (lidx & (1 << target)) != 0
+    fre = jnp.where(bit, blre, trre)
+    fim = jnp.where(bit, blim, trim)
+    nv = jnp.stack([fre * o[0] - fim * o[1], fre * o[1] + fim * o[0]])
     hi_ok = (hi_id & gm) == gv
     sel = ((lidx & lm) == lv) & hi_ok
     return jnp.where(sel, nv, v), hi_ok
@@ -229,12 +235,9 @@ def tile_local_invert(v, lidx, hi_id, target,
 # the Pallas window program (dense single-shard layout)
 # ---------------------------------------------------------------------------
 
-def _scalar_specs(nf: int, ni: int):
-    if pltpu is not None:
-        sm = pl.BlockSpec(memory_space=pltpu.SMEM)
-        return sm, sm
-    return (pl.BlockSpec((ni, 1), lambda i: (0, 0)),
-            pl.BlockSpec((nf, 1), lambda i: (0, 0)))
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+# packed scalar operands: SMEM on the TPU, honoured by the interpreter
+_SCALAR_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
@@ -274,20 +277,21 @@ def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
     return v
 
 
-def _segment_program(n: int, bp: int, seg: dict, slots, nf: int, ni: int,
-                     interpret: bool):
+def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
     """One pl.pallas_call for one segment: run(planes, iv, fv)."""
     block = 1 << bp
     nblk = 1 << (n - bp)
     lbits = block - 1
     xgen = seg["xgen"]
-    iv_spec, fv_spec = _scalar_specs(nf, ni)
+    iv_spec = fv_spec = _SCALAR_SPEC
     tile_spec = pl.BlockSpec((2, block), lambda i: (0, i))
 
     def in_tile_ops(v, blk, iv_ref, fv_ref):
         lidx = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)[0]
         for slot in seg["ops"]:
             v = _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp)
+            if interpret:  # XLA lowers the body: see tile_partner
+                v = jax.lax.optimization_barrier(v)
         return v
 
     if xgen is None:
@@ -302,6 +306,7 @@ def _segment_program(n: int, bp: int, seg: dict, slots, nf: int, ni: int,
                 grid=(nblk,),
                 in_specs=[iv_spec, fv_spec, tile_spec],
                 out_specs=tile_spec,
+                compiler_params=_COMPILER_PARAMS,
                 interpret=interpret,
             )(iv, fv, planes)
 
@@ -359,6 +364,7 @@ def _segment_program(n: int, bp: int, seg: dict, slots, nf: int, ni: int,
             in_specs=[iv_spec, fv_spec, tile_spec,
                       pl.BlockSpec((2, block), lambda i: (0, i ^ (1 << h)))],
             out_specs=tile_spec,
+            compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
         )(iv, fv, planes, planes)
 
@@ -375,8 +381,8 @@ def make_window_fn(n: int, structure: Tuple,
     shared structure-only cache key."""
     bp = min(block_pow, n)
     segments = plan_window(structure, bp)
-    slots, nf, ni = _operand_slots(structure)
-    programs = [_segment_program(n, bp, seg, slots, nf, max(ni, 1), interpret)
+    slots = _operand_slots(structure)
+    programs = [_segment_program(n, bp, seg, slots, interpret)
                 for seg in segments]
 
     def fn(planes, *operands):

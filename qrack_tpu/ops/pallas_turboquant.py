@@ -269,6 +269,8 @@ def make_tq_window(n: int, block_pow: int, bits: int, structure,
                 else:
                     dirty = dirty | hi_ok
             else:  # gen: target < tile pow guaranteed by _fuse_admit
+                if interpret:  # XLA lowers the body: see pk.tile_partner
+                    v = jax.lax.optimization_barrier(v)
                 v, hi_ok = pk.tile_local_2x2(v, lidx, blk, target, p,
                                              lo_cm, lo_cv, hi_cm, hi_cv)
                 dirty = dirty | hi_ok

@@ -37,7 +37,6 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from ..utils.compat import shard_map as _compat_shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -47,7 +46,7 @@ from ..utils.bits import is_pow2, log2
 
 
 def _shard_map(fn, mesh, in_specs, out_specs, **kw):
-    return _compat_shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, **kw)
 
 

@@ -53,7 +53,7 @@ def health_probe(site: Optional[str] = None) -> bool:
         return False  # mid-snapshot / oracle read: stand still
     br = _breaker.get_breaker()
     if br.open_remaining_s() > 0:
-        return False  # tunnel still cooling down
+        return False  # backend still cooling down
     if _faults.device_down(site):
         return False  # injected loss window still open
     if os.environ.get("QRACK_TPU_ELASTIC_PROBE", "") not in ("", "0"):

@@ -36,7 +36,7 @@ attribute read + truth test per site when inactive):
   wide quarantine list consumed by the pager's elastic re-paging
   (parallel/pager.py ``_device_pool``): the flaky chip is excluded and
   a spare takes its place at the next job boundary, instead of the
-  whole-tunnel breaker tripping.
+  whole-device breaker tripping.
 
 The serve-side canary verifier (serve/canary.py) feeds the same strike
 table from full-fidelity oracle replays of sampled jobs.

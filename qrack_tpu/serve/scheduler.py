@@ -4,8 +4,8 @@ Admission (all checks at submit(), synchronous, typed — errors.py):
 
 * bounded depth — past QRACK_SERVE_MAX_DEPTH jobs, QueueFull;
 * breaker-aware load shedding — while the resilience breaker is OPEN
-  and still cooling down, jobs whose session would dispatch over the
-  tunnel are refused with LoadShed (+ retry hint).  CPU-backed
+  and still cooling down, jobs whose session would dispatch to the
+  accelerator are refused with LoadShed (+ retry hint).  CPU-backed
   sessions, including already-failed-over ones, keep flowing;
 * queue-time budget — a job queued past QRACK_SERVE_QUEUE_BUDGET_MS
   is expired with QueueBudgetExceeded instead of executing stale.
@@ -219,7 +219,7 @@ class Scheduler:
                                      else shed_band)
             if job.session is not None:
                 remaining = _breaker.get_breaker().open_remaining_s()
-                if remaining > 0 and job.session.touches_tunnel():
+                if remaining > 0 and job.session.touches_accelerator():
                     if _tele._ENABLED:
                         _tele.inc("serve.jobs.shed")
                     raise LoadShed(job.session.sid, remaining)

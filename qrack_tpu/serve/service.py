@@ -185,15 +185,15 @@ class QrackService:
             # the subsystem costs nothing unless a dir is configured
             from ..checkpoint.store import CheckpointStore
             from ..checkpoint.warmstart import (ProgramManifest,
-                                                enable_warm_start)
+                                                enable_compile_cache)
             from . import batcher as _batcher_mod
 
             if spill_max_mb is None:
                 spill_max_mb = _env_float("QRACK_SERVE_SPILL_MAX_MB", 512.0)
             self.store = CheckpointStore(
                 checkpoint_dir, max_bytes=int(spill_max_mb * 1024 * 1024))
-            enable_warm_start(os.path.join(checkpoint_dir, "xla_cache"))
-            # device-class fingerprint lands next to xla_cache — the
+            enable_compile_cache()
+            # device-class fingerprint lands in the checkpoint dir — the
             # substrate the roofline ledger (and the future autotuner)
             # reads when no live backend is probeable
             from ..telemetry import roofline as _roofline

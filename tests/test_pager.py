@@ -359,9 +359,7 @@ def test_compose_ring_all_starts_and_no_allgather():
     def f(a, b):
         return shb.compose_ring(a, b, 8, L, n1, n1, n2)
 
-    from qrack_tpu.utils.compat import shard_map
-
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(None, "pages"), P()),
         out_specs=P(None, "pages")))
     a = jnp.zeros((2, 1 << n1), dtype=jnp.float32)

@@ -338,7 +338,6 @@ def test_apply_remap_random_oracle():
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from qrack_tpu.ops import sharded as shb
-    from qrack_tpu.parallel.pager import _compat_shard_map
 
     L, g = 4, 3
     n = L + g
@@ -356,7 +355,7 @@ def test_apply_remap_random_oracle():
             j |= ((np.arange(1 << n) >> p) & 1) << src[p]
         want = state[:, j]
         for batched in (True, False):
-            prog = jax.jit(_compat_shard_map(
+            prog = jax.jit(jax.shard_map(
                 lambda local: shb.apply_remap(local, 1 << g, L, swaps,
                                               batched=batched),
                 mesh=mesh, in_specs=P(None, "pages"),

@@ -248,7 +248,7 @@ def test_idle_sessions_evicted():
 # load shedding + failover (the acceptance flow)
 # ---------------------------------------------------------------------------
 
-def test_breaker_open_sheds_tunnel_jobs_and_failover_recovers():
+def test_breaker_open_sheds_accelerator_jobs_and_failover_recovers():
     res.reset_breaker(CircuitBreaker(threshold=2, cooldown_s=60.0))
     with _svc(engine_layers="tpu") as svc:
         hurt = svc.create_session(W, seed=1, rand_global_phase=False)
@@ -262,7 +262,7 @@ def test_breaker_open_sheds_tunnel_jobs_and_failover_recovers():
         stats = {s["sid"]: s for s in svc.sessions.stats()}
         assert stats[hurt]["failovers"] >= 1
         assert stats[hurt]["engine"] == "QEngineCPU"
-        # new tunnel-bound work is refused with the typed error + hint
+        # new accelerator-bound work is refused with the typed error + hint
         with pytest.raises(LoadShed) as exc:
             svc.submit(bystander, qft_qcircuit(W))
         assert exc.value.retry_in_s > 0
