@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(ctx):
+    trace = ctx["trace"]
+    if trace is None:
+        return None
+    return 100.0 * (1.0 - trace.busy_s() / trace.window_s())

@@ -1,0 +1,13 @@
+"""Device time of the window kernel's launches for one application:
+summed durations of its events in the device trace, over the
+applications traced."""
+
+
+def read(ctx):
+    trace = ctx["trace"]
+    if trace is None:
+        return None
+    events = trace.kernel_events("window_kernel")
+    if not events:
+        return None
+    return sum(d for _, _, d in events) / 1e6 / ctx["attempted"]
