@@ -1,0 +1,12 @@
+"""Device time of the in-tile sweeps of one application: the launches
+the program names ``qrack_window_intile`` (``kernels/window_intile.json``)."""
+
+
+def read(ctx):
+    trace = ctx["trace"]
+    if trace is None:
+        return None
+    events = trace.kernel_events("window_intile")
+    if not events:
+        return None
+    return sum(d for _, _, d in events) / 1e6 / ctx["attempted"]

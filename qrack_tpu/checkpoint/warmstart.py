@@ -45,12 +45,24 @@ def enable_compile_cache() -> str:
     many and individually small) for chip_smoke.py, bench.py and
     QrackService alike.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
     already reads it and no directory is set here; otherwise the cache
-    is ``<checkout>/.xla_cache``.  Idempotent; returns the directory."""
+    is ``<checkout>/.xla_cache``.  Idempotent; returns the directory.
+
+    A Mosaic kernel is serialized into its program with each operation's
+    Python traceback as its location, so with the default ten frames the
+    cache's key holds the lines on the way to the first call, and
+    telemetry switched on (another branch of ``_JitProgram.__call__``)
+    is another key (PERF.md, PR 27).  One frame, the operation's own,
+    makes the key depend on the program alone.  Dropping tracebacks
+    altogether (``jax_include_full_tracebacks_in_locations=False``, as
+    the benchmark does) would do that too, but it also drops the name
+    stack from every ``op_name``: no ``jax.named_scope`` and no kernel
+    name would reach a device trace (PERF.md, PR 28)."""
     global _ENABLED_DIR
     if _ENABLED_DIR is not None:
         return _ENABLED_DIR
     import jax
 
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = os.path.join(_CHECKOUT, ".xla_cache")

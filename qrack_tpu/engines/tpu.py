@@ -56,16 +56,17 @@ def _device_get(fn, *args):
     """Host-read boundary (site "tpu.device_get"): the sync that
     proves completion whatever block_until_ready does — and therefore
     the one that hangs when the backend hangs mid-flight."""
-    if _res._ACTIVE:
-        out = _res.call_guarded("tpu.device_get", fn, args)
-        from ..resilience import integrity as _integ
+    with _tele.span("engine.read"):
+        if _res._ACTIVE:
+            out = _res.call_guarded("tpu.device_get", fn, args)
+            from ..resilience import integrity as _integ
 
-        if _integ.enabled():
-            # boundary invariant piggybacked on the value the caller
-            # already forced to host — no extra HBM sweep
-            _integ.check_host("tpu.device_get", out)
-        return out
-    return fn(*args)
+            if _integ.enabled():
+                # boundary invariant piggybacked on the value the caller
+                # already forced to host — no extra HBM sweep
+                _integ.check_host("tpu.device_get", out)
+            return out
+        return fn(*args)
 
 
 def _discover(device_id: int):
@@ -384,49 +385,63 @@ class QEngineTPU(QEngine):
         """Lower the pending window into ONE parametric program dispatch
         (guarded site tpu.fuse.flush).  A window that merged down to a
         single op reuses the shared per-gate program families instead of
-        minting a one-op window program."""
+        minting a one-op window program.  Three host spans split the
+        flush (docs/OBSERVABILITY.md): lower, operands, dispatch."""
         from ..ops import fusion as fu
 
-        ops = fu.lower_gates(gates)
+        n = self.qubit_count
+        plan = None
+        with _tele.span("fuse.lower"):
+            ops = fu.lower_gates(gates)
+            if len(ops) > 1:
+                structure = fu.structure_of(ops)
+                plan, why = fu.kernel_lowering(n, structure)
+                if plan is not None:
+                    prog = fu.kernel_window_program(
+                        n, structure, self.dtype,
+                        interpret=plan["interpret"],
+                        block_pow=plan["block_pow"])
+                else:
+                    fu.record_kernel_fallback(why)
+                    prog = fu.dense_window_program(n, structure, self.dtype)
         if not ops:
             return 0
-        n = self.qubit_count
-        if len(ops) == 1:
-            op = ops[0]
-            m = op.m
-            if op.kind in ("cphase", "diag"):
-                d0, d1 = complex(m[0, 0]), complex(m[1, 1])
-                self._state = _j_apply_diag(
-                    self._owned_state(), d0.real, d0.imag, d1.real, d1.imag,
-                    n, 1 << op.target, op.cmask, op.cval)
-            elif op.kind == "inv":
-                tr, bl = complex(m[0, 1]), complex(m[1, 0])
-                self._state = _j_apply_invert(
-                    self._owned_state(), tr.real, tr.imag, bl.real, bl.imag,
-                    n, op.target, op.cmask, op.cval)
+        with _tele.span("fuse.operands"):
+            if len(ops) > 1:
+                operands = fu.dense_operands(ops, self.dtype)
             else:
-                mp = gk.mtrx_planes(m, self.dtype)
-                self._state = _j_apply_2x2(
-                    self._owned_state(), mp, n, op.target, op.cmask, op.cval)
-            return 1
-        structure = fu.structure_of(ops)
-        operands = fu.dense_operands(ops, self.dtype)
-        plan, why = fu.kernel_lowering(n, structure)
-        if plan is not None:
-            prog = fu.kernel_window_program(
-                n, structure, self.dtype, interpret=plan["interpret"],
-                block_pow=plan["block_pow"])
+                prog, operands = self._one_op_program(ops[0])
+        with _tele.span("fuse.dispatch"):
             self._state = prog(self._owned_state(), *operands)
-            fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
-                                   width=n,
-                                   esize=jnp.dtype(self.dtype).itemsize)
+        if _tele._ENABLED:
+            # a window issues one put per operand and its own program
+            _tele.inc(f"fuse.{self._tele_name}.programs",
+                      len(operands) + 1 if len(ops) > 1 else 1)
+        if len(ops) == 1:
             return 1
-        fu.record_kernel_fallback(why)
-        prog = fu.dense_window_program(n, structure, self.dtype)
-        self._state = prog(self._owned_state(), *operands)
-        fu.record_xla_flush(self._tele_name, len(ops), width=n,
-                            esize=jnp.dtype(self.dtype).itemsize)
+        esize = jnp.dtype(self.dtype).itemsize
+        if plan is not None:
+            fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
+                                   width=n, esize=esize, cross=plan["cross"])
+        else:
+            fu.record_xla_flush(self._tele_name, len(ops), width=n,
+                                esize=esize)
         return 1
+
+    def _one_op_program(self, op):
+        """``(program, arguments after the planes)`` of a window that
+        merged down to one op: the shared per-gate program families."""
+        n, m = self.qubit_count, op.m
+        if op.kind in ("cphase", "diag"):
+            d0, d1 = complex(m[0, 0]), complex(m[1, 1])
+            return _j_apply_diag, (d0.real, d0.imag, d1.real, d1.imag,
+                                   n, 1 << op.target, op.cmask, op.cval)
+        if op.kind == "inv":
+            tr, bl = complex(m[0, 1]), complex(m[1, 0])
+            return _j_apply_invert, (tr.real, tr.imag, bl.real, bl.imag,
+                                     n, op.target, op.cmask, op.cval)
+        return _j_apply_2x2, (gk.mtrx_planes(m, self.dtype),
+                              n, op.target, op.cmask, op.cval)
 
     def _k_apply_2x2(self, m2, target, controls, perm) -> None:
         cmask, cval = self._cmask_cval(controls, perm)
@@ -606,9 +621,11 @@ class QEngineTPU(QEngine):
 
     def SetPermutation(self, perm: int, phase=None) -> None:
         ph = self._rand_phase() if phase is None else complex(phase)
-        st = jnp.zeros((2, 1 << self.qubit_count), dtype=self.dtype)
-        st = st.at[:, perm].set(jnp.asarray([ph.real, ph.imag], dtype=self.dtype))
-        self._state = self._put(st)
+        with _tele.span("engine.set_permutation"):  # fill, scatter, put
+            st = jnp.zeros((2, 1 << self.qubit_count), dtype=self.dtype)
+            st = st.at[:, perm].set(
+                jnp.asarray([ph.real, ph.imag], dtype=self.dtype))
+            self._state = self._put(st)
         self.running_norm = 1.0
 
     def Clone(self) -> "QEngineTPU":

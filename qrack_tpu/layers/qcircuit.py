@@ -377,7 +377,14 @@ class QCircuit:
         import jax
 
         self._check_fused_range(n)
-        return jax.vmap(self.compile_fn(n))
+        body = jax.vmap(self.compile_fn(n))
+
+        def fn(stacked):
+            # the name a device trace knows the served batch's ops by
+            with jax.named_scope("qrack.serve.dispatch"):
+                return body(stacked)
+
+        return fn
 
     def compile_sharded_fn(self, mesh, n: int):
         """One jitted program applying the whole circuit to a ket sharded

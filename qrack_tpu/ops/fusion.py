@@ -155,36 +155,40 @@ def window_fn(n: int, structure: Tuple):
     """Traced body: fn(planes, *operands) applying the window in order.
     Pure and jit-safe; operand layout per module docstring."""
 
-    def fn(planes, *operands):
-        i = 0
-        for kind, target, has_ctrl in structure:
-            p = operands[i]
-            i += 1
-            if has_ctrl:
-                cm = operands[i]
-                cv = operands[i + 1]
-                i += 2
-            else:
-                cm = 0
-                cv = 0
-            if kind == "cphase":
-                comb = ((1 << target) | cm) if has_ctrl else (1 << target)
-                hit = (gk.iota_for(planes) & comb) == comb
-                one = jnp.ones((), planes.dtype)
-                zero = jnp.zeros((), planes.dtype)
-                planes = gk.cmul(jnp.where(hit, p[0], one),
-                                 jnp.where(hit, p[1], zero), planes)
-            elif kind == "diag":
-                planes = gk.apply_diag(planes, p[0, 0], p[0, 1], p[1, 0],
-                                       p[1, 1], n, 1 << target, cm, cv)
-            elif kind == "inv":
-                planes = gk.apply_invert(planes, p[0, 0], p[0, 1], p[1, 0],
-                                         p[1, 1], n, target, cm, cv)
-            else:
-                planes = gk.apply_2x2(planes, p, n, target, cm, cv)
+    # the function's name is the compiled module's (jit_qrack_xla_window):
+    # what a device trace knows this program by when locations carry no
+    # name stack; the scope is what it knows its operations by when they do
+    def qrack_xla_window(planes, *operands):
+        with jax.named_scope("qrack.fuse.xla_window"):
+            i = 0
+            for kind, target, has_ctrl in structure:
+                p = operands[i]
+                i += 1
+                if has_ctrl:
+                    cm = operands[i]
+                    cv = operands[i + 1]
+                    i += 2
+                else:
+                    cm = 0
+                    cv = 0
+                if kind == "cphase":
+                    comb = ((1 << target) | cm) if has_ctrl else (1 << target)
+                    hit = (gk.iota_for(planes) & comb) == comb
+                    one = jnp.ones((), planes.dtype)
+                    zero = jnp.zeros((), planes.dtype)
+                    planes = gk.cmul(jnp.where(hit, p[0], one),
+                                     jnp.where(hit, p[1], zero), planes)
+                elif kind == "diag":
+                    planes = gk.apply_diag(planes, p[0, 0], p[0, 1], p[1, 0],
+                                           p[1, 1], n, 1 << target, cm, cv)
+                elif kind == "inv":
+                    planes = gk.apply_invert(planes, p[0, 0], p[0, 1], p[1, 0],
+                                             p[1, 1], n, target, cm, cv)
+                else:
+                    planes = gk.apply_2x2(planes, p, n, target, cm, cv)
         return planes
 
-    return fn
+    return qrack_xla_window
 
 
 def dense_operands(ops: Sequence[FusedOp], dtype) -> List:
@@ -209,6 +213,16 @@ def dense_operands(ops: Sequence[FusedOp], dtype) -> List:
     return out
 
 
+def timed_build(build):
+    """A window program's builder under the span ``fuse.build``: what a
+    ``ProgramCache`` miss costs inside ``fuse.lower`` (planning and
+    wrapping; tracing and compiling happen at the first call)."""
+    def run():
+        with _tele.span("fuse.build"):
+            return build()
+    return run
+
+
 def dense_window_program(n: int, structure: Tuple, dtype):
     """One guarded jitted program per (width, dtype, structure) — payload
     values ride the operand vector, so every same-structure window is a
@@ -222,7 +236,7 @@ def dense_window_program(n: int, structure: Tuple, dtype):
                 "fuse.window", jax.jit(window_fn(n, structure),
                                        donate_argnums=(0,))))
 
-    return PROGRAMS.get_or_build(key, build)
+    return PROGRAMS.get_or_build(key, timed_build(build))
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +260,8 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
     """Cost model: should this window flush through the Pallas kernel?
 
     Returns ``(plan, fallback_reason)`` — exactly one is non-None.
-    ``plan`` is ``{"interpret": bool, "block_pow": int, "sweeps": int}``.
+    ``plan`` is ``{"interpret": bool, "block_pow": int, "sweeps": int,
+    "cross": int}`` (``cross``: the cross-tile segments among the sweeps).
 
     The decision inputs are the window length, op mix (how many planned
     segments the cross-tile non-diagonals force), width and block_pow:
@@ -272,9 +287,9 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
     if backend is None:
         backend = jax.default_backend()
     bp = min(pk.DEFAULT_BLOCK_POW, n)
-    sweeps = pk.plan_sweeps(structure, bp)
+    sweeps, cross = pk.plan_counts(structure, bp)
     plan = {"interpret": backend != "tpu",
-            "block_pow": bp, "sweeps": sweeps}
+            "block_pow": bp, "sweeps": sweeps, "cross": cross}
     if mode == "on":
         return plan, None
     if backend != "tpu":
@@ -307,19 +322,21 @@ def kernel_window_program(n: int, structure: Tuple, dtype,
             _tele.instrument_jit("fuse.window", jax.jit(fn,
                                                         donate_argnums=(0,))))
 
-    return PROGRAMS.get_or_build(key, build)
+    return PROGRAMS.get_or_build(key, timed_build(build))
 
 
 def record_kernel_flush(name: str, nops: int, sweeps: int,
-                        width=None, esize: int = 4) -> None:
-    """A window flushed through the Pallas kernel: count it and the HBM
-    sweeps it actually paid (telemetry_report derives sweeps/window).
+                        width=None, esize: int = 4, cross: int = 0) -> None:
+    """A window flushed through the Pallas kernel: count it, the HBM
+    sweeps it actually paid (telemetry_report derives sweeps/window)
+    and how many of them were cross-tile pair segments.
     Callers that supply the plane width also feed the sweep's planned
     bytes into the roofline ledger (`roofline.tpu.fuse.flush.*`)."""
     if _tele._ENABLED:
         _tele.inc("fuse.kernel.windows")
         _tele.inc("fuse.kernel.ops", nops)
         _tele.inc("fuse.kernel.sweeps", sweeps)
+        _tele.inc("fuse.kernel.sweeps.cross", cross)
         if width is not None:
             _roofline.note_bytes(
                 "tpu.fuse.flush",
@@ -567,7 +584,7 @@ def sharded_window_body(L: int, npg: int, structure: Tuple, remap=(),
 
     lbits = (1 << L) - 1
 
-    def fn(local, *operands):
+    def qrack_sharded_xla_window(local, *operands):  # the module's name
         if remap:
             local = shb.apply_remap(local, npg, L, remap, batched=batched)
         i = 0
@@ -606,7 +623,7 @@ def sharded_window_body(L: int, npg: int, structure: Tuple, remap=(),
                                              lm, lv, gm, gv)
         return local
 
-    return fn
+    return qrack_sharded_xla_window
 
 
 def sharded_operands(ops: Sequence[FusedOp], L: int, dtype) -> List:
@@ -731,29 +748,43 @@ def _sharded_run_operands(run, L: int, operands, offs, pid, dtype):
     return out
 
 
+def _sharded_nargs(kind: str, has_ctrl: bool) -> int:
+    """Operands one op takes in the sharded layout (sharded_operands)."""
+    return 1 + ((2 if kind == "cphase" else 4) if has_ctrl else 0)
+
+
+def sharded_operand_count(structure: Tuple) -> int:
+    return sum(_sharded_nargs(kind, has_ctrl)
+               for kind, _, has_ctrl in structure)
+
+
 def _sharded_offs(structure: Tuple) -> List[int]:
     offs: List[int] = []
     o = 0
     for kind, target, has_ctrl in structure:
         offs.append(o)
-        o += 1 + ((2 if kind == "cphase" else 4) if has_ctrl else 0)
+        o += _sharded_nargs(kind, has_ctrl)
     return offs
 
 
-def sharded_kernel_sweeps(structure: Tuple, L: int,
-                          block_pow: int = None) -> int:
-    """HBM sweeps the per-page kernel lowering pays: one per planned
-    kernel segment inside each local run, one per ppermute exchange."""
+def sharded_kernel_counts(structure: Tuple, L: int,
+                          block_pow: int) -> Tuple[int, int]:
+    """``(sweeps, cross)`` of the per-page kernel lowering: one sweep
+    per planned kernel segment inside each local run and one per
+    ppermute exchange; ``cross`` counts the runs' cross-tile pair
+    segments (an exchange is no kernel launch)."""
     from . import pallas_kernels as pk
 
-    bp = min(pk.DEFAULT_BLOCK_POW, L) if block_pow is None else block_pow
-    total = 0
+    total = cross = 0
     for seg in _sharded_segments(structure, L):
         if seg[0] == "global":
             total += 1
         else:
-            total += pk.plan_sweeps(_sharded_run_structure(seg[1], L), bp)
-    return total
+            s, x = pk.plan_counts(_sharded_run_structure(seg[1], L),
+                                  block_pow)
+            total += s
+            cross += x
+    return total, cross
 
 
 def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):
@@ -767,9 +798,9 @@ def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):
     if backend is None:
         backend = jax.default_backend()
     bp = min(pk.DEFAULT_BLOCK_POW, L)
-    sweeps = sharded_kernel_sweeps(structure, L, bp)
+    sweeps, cross = sharded_kernel_counts(structure, L, bp)
     plan = {"interpret": backend != "tpu",
-            "block_pow": bp, "sweeps": sweeps}
+            "block_pow": bp, "sweeps": sweeps, "cross": cross}
     if mode == "on":
         return plan, None
     if backend != "tpu":
@@ -798,7 +829,7 @@ def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
                                        block_pow=bp, interpret=interpret)
             for seg in segments if seg[0] == "run"}
 
-    def fn(local, *operands):
+    def qrack_sharded_kernel_window(local, *operands):  # the module's name
         if remap:
             local = shb.apply_remap(local, npg, L, remap, batched=batched)
         pid = shb.page_id()
@@ -818,7 +849,7 @@ def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
                 local = runs[id(seg)](local, *dops)
         return local
 
-    return fn
+    return qrack_sharded_kernel_window
 
 
 # ---------------------------------------------------------------------------
@@ -956,29 +987,33 @@ class GateStreamFuser:
                 guard = _integ
         self._flushing = True
         try:
-            while True:
-                try:
-                    if guard is not None:
-                        # snapshot → dispatch → verify → replay: silent
-                        # corruption inside the window restores the
-                        # pre-flush planes and re-dispatches the SAME
-                        # kept gates; repeated corruption escalates as
-                        # DispatchGiveUp into the shrink path below with
-                        # good planes already restored (integrity.py)
-                        dispatched = guard.guarded_flush(
-                            eng, lambda: eng._fuse_flush(self.gates))
-                    else:
-                        dispatched = eng._fuse_flush(self.gates)
-                    break
-                except Exception as e:  # noqa: BLE001 — filtered below
-                    from ..resilience.errors import FAILOVER_ERRORS
+            # one window, whatever the engine: parent of the engine's
+            # fuse.lower / fuse.operands / fuse.dispatch
+            with _tele.span("fuse.flush"):
+                while True:
+                    try:
+                        if guard is not None:
+                            # snapshot → dispatch → verify → replay:
+                            # silent corruption inside the window
+                            # restores the pre-flush planes and
+                            # re-dispatches the SAME kept gates; repeated
+                            # corruption escalates as DispatchGiveUp into
+                            # the shrink path below with good planes
+                            # already restored (integrity.py)
+                            dispatched = guard.guarded_flush(
+                                eng, lambda: eng._fuse_flush(self.gates))
+                        else:
+                            dispatched = eng._fuse_flush(self.gates)
+                        break
+                    except Exception as e:  # noqa: BLE001 — filtered below
+                        from ..resilience.errors import FAILOVER_ERRORS
 
-                    if not isinstance(e, FAILOVER_ERRORS):
-                        raise
-                    can_shrink = getattr(eng, "can_shrink", None)
-                    if can_shrink is None or not can_shrink():
-                        raise  # wrapper-level failover takes over
-                    eng.shrink_pages()
+                        if not isinstance(e, FAILOVER_ERRORS):
+                            raise
+                        can_shrink = getattr(eng, "can_shrink", None)
+                        if can_shrink is None or not can_shrink():
+                            raise  # wrapper-level failover takes over
+                        eng.shrink_pages()
         finally:
             self._flushing = False
         raw = self._raw

@@ -98,12 +98,12 @@ def plan_window(structure: Tuple, block_pow: int) -> List[dict]:
     return segs
 
 
-def plan_sweeps(structure: Tuple, block_pow: int = DEFAULT_BLOCK_POW,
-                n: Optional[int] = None) -> int:
-    """HBM sweeps the kernel lowering pays for this window (the XLA
-    window chain pays ~len(structure))."""
-    bp = min(block_pow, n) if n is not None else block_pow
-    return len(plan_window(structure, bp))
+def plan_counts(structure: Tuple, block_pow: int) -> Tuple[int, int]:
+    """``(sweeps, cross)``: the HBM sweeps the kernel lowering pays for
+    this window (the XLA window chain pays ~len(structure)), and how
+    many of them are cross-tile pair segments."""
+    segs = plan_window(structure, block_pow)
+    return len(segs), sum(seg["xgen"] is not None for seg in segs)
 
 
 def _operand_slots(structure: Tuple):
@@ -235,6 +235,12 @@ def tile_local_invert(v, lidx, hi_id, target,
 # the Pallas window program (dense single-shard layout)
 # ---------------------------------------------------------------------------
 
+# the names a device trace knows the two launches by (PERF.md section 3):
+# name= is the HLO instruction's name where locations carry the name
+# stack, metadata= rides the custom call's frontend attributes always
+INTILE_KERNEL_NAME = "qrack_window_intile"
+CROSS_KERNEL_NAME = "qrack_window_cross"
+
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 # packed scalar operands: SMEM on the TPU, honoured by the interpreter
 _SCALAR_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -308,6 +314,8 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
                 out_specs=tile_spec,
                 compiler_params=_COMPILER_PARAMS,
                 interpret=interpret,
+                name=INTILE_KERNEL_NAME,
+                metadata={"qrack_kernel": INTILE_KERNEL_NAME},
             )(iv, fv, planes)
 
         return run
@@ -366,6 +374,8 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
             out_specs=tile_spec,
             compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
+            name=CROSS_KERNEL_NAME,
+            metadata={"qrack_kernel": CROSS_KERNEL_NAME},
         )(iv, fv, planes, planes)
 
     return run
@@ -385,15 +395,19 @@ def make_window_fn(n: int, structure: Tuple,
     programs = [_segment_program(n, bp, seg, slots, interpret)
                 for seg in segments]
 
-    def fn(planes, *operands):
-        fv, iv = pack_operands(structure, operands, planes.dtype)
-        for run in programs:
-            planes = run(planes, iv, fv)
+    # named for the compiled module (jit_qrack_kernel_window), as
+    # fusion.window_fn's is; the scope covers pack_operands' stack and
+    # reshape too
+    def qrack_kernel_window(planes, *operands):
+        with jax.named_scope("qrack.fuse.kernel_window"):
+            fv, iv = pack_operands(structure, operands, planes.dtype)
+            for run in programs:
+                planes = run(planes, iv, fv)
         return planes
 
-    fn.sweeps = len(segments)
-    fn.block_pow = bp
-    return fn
+    qrack_kernel_window.sweeps = len(segments)
+    qrack_kernel_window.block_pow = bp
+    return qrack_kernel_window
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,13 @@ def page_id():
     return jax.lax.axis_index("pages")
 
 
+def exchange(x, perm):
+    """Every page-to-page transfer of this module, under the one scope
+    a device trace knows the pager's collectives by."""
+    with jax.named_scope("qrack.pager.exchange"):
+        return jax.lax.ppermute(x, "pages", perm)
+
+
 def apply_local_2x2(local, mp, L: int, target: int, lmask, lval, gmask, gval):
     """Non-diagonal gate on an in-page target, optionally page-selected."""
     out = gk.apply_2x2(local, mp, L, target, lmask, lval)
@@ -49,7 +56,7 @@ def apply_global_2x2(local, mp, npg: int, gpos: int, lmask, lval, gmask, gval):
         perm = [(j, j ^ (1 << gpos)) for j in range(npg)]
         pid = page_id()
         b = (pid >> gpos) & 1
-        other = jax.lax.ppermute(local, "pages", perm)
+        other = exchange(local, perm)
         re, im = mp[0], mp[1]
         dd_re = jnp.where(b == 0, re[0, 0], re[1, 1])
         dd_im = jnp.where(b == 0, im[0, 0], im[1, 1])
@@ -65,7 +72,7 @@ def apply_global_2x2(local, mp, npg: int, gpos: int, lmask, lval, gmask, gval):
     halves = local.reshape(local.shape[0], 2, half_n)  # [planes, top bit, rest]
     keep = jnp.where(b == 0, halves[:, 0], halves[:, 1])
     away = jnp.where(b == 0, halves[:, 1], halves[:, 0])
-    got = jax.lax.ppermute(away, "pages", perm)       # half-page payload
+    got = exchange(away, perm)       # half-page payload
     # this page now holds complete (a, b) pairs for local indices with
     # top bit == b: a = partner-0 amplitude, b = partner-1 amplitude
     a_amp = jnp.where(b == 0, keep, got)
@@ -82,7 +89,7 @@ def apply_global_2x2(local, mp, npg: int, gpos: int, lmask, lval, gmask, gval):
     b_out = jnp.where(lok & ((p1 & gmask) == gval), b_out, b_amp)
     mine = jnp.where(b == 0, a_out, b_out)
     theirs = jnp.where(b == 0, b_out, a_out)
-    back = jax.lax.ppermute(theirs, "pages", perm)    # half-page payload
+    back = exchange(theirs, perm)    # half-page payload
     lo = jnp.where(b == 0, mine, back)
     hi = jnp.where(b == 0, back, mine)
     return jnp.stack([lo, hi], axis=1).reshape(local.shape)
@@ -124,7 +131,7 @@ def gather_ring(local, npg: int, L: int, split_body, targs, keep_default=None):
             take = take & keep
         out = jnp.where(take, buf[:, sl], out)
         if k + 1 < npg:
-            buf = jax.lax.ppermute(buf, "pages", perm)
+            buf = exchange(buf, perm)
     return out
 
 
@@ -187,7 +194,7 @@ def compose_ring(a_local, b, npg: int, L_in: int, start: int, n1: int, n2: int):
         vals = jnp.stack([vr, vi])
         out = vals if take is None else jnp.where(take, vals, out)
         if k + 1 < npg and not aligned:
-            buf = jax.lax.ppermute(buf, "pages", perm)
+            buf = exchange(buf, perm)
     return out
 
 
@@ -201,7 +208,7 @@ def page_swap(local, npg: int, g1: int, g2: int):
         return j if b1 == b2 else j ^ ((1 << g1) | (1 << g2))
 
     perm = [(j, permute(j)) for j in range(npg)]
-    return jax.lax.ppermute(local, "pages", perm)
+    return exchange(local, perm)
 
 
 def mixed_swap(local, npg: int, L: int, lpos: int, gpos: int):
@@ -222,7 +229,7 @@ def mixed_swap(local, npg: int, L: int, lpos: int, gpos: int):
     keep = jnp.where(b == 0, a0, a1)   # l-bit == own g-bit: stays
     away = jnp.where(b == 0, a1, a0)   # l-bit != g-bit: belongs to partner
     perm = [(j, j ^ (1 << gpos)) for j in range(npg)]
-    got = jax.lax.ppermute(away, "pages", perm)
+    got = exchange(away, perm)
     s0 = jnp.where(b == 0, keep, got)
     s1 = jnp.where(b == 0, got, keep)
     return jnp.stack([s0, s1], axis=2).reshape(local.shape)
@@ -372,7 +379,7 @@ def batched_mixed_swap(local, npg: int, k: int, gpos):
         perm = [(j2, j2 ^ pd) for j2 in range(npg)]
         payload = jax.lax.dynamic_index_in_dim(sub, b ^ d, axis=1,
                                                keepdims=True)
-        got = jax.lax.ppermute(payload, "pages", perm)
+        got = exchange(payload, perm)
         out = jax.lax.dynamic_update_slice_in_dim(out, got, b ^ d, axis=1)
     return out.reshape(local.shape)
 
@@ -409,8 +416,7 @@ def apply_remap(local, npg: int, L: int, swaps, batched: bool = True):
     if plan.k:
         local = batched_mixed_swap(local, npg, plan.k, plan.gpos)
     if plan.page_dest is not None:
-        local = jax.lax.ppermute(local, "pages",
-                                 page_perm_of(plan.page_dest, g))
+        local = exchange(local, page_perm_of(plan.page_dest, g))
     for p1, p2 in plan.post:
         local = gk.swap_bits(local, L, p1, p2)
     return local

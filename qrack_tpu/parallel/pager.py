@@ -100,6 +100,7 @@ def _state_specs(n_scalars: int):
     return (P(None, "pages"),) + (P(),) * n_scalars
 
 
+from ..ops.sharded import exchange as _exchange
 from ..ops.sharded import split_masks as _split_masks  # single source of truth
 
 
@@ -552,7 +553,7 @@ class QPager(QEngine):
             perm = [(j, permute(j)) for j in range(npg)]
 
             def f(local):
-                return jax.lax.ppermute(local, "pages", perm)
+                return _exchange(local, perm)
 
             return jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P(None, "pages"), out_specs=P(None, "pages")
@@ -657,7 +658,7 @@ class QPager(QEngine):
 
             return _program(self._key("fusewin", str(self.dtype), structure,
                                       remap, batched),
-                            build, site="tpu.fuse.flush")
+                            fu.timed_build(build), site="tpu.fuse.flush")
 
         interpret = kernel_plan["interpret"]
         bp = kernel_plan["block_pow"]
@@ -682,7 +683,7 @@ class QPager(QEngine):
                                   "interp" if interpret else "mosaic", bp,
                                   str(self.dtype), structure, remap,
                                   batched),
-                        build, site="tpu.fuse.flush")
+                        fu.timed_build(build), site="tpu.fuse.flush")
 
     def _fuse_flush(self, gates) -> int:
         from ..ops import fusion as fu
@@ -706,68 +707,81 @@ class QPager(QEngine):
         post-remap table, run remap prologue + window as ONE shard_map
         program, and commit the table only after the dispatch returns —
         shrink-retry and exception paths replan from the unchanged
-        table (the kept window stays logical)."""
+        table (the kept window stays logical).  The dense engine's three
+        host spans split it (lower, operands, dispatch)."""
         from ..ops import fusion as fu
 
         L = self.local_bits
-        swaps = ()
-        new_qmap = self._qmap
-        batched = self._collective_batched()
-        if self._remap_active():
-            swaps, new_qmap = fu.plan_remaps(
-                ops, L, self._qmap, lookahead,
-                weights=self._exchange_weights, batched=batched)
-        tops = (fu.translate_ops(ops, new_qmap)
-                if (swaps or self._map_nonid()) else ops)
-        if len(tops) == 1 and not swaps:
+        with _tele.span("fuse.lower"):
+            swaps = ()
+            new_qmap = self._qmap
+            batched = self._collective_batched()
+            if self._remap_active():
+                swaps, new_qmap = fu.plan_remaps(
+                    ops, L, self._qmap, lookahead,
+                    weights=self._exchange_weights, batched=batched)
+            tops = (fu.translate_ops(ops, new_qmap)
+                    if (swaps or self._map_nonid()) else ops)
             # merged down to one op on the current placement: the shared
             # eager programs already exist and are cheaper than a fresh
             # one-op window structure
-            op = tops[0]
-            m = np.asarray(op.m)
-            lmask, lval, gmask, gval = _split_masks(op.cmask, op.cval, L)
-            if op.kind in ("cphase", "diag"):
-                tmask = 1 << op.target
-                d0, d1 = complex(m[0, 0]), complex(m[1, 1])
-                self._state = self._p_diag()(
-                    self._state, d0.real, d0.imag, d1.real, d1.imag,
-                    tmask & ((1 << L) - 1), tmask >> L,
-                    lmask, lval, gmask, gval)
+            one_op = len(tops) == 1 and not swaps
+            if not one_op:
+                structure = fu.sharded_structure_of(tops)
+                plan, why = fu.sharded_kernel_lowering(L, structure)
+                prog = self._p_fuse_window(
+                    structure, fu.sharded_operand_count(structure),
+                    kernel_plan=plan, remap=swaps, batched=batched)
+        with _tele.span("fuse.operands"):
+            if one_op:
+                prog, operands = self._one_op_program(tops[0])
             else:
-                mp = gk.mtrx_planes(m, self.dtype)
-                if op.target < L:
-                    self._state = self._p_local_2x2(op.target)(
-                        self._state, mp, lmask, lval, gmask, gval)
-                else:
-                    if _tele._ENABLED:
-                        self._tele_exchange("global_2x2", self._state.nbytes)
-                    self._state = self._p_global_2x2(op.target - L)(
-                        self._state, mp, lmask, lval, gmask, gval)
-            return 1
-        structure = fu.sharded_structure_of(tops)
-        operands = fu.sharded_operands(tops, L, self.dtype)
+                operands = fu.sharded_operands(tops, L, self.dtype)
         if _tele._ENABLED:
-            nb = self._state.nbytes
-            for kind, target, _ in structure:
-                if kind == "gen" and target >= L:
-                    self._tele_exchange("global_2x2", nb)
-            if swaps:
-                _tele.inc("remap.pager.windows")
-            self._tele_remap(swaps, batched=batched)
-        plan, why = fu.sharded_kernel_lowering(L, structure)
-        prog = self._p_fuse_window(structure, len(operands),
-                                   kernel_plan=plan, remap=swaps,
-                                   batched=batched)
-        self._state = prog(self._state, *operands)
+            # a window issues one put per operand and its own program
+            _tele.inc(f"fuse.{self._tele_name}.programs",
+                      1 if one_op else len(operands) + 1)
+            if not one_op:
+                nb = self._state.nbytes
+                for kind, target, _ in structure:
+                    if kind == "gen" and target >= L:
+                        self._tele_exchange("global_2x2", nb)
+                if swaps:
+                    _tele.inc("remap.pager.windows")
+                self._tele_remap(swaps, batched=batched)
+        with _tele.span("fuse.dispatch"):
+            self._state = prog(self._state, *operands)
+        if one_op:
+            return 1
         self._map_assign(new_qmap)
         if plan is not None:
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
-                                   width=self.qubit_count)
+                                   width=self.qubit_count,
+                                   cross=plan["cross"])
         else:
             fu.record_kernel_fallback(why)
             fu.record_xla_flush(self._tele_name, len(ops),
                                 width=self.qubit_count)
         return 1
+
+    def _one_op_program(self, op):
+        """``(program, arguments after the state)`` of a window that
+        merged down to one op: the shared eager programs."""
+        L = self.local_bits
+        m = np.asarray(op.m)
+        masks = _split_masks(op.cmask, op.cval, L)
+        if op.kind in ("cphase", "diag"):
+            tmask = 1 << op.target
+            d0, d1 = complex(m[0, 0]), complex(m[1, 1])
+            return self._p_diag(), (d0.real, d0.imag, d1.real, d1.imag,
+                                    tmask & ((1 << L) - 1), tmask >> L,
+                                    *masks)
+        mp = gk.mtrx_planes(m, self.dtype)
+        if op.target < L:
+            return self._p_local_2x2(op.target), (mp, *masks)
+        if _tele._ENABLED:
+            self._tele_exchange("global_2x2", self._state.nbytes)
+        return self._p_global_2x2(op.target - L), (mp, *masks)
 
     def _k_apply_4x4(self, m4, q1, q2) -> None:
         # decompose into primitive ops through the pager paths

@@ -72,11 +72,13 @@ def batch_program(circuit, n: int, batch: int):
 
         body = circuit.compile_batched_fn(n)
 
-        def run(planes):
+        # named for the compiled module (jit_qrack_serve_dispatch): what
+        # a device trace knows a served batch's program by
+        def qrack_serve_dispatch(planes):
             out = body(jnp.stack(planes))
             return tuple(out[i] for i in range(batch))
 
-        return jax.jit(run)
+        return jax.jit(qrack_serve_dispatch)
 
     fn = _PROGRAMS.get_or_build(key, build)
     if _MANIFEST is not None:

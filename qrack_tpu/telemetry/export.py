@@ -1,5 +1,4 @@
-"""Telemetry exporters: JSONL snapshots, Chrome trace-event JSON, and
-the jax.profiler xplane bracket.
+"""Telemetry exporters: JSONL snapshots and Chrome trace-event JSON.
 
 Formats:
 
@@ -20,14 +19,10 @@ Formats:
   can be followed from the front door's ``frontdoor.apply`` through the
   worker's ``worker.submit.journal`` to the executor's
   ``serve.execute`` devget on one screen.
-* **xplane** — :func:`xplane_bracket` wraps ``jax.profiler``
-  start/stop_trace; the resulting ``*.xplane.pb`` dumps are what
-  ``scripts/analyze_xplane.py`` parses for on-device op walls.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 from typing import Optional
@@ -95,7 +90,8 @@ def chrome_trace() -> dict:
         evs.append({
             "name": t["name"], "ph": "X", "cat": "span",
             "ts": ts, "dur": dur, "pid": pid, "tid": t["tid"],
-            "args": {"depth": t["depth"], "synced": t["synced"]},
+            "args": {"depth": t["depth"], "id": t["id"],
+                     "parent": t["parent"]},
         })
     for e in events:
         ts = e["t_s"] * _US
@@ -175,7 +171,8 @@ def merged_chrome_trace(sources) -> dict:
                     "tid": 0,
                     "args": {"name": f"{label} (pid {src.get('pid')})"}})
         for t in src.get("spans") or []:
-            args = {"depth": t.get("depth"), "synced": t.get("synced")}
+            args = {"depth": t.get("depth"), "id": t.get("id"),
+                    "parent": t.get("parent")}
             if t.get("trace") is not None:
                 args["trace"] = t["trace"]
             evs.append({
@@ -212,26 +209,3 @@ def write_merged_chrome_trace(path: str, sources) -> str:
     with open(path, "w") as f:
         json.dump(merged_chrome_trace(sources), f)
     return path
-
-
-@contextlib.contextmanager
-def xplane_bracket(logdir: Optional[str] = None, name: str = "telemetry"):
-    """Bracket a region with a jax.profiler trace when telemetry is on
-    and a log dir is configured (arg or QRACK_TPU_TELEMETRY_XPLANE);
-    otherwise a pass-through.  The dump under `logdir` is the input to
-    scripts/analyze_xplane.py."""
-    from . import _ENABLED, event
-
-    if logdir is None:
-        logdir = os.environ.get("QRACK_TPU_TELEMETRY_XPLANE", "")
-    if not (_ENABLED and logdir):
-        yield None
-        return
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield logdir
-    finally:
-        jax.profiler.stop_trace()
-        event("telemetry.xplane.dump", logdir=logdir, region=name)

@@ -13,6 +13,10 @@ Two directions, both enforced as a tier-1 test
   ``bench.py`` — bench-only names keep their doc rows alive but are
   not themselves required to be documented).
 
+``jax.named_scope("qrack....")`` literals and the ``*_KERNEL_NAME``
+constants of ``ops/pallas_kernels.py`` are names of the same document
+(what a device trace finds a program by) and are held the same way.
+
 Name extraction is AST-based, no imports of the package (so the lint
 is jax-free and runs in milliseconds).  f-string names contribute
 their literal *prefix* up to the first interpolation
@@ -70,6 +74,8 @@ def _first_arg_name(call: ast.Call):
 
 
 def _is_tele_call(func) -> bool:
+    if isinstance(func, ast.Attribute) and func.attr == "named_scope":
+        return True  # jax.named_scope: a name a device trace reads
     if isinstance(func, ast.Attribute) and func.attr in TELE_FUNCS:
         v = func.value
         if isinstance(v, ast.Name):
@@ -94,6 +100,11 @@ def extract_names(path: str, in_telemetry_pkg: bool):
                 got = _first_arg_name(node)
                 if got is not None:
                     yield got[0], got[1], node.lineno
+        elif (isinstance(node, ast.Assign) and len(node.targets) == 1
+              and isinstance(node.targets[0], ast.Name)
+              and node.targets[0].id.endswith("_KERNEL_NAME")
+              and isinstance(node.value, ast.Constant)):
+            yield node.value.value, False, node.lineno
         elif isinstance(node, ast.Subscript) and in_telemetry_pkg:
             v, s = node.value, node.slice
             if (isinstance(v, ast.Name) and v.id == "_COUNTERS"
@@ -182,7 +193,8 @@ def doc_patterns(doc_path: str):
                 continue
             first = cells[1]
             for tok in re.findall(r"`([^`]+)`", first):
-                if "." not in tok and "*" not in tok:
+                if ("." not in tok and "*" not in tok
+                        and not tok.startswith("qrack_")):
                     continue  # env var / prose, not a telemetry name
                 if not re.fullmatch(r"[A-Za-z0-9_.<>{}*,/-]+", tok):
                     continue

@@ -11,6 +11,7 @@ import: only the worker that is handed this file loads the TPU library.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -134,3 +135,85 @@ def test_sharded_kernel_window_four_pages(topo):
     text = _compile(fn, args).as_text()
     assert "collective-permute" in text
     assert "tpu_custom_call" in text
+
+
+def test_kernel_launches_carry_their_names(one_chip):
+    """What a device trace finds the two launches by.  The kernel's
+    ``metadata=`` rides the custom call's frontend attributes whatever
+    the locations carry; its ``name=`` is the name of the HLO
+    instruction, and the scope part of its ``op_name``, only while
+    locations keep the name stack (not with
+    ``jax_include_full_tracebacks_in_locations=False``).  The target
+    stays ``tpu_custom_call`` (benchmarks/kernels/*.json match both)."""
+    structure = (("gen", 3, False), ("gen", 20, True), ("cphase", 3, True))
+    assert [seg["xgen"] is not None
+            for seg in pk.plan_window(structure, pk.DEFAULT_BLOCK_POW)] \
+        == [False, True]
+    fn = pk.make_window_fn(W, structure)
+    args = _dense_args(structure, one_chip)
+    text = _compile(fn, args).as_text()
+    assert text.startswith("HloModule jit_qrack_kernel_window")
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        bare = _compile(pk.make_window_fn(W, structure), args).as_text()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+    assert bare.startswith("HloModule jit_qrack_kernel_window")
+    for name in (pk.INTILE_KERNEL_NAME, pk.CROSS_KERNEL_NAME):
+        # the metadata's JSON breaks the instruction over several lines
+        launch = re.findall(
+            r'%%%s[.\d]* = \S+ custom-call\([^)]*\), '
+            r'custom_call_target="tpu_custom_call"' % name, text)
+        assert len(launch) == 1, name
+        assert f"qrack.fuse.kernel_window/{name}/pallas_call" in text
+        for compiled in (text, bare):
+            assert len(re.findall(
+                r'kernel_metadata=\{\s*"qrack_kernel":"%s"' % name,
+                compiled)) == 1
+    assert 'op_name="pallas_call"' in bare  # no name stack, so no scope
+    assert not re.search(r'op_name="[^"]*qrack\.fuse\.kernel_window', bare)
+    assert "%" + pk.CROSS_KERNEL_NAME not in bare
+
+
+def _lowered_at_one_site(args, structure):
+    return jax.jit(pk.make_window_fn(20, structure)).lower(*args).as_text()
+
+
+def _lowered_at_another_site(args, structure):
+    # other lines, and one more frame, on the way to the same kernel
+    return (lambda: jax.jit(pk.make_window_fn(20, structure))
+            .lower(*args).as_text())()
+
+
+def test_compile_cache_key_does_not_hold_the_call_stack(
+        one_chip, monkeypatch, tmp_path):
+    """A Mosaic kernel is serialized with its operations' locations.
+    With the default ten frames the lowered text, and so the persistent
+    cache's key, changes with the call site (telemetry on takes another
+    branch of ``_JitProgram.__call__``: PERF.md, PR 27);
+    ``enable_compile_cache()`` keeps one frame, the operation's own, and
+    the names stay."""
+    from qrack_tpu.checkpoint import warmstart
+
+    structure = (("gen", 19, False), ("cphase", 3, True))
+    args = _args(fu.dense_operands(_ops(structure), jnp.float32),
+                 one_chip, one_chip, n=20)
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_traceback_in_locations_limit",
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_persistent_cache_min_compile_time_secs")}
+    try:
+        assert _lowered_at_one_site(args, structure) \
+            != _lowered_at_another_site(args, structure)
+        monkeypatch.setattr(warmstart, "_ENABLED_DIR", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        warmstart.enable_compile_cache()
+        assert jax.config.jax_traceback_in_locations_limit == 1
+        one = _lowered_at_one_site(args, structure)
+        assert one == _lowered_at_another_site(args, structure)
+        assert pk.CROSS_KERNEL_NAME in one
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

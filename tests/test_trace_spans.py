@@ -1,0 +1,363 @@
+"""Spans at the layer boundaries of the dense path (docs/OBSERVABILITY.md
+"Spans inside the fuser and the engine"): ids and parents, the three
+spans beneath every ``fuse.flush``, the counters that ride with them,
+the scopes a device trace finds the programs by, and the disabled path.
+
+The names a compiled kernel carries are held where the chip's compiler
+is (tests/test_chip_compile.py)."""
+
+import ast
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from qrack_tpu import telemetry as tele
+from qrack_tpu.engines.tpu import QEngineTPU
+from qrack_tpu.models.algorithms import trotter_qcircuit
+from qrack_tpu.ops import fusion as fu
+from qrack_tpu.ops import pallas_kernels as pk
+from qrack_tpu.parallel.pager import QPager
+from qrack_tpu.utils.rng import QrackRandom
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+W = 12
+PARTS = ("fuse.lower", "fuse.operands", "fuse.dispatch")
+
+
+@pytest.fixture(autouse=True)
+def _clean_telemetry():
+    tele.disable()
+    tele.reset()
+    yield
+    tele.disable()
+    tele.reset()
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """The kernel lowering under the interpreter, with tiles of 2^6 so
+    that a w12 ket has cross-tile targets as a w28 ket has."""
+    monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
+    monkeypatch.setattr(pk, "DEFAULT_BLOCK_POW", 6)
+    fu.PROGRAMS.clear()
+    yield
+    fu.PROGRAMS.clear()
+
+
+def _recorded():
+    return tele.local_trace_source()["spans"]
+
+
+def _qft(q):
+    q.SetPermutation(0b101101110011 & ((1 << q.qubit_count) - 1))
+    q.QFT(0, q.qubit_count)
+    return q.GetAmplitude(5)
+
+
+def _trotter(q):
+    trotter_qcircuit(q.qubit_count, steps=1).Run(q)
+    return q.GetAmplitude(3)
+
+
+def _dense():
+    return QEngineTPU(W, rng=QrackRandom(7), rand_global_phase=False)
+
+
+def _pager():
+    return QPager(W, rng=QrackRandom(7), rand_global_phase=False, n_pages=4)
+
+
+# -- the mechanism -------------------------------------------------------------
+
+def test_spans_record_id_and_parent():
+    tele.enable()
+    with tele.span("outer"):
+        with tele.span("first"):
+            pass
+        with tele.span("second"):
+            with tele.span("leaf"):
+                pass
+    by_name = {e["name"]: e for e in _recorded()}
+    ids = [e["id"] for e in _recorded()]
+    assert len(set(ids)) == 4 and all(isinstance(i, int) for i in ids)
+    assert by_name["outer"]["parent"] is None
+    assert by_name["first"]["parent"] == by_name["outer"]["id"]
+    assert by_name["second"]["parent"] == by_name["outer"]["id"]
+    assert by_name["leaf"]["parent"] == by_name["second"]["id"]
+    args = {e["name"]: e["args"] for e in tele.chrome_trace()["traceEvents"]
+            if e["ph"] == "X"}
+    assert args["leaf"]["parent"] == args["second"]["id"]
+    assert "synced" not in args["leaf"]
+
+
+def test_self_seconds_is_duration_minus_children():
+    entries = [
+        {"id": 1, "parent": None, "dur_s": 1.0},
+        {"id": 2, "parent": 1, "dur_s": 0.25},
+        {"id": 3, "parent": 1, "dur_s": 0.5},
+        {"id": 4, "parent": 3, "dur_s": 0.125},
+        {"id": 5, "parent": 99, "dur_s": 2.0},  # its parent fell off the ring
+    ]
+    own = tele.self_seconds(entries)
+    assert own == {1: 0.25, 2: 0.25, 3: 0.375, 4: 0.125, 5: 2.0}
+    tele.enable()
+    with tele.span("outer"):
+        with tele.span("inner"):
+            pass
+    rec = {e["name"]: e for e in _recorded()}
+    own = tele.self_seconds(_recorded())
+    assert own[rec["outer"]["id"]] == pytest.approx(
+        rec["outer"]["dur_s"] - rec["inner"]["dur_s"])
+
+
+def test_span_takes_no_sync_argument():
+    tele.enable()
+    with pytest.raises(TypeError):
+        tele.span("x", sync=jnp.zeros((2, 2)))
+    assert not hasattr(tele, "xplane_bracket")
+
+
+def test_an_enabled_span_is_an_event_of_an_open_profiler_trace(tmp_path):
+    """The span rides the profiler's clock: under an open trace it is a
+    host event named ``qrack.<name>`` of the same ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+
+    tele.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tele.span("fuse.flush"):
+            jnp.zeros(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    found = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+             for f in fs if f.endswith(".xplane.pb")]
+    names = {ev.name for plane in ProfileData.from_file(found[0]).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert "qrack.fuse.flush" in names
+
+
+# -- the disabled path ---------------------------------------------------------
+
+def test_disabled_records_nothing_and_builds_no_span():
+    assert tele.span("fuse.flush") is tele._NULL_SPAN
+    q = _dense()
+    _qft(q)
+    snap = tele.snapshot()
+    assert snap["counters"] == {} and snap["spans"] == {}
+    assert _recorded() == []
+
+
+def test_telemetry_module_imports_no_jax_at_top_level():
+    path = os.path.join(REPO, "qrack_tpu", "telemetry", "__init__.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    top = []
+    for node in tree.body:  # statements of the module itself, no bodies
+        if isinstance(node, ast.Import):
+            top += [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom) and node.level == 0:
+            top.append(node.module)
+    assert not [m for m in top if m.split(".")[0] in ("jax", "jaxlib")], top
+
+
+# -- one flush, three parts, and the counters ---------------------------------------
+
+def _flushes_and_their_parts():
+    rec = _recorded()
+    flushes = [e for e in rec if e["name"] == "fuse.flush"]
+    children = {f["id"]: [e["name"] for e in rec if e["parent"] == f["id"]]
+                for f in flushes}
+    return flushes, children
+
+
+def _spy_window_programs(monkeypatch):
+    """(structure, block_pow) of every window that asks for its kernel
+    program: what plan_window is asked about, independently of the
+    counter."""
+    seen = []
+    real = fu.kernel_lowering
+
+    def spy(n, structure, backend=None):
+        plan, why = real(n, structure, backend)
+        if plan is not None:
+            seen.append((structure, plan["block_pow"]))
+        return plan, why
+
+    monkeypatch.setattr(fu, "kernel_lowering", spy)
+    return seen
+
+
+def _assert_three_parts(flushes, children):
+    for f in flushes:
+        assert sorted(n for n in children[f["id"]] if n in PARTS) \
+            == sorted(PARTS), children[f["id"]]
+
+
+@pytest.mark.parametrize("drive", [_qft, _trotter], ids=["qft", "trotter"])
+def test_dense_flush_has_its_three_parts(small_tiles, monkeypatch, drive):
+    seen = _spy_window_programs(monkeypatch)
+    tele.enable()
+    q = _dense()
+    drive(q)
+    c = tele.snapshot()["counters"]
+    flushes, children = _flushes_and_their_parts()
+    # one span per flush; a flush is a counted window or a one-op flush
+    n_flush = sum(v for k, v in c.items() if k.startswith("fuse.tpu.flush."))
+    windows = c.get("fuse.kernel.windows", 0) + c.get("fuse.xla.windows", 0)
+    assert len(flushes) == n_flush >= 2
+    assert windows == len(seen) == c["fuse.kernel.windows"]
+    _assert_three_parts(flushes, children)
+    # a window issues one put per operand and its program, a one-op
+    # flush one eager program
+    operands = sum(1 + 2 * has_ctrl for structure, _ in seen
+                   for _, _, has_ctrl in structure)
+    assert c["fuse.tpu.programs"] == operands + windows + (n_flush - windows)
+    # cross-tile segments: the counter against plan_window itself
+    want = sum(1 for structure, bp in seen
+               for seg in pk.plan_window(structure, bp)
+               if seg["xgen"] is not None)
+    assert 0 < c["fuse.kernel.sweeps.cross"] == want < c["fuse.kernel.sweeps"]
+    # the read is a span of the engine, outside every flush
+    reads = [e for e in _recorded() if e["name"] == "engine.read"]
+    assert reads and all(e["parent"] is None for e in reads)
+
+
+def test_dense_set_permutation_and_build_spans(small_tiles):
+    tele.enable()
+    q = _dense()
+    _qft(q)
+    rec = _recorded()
+    # the constructor's and the test's
+    assert sum(e["name"] == "engine.set_permutation" for e in rec) == 2
+    builds = [e for e in rec if e["name"] == "fuse.build"]
+    lowers = {e["id"] for e in rec if e["name"] == "fuse.lower"}
+    assert builds and all(b["parent"] in lowers for b in builds)
+    assert len(builds) == tele.snapshot()["counters"]["compile.fuse.miss"]
+
+
+def test_one_op_flush_counts_one_program():
+    tele.enable()
+    q = _dense()
+    q.H(3)
+    q.GetAmplitude(0)
+    c = tele.snapshot()["counters"]
+    flushes, children = _flushes_and_their_parts()
+    assert len(flushes) == 1 and sorted(children[flushes[0]["id"]]) == sorted(PARTS)
+    assert c["fuse.tpu.programs"] == 1
+    assert "fuse.kernel.windows" not in c and "fuse.xla.windows" not in c
+
+
+def test_flush_parts_cover_the_flush():
+    """lower + operands + dispatch leave the flush only its own few
+    lines: its self time, by the rule an operator uses too."""
+    tele.enable()
+    q = _dense()
+    _trotter(q)
+    rec = _recorded()
+    own = tele.self_seconds(rec)
+    flushes = [e for e in rec if e["name"] == "fuse.flush"]
+    total = sum(f["dur_s"] for f in flushes)
+    assert sum(own[f["id"]] for f in flushes) < 0.2 * total  # CPU, w12: µs
+
+
+@pytest.mark.parametrize("drive", [_qft, _trotter], ids=["qft", "trotter"])
+def test_pager_flush_has_its_three_parts(small_tiles, monkeypatch, drive):
+    seen = []
+    real = fu.sharded_kernel_lowering
+
+    def spy(L, structure, backend=None):
+        plan, why = real(L, structure, backend)
+        if plan is not None:
+            seen.append((structure, L, plan["block_pow"]))
+        return plan, why
+
+    monkeypatch.setattr(fu, "sharded_kernel_lowering", spy)
+    tele.enable()
+    q = _pager()
+    drive(q)
+    c = tele.snapshot()["counters"]
+    flushes, children = _flushes_and_their_parts()
+    n_flush = sum(v for k, v in c.items() if k.startswith("fuse.pager.flush."))
+    assert len(flushes) == n_flush >= 2
+    _assert_three_parts(flushes, children)
+    windows = c.get("fuse.kernel.windows", 0) + c.get("fuse.xla.windows", 0)
+    assert windows == len(seen)
+    operands = sum(fu.sharded_operand_count(structure)
+                   for structure, _, _ in seen)
+    assert c["fuse.pager.programs"] == operands + windows + (n_flush - windows)
+    want = sum(1 for structure, L, bp in seen
+               for seg in fu._sharded_segments(structure, L) if seg[0] == "run"
+               for s in pk.plan_window(fu._sharded_run_structure(seg[1], L), bp)
+               if s["xgen"] is not None)
+    assert c["fuse.kernel.sweeps.cross"] == want
+
+
+def test_pager_and_dense_agree_with_spans_on():
+    tele.enable()
+    a, b = _dense(), _pager()
+    assert _qft(a) == pytest.approx(_qft(b), abs=1e-5)
+
+
+# -- the scopes a device trace finds the programs by ---------------------------------
+
+def _lowered_text(fn, *args):
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+def _ops(structure):
+    return [fu.FusedOp(kind, target, int(c), int(c), np.eye(2))
+            for kind, target, c in structure]
+
+
+STRUCTURE = (("gen", 9, False), ("cphase", 3, True), ("gen", 2, False))
+
+
+def test_window_fn_is_lowered_under_its_names():
+    """The scope is in the operations' locations; the function's name is
+    the module's, which a trace keeps whatever the locations carry."""
+    operands = fu.dense_operands(_ops(STRUCTURE), jnp.float32)
+    text = _lowered_text(fu.window_fn(W, STRUCTURE),
+                         jnp.zeros((2, 1 << W), jnp.float32), *operands)
+    assert "qrack.fuse.xla_window" in text
+    assert "module @jit_qrack_xla_window" in text
+
+
+def test_kernel_window_fn_is_lowered_under_its_names():
+    operands = fu.dense_operands(_ops(STRUCTURE), jnp.float32)
+    fn = pk.make_window_fn(W, STRUCTURE, block_pow=6, interpret=True)
+    text = _lowered_text(fn, jnp.zeros((2, 1 << W), jnp.float32), *operands)
+    assert "qrack.fuse.kernel_window" in text
+    assert "module @jit_qrack_kernel_window" in text
+
+
+def test_pager_window_program_is_lowered_under_its_scopes(small_tiles):
+    q = _pager()
+    L = q.local_bits
+    ops = _ops((("gen", W - 1, False), ("gen", 2, False), ("cphase", 1, True)))
+    structure = fu.sharded_structure_of(ops)
+    operands = fu.sharded_operands(ops, L, q.dtype)
+    plan, _ = fu.sharded_kernel_lowering(L, structure)
+    prog = q._p_fuse_window(structure, len(operands), kernel_plan=plan)
+    assert len(operands) == fu.sharded_operand_count(structure)
+    text = prog.lower(q._state, *operands).as_text(debug_info=True)
+    assert "qrack.pager.exchange" in text
+    assert "qrack.fuse.kernel_window" in text
+    assert "module @jit_qrack_sharded_kernel_window" in text
+
+
+def test_served_batch_program_is_lowered_under_its_scope():
+    from qrack_tpu.models.qft import qft_qcircuit
+
+    from qrack_tpu.serve import batcher
+
+    fn = qft_qcircuit(4).compile_batched_fn(4)
+    text = _lowered_text(fn, jnp.zeros((2, 2, 16), jnp.float32))
+    assert "qrack.serve.dispatch" in text
+    prog = batcher.batch_program(qft_qcircuit(4), 4, 2)
+    text = prog.lower([jnp.zeros((2, 16), jnp.float32)] * 2).as_text()
+    assert "module @jit_qrack_serve_dispatch" in text
