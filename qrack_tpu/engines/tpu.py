@@ -422,7 +422,8 @@ class QEngineTPU(QEngine):
         esize = jnp.dtype(self.dtype).itemsize
         if plan is not None:
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
-                                   width=n, esize=esize, cross=plan["cross"])
+                                   width=n, esize=esize, cross=plan["cross"],
+                                   dense=plan["dense"])
         else:
             fu.record_xla_flush(self._tele_name, len(ops), width=n,
                                 esize=esize)

@@ -261,7 +261,9 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
 
     Returns ``(plan, fallback_reason)`` — exactly one is non-None.
     ``plan`` is ``{"interpret": bool, "block_pow": int, "sweeps": int,
-    "cross": int}`` (``cross``: the cross-tile segments among the sweeps).
+    "cross": int, "dense": int}`` (``cross``: the cross-tile segments
+    among the sweeps; ``dense``: the sweeps whose kernel body computes
+    on the dense ``(rows, 128)`` tile, pallas_kernels.dense_tile).
 
     The decision inputs are the window length, op mix (how many planned
     segments the cross-tile non-diagonals force), width and block_pow:
@@ -287,9 +289,9 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
     if backend is None:
         backend = jax.default_backend()
     bp = min(pk.DEFAULT_BLOCK_POW, n)
-    sweeps, cross = pk.plan_counts(structure, bp)
-    plan = {"interpret": backend != "tpu",
-            "block_pow": bp, "sweeps": sweeps, "cross": cross}
+    sweeps, cross, dense = pk.plan_counts(structure, bp)
+    plan = {"interpret": backend != "tpu", "block_pow": bp,
+            "sweeps": sweeps, "cross": cross, "dense": dense}
     if mode == "on":
         return plan, None
     if backend != "tpu":
@@ -326,10 +328,12 @@ def kernel_window_program(n: int, structure: Tuple, dtype,
 
 
 def record_kernel_flush(name: str, nops: int, sweeps: int,
-                        width=None, esize: int = 4, cross: int = 0) -> None:
+                        width=None, esize: int = 4, cross: int = 0,
+                        dense: int = 0) -> None:
     """A window flushed through the Pallas kernel: count it, the HBM
-    sweeps it actually paid (telemetry_report derives sweeps/window)
-    and how many of them were cross-tile pair segments.
+    sweeps it actually paid (telemetry_report derives sweeps/window),
+    how many of them were cross-tile pair segments and how many
+    computed on the dense tile.
     Callers that supply the plane width also feed the sweep's planned
     bytes into the roofline ledger (`roofline.tpu.fuse.flush.*`)."""
     if _tele._ENABLED:
@@ -337,6 +341,7 @@ def record_kernel_flush(name: str, nops: int, sweeps: int,
         _tele.inc("fuse.kernel.ops", nops)
         _tele.inc("fuse.kernel.sweeps", sweeps)
         _tele.inc("fuse.kernel.sweeps.cross", cross)
+        _tele.inc("fuse.kernel.sweeps.dense", dense)
         if width is not None:
             _roofline.note_bytes(
                 "tpu.fuse.flush",
@@ -768,23 +773,25 @@ def _sharded_offs(structure: Tuple) -> List[int]:
 
 
 def sharded_kernel_counts(structure: Tuple, L: int,
-                          block_pow: int) -> Tuple[int, int]:
-    """``(sweeps, cross)`` of the per-page kernel lowering: one sweep
-    per planned kernel segment inside each local run and one per
+                          block_pow: int) -> Tuple[int, int, int]:
+    """``(sweeps, cross, dense)`` of the per-page kernel lowering: one
+    sweep per planned kernel segment inside each local run and one per
     ppermute exchange; ``cross`` counts the runs' cross-tile pair
-    segments (an exchange is no kernel launch)."""
+    segments and ``dense`` their dense-tile ones (an exchange is no
+    kernel launch)."""
     from . import pallas_kernels as pk
 
-    total = cross = 0
+    total = cross = dense = 0
     for seg in _sharded_segments(structure, L):
         if seg[0] == "global":
             total += 1
         else:
-            s, x = pk.plan_counts(_sharded_run_structure(seg[1], L),
-                                  block_pow)
+            s, x, d = pk.plan_counts(_sharded_run_structure(seg[1], L),
+                                     block_pow)
             total += s
             cross += x
-    return total, cross
+            dense += d
+    return total, cross, dense
 
 
 def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):
@@ -798,9 +805,9 @@ def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):
     if backend is None:
         backend = jax.default_backend()
     bp = min(pk.DEFAULT_BLOCK_POW, L)
-    sweeps, cross = sharded_kernel_counts(structure, L, bp)
-    plan = {"interpret": backend != "tpu",
-            "block_pow": bp, "sweeps": sweeps, "cross": cross}
+    sweeps, cross, dense = sharded_kernel_counts(structure, L, bp)
+    plan = {"interpret": backend != "tpu", "block_pow": bp,
+            "sweeps": sweeps, "cross": cross, "dense": dense}
     if mode == "on":
         return plan, None
     if backend != "tpu":

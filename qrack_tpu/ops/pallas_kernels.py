@@ -17,8 +17,8 @@ Vocabulary (everything the fuser emits):
   splits at runtime inside the kernel into a tile-local part tested
   against the in-tile index and a high part tested against the grid
   block id, so high targets cost one scalar compare per tile.
-* inv / gen with target < block_pow — in-tile pair mix: each lane
-  reads its partner 2^target lanes away through two lane rotations
+* inv / gen with target < block_pow — in-tile pair mix: each element
+  reads its partner 2^target amplitudes away through two rotations
   (tile_partner); controls anywhere (runtime mask split).
 * inv / gen with target >= block_pow — CROSS-TILE: the planner starts a
   new segment led by the op, and the segment's grid maps block PAIRS:
@@ -30,6 +30,22 @@ Vocabulary (everything the fuser emits):
 
 ``sweeps == len(segments)``: a window with no cross-tile non-diagonal
 op is exactly one sweep; each cross-tile op opens one more.
+
+The dense tile.  The refs hold a block as ``(2, 2^block_pow)``: two
+rows in a vreg's eight sublanes, every vreg a quarter full.  From
+``block_pow`` 10 on (dense_tile) the body casts what it loads to
+``(2, rows, 128)`` with ``rows = 2^(block_pow - 7)`` and casts back
+before the store, so its arithmetic runs on full vregs; the BlockSpecs,
+the grid and the custom call's operand shapes are those of the flat
+tile.  The in-tile index is ``row * 128 + lane`` and every mask test
+reads it unchanged; the pair partner ``i ^ 2^target`` is a lane roll by
+``2^target`` for ``target < 7`` and a sublane roll by ``2^(target - 7)``
+rows above that (by whole vregs from target 10).  The arithmetic of
+each amplitude, and its order, are those of the flat tile: the results
+are bit-identical.  Under ``block_pow`` 10 (kets under 1024 amplitudes
+a shard; Mosaic wants rows a multiple of 8) the body keeps the flat
+tile, the one-axis case of the same tile_* functions.  Telemetry counts
+the sweeps that computed dense as ``fuse.kernel.sweeps.dense``.
 
 Scalar operands ride in two packed SMEM refs (floats and int32 masks),
 a (K, 1) column each — TPU SMEM wants 2-D refs.  ``interpret=True``
@@ -51,12 +67,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_POW = 16
+_LANE_POW = 7  # a vreg is 8 sublanes of 128 lanes
 
 # A pair-grid segment holds two input tiles and one output tile, each
 # double-buffered and padded from 2 to 8 sublanes (12 MiB at block_pow
-# 16), beside the body's temporaries: a controlled cross-tile gen with
-# five cphases behind it asked for 17.04 MiB against the compiler's
-# default 16 MiB scoped limit (v5e, w28 over 4 pages).  The v5e has
+# 16), beside the body's temporaries.  On the flat tile a controlled
+# cross-tile gen with five cphases behind it asked for 17.04 MiB against
+# the compiler's default 16 MiB scoped limit (v5e, w28 over 4 pages); on
+# the dense tile the same body, and a 16-op one, compile inside 13 MiB
+# (described v5e, PR 29).  The limit stays as headroom: the v5e has
 # 128 MiB of VMEM.
 _VMEM_LIMIT_BYTES = 32 << 20
 
@@ -98,12 +117,24 @@ def plan_window(structure: Tuple, block_pow: int) -> List[dict]:
     return segs
 
 
-def plan_counts(structure: Tuple, block_pow: int) -> Tuple[int, int]:
-    """``(sweeps, cross)``: the HBM sweeps the kernel lowering pays for
-    this window (the XLA window chain pays ~len(structure)), and how
-    many of them are cross-tile pair segments."""
+def dense_tile(block_pow: int) -> Optional[Tuple[int, int]]:
+    """The ``(rows, 128)`` view in which a kernel body sees its tile, or
+    None where it keeps the flat one: Mosaic takes the cast of the
+    loaded ``(2, block)`` value when rows is a multiple of the eight
+    sublanes."""
+    if block_pow < _LANE_POW + 3:
+        return None
+    return (1 << (block_pow - _LANE_POW), 1 << _LANE_POW)
+
+
+def plan_counts(structure: Tuple, block_pow: int) -> Tuple[int, int, int]:
+    """``(sweeps, cross, dense)``: the HBM sweeps the kernel lowering
+    pays for this window (the XLA window chain pays ~len(structure)),
+    how many of them are cross-tile pair segments, and how many
+    compute on the dense tile (all, where the block has one)."""
     segs = plan_window(structure, block_pow)
-    return len(segs), sum(seg["xgen"] is not None for seg in segs)
+    return (len(segs), sum(seg["xgen"] is not None for seg in segs),
+            len(segs) if dense_tile(block_pow) else 0)
 
 
 def _operand_slots(structure: Tuple):
@@ -184,20 +215,29 @@ def tile_diag(v, lidx, hi_id, target, L,
 
 
 def tile_partner(v, lidx, target):
-    """``v[:, i ^ (1 << target)]`` on one (2, block) tile, as two lane
-    rotations and a select on the target bit.  The tile keeps its
-    (2, block) shape: Mosaic refuses the (2, high, 2, low) view for
-    low < 128 lanes, and XLA pads it 128/low-fold.
+    """``v[:, i ^ (1 << target)]`` on one tile, as two rotations and a
+    select on the target bit.  The tile is ``v.shape[1:]``: flat
+    ``(block,)``, or ``(rows, lanes)`` with index ``row * lanes + lane``.
+    A target below the lane power is a lane roll by ``2^target``; one
+    above it a sublane roll by ``2^(target - lane power)`` rows, which
+    from eight rows on moves whole vregs and costs nothing.  The flat
+    tile is the case of one axis: every in-tile target is a lane roll.
+
+    The flat tile stays flat: Mosaic refuses the (2, high, 2, low) view
+    for low < 128 lanes, and XLA pads it 128/low-fold.
 
     Mosaic emits the rotations as written.  Where XLA lowers this code
     instead (the interpreter; a chunk body outside any kernel) the
     caller puts an optimization barrier between ops, or XLA fuses a run
     of k ops by recomputing each input at every read, 3^k-fold."""
-    dist = 1 << target
-    axis = v.ndim - 1
-    down = pltpu.roll(v, dist, axis)                   # v[i - dist]
-    up = pltpu.roll(v, v.shape[axis] - dist, axis)     # v[i + dist]
-    return jnp.where((lidx & dist) != 0, down, up)
+    lane_pow = (v.shape[-1] - 1).bit_length()
+    if target < lane_pow:
+        axis, dist = v.ndim - 1, 1 << target
+    else:
+        axis, dist = v.ndim - 2, 1 << (target - lane_pow)
+    down = pltpu.roll(v, dist, axis)                   # v[i - 2^target]
+    up = pltpu.roll(v, v.shape[axis] - dist, axis)     # v[i + 2^target]
+    return jnp.where((lidx & (1 << target)) != 0, down, up)
 
 
 def tile_local_2x2(v, lidx, hi_id, target, mp, lm, lv, gm, gv):
@@ -246,6 +286,17 @@ _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 _SCALAR_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+def _tile_index(tile: Tuple[int, ...]):
+    """The in-tile amplitude index of every element of a ``(block,)`` or
+    ``(rows, lanes)`` tile, int32: ``row * lanes + lane``.  Built from a
+    2-D iota either way: the TPU has no 1-D one."""
+    shape = (1,) * (2 - len(tile)) + tuple(tile)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if len(tile) == 1:
+        return lane[0]
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0) * shape[1] + lane
+
+
 def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
     """Apply one in-tile window op to the loaded tile value.  Masks are
     runtime scalars; the lo/hi split happens here (dense widths are
@@ -292,17 +343,28 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
     iv_spec = fv_spec = _SCALAR_SPEC
     tile_spec = pl.BlockSpec((2, block), lambda i: (0, i))
 
+    # the tile the body computes on: (rows, 128) full vregs where the
+    # block has them, else the (block,) row the refs hold.  A cross-tile
+    # segment casts its two inputs, ahead of the mix: fewer instructions
+    # than casting the mixed value (PERF.md section 6, PR 29)
+    tile = dense_tile(bp) or (block,)
+
+    def load(ref):
+        return ref[...].reshape((2,) + tile)
+
     def in_tile_ops(v, blk, iv_ref, fv_ref):
-        lidx = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)[0]
+        """The segment's in-tile ops on a loaded (or mixed) tile value,
+        back in the refs' ``(2, block)`` shape."""
+        lidx = _tile_index(tile)
         for slot in seg["ops"]:
             v = _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp)
             if interpret:  # XLA lowers the body: see tile_partner
                 v = jax.lax.optimization_barrier(v)
-        return v
+        return v.reshape(2, block)
 
     if xgen is None:
         def kernel(iv_ref, fv_ref, in_ref, out_ref):
-            out_ref[...] = in_tile_ops(in_ref[...], pl.program_id(0),
+            out_ref[...] = in_tile_ops(load(in_ref), pl.program_id(0),
                                        iv_ref, fv_ref)
 
         def run(planes, iv, fv):
@@ -328,8 +390,8 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
     def kernel(iv_ref, fv_ref, in_ref, pa_ref, out_ref):
         blk = pl.program_id(0)
         b = (blk >> h) & 1
-        mine = in_ref[...]
-        other = pa_ref[...]
+        mine = load(in_ref)
+        other = load(pa_ref)
         # target-bit-0 / target-bit-1 operands of the 2x2, from my side
         lo_r = jnp.where(b == 0, mine[0], other[0])
         lo_i = jnp.where(b == 0, mine[1], other[1])
@@ -358,7 +420,7 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
         if has_ctrl:
             cm = iv_ref[ioff_x, 0]
             cv = iv_ref[ioff_x + 1, 0]
-            lidx = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)[0]
+            lidx = _tile_index(tile)
             sel = (((lidx & (cm & lbits)) == (cv & lbits))
                    & ((blk & (cm >> bp)) == (cv >> bp)))
             nv = jnp.where(sel, nv, mine)

@@ -757,7 +757,7 @@ class QPager(QEngine):
         if plan is not None:
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
                                    width=self.qubit_count,
-                                   cross=plan["cross"])
+                                   cross=plan["cross"], dense=plan["dense"])
         else:
             fu.record_kernel_fallback(why)
             fu.record_xla_flush(self._tele_name, len(ops),

@@ -12,6 +12,7 @@ import: only the worker that is handed this file loads the TPU library.
 
 import os
 import re
+import time
 
 import numpy as np
 import pytest
@@ -73,11 +74,25 @@ def _dense_args(structure, sharding):
 
 
 def _compile(fn, args):
-    return jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    """Compiled for the described chip; the compiler's seconds (Mosaic's,
+    for a kernel window: XLA's own part is a few operand ops) go to the
+    test's output, where ``pytest -rP`` or a failure shows them, so that
+    a change which multiplies them is seen without a chip."""
+    lowered = jax.jit(fn, donate_argnums=(0,)).lower(*args)
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    print(f"compile_s={time.perf_counter() - t0:.2f} "
+          f"{getattr(fn, '__name__', fn)} "
+          f"temp_bytes={compiled.memory_analysis().temp_size_in_bytes}")
+    return compiled
 
 
 QFT16 = (("gen", 27, False),) + tuple(
     ("cphase", 26 - k, True) for k in range(15))
+# the same window with every target inside the tile: what most of a
+# QFT's sweeps are, and the body with the most arithmetic a tile
+QFT16_INTILE = (("gen", 15, False),) + tuple(
+    ("cphase", 14 - k, True) for k in range(15))
 
 
 @pytest.mark.parametrize("kind,target", [
@@ -99,6 +114,11 @@ def test_xla_one_op_window(one_chip, kind, target):
     # the pager's per-page run at w28 / 4 pages that asked for 17 MiB of
     # VMEM: a controlled cross-tile gen with five cphases behind it
     (("gen", 17, True),) + (("cphase", 18, True),) * 5,
+    QFT16_INTILE,
+    # a controlled cross-tile gen whose mixed value goes on through
+    # lane, sublane and whole-vreg pair ops and cphases
+    (("gen", 21, True), ("gen", 3, False), ("cphase", 20, True),
+     ("inv", 8, True), ("cphase", 5, True), ("gen", 12, True)),
 ], ids=lambda s: "-".join(f"{k}{t}{'c' if c else ''}" for k, t, c in s))
 def test_kernel_window(one_chip, structure):
     compiled = _compile(pk.make_window_fn(W, structure),
@@ -132,9 +152,14 @@ def test_sharded_kernel_window_four_pages(topo):
     fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P(None, "pages"),) + (P(),) * (len(args) - 1),
                        out_specs=P(None, "pages"), check_vma=False)
-    text = _compile(fn, args).as_text()
+    compiled = _compile(fn, args)
+    text = compiled.as_text()
     assert "collective-permute" in text
     assert "tpu_custom_call" in text
+    # bytes of one device: three pages, the exchange's (the sent copy,
+    # the partner's page, the mixed one); the kernel's tile adds none
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 3 * KET_BYTES // npg + SLACK
 
 
 def test_kernel_launches_carry_their_names(one_chip):
@@ -205,6 +230,9 @@ def test_compile_cache_key_does_not_hold_the_call_stack(
         "jax_persistent_cache_min_entry_size_bytes",
         "jax_persistent_cache_min_compile_time_secs")}
     try:
+        # the default, whatever an earlier test of this worker left: a
+        # QrackService with a warm start keeps one frame for the process
+        jax.config.update("jax_traceback_in_locations_limit", 10)
         assert _lowered_at_one_site(args, structure) \
             != _lowered_at_another_site(args, structure)
         monkeypatch.setattr(warmstart, "_ENABLED_DIR", None)

@@ -12,6 +12,8 @@ grade, not perf grade (docs/PERFORMANCE.md) — which is exactly what
 these tests exercise.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -333,3 +335,150 @@ def test_w12_qft_block_pow8_numeric_parity():
     got = np.asarray(fn(jnp.asarray(basis_planes(12, 1234 & ((1 << 12) - 1)))))
     assert fn.sweeps < len(ops)
     assert float(np.max(np.abs(want - got))) < 3e-5
+
+
+# ---------------------------------------------------------------------------
+# the dense tile: from block_pow 10 on the kernel body computes on a
+# (2, rows, 128) view of its block and reads a pair partner by a lane
+# roll (target < 7) or a sublane roll (7 <= target < block_pow); below
+# that it keeps the flat (2, block) tile.  Every kind x where the target
+# lies x where the controls lie, against the XLA window chain.
+# ---------------------------------------------------------------------------
+
+_DENSE_MATRICES = {
+    "cphase": np.diag([1.0, np.exp(0.37j)]),
+    "diag": np.diag([np.exp(-0.21j), np.exp(0.53j)]),
+    "inv": np.array([[0, np.exp(0.3j)], [np.exp(-0.8j), 0]]),
+    "gen": np.array([[np.cos(0.4), -np.exp(0.6j) * np.sin(0.4)],
+                     [np.exp(0.2j) * np.sin(0.4),
+                      np.exp(0.8j) * np.cos(0.4)]]),
+}
+
+
+def _dense_cases():
+    """(width, block_pow, kind, target, cmask, cval, behind).  A
+    cross-tile inv/gen leads its segment: ``behind`` puts an in-tile gen
+    after it, so that the mixed value goes on through in-tile ops; the
+    bare cases leave the mix the whole body."""
+    cases = []
+    for n, bp, targets in (
+            (18, 16, {"lane": 3, "sublane": 8, "vreg": 12, "cross": 17}),
+            (12, 10, {"lane": 6, "sublane": 9, "cross": 11})):
+        # controls: a lane bit and a sublane bit below the block, and
+        # the block's lowest bit above it (no target is one of them)
+        low, high = (1 << 1) | (1 << 7), 1 << bp
+        for cls, target in targets.items():
+            for kind in ("cphase", "diag", "inv", "gen"):
+                leads = cls == "cross" and kind in ("inv", "gen")
+                for cname, cmask in (("none", 0), ("low", low),
+                                     ("high", high), ("both", low | high)):
+                    # cphase is the all-ones control by definition; the
+                    # others also test an anti-control on the lowest bit
+                    cval = cmask if kind == "cphase" else cmask & (cmask - 1)
+                    bare = leads and cname in ("none", "both")
+                    for behind in (True, False) if bare else (leads,):
+                        cases.append(pytest.param(
+                            n, bp, kind, target, cmask, cval, behind,
+                            id=f"w{n}-{kind}-{cls}{target}-{cname}"
+                               + ("-bare" if leads and not behind else "")))
+    return cases
+
+
+@functools.lru_cache(maxsize=None)
+def _window_programs(n, bp, structure):
+    """(XLA chain, interpreted kernel) of a structure, jitted once: the
+    masks are operands, so the controlled cases of a target share both."""
+    import jax
+
+    return (jax.jit(fu.window_fn(n, structure)),
+            jax.jit(pk.make_window_fn(n, structure, block_pow=bp,
+                                      interpret=True)))
+
+
+def _window_against_chain(n, bp, ops, seed):
+    import jax.numpy as jnp
+
+    structure = fu.structure_of(ops)
+    operands = fu.dense_operands(ops, jnp.float32)
+    rng = np.random.default_rng(seed)
+    ket = rng.standard_normal((2, 1 << n)).astype(np.float32)
+    ket /= np.sqrt((ket ** 2).sum())
+    chain, kernel = _window_programs(n, bp, structure)
+    want = np.asarray(chain(jnp.asarray(ket), *operands))
+    got = np.asarray(kernel(jnp.asarray(ket), *operands))
+    return structure, float(np.max(np.abs(want - got)))
+
+
+@pytest.mark.parametrize("n,bp,kind,target,cmask,cval,behind", _dense_cases())
+def test_dense_tile_parity(n, bp, kind, target, cmask, cval, behind):
+    ops = [fu.FusedOp(kind, target, cmask, cval, _DENSE_MATRICES[kind])]
+    if behind:
+        ops.append(fu.FusedOp("gen", 5, 1 << 4, 1 << 4,
+                              _DENSE_MATRICES["gen"]))
+    assert fu.classify(ops[0].m, cmask, cval) == kind
+    structure, err = _window_against_chain(n, bp, ops, seed=target + cmask)
+    cross = kind in ("inv", "gen") and target >= bp
+    assert pk.plan_counts(structure, bp) == (1, cross, 1)
+    assert err < 2e-7
+
+
+def test_flat_tile_below_block_pow_10():
+    """A block of 512 amplitudes has four rows of 128: the body keeps
+    the flat tile, the plan counts no dense sweep, the result holds."""
+    assert pk.dense_tile(9) is None
+    assert pk.dense_tile(10) == (8, 128)
+    assert pk.dense_tile(16) == (512, 128)
+    ops = [fu.FusedOp("gen", 8, 1 << 1, 1 << 1, _DENSE_MATRICES["gen"]),
+           fu.FusedOp("inv", 10, 1 << 7, 0, _DENSE_MATRICES["inv"]),
+           fu.FusedOp("cphase", 3, 1 << 9, 1 << 9, _DENSE_MATRICES["cphase"])]
+    structure, err = _window_against_chain(11, 9, ops, seed=5)
+    assert pk.plan_counts(structure, 9) == (2, 1, 0)
+    assert err < 2e-7
+
+
+@pytest.fixture
+def benchmark_plans(monkeypatch):
+    """``benchmarks/tests/structure.py``: the windows the fuser plans for
+    one application of a cell's family at w28, no ket allocated.  The
+    benchmark's modules are imported for this test alone."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    before = set(sys.modules)
+    monkeypatch.syspath_prepend(bench)
+    monkeypatch.syspath_prepend(os.path.join(bench, "tests"))
+    import families
+    import structure
+
+    def windows(name):
+        return structure.plan_application(families.family(name), 28,
+                                          families.PARAMS[name])
+
+    yield windows
+    for name in set(sys.modules) - before:
+        if name in ("families", "structure", "harness") \
+                or name.startswith("bench_"):
+            del sys.modules[name]
+
+
+@pytest.mark.parametrize("family,sweeps,carry_ops", [("qft", 37, 37),
+                                                     ("tfim", 28, 16)])
+def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
+                                     carry_ops):
+    """What ``fuse.kernel.sweeps.dense`` reads in a traced run of each
+    cell: every planned kernel segment of an application at w28.  Of
+    TFIM's 24 cross-tile segments 12 carry an in-tile op behind the mix
+    (11 a ``diag``, one a window's 15 ``gen``) and 12 are the controlled
+    ``inv`` alone, whose select is what the dense tile shortens."""
+    dense = with_ops = 0
+    for w in benchmark_plans(family):
+        if w["path"] != "kernel":
+            continue
+        plan, _ = fu.kernel_lowering(28, w["structure"], backend="tpu")
+        segments = pk.plan_window(w["structure"], plan["block_pow"])
+        assert plan["dense"] == plan["sweeps"] == len(segments)
+        dense += plan["dense"]
+        with_ops += sum(bool(seg["ops"]) for seg in segments)
+    assert (dense, with_ops) == (sweeps, carry_ops)

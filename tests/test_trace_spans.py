@@ -37,15 +37,28 @@ def _clean_telemetry():
     tele.reset()
 
 
-@pytest.fixture
-def small_tiles(monkeypatch):
-    """The kernel lowering under the interpreter, with tiles of 2^6 so
-    that a w12 ket has cross-tile targets as a w28 ket has."""
+def _kernel_tiles(monkeypatch, block_pow):
+    """The kernel lowering under the interpreter, with tiles of
+    ``2^block_pow``."""
     monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
-    monkeypatch.setattr(pk, "DEFAULT_BLOCK_POW", 6)
+    monkeypatch.setattr(pk, "DEFAULT_BLOCK_POW", block_pow)
     fu.PROGRAMS.clear()
     yield
     fu.PROGRAMS.clear()
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of 2^6, so that a w12 ket has cross-tile targets as a w28
+    ket has; the kernel body keeps the flat tile."""
+    yield from _kernel_tiles(monkeypatch, 6)
+
+
+@pytest.fixture
+def dense_tiles(monkeypatch):
+    """Tiles of 2^10, the smallest the kernel body views as (rows, 128):
+    a w12 ket, and a 4-page pager's 2^10 pages, compute dense."""
+    yield from _kernel_tiles(monkeypatch, 10)
 
 
 def _recorded():
@@ -225,6 +238,40 @@ def test_dense_flush_has_its_three_parts(small_tiles, monkeypatch, drive):
     # the read is a span of the engine, outside every flush
     reads = [e for e in _recorded() if e["name"] == "engine.read"]
     assert reads and all(e["parent"] is None for e in reads)
+
+
+@pytest.mark.parametrize("drive", [_qft, _trotter], ids=["qft", "trotter"])
+def test_dense_sweeps_are_those_of_the_plan(dense_tiles, monkeypatch, drive):
+    seen = _spy_window_programs(monkeypatch)
+    tele.enable()
+    drive(_dense())
+    c = tele.snapshot()["counters"]
+    assert {bp for _, bp in seen} == {10}
+    want = sum(len(pk.plan_window(structure, bp)) for structure, bp in seen)
+    assert 0 < c["fuse.kernel.sweeps.dense"] == want == c["fuse.kernel.sweeps"]
+    assert c["fuse.kernel.sweeps.cross"] > 0
+
+
+def test_flat_sweeps_are_not_counted_dense(small_tiles):
+    tele.enable()
+    _qft(_dense())
+    _qft(_pager())
+    c = tele.snapshot()["counters"]
+    assert c["fuse.kernel.sweeps"] > 0 == c.get("fuse.kernel.sweeps.dense", 0)
+
+
+def test_pager_dense_sweeps_are_its_kernel_segments(dense_tiles):
+    """An exchange is a sweep and no launch: the pager's dense sweeps
+    are the planned segments of its local runs."""
+    tele.enable()
+    q = _pager()
+    assert q.local_bits == 10
+    _trotter(q)
+    c = tele.snapshot()["counters"]
+    exchanges = c.get("exchange.pager.global_2x2", 0)
+    assert exchanges > 0
+    assert 0 < c["fuse.kernel.sweeps.dense"] \
+        == c["fuse.kernel.sweeps"] - exchanges
 
 
 def test_dense_set_permutation_and_build_spans(small_tiles):
