@@ -14,13 +14,15 @@ free-fermion model and every gate of a step is a rotation of two
 Majorana operators, so its bond correlations ``<Z_j Z_j+1>`` after any
 number of steps follow exactly from a 2n x 2n orthogonal matrix
 (``bond_zz``).  After the window the ket the engine holds is reduced to
-those 27 numbers and held to them, and to the norm.
+those width - 1 numbers and held to them, and to the norm.
 """
 
 import cmath
 import math
 
 import numpy as np
+
+import harness
 
 X2 = np.array([[0, 1], [1, 0]], dtype=np.complex128)
 
@@ -96,19 +98,22 @@ def bond_zz(width, params, x, steps):
     return [corr[2 * j + 1, 2 * j + 2] for j in range(width - 1)]
 
 
-def measured_bond_zz(planes, width):
-    """The same 27 numbers from split planes (2, 2^width), by a reduction
-    of the benchmark's own."""
+def measured_bond_zz(planes, positions):
+    """The same numbers from split planes (2, 2^width), by a reduction of
+    the benchmark's own.  ``positions[j]`` is the bit of the planes'
+    index that holds logical qubit ``j`` (``harness.bit_positions``)."""
     import jax
     import jax.numpy as jnp
+
+    width = len(positions)
 
     @jax.jit
     def reduce(planes):
         p = jnp.sum(planes * planes, axis=0)
         idx = jax.lax.iota(jnp.int32, 1 << width)
         return jnp.stack([
-            jnp.sum(jnp.where(((idx >> j) ^ (idx >> (j + 1))) & 1 == 1, -p, p))
-            for j in range(width - 1)])
+            jnp.sum(jnp.where(((idx >> a) ^ (idx >> b)) & 1 == 1, -p, p))
+            for a, b in zip(positions, positions[1:])])
 
     return [float(v) for v in reduce(planes)]
 
@@ -198,7 +203,11 @@ def final_check(q, plan, last_i, spans, checks):
     a fresh basis state through the programs the window has just used."""
     steps = last_i + 1
     checks.norm_drift("evolved_ket", q, steps)
-    got = measured_bond_zz(q._state, plan.width)
+    planes = q._state  # before the table: the read flushes what is queued
+    positions = harness.bit_positions(q)
+    harness.say(evolved_ket_bit_positions=positions,
+                identity=positions == sorted(positions))
+    got = measured_bond_zz(planes, positions)
     want = bond_zz(plan.width, plan.params, plan.window_start, steps)
     checks.compare("evolved_ket.bond_zz",
                    max(abs(g - w) for g, w in zip(got, want)), "bond_zz_abs_err")

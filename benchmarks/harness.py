@@ -206,6 +206,17 @@ class Checks:
         return [r["check"] for r in self.records if not r["ok"]]
 
 
+def bit_positions(q):
+    """The bit position, in the planes the engine holds, of each logical
+    qubit: the engine's placement table where it keeps one (the pager's
+    ``_qmap``), else the identity.  Read it after the planes: a read of
+    ``q._state`` flushes the last window, which may move the table.  The
+    table is read, never undone: ``_unmap()`` is an exchange of the
+    program's own and would change what the next step runs."""
+    table = getattr(q, "_qmap", None)
+    return list(range(q.qubit_count)) if table is None else list(table)
+
+
 def self_check(family, params, reference, width, seed):
     """The family's closed form against the plain reference, every
     amplitude, at a width the host holds: no device program."""

@@ -1,5 +1,5 @@
-"""Device time of the cross-tile pair sweeps of one application: the
-launches the program names ``qrack_window_cross``
+"""A chip's device time in the cross-tile pair sweeps of one
+application: the launches the program names ``qrack_window_cross``
 (``kernels/window_cross.json``).  Their count has to be the program's
 ``fuse.kernel.sweeps.cross``; both are printed on an earlier line."""
 
@@ -14,7 +14,8 @@ def read(ctx):
     if not events:
         return None
     planned = ctx["window_counters"].get("fuse.kernel.sweeps.cross")
-    harness.say(cross_launches_in_trace=len(events),
+    launches = trace.chip_count(events)
+    harness.say(cross_launches_in_trace=launches,
                 fuse_kernel_sweeps_cross_counted=planned,
-                equal=len(events) == planned)
-    return sum(d for _, _, d in events) / 1e6 / ctx["attempted"]
+                equal=launches == planned)
+    return trace.chip_ns(events) / 1e6 / ctx["attempted"]

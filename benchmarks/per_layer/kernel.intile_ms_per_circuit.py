@@ -1,5 +1,6 @@
-"""Device time of the in-tile sweeps of one application: the launches
-the program names ``qrack_window_intile`` (``kernels/window_intile.json``)."""
+"""A chip's device time in the in-tile sweeps of one application: the
+launches the program names ``qrack_window_intile``
+(``kernels/window_intile.json``)."""
 
 
 def read(ctx):
@@ -9,4 +10,4 @@ def read(ctx):
     events = trace.kernel_events("window_intile")
     if not events:
         return None
-    return sum(d for _, _, d in events) / 1e6 / ctx["attempted"]
+    return trace.chip_ns(events) / 1e6 / ctx["attempted"]

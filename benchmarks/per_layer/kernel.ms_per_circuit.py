@@ -1,6 +1,6 @@
-"""Device time of the window kernel's launches for one application:
-summed durations of its events in the device trace, over the
-applications traced."""
+"""A chip's device time in the window kernel's launches for one
+application: summed durations of its events in the device trace, a
+chip, over the applications traced."""
 
 
 def read(ctx):
@@ -10,4 +10,4 @@ def read(ctx):
     events = trace.kernel_events("window_kernel")
     if not events:
         return None
-    return sum(d for _, _, d in events) / 1e6 / ctx["attempted"]
+    return trace.chip_ns(events) / 1e6 / ctx["attempted"]

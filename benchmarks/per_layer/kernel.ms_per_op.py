@@ -1,5 +1,5 @@
-"""Device time of the window kernel per fused op it carried: all its
-launches in the window over the program's ``fuse.kernel.ops``."""
+"""A chip's device time in the window kernel per fused op it carried:
+its launches in the window over the program's ``fuse.kernel.ops``."""
 
 
 def read(ctx):
@@ -10,4 +10,4 @@ def read(ctx):
     events = trace.kernel_events("window_kernel")
     if not events:
         return None
-    return sum(d for _, _, d in events) / 1e6 / ops
+    return trace.chip_ns(events) / 1e6 / ops

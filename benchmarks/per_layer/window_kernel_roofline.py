@@ -1,9 +1,11 @@
 """The window kernel's share of its HBM roofline.  Bound: HBM.
 
-The least a launch can move is one read and one write of the planes:
-``2 * ket bytes``.  Launches are counted in the trace; the peak is the
-table's.  It cannot pass 100 %: a launch that reads its partner tile too
-moves more than is counted here, never less."""
+The least a chip's launch can move is one read and one write of the
+planes that chip holds: ``2 * ket bytes / pages``
+(``roofline.launch_bytes``).  Launches and their time are a chip's,
+counted in the trace; the peak is the table's.  It cannot pass 100 %: a
+launch that reads its partner tile too moves more than is counted here,
+never less."""
 
 import harness
 import roofline
@@ -17,11 +19,12 @@ def read(ctx):
     if not events:
         return None
     planned = ctx["window_counters"].get("fuse.kernel.sweeps")
-    harness.say(kernel_launches_in_trace=len(events),
+    launches = trace.chip_count(events)
+    harness.say(kernel_launches_in_trace=launches,
                 fuse_kernel_sweeps_counted=planned,
-                equal=len(events) == planned)
-    seconds = sum(d for _, _, d in events) / 1e9
+                equal=launches == planned)
+    seconds = trace.chip_ns(events) / 1e9
     least = roofline.least_seconds(
-        hbm_bytes=len(events) * roofline.sweep_bytes(ctx["width"]),
+        hbm_bytes=launches * roofline.launch_bytes(ctx["width"], ctx["pages"]),
         peaks=ctx["peaks"])
     return 100.0 * least / seconds

@@ -236,38 +236,44 @@ class ProgramSpans:
         return span[2] - sum(s[2] for s in children)
 
     def idle_by_span(self):
-        """The device's idle time inside the window (first device
-        plane), in ns, by the innermost ``qrack.*`` span that covers the
-        middle of each gap; where none does, by the innermost
-        ``bench.*`` span, and ``between`` where none does either."""
-        if not self.device:
-            return {}
-        events = next(iter(self.device.values()))
-        totals, cursor = {}, self.start
-        for _, s, d, _ in events + [("", self.end, 0, "")]:
-            if s > cursor:
-                mid = (cursor + s) // 2
-                covering = [sp for sp in self.spans
-                            if sp[1] <= mid < sp[1] + sp[2]]
-                label = "between"
-                for prefix in (PROGRAM, BENCH):
-                    mine = [sp for sp in covering if sp[0].startswith(prefix)]
-                    if mine:
-                        label = min(mine, key=lambda sp: sp[2])[0]
-                        break
-                totals[label] = totals.get(label, 0) + (s - cursor)
-            cursor = max(cursor, s + d)
-        return totals
+        """A chip's idle time inside the window, in ns, by the innermost
+        ``qrack.*`` span that covers the middle of each gap; where none
+        does, by the innermost ``bench.*`` span, and ``between`` where
+        none does either.  Every device plane's gaps, averaged over the
+        planes (``planes`` names them)."""
+        totals = {}
+        for events in self.device.values():
+            cursor = self.start
+            for _, s, d, _ in events + [("", self.end, 0, "")]:
+                if s > cursor:
+                    mid = (cursor + s) // 2
+                    covering = [sp for sp in self.spans
+                                if sp[1] <= mid < sp[1] + sp[2]]
+                    label = "between"
+                    for prefix in (PROGRAM, BENCH):
+                        mine = [sp for sp in covering
+                                if sp[0].startswith(prefix)]
+                        if mine:
+                            label = min(mine, key=lambda sp: sp[2])[0]
+                            break
+                    totals[label] = totals.get(label, 0) + (s - cursor)
+                cursor = max(cursor, s + d)
+        return {k: v / len(self.device) for k, v in totals.items()}
+
+    @property
+    def planes(self):
+        return sorted(self.device)
 
     def device_classes(self, launches):
-        """Device time (ns, all planes) of every operation that is no
-        kernel launch (``launches``: compiled expressions of
-        ``kernels/*.json``), as ``{"<module>:<what>": ns}``.  The module
-        is the program's own (``jit_qrack_xla_window``, ...) or
-        ``eager`` for any other (an eager operation compiles once per
-        primitive and has no name of the program's); ``what`` is
-        ``small`` for an operation that touches no ket-sized array
-        (building operands), else the operation (``copy``, ``fusion``)."""
+        """A chip's device time (ns: every plane's, averaged over the
+        planes) in every operation that is no kernel launch
+        (``launches``: compiled expressions of ``kernels/*.json``), as
+        ``{"<module>:<what>": ns}``.  The module is the program's own
+        (``jit_qrack_xla_window``, ...) or ``eager`` for any other (an
+        eager operation compiles once per primitive and has no name of
+        the program's); ``what`` is ``small`` for an operation that
+        touches no ket-sized array (building operands), else the
+        operation (``copy``, ``fusion``, ``collective-permute``)."""
         totals = {}
         for events in self.device.values():
             for name, _, dur, module in events:
@@ -281,7 +287,7 @@ class ProgramSpans:
                                   .split(" ")[0].lstrip("%"))
                 label = module + ":" + what
                 totals[label] = totals.get(label, 0) + dur
-        return totals
+        return {k: v / len(self.device) for k, v in totals.items()}
 
 
 def _largest_shape(hlo_text):

@@ -43,6 +43,7 @@ class Env:
         self.seed = args.seed
         self.width = cell.config["rehearse_qubit_count" if rehearsal
                                  else "qubit_count"]
+        self.pages = cell.config.get("pages", 1)
         params = dict(cell.config["circuit"],
                       warmup_applications=cell.traffic["warmup_applications"])
         self.plan = cell.family.Plan(self.width, params, args.seed)
@@ -57,26 +58,37 @@ class Env:
         return time.perf_counter() - T_START
 
     def make_engine(self):
+        """The engine the configuration names: its ``engine`` gives the
+        class the stack has to end in and the factory's keyword arguments."""
         from qrack_tpu import create_quantum_interface, resilience
-        from qrack_tpu.engines.tpu import QEngineTPU
         from qrack_tpu.utils.rng import QrackRandom
 
+        engine = self.cell.config["engine"]
         self.checks.require("resilience_off", not resilience._ACTIVE)
         # QrackRandom takes 32 bits; the plan, not the engine, uses the seed
         q = create_quantum_interface(
             self.cell.config["stack"], self.width,
-            rng=QrackRandom(self.seed & 0x7FFFFFFF), rand_global_phase=False)
-        self.checks.require("engine_is_QEngineTPU", type(q) is QEngineTPU,
+            rng=QrackRandom(self.seed & 0x7FFFFFFF), rand_global_phase=False,
+            **engine["kwargs"])
+        self.checks.require(f"engine_is_{engine['class']}",
+                            type(q).__name__ == engine["class"],
                             type(q).__name__)
         return q
 
     def engine_on_device(self, q):
-        planes = q._state
+        """float32 planes, divided evenly over exactly the cell's chips:
+        one page a chip where the configuration pages the ket."""
+        planes, chips = q._state, self.cell.chips
+        on = planes.devices()
+        shard = planes.sharding.shard_shape(planes.shape)
         self.checks.require(
             "planes_on_device_float32",
-            planes.devices() == {self.jax.devices()[0]}
+            on == set(self.jax.devices()[:chips])
+            and shard[-1] * chips == planes.shape[-1]
+            and getattr(q, "n_pages", 1) == self.pages == chips
             and str(planes.dtype) == "float32",
-            f"{planes.devices()} {planes.dtype}")
+            f"{on} {planes.dtype} shard {shard} of {planes.shape}, "
+            f"{getattr(q, 'n_pages', 1)} page(s)")
 
     def counters(self):
         """The program's counters, which only a traced run switches on."""
@@ -129,13 +141,22 @@ def parse(argv=None):
 
 def execute(args):
     """One run.  Returns (exit code, result line or None, checks or None)."""
-    if args.rehearse_cpu:
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        os.environ["QRACK_TPU_FUSE_KERNEL"] = "on"  # interpreter, not a speed
     import harness
     import reference
 
     cell = harness.Cell(args.workload)
+    if args.rehearse_cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["QRACK_TPU_FUSE_KERNEL"] = "on"  # interpreter, not a speed
+        # as many host devices as the cell has chips; JAX reads the flag
+        # when its backend starts, so a process that has one keeps it
+        flag = "--xla_force_host_platform_device_count="
+        flags = os.environ.get("XLA_FLAGS", "").split()
+        have = max([int(f[len(flag):]) for f in flags if f.startswith(flag)]
+                   or [1])
+        if have < cell.chips:
+            os.environ["XLA_FLAGS"] = " ".join(
+                flags + [flag + str(cell.chips)])
 
     import jax
 
@@ -147,6 +168,11 @@ def execute(args):
               file=sys.stderr)
         return 2, None, None
     rehearsal = not on_chip
+    if rehearsal and len(devices) < cell.chips:
+        print(f"benchmarks/run.py: a rehearsal of {cell.name} needs "
+              f"{cell.chips} host devices; this process's JAX started with "
+              f"{len(devices)}", file=sys.stderr)
+        return 2, None, None
     cache_dir = harness.compile_cache_dir(jax)
     peaks = harness.load_json("peaks.json")
     kind = devices[0].device_kind
@@ -187,7 +213,7 @@ def execute(args):
                 compare_seconds_before_window=env.checks.untimed_seconds,
                 total_seconds=env.since_start(), barrier=result["barrier"])
     context = dict(result, cell=cell, peaks=peaks.get(kind), width=env.width,
-                   rehearsal=rehearsal)
+                   pages=env.pages, rehearsal=rehearsal)
     device = harness.device_dict(jax)
     if not env.trace:
         metrics = harness.read_metrics("end_to_end", cell, context)
@@ -196,6 +222,11 @@ def execute(args):
 
         trace = tracing.load(env.trace_dir) if not rehearsal else None
         context.update(trace=trace)
+        # a reader's time is one chip's: the planes' sum over their number
+        env.checks.require(
+            "device_planes_in_trace_equal_chips",
+            rehearsal or trace.chips == cell.chips,
+            "rehearsal" if rehearsal else sorted(trace.devices))
         env.checks.require(
             "no_cpu_backend_fallback", rehearsal or
             "fuse.kernel.fallback.cpu_backend" not in env.counters())

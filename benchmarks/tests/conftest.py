@@ -7,6 +7,11 @@ import os
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a cell on four chips rehearses on four host devices, and JAX takes the
+# flag when its backend starts: before any test of this directory
+os.environ["XLA_FLAGS"] = " ".join(
+    [os.environ.get("XLA_FLAGS", ""),
+     "--xla_force_host_platform_device_count=4"]).strip()
 
 TESTS = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(TESTS)

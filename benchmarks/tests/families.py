@@ -5,10 +5,11 @@ import os
 
 import harness
 
-PARAMS, LIMITS = {}, {}
+PARAMS, LIMITS, CONFIGS = {}, {}, {}
 for _name in sorted(os.listdir(os.path.join(harness.HERE, "configs"))):
     with open(os.path.join(harness.HERE, "configs", _name)) as _f:
         _cfg = json.load(_f)
+    CONFIGS[_cfg["name"]] = _cfg
     PARAMS[_cfg["family"]] = dict(_cfg["circuit"], warmup_applications=1)
     LIMITS[_cfg["family"]] = _cfg["limits"]
 FAMILIES = sorted(PARAMS)

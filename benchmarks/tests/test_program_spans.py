@@ -17,7 +17,8 @@ from conftest import ROOT, TESTS
 W = 28
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     _PER_LAYER = json.load(_f)["per_layer"]
-NEW = [m["name"] for m in _PER_LAYER[9:]]  # the nine of PR 27 come first
+CELL = "tfim_w28.library"
+NEW = [m["name"] for m in _PER_LAYER if CELL in m.get("workloads", ())]
 PARTS = ("qrack.fuse.lower", "qrack.fuse.operands", "qrack.fuse.dispatch")
 # one Trotter step at w28 (tests/test_structure.py): 7 windows, 6 of them
 # through the kernel in 28 sweeps, 24 of those cross-tile; 109 gates
@@ -45,6 +46,7 @@ def ctx(recorded, spans):
                   if s[0].startswith("bench.")]})
     return {
         "trace": trace, "program_spans": spans, "attempted": n, "width": W,
+        "pages": 1,
         "peaks": harness.load_json("peaks.json")["TPU v5 lite"],
         "window_counters": {"fuse.kernel.sweeps": LAUNCHES * n,
                             "fuse.kernel.sweeps.cross": CROSS * n,
@@ -154,7 +156,7 @@ def test_a_trace_without_the_programs_names_reads_as_nothing():
     ctx = {"trace": tracing.Trace.from_events(old), "attempted": 1,
            "width": W, "window_counters": {"fuse.kernel.sweeps": 28},
            "host_spans": {}}
-    for metric in NEW:
+    for metric in (m["name"] for m in _PER_LAYER if "workloads" in m):
         assert _read(metric, ctx) is None, metric
     spans = program_spans.ProgramSpans(
         {}, [("bench.window", 0, 100, "t"), ("bench.gate_calls", 10, 50, "t")])

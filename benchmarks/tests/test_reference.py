@@ -96,5 +96,5 @@ def test_free_fermion_bond_correlations_are_the_reference(width, steps):
             for j in range(width - 1)]
     assert np.max(np.abs(np.array(fam.bond_zz(width, p, x, steps)) - want)) < 1e-12
     planes = np.stack([state.real, state.imag]).astype(np.float32)
-    got = fam.measured_bond_zz(planes, width)
+    got = fam.measured_bond_zz(planes, list(range(width)))
     assert np.max(np.abs(np.array(got) - want)) < 1e-5

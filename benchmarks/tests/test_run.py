@@ -44,11 +44,21 @@ def test_a_sound_rehearsal_passes_every_comparison(run, cell, trace):
     assert checks.correct, checks.failures
 
 
-def _drop_a_window(monkeypatch):
-    """A flush that returns the ket unchanged, once in a while."""
-    from qrack_tpu.engines.tpu import QEngineTPU
+ENGINES = {"QEngineTPU": "qrack_tpu.engines.tpu",
+           "QPager": "qrack_tpu.parallel.pager"}
 
-    real, calls = QEngineTPU._fuse_flush, [0]
+
+def _engine_class(cell):
+    """The class the cell's configuration names: what the window drives."""
+    import harness
+
+    name = harness.Cell(cell).config["engine"]["class"]
+    return getattr(importlib.import_module(ENGINES[name]), name)
+
+
+def _drop_a_window(monkeypatch, engine):
+    """A flush that returns the ket unchanged, once in a while."""
+    real, calls = engine._fuse_flush, [0]
 
     def flush(self, gates):
         calls[0] += 1
@@ -56,22 +66,20 @@ def _drop_a_window(monkeypatch):
             return 1  # claims a dispatch, applies nothing
         return real(self, gates)
 
-    monkeypatch.setattr(QEngineTPU, "_fuse_flush", flush)
+    monkeypatch.setattr(engine, "_fuse_flush", flush)
 
 
-def _alter_a_read(monkeypatch):
+def _alter_a_read(monkeypatch, engine):
     """An answer altered where it is produced."""
-    from qrack_tpu.engines.tpu import QEngineTPU
-
-    real = QEngineTPU.GetAmplitude
-    monkeypatch.setattr(QEngineTPU, "GetAmplitude",
+    real = engine.GetAmplitude
+    monkeypatch.setattr(engine, "GetAmplitude",
                         lambda self, perm: real(self, perm) * (1 + 1e-3))
 
 
 @pytest.mark.parametrize("cell", _cells())
 @pytest.mark.parametrize("break_it", [_drop_a_window, _alter_a_read])
 def test_a_broken_timed_path_is_not_correct(run, cell, break_it, monkeypatch):
-    break_it(monkeypatch)
+    break_it(monkeypatch, _engine_class(cell))
     code, line, checks = run.execute(_args(cell))
     assert not checks.correct
     assert line["correct"] is False

@@ -60,6 +60,18 @@ def short_name(name, limit=120):
     return f"{m.group(1)} {op}({','.join(shapes)})"[:limit]
 
 
+def result_bytes(name):
+    """Bytes of the first array in the result of an instruction's HLO
+    text: ``%x = (f32[2,8]{...}, ...) op(...)`` gives 64."""
+    m = re.search(r"=\s*\(?\s*(pred|bf16|[sfu]\d+)\[([\d,]*)\]", name)
+    if not m:
+        return 0
+    size = {"pred": 1, "bf16": 2}.get(m.group(1)) or int(m.group(1)[1:]) // 8
+    for d in filter(None, m.group(2).split(",")):
+        size *= int(d)
+    return size
+
+
 def _union_ns(intervals):
     """Total length of the union of (start, end) intervals."""
     total, cur_s, cur_e = 0, None, None
@@ -124,6 +136,25 @@ class Trace:
                     for events in self.device_events().values()]
         return sum(per_chip) / len(per_chip) if per_chip else 0.0
 
+    # -- one chip's share ----------------------------------------------------
+    # Every time a reader reports is what *one chip* spends: taken over
+    # every device plane and averaged over them, as ``busy_s`` is.  With
+    # one plane that is the plane's own sum.
+
+    @property
+    def chips(self):
+        """Device planes that hold an operation: the chips that worked."""
+        return len(self.devices)
+
+    def chip_ns(self, events):
+        """Summed duration of ``events`` (of all planes), a chip."""
+        return sum(d for _, _, d in events) / self.chips
+
+    def chip_count(self, events):
+        """How many of ``events`` (of all planes) one chip ran."""
+        n, rest = divmod(len(events), self.chips)
+        return n if not rest else len(events) / self.chips
+
     # -- kernels -----------------------------------------------------------
 
     def is_kernel(self, kernel, name):
@@ -138,6 +169,45 @@ class Trace:
         return [e for events in self.device_events().values() for e in events
                 if not any(self.is_kernel(k, e[0]) for k in self.kernels)]
 
+    # -- transfers between chips ----------------------------------------------
+
+    def transfers(self, kernel):
+        """Per device plane, the transfers of the collective ``kernel``
+        inside the window: ``(begin_ns, end_ns, sent_bytes)``.  An
+        operation split into ``-start`` and ``-done`` is one transfer,
+        from the start's begin to the done's end, whatever ran between
+        them; the bytes are the first array of the start's (or the whole
+        operation's) result: what this chip sends."""
+        out = {}
+        for plane, events in self.device_events().items():
+            found, opened = [], []
+            for name, start, dur in events:
+                if not self.is_kernel(kernel, name):
+                    continue
+                if re.search(r"-done\(", name):
+                    begin, sent = opened.pop() if opened else (start, 0)
+                    found.append((begin, start + dur, sent))
+                elif re.search(r"-start\(", name):
+                    opened.append((start, result_bytes(name)))
+                else:
+                    found.append((start, start + dur, result_bytes(name)))
+            out[plane] = found
+        return out
+
+    def exposed_ns(self, kernel):
+        """Per device plane ``(in flight, exposed)``: the time in which a
+        transfer of ``kernel`` was under way, and the part of it in which
+        no other operation ran on that chip."""
+        out, events = {}, self.device_events()
+        for plane, found in self.transfers(kernel).items():
+            flight = [(b, e) for b, e, _ in found]
+            others = [(s, s + d) for n, s, d in events[plane]
+                      if not self.is_kernel(kernel, n)]
+            covered = _union_ns(flight)
+            both = covered + _union_ns(others) - _union_ns(flight + others)
+            out[plane] = (covered, covered - both)
+        return out
+
     # -- the breakdown the ledger keeps -----------------------------------------
 
     def top_ops(self, limit=10):
@@ -151,24 +221,23 @@ class Trace:
 
     def idle_gaps(self, limit=10, labels=("set_permutation", "gate_calls",
                                          "completion_read")):
-        """The device's idle time inside the window, by the benchmark's
-        own host span that covers the middle of each gap (``between``
-        where none does).  First device plane."""
-        if not self.devices:
-            return []
-        events = next(iter(self.device_events().values()))
+        """A chip's idle time inside the window, by the benchmark's own
+        host span that covers the middle of each gap (``between`` where
+        none does): every device plane's gaps, averaged over the planes."""
         start, end = self.window()
         spans = [s for s in self.spans if s[0] in labels]
-        totals, cursor = {}, start
-        for _, s, d in events + [("", end, 0)]:
-            if s > cursor:
-                mid = (cursor + s) // 2
-                label = next((n for n, ss, sd in spans if ss <= mid < ss + sd),
-                             "between")
-                totals[label] = totals.get(label, 0) + (s - cursor)
-            cursor = max(cursor, s + d)
+        totals = {}
+        for events in self.device_events().values():
+            cursor = start
+            for _, s, d in events + [("", end, 0)]:
+                if s > cursor:
+                    mid = (cursor + s) // 2
+                    label = next((n for n, ss, sd in spans
+                                  if ss <= mid < ss + sd), "between")
+                    totals[label] = totals.get(label, 0) + (s - cursor)
+                cursor = max(cursor, s + d)
         top = sorted(totals.items(), key=lambda kv: -kv[1])[:limit]
-        return [[name, ns / 1e9] for name, ns in top]
+        return [[name, ns / self.chips / 1e9] for name, ns in top]
 
 
 def load(path, kernels=None):
