@@ -99,9 +99,10 @@ def _qft_form(width: int) -> str:
 def _make_fused_qft_fn(width: int, dtype):
     """The gate-stream fuser's own window program over the whole QFT:
     qft_qcircuit -> neighbor-merged ops -> ONE structure-keyed compiled
-    program taking every rotation as a runtime operand (constant-free;
-    qrack_tpu/ops/fusion.py).  This is literally what the engine fuser
-    dispatches, so its wall-clock is the fused-path headline.
+    program taking every rotation at run time, in the two packed
+    operand columns (constant-free; qrack_tpu/ops/fusion.py).  This is
+    literally what the engine fuser dispatches, so its wall-clock is
+    the fused-path headline.
 
     The lowering mirrors the engine flush: the cost model picks the
     single-sweep Pallas kernel or the XLA op chain per
@@ -125,10 +126,10 @@ def _make_fused_qft_fn(width: int, dtype):
         prog = fu.dense_window_program(width, structure, dtype)
         sweeps = len(ops)
         lowering = "xla_chain"
-    operands = fu.dense_operands(ops, dtype)
+    iv, fv = fu.pack_operands(ops, dtype)
 
     def fn(planes):
-        return prog(planes, *operands)
+        return prog(planes, iv, fv)
 
     fn.already_compiled = True  # _measure must not re-wrap in jax.jit
     fn.window_ops = len(ops)

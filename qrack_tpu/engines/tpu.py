@@ -408,13 +408,15 @@ class QEngineTPU(QEngine):
             return 0
         with _tele.span("fuse.operands"):
             if len(ops) > 1:
-                operands = fu.dense_operands(ops, self.dtype)
+                # two host columns, whatever the window holds: the
+                # dispatch puts them on the device with the program
+                operands = fu.pack_operands(ops, self.dtype)
             else:
                 prog, operands = self._one_op_program(ops[0])
         with _tele.span("fuse.dispatch"):
             self._state = prog(self._owned_state(), *operands)
         if _tele._ENABLED:
-            # a window issues one put per operand and its own program
+            # a window issues one put per operand column and its program
             _tele.inc(f"fuse.{self._tele_name}.programs",
                       len(operands) + 1 if len(ops) > 1 else 1)
         if len(ops) == 1:

@@ -882,7 +882,7 @@ class QEngineTurboQuant(QEngineTPU):
             # single-pass per VMEM tile: masks split at the tile
             # boundary, whole window in-register between dequant/requant
             tp = self._pallas_tile_pow()
-            operands = fu.sharded_operands(ops, tp, jnp.float32)
+            operands = fu.per_op_operands(ops, jnp.float32, split_at=tp)
             self._note_transient(1)
             self._note_window(len(ops))
             prog = self._p_pallas_window(structure, tp)
@@ -891,8 +891,8 @@ class QEngineTurboQuant(QEngineTPU):
                 *operands)
             self._note_resident()
             return 1
-        operands = fu.sharded_operands(ops, self._tq_chunk_pow,
-                                       jnp.float32)
+        operands = fu.per_op_operands(ops, jnp.float32,
+                                      split_at=self._tq_chunk_pow)
         self._note_transient(1)
         self._note_window(len(ops))
         prog = self._p_fuse_window(structure)

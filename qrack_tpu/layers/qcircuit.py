@@ -228,7 +228,7 @@ class QCircuit:
                 # _owned_state: the window program donates its input —
                 # never hand it a plane ref the prefix cache holds
                 qsim._state = prog(qsim._owned_state(),
-                                   *fu.dense_operands(ops, qsim.dtype))
+                                   *fu.pack_operands(ops, qsim.dtype))
                 return
             ops = fu.lower_gates(self.gates)
             if not ops:
@@ -236,7 +236,7 @@ class QCircuit:
             prog = fu.dense_window_program(n, fu.structure_of(ops),
                                            qsim.dtype)
             qsim._state = prog(qsim._owned_state(),
-                               *fu.dense_operands(ops, qsim.dtype))
+                               *fu.pack_operands(ops, qsim.dtype))
             return
         if isinstance(qsim, QPager) and self.gates:
             n = qsim.qubit_count
@@ -453,7 +453,7 @@ class QCircuit:
                                 interpret=interpret)
 
         def fn(planes):
-            return wfn(planes, *fu.dense_operands(ops, planes.dtype))
+            return wfn(planes, *fu.pack_operands(ops, planes.dtype))
 
         fn.sweeps = wfn.sweeps
         return fn

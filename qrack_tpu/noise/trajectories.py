@@ -256,7 +256,7 @@ def _sample_operands(ops: Sequence[object], key: int,
                     np.asarray([op.ch.priors[i] for i in idxs]),
                     dtype=jnp.float32))
             continue
-        single = fu.dense_operands([op], dtype)
+        single = fu.per_op_operands([op], dtype)
         for arr in single:
             out.append(jnp.broadcast_to(arr, (B,) + arr.shape))
     return out

@@ -32,18 +32,28 @@ the *default* execution mode of the dense engines:
   through the guarded site ``tpu.fuse.flush`` (watchdog / retry /
   breaker / fault injection — docs/RESILIENCE.md).
 
-Operand layout (per op, in window order):
+Operand layout.  A window's operands are two host (numpy) columns,
+packed by :func:`pack_operands` at the offsets of
+``pallas_kernels._operand_slots`` and handed to the window program as
+its two arguments after the planes; every window body reads its scalars
+from them by static offset (:func:`operand_views`).  Per op, in window
+order:
 
-  kind      payload operand                      extra (iff controlled)
-  cphase    (2,)  [d1.re, d1.im]                 cmask:int32, cval:int32
-  diag      (2,2) [[d0.re,d0.im],[d1.re,d1.im]]  cmask:int32, cval:int32
-  inv       (2,2) [[tr.re,tr.im],[bl.re,bl.im]]  cmask:int32, cval:int32
-  gen       (2,2,2) mtrx_planes                  cmask:int32, cval:int32
+  fv (F, 1), the planes' dtype          iv (I, 1) int32, iff controlled
+  cphase  2  [d1.re, d1.im]             dense    2  [cmask, cval]
+  diag    4  [d0.re,d0.im,d1.re,d1.im]  sharded  cphase 2: the combined
+  inv     4  [tr.re,tr.im,bl.re,bl.im]           mask's (local, page)
+  gen     8  mtrx_planes (2,2,2),                halves; diag/gen 4:
+             row-major                           split_masks' four
+
+(``iv`` keeps one dead slot where no op is controlled).  The values are
+rounded to the planes' dtype by numpy, on the host, once; nothing is
+put on the device per op.
 
 "cphase" is the measured hot case (controlled phase with d0 == 1 and
 positive controls — all 231 QFT phases): the factor select collapses to
 one combined-mask test, (idx & (tmask|cmask)) == (tmask|cmask).
-Uncontrolled ops pass NO mask operands, so apply_2x2/apply_invert keep
+Uncontrolled ops hold NO mask slots, so apply_2x2/apply_invert keep
 their static cmask==0 short-circuit inside the trace.
 """
 
@@ -148,29 +158,106 @@ def structure_of(ops: Sequence[FusedOp]) -> Tuple:
 
 
 # ---------------------------------------------------------------------------
+# a window's operands: two packed host columns (module docstring)
+# ---------------------------------------------------------------------------
+
+_PAYLOAD_SHAPE = {"cphase": (2,), "diag": (2, 2), "inv": (2, 2),
+                  "gen": (2, 2, 2)}
+
+
+def _payload(kind: str, m) -> tuple:
+    """An op's floats, in the order of its slot."""
+    if kind == "cphase":
+        return (m[1, 1].real, m[1, 1].imag)
+    if kind == "diag":
+        return (m[0, 0].real, m[0, 0].imag, m[1, 1].real, m[1, 1].imag)
+    if kind == "inv":
+        return (m[0, 1].real, m[0, 1].imag, m[1, 0].real, m[1, 0].imag)
+    return (*m.real.ravel(), *m.imag.ravel())  # mtrx_planes, row-major
+
+
+def pack_operands(ops: Sequence[FusedOp], dtype, split_at: int = None):
+    """``(iv, fv)``: the window's int32 masks and its float payloads in
+    the planes' ``dtype``, numpy columns laid out by
+    ``pallas_kernels._operand_slots``.  ``split_at`` gives the sharded
+    layout: masks split at that many local bits, 'inv' folded into
+    'gen' (sharded_structure_of).  Host work only."""
+    from . import pallas_kernels as pk
+    from .sharded import split_masks
+
+    split = split_at is not None
+    structure = sharded_structure_of(ops) if split else structure_of(ops)
+    slots, nf, ni = pk._operand_slots(structure, split)
+    floats = [0.0] * nf
+    ints = [0] * ni
+    for op, (kind, _, _), (f, i) in zip(ops, structure, slots):
+        vals = _payload(kind, np.asarray(op.m))
+        floats[f:f + len(vals)] = vals
+        if not op.cmask:
+            continue
+        if not split:
+            masks = (op.cmask, op.cval)
+        elif kind == "cphase":
+            comb = (1 << op.target) | op.cmask
+            masks = (comb & ((1 << split_at) - 1), comb >> split_at)
+        else:
+            masks = split_masks(op.cmask, op.cval, split_at)
+        ints[i:i + len(masks)] = masks
+    # from Python ints: a mask past int32 raises, as it always did
+    iv = np.array(ints, dtype=np.int32).reshape(-1, 1)
+    fv = np.array(floats, dtype=np.float64).astype(dtype).reshape(-1, 1)
+    return iv, fv
+
+
+def operand_views(structure: Tuple, iv, fv, split: bool = False) -> List:
+    """Per op ``(payload, masks)`` cut from the packed columns by static
+    offset: the payload in its shape ((2,), (2, 2) or (2, 2, 2)), the
+    masks a tuple of scalars, empty where the op is uncontrolled.
+    Columns traced (inside a window body) or numpy (on the host)."""
+    from . import pallas_kernels as pk
+
+    slots, _, _ = pk._operand_slots(structure, split)
+    out: List = []
+    for (kind, _, has_ctrl), (f, i) in zip(structure, slots):
+        p = fv[f:f + pk._NFLOATS[kind], 0].reshape(_PAYLOAD_SHAPE[kind])
+        out.append((p, tuple(iv[i + j, 0] for j in range(
+            pk._nints(kind, has_ctrl, split)))))
+    return out
+
+
+def per_op_operands(ops: Sequence[FusedOp], dtype,
+                    split_at: int = None) -> List:
+    """The packed columns as one host array per payload and per mask, in
+    window order: for the bodies that take an operand per op (the
+    vmapped trajectory window, the compressed engine's window kernels).
+    Views of :func:`pack_operands`' arrays, not a second encoding."""
+    split = split_at is not None
+    structure = sharded_structure_of(ops) if split else structure_of(ops)
+    out: List = []
+    for p, masks in operand_views(structure,
+                                  *pack_operands(ops, dtype, split_at),
+                                  split=split):
+        out.append(p)
+        out.extend(masks)
+    return out
+
+
+# ---------------------------------------------------------------------------
 # dense (single-shard) parametric window program
 # ---------------------------------------------------------------------------
 
 def window_fn(n: int, structure: Tuple):
-    """Traced body: fn(planes, *operands) applying the window in order.
+    """Traced body: fn(planes, iv, fv) applying the window in order.
     Pure and jit-safe; operand layout per module docstring."""
 
     # the function's name is the compiled module's (jit_qrack_xla_window):
     # what a device trace knows this program by when locations carry no
     # name stack; the scope is what it knows its operations by when they do
-    def qrack_xla_window(planes, *operands):
+    def qrack_xla_window(planes, iv, fv):
         with jax.named_scope("qrack.fuse.xla_window"):
-            i = 0
-            for kind, target, has_ctrl in structure:
-                p = operands[i]
-                i += 1
-                if has_ctrl:
-                    cm = operands[i]
-                    cv = operands[i + 1]
-                    i += 2
-                else:
-                    cm = 0
-                    cv = 0
+            views = operand_views(structure, iv, fv)
+            for (kind, target, has_ctrl), (p, masks) in zip(structure, views):
+                cm, cv = masks if has_ctrl else (0, 0)
                 if kind == "cphase":
                     comb = ((1 << target) | cm) if has_ctrl else (1 << target)
                     hit = (gk.iota_for(planes) & comb) == comb
@@ -191,28 +278,6 @@ def window_fn(n: int, structure: Tuple):
     return qrack_xla_window
 
 
-def dense_operands(ops: Sequence[FusedOp], dtype) -> List:
-    out: List = []
-    for op in ops:
-        m = np.asarray(op.m)
-        if op.kind == "cphase":
-            out.append(jnp.asarray([m[1, 1].real, m[1, 1].imag], dtype=dtype))
-        elif op.kind == "diag":
-            out.append(jnp.asarray(
-                [[m[0, 0].real, m[0, 0].imag], [m[1, 1].real, m[1, 1].imag]],
-                dtype=dtype))
-        elif op.kind == "inv":
-            out.append(jnp.asarray(
-                [[m[0, 1].real, m[0, 1].imag], [m[1, 0].real, m[1, 0].imag]],
-                dtype=dtype))
-        else:
-            out.append(gk.mtrx_planes(m, dtype))
-        if op.cmask:
-            out.append(jnp.asarray(op.cmask, dtype=jnp.int32))
-            out.append(jnp.asarray(op.cval, dtype=jnp.int32))
-    return out
-
-
 def timed_build(build):
     """A window program's builder under the span ``fuse.build``: what a
     ``ProgramCache`` miss costs inside ``fuse.lower`` (planning and
@@ -225,8 +290,8 @@ def timed_build(build):
 
 def dense_window_program(n: int, structure: Tuple, dtype):
     """One guarded jitted program per (width, dtype, structure) — payload
-    values ride the operand vector, so every same-structure window is a
-    compile.fuse hit."""
+    values ride the two operand columns, so every same-structure window
+    is a compile.fuse hit."""
     key = ("dense", n, str(jnp.dtype(dtype)), structure)
 
     def build():
@@ -578,28 +643,26 @@ def sharded_structure_of(ops: Sequence[FusedOp]) -> Tuple:
 
 def sharded_window_body(L: int, npg: int, structure: Tuple, remap=(),
                         batched: bool = True):
-    """Per-shard traced body fn(local, *operands) for one window.  Masks
-    arrive pre-split host-side into (local, page) int32 halves — same
-    exact-past-int32 discipline as the eager pager kernels: cphase takes
-    2 combined-mask scalars, diag/gen take 4 split-mask scalars, and
-    uncontrolled ops take none (their masks stay static in the trace).
+    """Per-shard traced body fn(local, iv, fv) for one window, on the
+    sharded layout of :func:`pack_operands`.  Masks arrive pre-split
+    host-side into (local, page) int32 halves — same exact-past-int32
+    discipline as the eager pager kernels: cphase holds 2 combined-mask
+    scalars, diag/gen hold 4 split-mask scalars, and uncontrolled ops
+    hold none (their masks stay static in the trace).
     ``remap`` is the planner's physical-transposition prologue — applied
     before the ops, inside the same program."""
     from . import sharded as shb
 
     lbits = (1 << L) - 1
 
-    def qrack_sharded_xla_window(local, *operands):  # the module's name
+    def qrack_sharded_xla_window(local, iv, fv):  # the module's name
         if remap:
             local = shb.apply_remap(local, npg, L, remap, batched=batched)
-        i = 0
-        for kind, target, has_ctrl in structure:
-            p = operands[i]
-            i += 1
+        views = operand_views(structure, iv, fv, split=True)
+        for (kind, target, has_ctrl), (p, masks) in zip(structure, views):
             if kind == "cphase":
                 if has_ctrl:
-                    clo, chi = operands[i], operands[i + 1]
-                    i += 2
+                    clo, chi = masks
                 else:
                     comb = 1 << target
                     clo, chi = comb & lbits, comb >> L
@@ -610,11 +673,7 @@ def sharded_window_body(L: int, npg: int, structure: Tuple, remap=(),
                 local = gk.cmul(jnp.where(hit, p[0], one),
                                 jnp.where(hit, p[1], zero), local)
                 continue
-            if has_ctrl:
-                lm, lv, gm, gv = operands[i:i + 4]
-                i += 4
-            else:
-                lm = lv = gm = gv = 0
+            lm, lv, gm, gv = masks if has_ctrl else (0, 0, 0, 0)
             if kind == "diag":
                 tmask = 1 << target
                 local = shb.apply_diag(local, p[0, 0], p[0, 1], p[1, 0],
@@ -629,32 +688,6 @@ def sharded_window_body(L: int, npg: int, structure: Tuple, remap=(),
         return local
 
     return qrack_sharded_xla_window
-
-
-def sharded_operands(ops: Sequence[FusedOp], L: int, dtype) -> List:
-    from .sharded import split_masks
-
-    out: List = []
-    for op in ops:
-        m = np.asarray(op.m)
-        kind = "gen" if op.kind == "inv" else op.kind
-        if kind == "cphase":
-            out.append(jnp.asarray([m[1, 1].real, m[1, 1].imag], dtype=dtype))
-            if op.cmask:
-                comb = (1 << op.target) | op.cmask
-                out.append(jnp.asarray(comb & ((1 << L) - 1), dtype=jnp.int32))
-                out.append(jnp.asarray(comb >> L, dtype=jnp.int32))
-            continue
-        if kind == "diag":
-            out.append(jnp.asarray(
-                [[m[0, 0].real, m[0, 0].imag], [m[1, 1].real, m[1, 1].imag]],
-                dtype=dtype))
-        else:
-            out.append(gk.mtrx_planes(m, dtype))
-        if op.cmask:
-            out.extend(jnp.asarray(v, dtype=jnp.int32)
-                       for v in split_masks(op.cmask, op.cval, L))
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -698,22 +731,25 @@ def _sharded_run_structure(run, L: int) -> Tuple:
     return tuple(out)
 
 
-def _sharded_run_operands(run, L: int, operands, offs, pid, dtype):
-    """Traced per-shard dense-layout operands for one local run: local
-    masks pass through, page-level tests collapse into the payload
-    (identity payload when this page misses the page-mask)."""
+def _sharded_run_operands(run, L: int, views, pid, dtype):
+    """Traced per-shard ``(iv, fv)`` of one local run, in the dense
+    layout of its kernel (_sharded_run_structure: every op controlled):
+    local masks pass through, page-level tests collapse into the payload
+    (identity payload when this page misses the page-mask).  This
+    rewrite depends on ``page_id`` and so stays inside the program; the
+    window's own columns (``views``) came packed from the host."""
     lbits = (1 << L) - 1
     one = jnp.ones((), dtype)
     zero = jnp.zeros((), dtype)
     ident_planes = jnp.asarray(
         [[[1.0, 0.0], [0.0, 1.0]], [[0.0, 0.0], [0.0, 0.0]]], dtype)
-    out: List = []
+    payloads: List = []
+    ints: List = []
     for (idx, kind, target, has_ctrl) in run:
-        p = operands[offs[idx]]
+        p, masks = views[idx]
         if kind == "cphase":
             if has_ctrl:
-                clo = operands[offs[idx] + 1]
-                chi = operands[offs[idx] + 2]
+                clo, chi = masks
             else:
                 comb = 1 << target
                 clo = jnp.int32(comb & lbits)
@@ -722,54 +758,36 @@ def _sharded_run_operands(run, L: int, operands, offs, pid, dtype):
             fre = jnp.where(page_ok, p[0], one)
             fim = jnp.where(page_ok, p[1], zero)
             if target < L:
-                out.append(jnp.stack([fre, fim]))
+                payloads.append(jnp.stack([fre, fim]))
                 cm = clo & jnp.int32(~(1 << target) & lbits)
             else:
                 d = jnp.stack([fre, fim])
-                out.append(jnp.stack([d, d]))
+                payloads.append(jnp.stack([d, d]))
                 cm = clo
-            out.extend([jnp.asarray(cm, jnp.int32),
-                        jnp.asarray(cm, jnp.int32)])
+            ints += [cm, cm]
             continue
         if has_ctrl:
-            lm, lv, gm, gv = operands[offs[idx] + 1:offs[idx] + 5]
+            lm, lv, gm, gv = masks
         else:
             lm = lv = gm = gv = jnp.int32(0)
         page_ok = (pid & gm) == gv
         if kind == "diag":
             if target < L:
                 ident = jnp.asarray([[1.0, 0.0], [1.0, 0.0]], dtype)
-                out.append(jnp.where(page_ok, p, ident))
+                payloads.append(jnp.where(page_ok, p, ident))
             else:
                 tb = (pid & jnp.int32((1 << target) >> L)) != 0
                 d = jnp.where(tb, p[1], p[0])
                 dre = jnp.where(page_ok, d[0], one)
                 dim = jnp.where(page_ok, d[1], zero)
                 d = jnp.stack([dre, dim])
-                out.append(jnp.stack([d, d]))
+                payloads.append(jnp.stack([d, d]))
         else:  # gen, target < L (globals were split out)
-            out.append(jnp.where(page_ok, p, ident_planes))
-        out.extend([jnp.asarray(lm, jnp.int32), jnp.asarray(lv, jnp.int32)])
-    return out
-
-
-def _sharded_nargs(kind: str, has_ctrl: bool) -> int:
-    """Operands one op takes in the sharded layout (sharded_operands)."""
-    return 1 + ((2 if kind == "cphase" else 4) if has_ctrl else 0)
-
-
-def sharded_operand_count(structure: Tuple) -> int:
-    return sum(_sharded_nargs(kind, has_ctrl)
-               for kind, _, has_ctrl in structure)
-
-
-def _sharded_offs(structure: Tuple) -> List[int]:
-    offs: List[int] = []
-    o = 0
-    for kind, target, has_ctrl in structure:
-        offs.append(o)
-        o += _sharded_nargs(kind, has_ctrl)
-    return offs
+            payloads.append(jnp.where(page_ok, p, ident_planes))
+        ints += [lm, lv]
+    iv = jnp.stack([jnp.asarray(x, jnp.int32) for x in ints])
+    fv = jnp.concatenate([q.reshape(-1) for q in payloads])
+    return iv.reshape(-1, 1), fv.reshape(-1, 1)
 
 
 def sharded_kernel_counts(structure: Tuple, L: int,
@@ -823,7 +841,7 @@ def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
                                block_pow: int = None,
                                interpret: bool = False, remap=(),
                                batched: bool = True):
-    """Per-shard traced body fn(local, *operands) — SAME sharded operand
+    """Per-shard traced body fn(local, iv, fv) — SAME sharded operand
     layout as :func:`sharded_window_body`, kernel-lowered local runs,
     with the optional remap prologue ahead of the first segment."""
     from . import pallas_kernels as pk
@@ -831,29 +849,25 @@ def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
 
     bp = min(pk.DEFAULT_BLOCK_POW, L) if block_pow is None else block_pow
     segments = _sharded_segments(structure, L)
-    offs = _sharded_offs(structure)
     runs = {id(seg): pk.make_window_fn(L, _sharded_run_structure(seg[1], L),
                                        block_pow=bp, interpret=interpret)
             for seg in segments if seg[0] == "run"}
 
-    def qrack_sharded_kernel_window(local, *operands):  # the module's name
+    def qrack_sharded_kernel_window(local, iv, fv):  # the module's name
         if remap:
             local = shb.apply_remap(local, npg, L, remap, batched=batched)
         pid = shb.page_id()
+        views = operand_views(structure, iv, fv, split=True)
         for seg in segments:
             if seg[0] == "global":
                 idx, target, has_ctrl = seg[1]
-                p = operands[offs[idx]]
-                if has_ctrl:
-                    lm, lv, gm, gv = operands[offs[idx] + 1:offs[idx] + 5]
-                else:
-                    lm = lv = gm = gv = 0
+                p, masks = views[idx]
+                lm, lv, gm, gv = masks if has_ctrl else (0, 0, 0, 0)
                 local = shb.apply_global_2x2(local, p, npg, target - L,
                                              lm, lv, gm, gv)
             else:
-                dops = _sharded_run_operands(seg[1], L, operands, offs,
-                                             pid, local.dtype)
-                local = runs[id(seg)](local, *dops)
+                local = runs[id(seg)](local, *_sharded_run_operands(
+                    seg[1], L, views, pid, local.dtype))
         return local
 
     return qrack_sharded_kernel_window

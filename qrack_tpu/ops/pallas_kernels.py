@@ -4,12 +4,12 @@ A fused gate window (ops/fusion.py) lowers here to ONE Pallas sweep per
 *segment*: the ket streams through VMEM tile by tile and every in-tile
 window op is applied while the tile is resident, instead of the one
 full HBM read+write per gate the XLA op-chain pays.  Matrices, control
-masks and phase operands enter as RUNTIME arguments in exactly the
-dense operand layout of fusion.window_fn — the compiled program is
-keyed by the window's *structure* tuple alone, so same-structure
-windows with different rotation angles never retrace (the property the
-XLA window path already had; the old baked-constant segment kernel did
-not).
+masks and phase operands enter as RUNTIME arguments, the two packed
+columns of fusion.pack_operands that fusion.window_fn reads too — the
+compiled program is keyed by the window's *structure* tuple alone, so
+same-structure windows with different rotation angles never retrace
+(the property the XLA window path already had; the old baked-constant
+segment kernel did not).
 
 Vocabulary (everything the fuser emits):
 
@@ -48,11 +48,12 @@ tile, the one-axis case of the same tile_* functions.  Telemetry counts
 the sweeps that computed dense as ``fuse.kernel.sweeps.dense``.
 
 Scalar operands ride in two packed SMEM refs (floats and int32 masks),
-a (K, 1) column each — TPU SMEM wants 2-D refs.  ``interpret=True``
-runs the same kernel under the Pallas interpreter for CPU parity
-tests; the interpreter re-materializes full buffers per grid step, so
-it is a CORRECTNESS harness, not a fast path (docs/PERFORMANCE.md,
-"interpret caveat").
+a (K, 1) column each — TPU SMEM wants 2-D refs — packed on the host
+(fusion.pack_operands) at the offsets of _operand_slots.
+``interpret=True`` runs the same kernel under the Pallas interpreter
+for CPU parity tests; the interpreter re-materializes full buffers per
+grid step, so it is a CORRECTNESS harness, not a fast path
+(docs/PERFORMANCE.md, "interpret caveat").
 """
 
 from __future__ import annotations
@@ -137,42 +138,28 @@ def plan_counts(structure: Tuple, block_pow: int) -> Tuple[int, int, int]:
             len(segs) if dense_tile(block_pow) else 0)
 
 
-def _operand_slots(structure: Tuple):
-    """Per-op (float, int) offsets into the packed scalar vectors."""
+def _nints(kind: str, has_ctrl: bool, split: bool = False) -> int:
+    """int32 slots of one op: ``[cmask, cval]`` where it is controlled;
+    with ``split`` (fusion's sharded layout, masks split at a shard
+    boundary) a cphase holds its combined mask's two halves and a
+    diag/gen the four of ``sharded.split_masks``."""
+    if not has_ctrl:
+        return 0
+    return 4 if split and kind != "cphase" else 2
+
+
+def _operand_slots(structure: Tuple, split: bool = False):
+    """``(slots, F, I)``: per-op (float, int) offsets into the packed
+    scalar columns ``fv (F, 1)`` / ``iv (I, 1)`` and the columns'
+    lengths (``_NFLOATS``, ``_nints``).  ``iv`` keeps one dead slot
+    where no op is controlled: a Pallas ref cannot be empty."""
     slots = []
     f = i = 0
     for kind, target, has_ctrl in structure:
         slots.append((f, i))
         f += _NFLOATS[kind]
-        i += 2 if has_ctrl else 0
-    return slots
-
-
-def pack_operands(structure: Tuple, operands: Sequence, dtype=jnp.float32):
-    """Flatten a dense-layout operand vector (fusion.dense_operands)
-    into the kernel's packed scalar columns: fv (F, 1) float, iv (I, 1)
-    int32.  Trace-safe — composes under jit with traced operands."""
-    fs: List = []
-    iv: List = []
-    k = 0
-    for kind, target, has_ctrl in structure:
-        p = operands[k]
-        k += 1
-        if kind == "cphase":
-            fs += [p[0], p[1]]
-        elif kind in ("diag", "inv"):
-            fs += [p[0, 0], p[0, 1], p[1, 0], p[1, 1]]
-        else:  # gen: mtrx_planes (2, 2, 2) [plane, row, col]
-            fs += [p[0, 0, 0], p[0, 0, 1], p[0, 1, 0], p[0, 1, 1],
-                   p[1, 0, 0], p[1, 0, 1], p[1, 1, 0], p[1, 1, 1]]
-        if has_ctrl:
-            iv += [operands[k], operands[k + 1]]
-            k += 2
-    fv = jnp.stack([jnp.asarray(x, dtype) for x in fs]).reshape(-1, 1)
-    if not iv:
-        iv = [jnp.int32(0)]  # pallas refs must be non-empty; dead slot
-    ivec = jnp.stack([jnp.asarray(x, jnp.int32) for x in iv])
-    return fv, ivec.reshape(-1, 1)
+        i += _nints(kind, has_ctrl, split)
+    return slots, f, max(i, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -446,23 +433,22 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
 def make_window_fn(n: int, structure: Tuple,
                    block_pow: int = DEFAULT_BLOCK_POW,
                    interpret: bool = False):
-    """The parametric window kernel: fn(planes, *operands) with the
-    dense fusion operand layout, lowering to ``fn.sweeps`` Pallas
-    sweeps (one per planned segment).  Trace it under jit exactly like
-    fusion.window_fn — fusion.kernel_window_program does, with the
-    shared structure-only cache key."""
+    """The parametric window kernel: fn(planes, iv, fv) on the packed
+    scalar columns of fusion.pack_operands (``fv`` in the planes'
+    dtype), lowering to ``fn.sweeps`` Pallas sweeps (one per planned
+    segment).  Trace it under jit exactly like fusion.window_fn —
+    fusion.kernel_window_program does, with the shared structure-only
+    cache key."""
     bp = min(block_pow, n)
     segments = plan_window(structure, bp)
-    slots = _operand_slots(structure)
+    slots, _, _ = _operand_slots(structure)
     programs = [_segment_program(n, bp, seg, slots, interpret)
                 for seg in segments]
 
     # named for the compiled module (jit_qrack_kernel_window), as
-    # fusion.window_fn's is; the scope covers pack_operands' stack and
-    # reshape too
-    def qrack_kernel_window(planes, *operands):
+    # fusion.window_fn's is; the columns go to the launches as they came
+    def qrack_kernel_window(planes, iv, fv):
         with jax.named_scope("qrack.fuse.kernel_window"):
-            fv, iv = pack_operands(structure, operands, planes.dtype)
             for run in programs:
                 planes = run(planes, iv, fv)
         return planes
@@ -494,10 +480,10 @@ def make_segment_fn(ops: Sequence[Tuple], n: int,
     structure = fu.structure_of(fused)
     wfn = make_window_fn(n, structure, block_pow=block_pow,
                          interpret=interpret)
-    operands = fu.dense_operands(fused, jnp.float32)
+    iv, fv = fu.pack_operands(fused, jnp.float32)
 
     def fn(planes):
-        return wfn(planes, *operands)
+        return wfn(planes, iv, fv)
 
     fn.sweeps = wfn.sweeps
     return fn

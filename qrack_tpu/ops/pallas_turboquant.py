@@ -189,8 +189,8 @@ def make_tq_window(n: int, block_pow: int, bits: int, structure,
     window — ONE dequant, every op, ONE requant — per VMEM tile.
 
     `structure` is fusion.sharded_structure_of's (kind, target,
-    controlled?) tuple and `operands` fusion.sharded_operands' layout
-    with the lo/hi mask split at THIS kernel's tile boundary: cphase
+    controlled?) tuple and `operands` fusion.per_op_operands' views of
+    the sharded layout, split at THIS kernel's tile boundary: cphase
     ops carry a (2,) phase payload (+2 combined-mask scalars when
     controlled), diag a (2, 2) factor table (+4 split-mask scalars),
     gen a (2, 2, 2) matrix-planes payload (+4).  Per-op tile math is
@@ -210,7 +210,7 @@ def make_tq_window(n: int, block_pow: int, bits: int, structure,
     cdt = jnp.int8 if bits <= 8 else jnp.int16
     lbits = T - 1
 
-    # operand slot layout mirroring fusion.sharded_operands: "f" slots
+    # one ref per view of fusion.per_op_operands(split_at=tile): "f" slots
     # are small float payload arrays, "i" slots int32 mask scalars
     slots = []
     for kind, _target, has_ctrl in structure:

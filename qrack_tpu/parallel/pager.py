@@ -640,8 +640,10 @@ class QPager(QEngine):
         # targets included (the pair exchange runs inside the program)
         return True
 
-    def _p_fuse_window(self, structure, n_operands: int, kernel_plan=None,
-                       remap=(), batched: bool = True):
+    def _p_fuse_window(self, structure, kernel_plan=None, remap=(),
+                       batched: bool = True):
+        """The window's shard_map program: the paged state, then the two
+        packed operand columns (fusion.pack_operands), replicated."""
         from ..ops import fusion as fu
 
         L, mesh, npg = self.local_bits, self.mesh, self.n_pages
@@ -652,7 +654,7 @@ class QPager(QEngine):
                                               batched=batched)
                 return _tele.instrument_jit("fuse.window", jax.jit(
                     jax.shard_map(body, mesh=mesh,
-                                      in_specs=_state_specs(n_operands),
+                                      in_specs=_state_specs(2),
                                       out_specs=P(None, "pages")),
                     donate_argnums=(0,)))
 
@@ -674,7 +676,7 @@ class QPager(QEngine):
             # check is safely off for this one program
             return _tele.instrument_jit("fuse.window", jax.jit(
                 jax.shard_map(body, mesh=mesh,
-                                  in_specs=_state_specs(n_operands),
+                                  in_specs=_state_specs(2),
                                   out_specs=P(None, "pages"),
                                   check_vma=False),
                 donate_argnums=(0,)))
@@ -729,16 +731,15 @@ class QPager(QEngine):
             if not one_op:
                 structure = fu.sharded_structure_of(tops)
                 plan, why = fu.sharded_kernel_lowering(L, structure)
-                prog = self._p_fuse_window(
-                    structure, fu.sharded_operand_count(structure),
-                    kernel_plan=plan, remap=swaps, batched=batched)
+                prog = self._p_fuse_window(structure, kernel_plan=plan,
+                                           remap=swaps, batched=batched)
         with _tele.span("fuse.operands"):
             if one_op:
                 prog, operands = self._one_op_program(tops[0])
             else:
-                operands = fu.sharded_operands(tops, L, self.dtype)
+                operands = fu.pack_operands(tops, self.dtype, split_at=L)
         if _tele._ENABLED:
-            # a window issues one put per operand and its own program
+            # a window issues one put per operand column and its program
             _tele.inc(f"fuse.{self._tele_name}.programs",
                       1 if one_op else len(operands) + 1)
             if not one_op:

@@ -54,8 +54,8 @@ def one_chip(topo):
 
 
 def _ops(structure):
-    """Placeholder ops of a window structure: the fuser's own operand
-    builders then give the operand shapes, so they cannot drift."""
+    """Placeholder ops of a window structure: the fuser's own packer
+    then gives the two operand columns' shapes, so they cannot drift."""
     return [fu.FusedOp(kind, target, int(has_ctrl), int(has_ctrl), np.eye(2))
             for kind, target, has_ctrl in structure]
 
@@ -69,13 +69,13 @@ def _args(operands, state_sharding, operand_sharding, n=W):
 
 
 def _dense_args(structure, sharding):
-    return _args(fu.dense_operands(_ops(structure), jnp.float32),
+    return _args(fu.pack_operands(_ops(structure), jnp.float32),
                  sharding, sharding)
 
 
 def _compile(fn, args):
     """Compiled for the described chip; the compiler's seconds (Mosaic's,
-    for a kernel window: XLA's own part is a few operand ops) go to the
+    for a kernel window: XLA's own part is the ket's copies) go to the
     test's output, where ``pytest -rP`` or a failure shows them, so that
     a change which multiplies them is seen without a chip."""
     lowered = jax.jit(fn, donate_argnums=(0,)).lower(*args)
@@ -146,7 +146,7 @@ def test_sharded_kernel_window_four_pages(topo):
     mesh = Mesh(np.array(topo.devices[:npg]), ("pages",))
     ops = _ops((("gen", 27, False), ("gen", 3, False)))
     body = fu.sharded_kernel_window_body(L, npg, fu.sharded_structure_of(ops))
-    args = _args(fu.sharded_operands(ops, L, jnp.float32),
+    args = _args(fu.pack_operands(ops, jnp.float32, split_at=L),
                  NamedSharding(mesh, P(None, "pages")),
                  NamedSharding(mesh, P()))
     fn = jax.shard_map(body, mesh=mesh,
@@ -222,7 +222,7 @@ def test_compile_cache_key_does_not_hold_the_call_stack(
     from qrack_tpu.checkpoint import warmstart
 
     structure = (("gen", 19, False), ("cphase", 3, True))
-    args = _args(fu.dense_operands(_ops(structure), jnp.float32),
+    args = _args(fu.pack_operands(_ops(structure), jnp.float32),
                  one_chip, one_chip, n=20)
     saved = {k: getattr(jax.config, k) for k in (
         "jax_traceback_in_locations_limit",

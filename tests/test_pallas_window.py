@@ -328,7 +328,7 @@ def test_w12_qft_block_pow8_numeric_parity():
     circ = qft_qcircuit(12)
     ops = fu.lower_gates(circ.gates)
     structure = fu.structure_of(ops)
-    operands = fu.dense_operands(ops, jnp.float32)
+    operands = fu.pack_operands(ops, jnp.float32)
     planes = jnp.asarray(basis_planes(12, 1234 & ((1 << 12) - 1)))
     want = np.asarray(fu.window_fn(12, structure)(planes, *operands))
     fn = circ.compile_fn_pallas(12, block_pow=8, interpret=True)
@@ -399,7 +399,7 @@ def _window_against_chain(n, bp, ops, seed):
     import jax.numpy as jnp
 
     structure = fu.structure_of(ops)
-    operands = fu.dense_operands(ops, jnp.float32)
+    operands = fu.pack_operands(ops, jnp.float32)
     rng = np.random.default_rng(seed)
     ket = rng.standard_normal((2, 1 << n)).astype(np.float32)
     ket /= np.sqrt((ket ** 2).sum())

@@ -225,11 +225,9 @@ def test_dense_flush_has_its_three_parts(small_tiles, monkeypatch, drive):
     assert len(flushes) == n_flush >= 2
     assert windows == len(seen) == c["fuse.kernel.windows"]
     _assert_three_parts(flushes, children)
-    # a window issues one put per operand and its program, a one-op
-    # flush one eager program
-    operands = sum(1 + 2 * has_ctrl for structure, _ in seen
-                   for _, _, has_ctrl in structure)
-    assert c["fuse.tpu.programs"] == operands + windows + (n_flush - windows)
+    # a window issues its two operand columns and its program, a
+    # one-op flush one eager program
+    assert c["fuse.tpu.programs"] == 3 * windows + (n_flush - windows)
     # cross-tile segments: the counter against plan_window itself
     want = sum(1 for structure, bp in seen
                for seg in pk.plan_window(structure, bp)
@@ -334,14 +332,98 @@ def test_pager_flush_has_its_three_parts(small_tiles, monkeypatch, drive):
     _assert_three_parts(flushes, children)
     windows = c.get("fuse.kernel.windows", 0) + c.get("fuse.xla.windows", 0)
     assert windows == len(seen)
-    operands = sum(fu.sharded_operand_count(structure)
-                   for structure, _, _ in seen)
-    assert c["fuse.pager.programs"] == operands + windows + (n_flush - windows)
+    # a window: its two operand columns and its program; else one program
+    assert c["fuse.pager.programs"] == 3 * windows + (n_flush - windows)
     want = sum(1 for structure, L, bp in seen
                for seg in fu._sharded_segments(structure, L) if seg[0] == "run"
                for s in pk.plan_window(fu._sharded_run_structure(seg[1], L), bp)
                if s["xgen"] is not None)
     assert c["fuse.kernel.sweeps.cross"] == want
+
+
+# -- one packed operand put per window -----------------------------------------
+
+class _OperandSpy:
+    """What a window program is handed after the planes, and the device
+    calls made under the ``fuse.operands`` span of the same flush."""
+
+    DEVICE_CALLS = ((jnp, "asarray"), (jnp, "array"), (jnp, "stack"),
+                    (jnp, "concatenate"), (jax, "device_put"))
+
+    def __init__(self, monkeypatch):
+        self.windows = []       # (arguments after the planes, device calls)
+        self._in_operands = False
+        self._device_calls = 0
+        real_span = tele.span
+        spy = self
+
+        class span:
+            def __init__(self, name, *a, **kw):
+                self._name = name
+                self._real = real_span(name, *a, **kw)
+
+            def __enter__(self):
+                if self._name == "fuse.operands":
+                    spy._in_operands, spy._device_calls = True, 0
+                return self._real.__enter__()
+
+            def __exit__(self, *exc):
+                if self._name == "fuse.operands":
+                    spy._in_operands = False
+                return self._real.__exit__(*exc)
+
+        monkeypatch.setattr(tele, "span", span)
+        for mod, name in self.DEVICE_CALLS:
+            monkeypatch.setattr(mod, name, self._counted(getattr(mod, name)))
+        for name in ("dense_window_program", "kernel_window_program"):
+            monkeypatch.setattr(fu, name, self._spied(getattr(fu, name)))
+        monkeypatch.setattr(QPager, "_p_fuse_window",
+                            self._spied(QPager._p_fuse_window))
+
+    def _counted(self, real):
+        def call(*a, **kw):
+            self._device_calls += self._in_operands
+            return real(*a, **kw)
+        return call
+
+    def _spied(self, real_program):
+        def program(*a, **kw):
+            prog = real_program(*a, **kw)
+
+            def run(planes, *operands):
+                self.windows.append((operands, self._device_calls))
+                return prog(planes, *operands)
+            return run
+        return program
+
+
+@pytest.mark.parametrize("tiles", ["chain", "small_tiles"])
+@pytest.mark.parametrize("drive", [_qft, _trotter], ids=["qft", "trotter"])
+@pytest.mark.parametrize("make", [_dense, _pager], ids=["dense", "pager"])
+def test_a_window_issues_two_operand_arrays(request, monkeypatch, make, drive,
+                                            tiles):
+    """Whatever a window holds, the flush hands its program two host
+    columns after the planes, packed without one call to the device, and
+    counts them and the program: 3 a window, 1 a one-op flush."""
+    if tiles != "chain":
+        request.getfixturevalue(tiles)
+    spy = _OperandSpy(monkeypatch)
+    tele.enable()
+    q = make()
+    drive(q)
+    c = tele.snapshot()["counters"]
+    name = q._tele_name
+    n_flush = sum(v for k, v in c.items()
+                  if k.startswith(f"fuse.{name}.flush."))
+    windows = c.get("fuse.kernel.windows", 0) + c.get("fuse.xla.windows", 0)
+    assert windows == len(spy.windows) >= 2
+    assert c[f"fuse.{name}.programs"] == 3 * windows + (n_flush - windows)
+    for operands, device_calls in spy.windows:
+        iv, fv = operands
+        assert type(iv) is np.ndarray and type(fv) is np.ndarray
+        assert iv.dtype == np.int32 and iv.ndim == 2 and iv.shape[1] == 1
+        assert fv.dtype == q.dtype and fv.ndim == 2 and fv.shape[1] == 1
+        assert device_calls == 0
 
 
 def test_pager_and_dense_agree_with_spans_on():
@@ -367,7 +449,7 @@ STRUCTURE = (("gen", 9, False), ("cphase", 3, True), ("gen", 2, False))
 def test_window_fn_is_lowered_under_its_names():
     """The scope is in the operations' locations; the function's name is
     the module's, which a trace keeps whatever the locations carry."""
-    operands = fu.dense_operands(_ops(STRUCTURE), jnp.float32)
+    operands = fu.pack_operands(_ops(STRUCTURE), jnp.float32)
     text = _lowered_text(fu.window_fn(W, STRUCTURE),
                          jnp.zeros((2, 1 << W), jnp.float32), *operands)
     assert "qrack.fuse.xla_window" in text
@@ -375,7 +457,7 @@ def test_window_fn_is_lowered_under_its_names():
 
 
 def test_kernel_window_fn_is_lowered_under_its_names():
-    operands = fu.dense_operands(_ops(STRUCTURE), jnp.float32)
+    operands = fu.pack_operands(_ops(STRUCTURE), jnp.float32)
     fn = pk.make_window_fn(W, STRUCTURE, block_pow=6, interpret=True)
     text = _lowered_text(fn, jnp.zeros((2, 1 << W), jnp.float32), *operands)
     assert "qrack.fuse.kernel_window" in text
@@ -387,10 +469,11 @@ def test_pager_window_program_is_lowered_under_its_scopes(small_tiles):
     L = q.local_bits
     ops = _ops((("gen", W - 1, False), ("gen", 2, False), ("cphase", 1, True)))
     structure = fu.sharded_structure_of(ops)
-    operands = fu.sharded_operands(ops, L, q.dtype)
+    operands = fu.pack_operands(ops, q.dtype, split_at=L)
     plan, _ = fu.sharded_kernel_lowering(L, structure)
-    prog = q._p_fuse_window(structure, len(operands), kernel_plan=plan)
-    assert len(operands) == fu.sharded_operand_count(structure)
+    prog = q._p_fuse_window(structure, kernel_plan=plan)
+    _, nf, ni = pk._operand_slots(structure, split=True)
+    assert [o.shape for o in operands] == [(ni, 1), (nf, 1)]
     text = prog.lower(q._state, *operands).as_text(debug_info=True)
     assert "qrack.pager.exchange" in text
     assert "qrack.fuse.kernel_window" in text
