@@ -42,8 +42,8 @@ _ENABLED_DIR: Optional[str] = None
 def enable_compile_cache() -> str:
     """Turn on the JAX persistent compilation cache (with the size/time
     admission thresholds disabled — window and serving programs are
-    many and individually small) for chip_smoke.py, bench.py and
-    QrackService alike.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    many and individually small) for chip_smoke.py and QrackService
+    alike.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
     already reads it and no directory is set here; otherwise the cache
     is ``<checkout>/.xla_cache``.  Idempotent; returns the directory.
 

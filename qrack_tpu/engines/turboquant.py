@@ -696,8 +696,8 @@ class QEngineTurboQuant(QEngineTPU):
 
     # opt-in fused Pallas path (ops/pallas_turboquant.py): one HBM
     # read+write of the b-bit CODES per gate.  Single-device only (the
-    # sharded subclass keeps the shard_map XLA programs); same
-    # QRACK_USE_PALLAS flag as the dense segment sweep.
+    # sharded subclass keeps the shard_map XLA programs); QRACK_USE_PALLAS
+    # is read here and nowhere else.
     _pallas_capable = True
     _PALLAS_TILE_POW = int(os.environ.get("QRACK_PALLAS_TQ_TILE_QB", "18"))
 

@@ -207,29 +207,8 @@ class QCircuit:
             # unsound past the dense width cap
             return self.Run(qsim)
         if isinstance(qsim, QEngineTPU) and self.gates:
-            import os
-
             n = qsim.qubit_count
             self._check_fused_range(n)
-            if os.environ.get("QRACK_USE_PALLAS") == "1":
-                import jax
-
-                ops = fu.lower_gates(self.gates)
-                if not ops:
-                    return
-                # the parametric window kernel takes payloads as runtime
-                # operands, so this keys on STRUCTURE in the shared fuse
-                # cache — same-skeleton circuits with different angles
-                # hit one executable, exactly like the XLA window path
-                # (the old baked segment sweep needed a payload digest)
-                prog = fu.kernel_window_program(
-                    n, fu.structure_of(ops), qsim.dtype,
-                    interpret=jax.default_backend() != "tpu")
-                # _owned_state: the window program donates its input —
-                # never hand it a plane ref the prefix cache holds
-                qsim._state = prog(qsim._owned_state(),
-                                   *fu.pack_operands(ops, qsim.dtype))
-                return
             ops = fu.lower_gates(self.gates)
             if not ops:
                 return
@@ -390,7 +369,7 @@ class QCircuit:
         """One jitted program applying the whole circuit to a ket sharded
         across the 'pages' mesh axis: in-page gates per device, paged
         targets over lax.ppermute, diagonals always collective-free.
-        Returns (fn, sharding) like models.qft.make_sharded_qft_fn."""
+        Returns (fn, sharding); ``fn`` donates its argument."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -434,29 +413,6 @@ class QCircuit:
             donate_argnums=(0,),
         )
         return fn, sharding
-
-    def compile_fn_pallas(self, n: int, block_pow: int = 16,
-                          interpret: bool = False):
-        """fn(planes) applying the circuit through the parametric Pallas
-        window kernel: one HBM sweep per planned segment, matrices and
-        masks as runtime operands (trace shape depends only on circuit
-        structure).  Non-diagonal targets at/above the tile no longer
-        bridge out to XLA or raise — they lead pair-mapped cross-tile
-        segments (ops/pallas_kernels.py plan_window).  ``fn.sweeps``
-        reports the planned sweep count."""
-        from ..ops import fusion as fu
-        from ..ops import pallas_kernels as pk
-
-        ops = fu.lower_gates(self.gates)
-        structure = fu.structure_of(ops)
-        wfn = pk.make_window_fn(n, structure, block_pow=block_pow,
-                                interpret=interpret)
-
-        def fn(planes):
-            return wfn(planes, *fu.pack_operands(ops, planes.dtype))
-
-        fn.sweeps = wfn.sweeps
-        return fn
 
     def compile_fn(self, n: int):
         """Return a pure jittable fn(planes) applying the whole circuit

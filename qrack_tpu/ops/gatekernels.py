@@ -70,15 +70,6 @@ def mtrx_planes(m, dtype=jnp.float32):
     m = np.asarray(m)
     return jnp.stack([jnp.asarray(m.real, dtype=dtype), jnp.asarray(m.imag, dtype=dtype)])
 
-def _mix(mp):
-    """(2, d, d) matrix planes -> (2, d, 2, d) real mixing tensor M with
-    out[P, A] = sum_{p, a} M[P, A, p, a] * v[p, a], implementing complex
-    multiply: Re' = Re·re - Im·im ; Im' = Re·im + Im·re."""
-    re, im = mp[0], mp[1]
-    row0 = jnp.stack([re, -im], axis=1)  # [d, 2, d]
-    row1 = jnp.stack([im, re], axis=1)
-    return jnp.stack([row0, row1])  # [2, d, 2, d]
-
 def iota_for(planes):
     return jax.lax.iota(IDX_DTYPE, planes.shape[-1])
 
@@ -172,21 +163,6 @@ def apply_invert(planes, tr_re, tr_im, bl_re, bl_im, n: int, target: int, cmask=
     if isinstance(cmask, int) and cmask == 0:
         return out
     return _ctrl_select(out, planes, cmask, cval)
-
-
-def apply_kxk(planes, mp, n: int, start: int, k: int):
-    """Arbitrary gate on k CONTIGUOUS qubits [start, start+k) as one
-    plane-mixing contraction; `mp` is (2, 2^k, 2^k) matrix planes.
-    The contraction axis is 2^k wide — at k=6/7 this is a 64/128-wide
-    matmul the MXU tiles natively, so fusing a layer of independent
-    single-qubit gates into clusters (see models.rcs) trades n HBM
-    passes for ~n/k at negligible FLOP cost (dense simulation is
-    bandwidth-bound).  apply_2x2 is the k=1 special case."""
-    high = 1 << (n - start - k)
-    low = 1 << start
-    v = planes.reshape(2, high, 1 << k, low)
-    out = jnp.einsum("PApa,phal->PhAl", _mix(mp), v, precision=PREC)
-    return out.reshape(2, -1)
 
 
 def apply_4x4(planes, mp4, n: int, q1: int, q2: int):

@@ -8,8 +8,7 @@ masks and phase operands enter as RUNTIME arguments, the two packed
 columns of fusion.pack_operands that fusion.window_fn reads too — the
 compiled program is keyed by the window's *structure* tuple alone, so
 same-structure windows with different rotation angles never retrace
-(the property the XLA window path already had; the old baked-constant
-segment kernel did not).
+(the property the XLA window path already had).
 
 Vocabulary (everything the fuser emits):
 
@@ -58,9 +57,7 @@ grid step, so it is a CORRECTNESS harness, not a fast path
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
-
-import numpy as np
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -456,34 +453,3 @@ def make_window_fn(n: int, structure: Tuple,
     qrack_kernel_window.sweeps = len(segments)
     qrack_kernel_window.block_pow = bp
     return qrack_kernel_window
-
-
-# ---------------------------------------------------------------------------
-# baked-segment back-compat (QCircuit.compile_fn_pallas)
-# ---------------------------------------------------------------------------
-
-def make_segment_fn(ops: Sequence[Tuple], n: int,
-                    block_pow: int = DEFAULT_BLOCK_POW,
-                    interpret: bool = False):
-    """Back-compat shim for the old baked-constant segment API:
-    ``ops`` is a list of (kind, target, cmask, cval, m) tuples.  Now a
-    thin closure over the runtime-operand window kernel — matrices ride
-    the operand vector instead of being baked into the trace (one
-    compiled program per structure, not per angle), and cross-tile
-    targets plan into pair-mapped segments instead of raising
-    ValueError."""
-    from . import fusion as fu
-
-    fused = [fu.FusedOp(fu.classify(np.asarray(m), cmask, cval), target,
-                        cmask, cval, np.asarray(m))
-             for (kind, target, cmask, cval, m) in ops]
-    structure = fu.structure_of(fused)
-    wfn = make_window_fn(n, structure, block_pow=block_pow,
-                         interpret=interpret)
-    iv, fv = fu.pack_operands(fused, jnp.float32)
-
-    def fn(planes):
-        return wfn(planes, iv, fv)
-
-    fn.sweeps = wfn.sweeps
-    return fn

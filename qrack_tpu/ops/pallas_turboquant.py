@@ -34,7 +34,7 @@ STRUCTURE (per-op kind/target/controlled), so every QFT sweep at one
 width shares one binary.  Tiles no window op dirtied keep their codes
 bit-for-bit, same as the per-gate kernels.
 
-Opt-in via QRACK_USE_PALLAS=1 (same flag as the dense segment sweep;
+Opt-in via QRACK_USE_PALLAS=1 (read by engines/turboquant.py alone;
 off by default until validated on a healthy chip); `interpret=True`
 runs the identical kernels on CPU for the conformance tests.
 """

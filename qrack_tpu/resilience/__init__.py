@@ -3,8 +3,7 @@ fault injection, and TPU→CPU failover.
 
 The whole layer is OFF by default: every guarded site costs one
 module-attribute read plus a truth test until :data:`_ACTIVE` flips
-(the telemetry `_ENABLED` discipline — bench.py qft w20 A/B overhead
-must stay <2%).  Activation:
+(the telemetry `_ENABLED` discipline).  Activation:
 
 * env — ``QRACK_TPU_RESILIENCE=1``, or any nonempty
   ``QRACK_TPU_FAULTS`` (injecting faults implies you want the layer

@@ -127,7 +127,7 @@ def run_batch(jobs: List, engines: List):
 
 def sync_scalar(arr) -> None:
     """Honest completion for a batch output: one real device->host read
-    of a single element (the utils/timing.py devget discipline —
+    of a single element (the devget discipline —
     block_until_ready on a remote-attached device acks dispatch, not completion).
     Reading ANY element of ANY output forces the whole producing
     program to finish, so for the tuple a batch program returns it

@@ -6,9 +6,8 @@ file path with ``importlib`` so the campaign's evidence bookkeeping (which
 runs under ``env -u PYTHONPATH`` while the backend may be hung) can
 never hang on backend init.  Everything here is the single source of truth:
 
-- ``implied_gbps``      — the one implied-bandwidth formula (bytes/wall/1e9)
-  that bench.py, turboquant_bench.py, microbench.py, and the campaign stages
-  previously hand-rolled three-plus times.
+- ``implied_gbps``      — the one implied-bandwidth formula (bytes/wall/1e9),
+  for turboquant_bench.py and the roofline ledger.
 - ``PEAK_GBPS`` / ``peak_gbps`` — the one per-device-class HBM peak table
   (v5e 819 GB/s default), env-overridable via ``QRACK_TPU_PEAK_GBPS``.
 - ``plane_pass_bytes``  — bytes moved by one full sweep over the two ket

@@ -1,7 +1,7 @@
 """Telemetry-name docs lint: code and docs/OBSERVABILITY.md must agree.
 
 Two directions, both enforced as a tier-1 test
-(tests/test_telemetry_docs.py):
+(tests/test_observability.py):
 
 * **undocumented** — every telemetry name literal emitted from
   ``qrack_tpu/`` (first argument of ``inc / event / gauge / observe /
@@ -9,9 +9,9 @@ Two directions, both enforced as a tier-1 test
   subscripts inside the telemetry package) must match a pattern in the
   first column of a table row in docs/OBSERVABILITY.md.
 * **dead** — every documented pattern must match at least one name
-  still emitted from the code (``qrack_tpu/`` or ``scripts/`` /
-  ``bench.py`` — bench-only names keep their doc rows alive but are
-  not themselves required to be documented).
+  still emitted from the code (``qrack_tpu/`` or ``scripts/`` —
+  script-only names keep their doc rows alive but are not themselves
+  required to be documented).
 
 ``jax.named_scope("qrack....")`` literals and the ``*_KERNEL_NAME``
 constants of ``ops/pallas_kernels.py`` are names of the same document
@@ -229,10 +229,6 @@ def main() -> int:
                     telemetry_pkg_prefix=os.path.join("qrack_tpu",
                                                       "telemetry"))
     extra = scan_tree(os.path.join(REPO, "scripts"))
-    bench = os.path.join(REPO, "bench.py")
-    if os.path.exists(bench):
-        extra += [(t, p, "bench.py", ln)
-                  for t, p, ln in extract_names(bench, False)]
     pats = doc_patterns(DOC)
     if not pats:
         print(f"FAIL: no telemetry-name patterns found in {DOC}")

@@ -5,7 +5,9 @@ dry-runs the sharded path via __graft_entry__.dryrun_multichip).
 The pinning itself lives in qrack_tpu.utils.platform (shared with the
 driver entry point) and must run before any backend init.  The driver
 runs this suite with JAX_PLATFORMS=cpu; the chip is exercised by
-chip_smoke.py, never from here."""
+benchmarks/run.py (the cells of BENCHMARK.json: every time comes from
+there) and by chip_smoke.py (the served batch and the default stack,
+which have no cell yet), never from here."""
 
 import faulthandler
 import os

@@ -51,32 +51,27 @@ def main() -> None:
     p3 = q.Prob(3)
     m = q.MAll()                 # collapse: identical draw everywhere
 
-    # 2) the flagship fused sharded programs over the SAME global mesh:
-    #    whole-circuit QFT / brick-wall RCS / fori_loop Grover running
-    #    with shards owned by different processes (gloo as the DCN
-    #    stand-in); reads go through a replicated-output fetch, the only
-    #    read pattern legal when no process addresses every shard
-    from jax.sharding import Mesh
-
-    from qrack_tpu.models import grover as grm
+    # 2) the QFT and RCS families through RunFused over the SAME global
+    #    mesh: the whole circuit lowers into the pager's sharded window
+    #    program, run with shards owned by different processes (gloo as
+    #    the DCN stand-in); reads go through the replicated collective
+    #    fetch, the only read pattern legal when no process addresses
+    #    every shard
     from qrack_tpu.models import qft as qftm
     from qrack_tpu.models import rcs as rcsm
-    from qrack_tpu.parallel.cluster import replicate_program
 
-    mesh = Mesh(np.array(jax.devices()), ("pages",))
-    fetch = replicate_program(mesh, 1 << n)
+    def paged():
+        return QPager(n, rng=QrackRandom(777), rand_global_phase=False,
+                      devices=jax.devices(), n_pages=8)
 
-    qfn, qsh = qftm.make_sharded_qft_fn(mesh, n)
-    qout = qfn(qftm.basis_planes(n, 5, sharding=qsh))
-    qamps = np.asarray(jax.device_get(fetch(qout, 0)))
+    qq = paged()
+    qq.SetPermutation(5)
+    qftm.qft_qcircuit(n).RunFused(qq)
+    qamps = np.asarray(qq.GetQuantumState())
 
-    rfn, rsh = rcsm.make_sharded_rcs_fn(mesh, n, depth=4, seed=11)
-    rout = rfn(qftm.basis_planes(n, 0, sharding=rsh))
-    ramps = np.asarray(jax.device_get(fetch(rout, 0)))
-
-    gfn, gsh, _ = grm.make_sharded_grover_fn(mesh, n, target=3)
-    gout = gfn(qftm.basis_planes(n, 0, sharding=gsh))
-    gamps = np.asarray(jax.device_get(fetch(gout, 0)))
+    rq = paged()
+    rcsm.rcs_qcircuit(n, 4, 11).RunFused(rq)
+    ramps = np.asarray(rq.GetQuantumState())
 
     # 3) the sharded COMPRESSED ket over the same global mesh: chunked
     #    shard_map programs + b-bit ppermute pair exchange across the
@@ -105,10 +100,9 @@ def main() -> None:
         "im": [float(x) for x in state.imag],
         "prob3": float(p3),
         "mall": int(m),
-        "qft_re": [float(x) for x in qamps[0]],
-        "qft_im": [float(x) for x in qamps[1]],
-        "rcs_norm": float((ramps[0] ** 2 + ramps[1] ** 2).sum()),
-        "grover_p_target": grm.success_probability(gamps, 3),
+        "qft_re": [float(x) for x in qamps.real],
+        "qft_im": [float(x) for x in qamps.imag],
+        "rcs_norm": float((np.abs(ramps) ** 2).sum()),
         "tq_prob3": float(tq_p3),
         "tq_prob6": float(tq_p6),
         "tq_amp0_abs": abs(tq_amp0),

@@ -7,7 +7,18 @@ import pytest
 from qrack_tpu.layers.qcircuit import QCircuit
 from qrack_tpu.models import qft as qftm
 from qrack_tpu import matrices as mat
+from qrack_tpu.ops import fusion as fu
+from qrack_tpu.ops import pallas_kernels as pk
 from qrack_tpu.utils.rng import QrackRandom
+
+
+def through_window_kernel(circ, n, planes, block_pow):
+    """The whole circuit as one window of the Pallas kernel under the
+    interpreter: what the engine's flush hands the chip."""
+    ops = fu.lower_gates(circ.gates)
+    wfn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=block_pow,
+                            interpret=True)
+    return np.asarray(wfn(planes, *fu.pack_operands(ops, planes.dtype)))
 
 
 def build_circuit(n, seed, gates=30):
@@ -43,8 +54,7 @@ def test_pallas_segments_match_xla(seed):
     want = np.asarray(jax.jit(c.compile_fn(n))(planes))
     # tiny tiles force multi-block grids AND high-target bridges
     for bp in (4, 6, n):
-        got = np.asarray(c.compile_fn_pallas(n, block_pow=bp,
-                                             interpret=True)(planes))
+        got = through_window_kernel(c, n, planes, bp)
         np.testing.assert_allclose(got, want, atol=3e-5, err_msg=f"bp={bp}")
 
 
@@ -60,7 +70,7 @@ def test_pallas_high_diag_and_controls():
     c.append_ctrl((0,), 1, np.asarray(mat.X2), 1)
     planes = qftm.basis_planes(n, 0)
     want = np.asarray(jax.jit(c.compile_fn(n))(planes))
-    got = np.asarray(c.compile_fn_pallas(n, block_pow=4, interpret=True)(planes))
+    got = through_window_kernel(c, n, planes, 4)
     np.testing.assert_allclose(got, want, atol=3e-5)
 
 

@@ -310,11 +310,11 @@ def test_w20_qft_block_pow8_plans_and_builds():
     from qrack_tpu.models.qft import qft_qcircuit
 
     circ = qft_qcircuit(20)
-    fn = circ.compile_fn_pallas(20, block_pow=8, interpret=True)
     ops = fu.lower_gates(circ.gates)
+    structure = fu.structure_of(ops)
+    fn = pk.make_window_fn(20, structure, block_pow=8, interpret=True)
     assert 1 <= fn.sweeps < len(ops)
     # the plan covers every op exactly once, in order
-    structure = fu.structure_of(ops)
     plan = pk.plan_window(structure, 8)
     covered = [s[0] for seg in plan
                for s in ([seg["xgen"]] if seg["xgen"] else []) + seg["ops"]]
@@ -331,8 +331,9 @@ def test_w12_qft_block_pow8_numeric_parity():
     operands = fu.pack_operands(ops, jnp.float32)
     planes = jnp.asarray(basis_planes(12, 1234 & ((1 << 12) - 1)))
     want = np.asarray(fu.window_fn(12, structure)(planes, *operands))
-    fn = circ.compile_fn_pallas(12, block_pow=8, interpret=True)
-    got = np.asarray(fn(jnp.asarray(basis_planes(12, 1234 & ((1 << 12) - 1)))))
+    fn = pk.make_window_fn(12, structure, block_pow=8, interpret=True)
+    got = np.asarray(fn(jnp.asarray(basis_planes(12, 1234 & ((1 << 12) - 1))),
+                        *operands))
     assert fn.sweeps < len(ops)
     assert float(np.max(np.abs(want - got))) < 3e-5
 

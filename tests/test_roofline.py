@@ -97,8 +97,8 @@ def test_clamp_threshold_tracks_env_peak(monkeypatch):
 
 
 def test_record_computes_sample_even_when_disabled():
-    # bench.py runs with telemetry off by default: the ledger must still
-    # hand back the numbers for the JSON line without touching counters
+    # a script runs with telemetry off by default: the ledger must still
+    # hand back the numbers for its JSON line without touching counters
     sample = roofline.record("unit.off", 100e9, 1.0)
     assert sample["implied_hbm_gbps"] == 100.0
     assert tele.snapshot(include_events=False)["counters"] == {}
