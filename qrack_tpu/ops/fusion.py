@@ -305,11 +305,12 @@ def dense_window_program(n: int, structure: Tuple, dtype):
 
 
 # ---------------------------------------------------------------------------
-# single-sweep Pallas kernel lowering — cost-model-selected against the
-# XLA window chain above.  The kernel streams the ket through VMEM once
-# per planned segment (ops/pallas_kernels.py) instead of once per gate,
-# with the SAME runtime-operand layout and structure-only cache keys,
-# so choosing it never changes retrace behavior — only the lowering.
+# single-sweep Pallas kernel lowering — what every multi-op window
+# takes on the TPU, in place of the XLA window chain above.  The kernel
+# streams the ket through VMEM once per planned segment
+# (ops/pallas_kernels.py) instead of once per gate, with the SAME
+# runtime-operand layout and structure-only cache keys, so choosing it
+# never changes retrace behavior — only the lowering.
 # ---------------------------------------------------------------------------
 
 def kernel_mode() -> str:
@@ -322,7 +323,7 @@ def kernel_mode() -> str:
 
 
 def kernel_lowering(n: int, structure: Tuple, backend: str = None):
-    """Cost model: should this window flush through the Pallas kernel?
+    """Should this window flush through the Pallas kernel?
 
     Returns ``(plan, fallback_reason)`` — exactly one is non-None.
     ``plan`` is ``{"interpret": bool, "block_pow": int, "sweeps": int,
@@ -330,8 +331,9 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
     among the sweeps; ``dense``: the sweeps whose kernel body computes
     on the dense ``(rows, 128)`` tile, pallas_kernels.dense_tile).
 
-    The decision inputs are the window length, op mix (how many planned
-    segments the cross-tile non-diagonals force), width and block_pow:
+    The decision inputs are the mode, the backend and the window length
+    (the plan's counts depend on the op mix, width and block_pow, the
+    choice does not):
 
     * mode off — never (reason ``mode_off``).
     * mode on — always; off-TPU the kernel runs under the Pallas
@@ -340,21 +342,29 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
     * mode auto — TPU-class backends only (reason ``cpu_backend``
       elsewhere: the CPU XLA chain is measured compute-bound at these
       widths, so a single-sweep lowering cannot beat it and interpret
-      certainly cannot).  On TPU the kernel wins when it saves HBM
-      sweeps: windows whose planned segment count is not below the op
-      count (e.g. every op a cross-tile gen) fall back with reason
-      ``no_sweep_gain``; single-op windows with ``single_op`` (the
-      eager per-gate programs already pay one sweep).
+      certainly cannot).  On TPU every window of two or more ops
+      takes the kernel, a window of bare cross-tile gen (as many
+      segments as ops) included: on the chip a chain op costs three
+      passes over the ket behind its barrier (31.9 ms at w28) where a
+      one-op kernel sweep costs 7.6-11.7 ms (PERF.md §6, PR 35).
+      Single-op windows fall back with ``single_op`` (the eager
+      per-gate programs already pay one sweep).
     """
     from . import pallas_kernels as pk
 
+    return _lowering(structure, backend, min(pk.DEFAULT_BLOCK_POW, n),
+                     pk.plan_counts)
+
+
+def _lowering(structure: Tuple, backend, bp: int, counts):
+    """The choice both lowerings share; ``counts(structure, bp)`` gives
+    the plan's ``(sweeps, cross, dense)``."""
     mode = kernel_mode()
     if mode == "off":
         return None, "mode_off"
     if backend is None:
         backend = jax.default_backend()
-    bp = min(pk.DEFAULT_BLOCK_POW, n)
-    sweeps, cross, dense = pk.plan_counts(structure, bp)
+    sweeps, cross, dense = counts(structure, bp)
     plan = {"interpret": backend != "tpu", "block_pow": bp,
             "sweeps": sweeps, "cross": cross, "dense": dense}
     if mode == "on":
@@ -363,8 +373,6 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
         return None, "cpu_backend"
     if len(structure) <= 1:
         return None, "single_op"
-    if sweeps >= len(structure):
-        return None, "no_sweep_gain"
     return plan, None
 
 
@@ -817,24 +825,8 @@ def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):
     sweeps counted through the run/exchange split."""
     from . import pallas_kernels as pk
 
-    mode = kernel_mode()
-    if mode == "off":
-        return None, "mode_off"
-    if backend is None:
-        backend = jax.default_backend()
-    bp = min(pk.DEFAULT_BLOCK_POW, L)
-    sweeps, cross, dense = sharded_kernel_counts(structure, L, bp)
-    plan = {"interpret": backend != "tpu", "block_pow": bp,
-            "sweeps": sweeps, "cross": cross, "dense": dense}
-    if mode == "on":
-        return plan, None
-    if backend != "tpu":
-        return None, "cpu_backend"
-    if len(structure) <= 1:
-        return None, "single_op"
-    if sweeps >= len(structure):
-        return None, "no_sweep_gain"
-    return plan, None
+    return _lowering(structure, backend, min(pk.DEFAULT_BLOCK_POW, L),
+                     lambda st, bp: sharded_kernel_counts(st, L, bp))
 
 
 def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,

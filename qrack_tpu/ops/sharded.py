@@ -69,9 +69,13 @@ def apply_global_2x2(local, mp, npg: int, gpos: int, lmask, lval, gmask, gval):
     pid = page_id()
     b = (pid >> gpos) & 1
     half_n = local.shape[-1] // 2
-    halves = local.reshape(local.shape[0], 2, half_n)  # [planes, top bit, rest]
-    keep = jnp.where(b == 0, halves[:, 0], halves[:, 1])
-    away = jnp.where(b == 0, halves[:, 1], halves[:, 0])
+    # the halves are slices of the minor axis, never a (planes, 2, half)
+    # view: behind a kernel launch the TPU compiler lays that view out
+    # tile by tile (1172 s for a window of five ops at a 2 GiB page,
+    # 2 s sliced: PERF.md §6, PR 35)
+    h0, h1 = local[:, :half_n], local[:, half_n:]
+    keep = jnp.where(b == 0, h0, h1)
+    away = jnp.where(b == 0, h1, h0)
     got = exchange(away, perm)       # half-page payload
     # this page now holds complete (a, b) pairs for local indices with
     # top bit == b: a = partner-0 amplitude, b = partner-1 amplitude
@@ -92,7 +96,7 @@ def apply_global_2x2(local, mp, npg: int, gpos: int, lmask, lval, gmask, gval):
     back = exchange(theirs, perm)    # half-page payload
     lo = jnp.where(b == 0, mine, back)
     hi = jnp.where(b == 0, back, mine)
-    return jnp.stack([lo, hi], axis=1).reshape(local.shape)
+    return jnp.concatenate([lo, hi], axis=-1)
 
 
 def apply_diag(local, d0re, d0im, d1re, d1im, tlo, thi, clo, cvlo, chi, cvhi):

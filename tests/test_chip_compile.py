@@ -87,6 +87,12 @@ def _compile(fn, args):
     return compiled
 
 
+# the Trotter step's last window at w28 (RX on 15-27: 13 planned sweeps
+# for 13 ops, 12 of them cross-tile) and the paged step's at 2^28 pages
+# (RX on 25-29: 28 and 29 are paged); PR 35 sent both to the kernel
+TFIM_LAST = tuple(("gen", t, False) for t in range(15, 28))
+TFIM_LAST_PAGED = tuple(("gen", t, False) for t in range(25, 30))
+
 QFT16 = (("gen", 27, False),) + tuple(
     ("cphase", 26 - k, True) for k in range(15))
 # the same window with every target inside the tile: what most of a
@@ -139,16 +145,26 @@ def test_qft_window_kernel(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes <= KET_BYTES + SLACK
 
 
-def test_sharded_kernel_window_four_pages(topo):
-    """The pager's per-page kernel body on a 2x2 mesh: one global gen
-    (pair exchange over the pages axis) and one local low-lane gen."""
-    npg, L = 4, W - 2
+def test_tfim_last_window_kernel(one_chip):
+    """13 launches in one program: two kets in flight beside the
+    donated one, whatever the count of launches."""
+    plan, why = fu.kernel_lowering(W, TFIM_LAST, backend="tpu")
+    assert why is None and (plan["sweeps"], plan["cross"]) == (13, 12)
+    compiled = _compile(pk.make_window_fn(W, TFIM_LAST),
+                        _dense_args(TFIM_LAST, one_chip))
+    assert compiled.as_text().count("tpu_custom_call") >= 13
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * KET_BYTES + SLACK
+
+
+def _compile_sharded(topo, structure, n, npg=4):
+    """The pager's per-page kernel body of a window on a 2x2 mesh."""
+    L = n - 2
     mesh = Mesh(np.array(topo.devices[:npg]), ("pages",))
-    ops = _ops((("gen", 27, False), ("gen", 3, False)))
+    ops = _ops(structure)
     body = fu.sharded_kernel_window_body(L, npg, fu.sharded_structure_of(ops))
     args = _args(fu.pack_operands(ops, jnp.float32, split_at=L),
                  NamedSharding(mesh, P(None, "pages")),
-                 NamedSharding(mesh, P()))
+                 NamedSharding(mesh, P()), n=n)
     fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P(None, "pages"),) + (P(),) * (len(args) - 1),
                        out_specs=P(None, "pages"), check_vma=False)
@@ -156,10 +172,35 @@ def test_sharded_kernel_window_four_pages(topo):
     text = compiled.as_text()
     assert "collective-permute" in text
     assert "tpu_custom_call" in text
+    return compiled
+
+
+def test_sharded_kernel_window_four_pages(topo):
+    """One global gen (pair exchange over the pages axis) and one local
+    low-lane gen."""
+    compiled = _compile_sharded(
+        topo, (("gen", 27, False), ("gen", 3, False)), W)
     # bytes of one device: three pages, the exchange's (the sent copy,
     # the partner's page, the mixed one); the kernel's tile adds none
     assert compiled.memory_analysis().temp_size_in_bytes \
-        <= 3 * KET_BYTES // npg + SLACK
+        <= 3 * KET_BYTES // 4 + SLACK
+
+
+def test_tfim_last_window_sharded_kernel(topo):
+    """The paged Trotter step's last window at w30: three local
+    cross-tile launches on a 2 GiB page, then two exchanges."""
+    plan, why = fu.sharded_kernel_lowering(W, TFIM_LAST_PAGED, backend="tpu")
+    assert why is None and (plan["sweeps"], plan["cross"]) == (5, 3)
+    t0 = time.perf_counter()
+    compiled = _compile_sharded(topo, TFIM_LAST_PAGED, W + 2)
+    # 2 s with the exchange's halves sliced off the minor axis; 1172 s
+    # with a (planes, 2, half) view of a launch's result (PR 35)
+    assert time.perf_counter() - t0 < 120
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # a page is a w28 ket here: three and a half by the compiler's count
+    # (the step's sixth window, launches between exchanges, reads 3.63)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 7 * KET_BYTES // 2 + SLACK
 
 
 def test_kernel_launches_carry_their_names(one_chip):

@@ -293,6 +293,94 @@ def test_sixteen_gate_window_records_one_sweep(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the choice on the TPU: every window of two or more ops takes the
+# kernel, a window of bare cross-tile gen (as many segments as ops)
+# included — on the chip a chain op costs three passes over the ket
+# where a one-op kernel sweep costs one (PERF.md §6, PR 35)
+# ---------------------------------------------------------------------------
+
+def _gen_structure(targets):
+    return tuple(("gen", t, False) for t in targets)
+
+
+# (lowering, width or local bits, targets, sweeps, cross-tile): the last
+# window of the dense Trotter step at w28 (RX on 15-27: q15 in-tile),
+# of the paged one (RX on 25-29 at 2^28 pages: 28 and 29 exchange),
+# and bare cross-tile windows of 2, 3 and 13 ops
+_TPU_WINDOWS = [
+    ("dense", 28, range(15, 28), 13, 12),
+    ("paged", 28, range(25, 30), 5, 3),
+    ("dense", 28, (16, 27), 2, 2),
+    ("dense", 18, (16, 17), 2, 2),
+    ("dense", 22, (19, 20, 21), 3, 3),
+    ("dense", 30, range(16, 29), 13, 13),
+    ("paged", 28, (26, 27), 2, 2),
+]
+
+
+@pytest.mark.parametrize("lowering,n,targets,sweeps,cross", _TPU_WINDOWS,
+                         ids=[f"{w[0]}-w{w[1]}-{len(w[2])}gen"
+                              for w in _TPU_WINDOWS])
+def test_tpu_takes_the_kernel_for_bare_cross_tile_windows(lowering, n, targets,
+                                                          sweeps, cross):
+    lower = {"dense": fu.kernel_lowering,
+             "paged": fu.sharded_kernel_lowering}[lowering]
+    plan, why = lower(n, _gen_structure(targets), backend="tpu")
+    assert why is None and not plan["interpret"]
+    assert (plan["sweeps"], plan["cross"]) == (sweeps, cross)
+    assert sweeps == len(targets)       # the window the old rule refused
+
+
+@pytest.mark.parametrize("lower", [fu.kernel_lowering,
+                                   fu.sharded_kernel_lowering],
+                         ids=["dense", "paged"])
+@pytest.mark.parametrize("mode,backend,targets,reason", [
+    (None, "tpu", (20,), "single_op"),
+    (None, "cpu", (20, 21), "cpu_backend"),
+    ("off", "tpu", (20, 21), "mode_off"),
+])
+def test_the_reasons_that_keep_the_chain(lower, mode, backend, targets,
+                                         reason, monkeypatch):
+    if mode is not None:
+        monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", mode)
+    assert lower(24, _gen_structure(targets), backend=backend) == (None, reason)
+
+
+# (width, block_pow, targets): every target at or above block_pow; the
+# first two on the dense (rows, 128) tile (tests/test_trace_spans.py has
+# the flat tile's small windows beside their spans), the last the dense
+# Trotter step's 13 ops, all of them cross-tile
+_BARE_CROSS = [(12, 10, (11, 10)), (13, 10, (10, 12, 11)),
+               (15, 2, tuple(range(2, 15)))]
+
+
+@pytest.mark.parametrize("n,bp,targets", _BARE_CROSS,
+                         ids=[f"w{n}-{len(t)}gen" for n, _, t in _BARE_CROSS])
+def test_bare_cross_tile_gen_window_matches_cpu(n, bp, targets, monkeypatch):
+    """The window the rule kept on the chain, through the engine's gate
+    calls and the forced kernel: one window, a sweep an op, all
+    cross-tile, nothing on the chain, the CPU engine's amplitudes."""
+    monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
+    monkeypatch.setattr(pk, "DEFAULT_BLOCK_POW", bp)
+    tele.enable()
+    eng = QEngineTPU(n, rng=QrackRandom(7), rand_global_phase=False)
+    o = QEngineCPU(n, rng=QrackRandom(7), rand_global_phase=False)
+    for q in (eng, o):
+        q.SetPermutation(0b10110 & ((1 << n) - 1))
+        for j, t in enumerate(targets):
+            q.RX(0.3 + 0.17 * j, t)
+    got = np.asarray(eng.GetQuantumState())
+    assert np.max(np.abs(got - np.asarray(o.GetQuantumState()))) < 1e-6
+    c = tele.snapshot(include_events=False)["counters"]
+    k = len(targets)
+    assert (c["fuse.kernel.windows"], c["fuse.kernel.ops"],
+            c["fuse.kernel.sweeps"], c["fuse.kernel.sweeps.cross"]) \
+        == (1, k, k, k), c
+    assert c.get("fuse.xla.windows", 0) == 0
+    assert not [name for name in c if name.startswith("fuse.kernel.fallback")]
+
+
+# ---------------------------------------------------------------------------
 # planner regression: cross-tile non-diagonal targets SPLIT, never raise
 # ---------------------------------------------------------------------------
 
@@ -465,14 +553,15 @@ def benchmark_plans(monkeypatch):
 
 
 @pytest.mark.parametrize("family,sweeps,carry_ops", [("qft", 37, 37),
-                                                     ("tfim", 28, 16)])
+                                                     ("tfim", 41, 17)])
 def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
                                      carry_ops):
     """What ``fuse.kernel.sweeps.dense`` reads in a traced run of each
     cell: every planned kernel segment of an application at w28.  Of
-    TFIM's 24 cross-tile segments 12 carry an in-tile op behind the mix
-    (11 a ``diag``, one a window's 15 ``gen``) and 12 are the controlled
-    ``inv`` alone, whose select is what the dense tile shortens."""
+    TFIM's 36 cross-tile segments 12 carry an in-tile op behind the mix
+    (11 a ``diag``, one a window's 15 ``gen``), 12 are the controlled
+    ``inv`` alone, whose select is what the dense tile shortens, and 12
+    the last window's bare ``gen`` (its 13th, on qubit 15, is in-tile)."""
     dense = with_ops = 0
     for w in benchmark_plans(family):
         if w["path"] != "kernel":

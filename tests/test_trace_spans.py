@@ -341,6 +341,69 @@ def test_pager_flush_has_its_three_parts(small_tiles, monkeypatch, drive):
     assert c["fuse.kernel.sweeps.cross"] == want
 
 
+# -- a window of bare cross-tile gen is a kernel window ------------------------
+
+def _noremap_pager():
+    """Upstream's placement, as the paged cell runs it: qubits 10 and 11
+    stay paged, a gate on them exchanges half pages."""
+    return QPager(W, rng=QrackRandom(7), rand_global_phase=False, n_pages=4,
+                  remap="off")
+
+
+# (engine, RX targets, exchanges): at tiles of 2^6 every target from 6
+# on is cross-tile; as many planned sweeps as ops, the window the fuser
+# kept on the XLA chain until PR 35.  The last is the paged Trotter
+# step's last window in small: three local cross-tile gen, then two on
+# paged qubits
+_BARE_CROSS_WINDOWS = [(_dense, (7, 11), 0), (_dense, (6, 9, 11), 0),
+                       (_dense, tuple(range(6, 12)), 0),
+                       (_noremap_pager, (8, 9), 0),
+                       (_noremap_pager, (7, 8, 9, 10, 11), 2)]
+
+
+@pytest.mark.parametrize(
+    "make,targets,exchanges", _BARE_CROSS_WINDOWS,
+    ids=[f"{m.__name__.strip('_')}-{len(t)}gen"
+         for m, t, _ in _BARE_CROSS_WINDOWS])
+def test_bare_cross_tile_window_is_a_kernel_window(small_tiles, make, targets,
+                                                   exchanges):
+    from qrack_tpu import QEngineCPU
+
+    tele.enable()
+    q = make()
+    o = QEngineCPU(W, rng=QrackRandom(7), rand_global_phase=False)
+    for e in (q, o):
+        e.SetPermutation(0b101101110011)
+        for j, t in enumerate(targets):
+            e.RX(0.3 + 0.17 * j, t)
+    got = np.asarray(q.GetQuantumState())
+    assert np.max(np.abs(got - np.asarray(o.GetQuantumState()))) < 1e-6
+    c = tele.snapshot()["counters"]
+    k = len(targets)
+    assert (c["fuse.kernel.windows"], c["fuse.kernel.ops"]) == (1, k)
+    # an exchange is counted a sweep and is no launch
+    assert c["fuse.kernel.sweeps"] == k
+    assert c["fuse.kernel.sweeps.cross"] == k - exchanges
+    assert c.get("exchange.pager.global_2x2", 0) == exchanges
+    assert c.get("fuse.xla.windows", 0) == c.get("fuse.xla.sweeps", 0) == 0
+    assert not [name for name in c if name.startswith("fuse.kernel.fallback")]
+    flushes, children = _flushes_and_their_parts()
+    assert len(flushes) == 1
+    _assert_three_parts(flushes, children)
+    assert c[f"fuse.{q._tele_name}.programs"] == 3
+
+
+def test_no_code_path_writes_no_sweep_gain():
+    """The reason left with the rule: the package's source does not
+    spell it, and the fallback counter is written from the reason."""
+    pkg = os.path.join(REPO, "qrack_tpu")
+    for root, _, files in os.walk(pkg):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(root, name)) as f:
+                    assert "no_sweep_gain" not in f.read(), name
+
+
 # -- one packed operand put per window -----------------------------------------
 
 class _OperandSpy:
