@@ -371,7 +371,8 @@ class QEngineTPU(QEngine):
     # ------------------------------------------------------------------
 
     def _fuse_admit(self, m, target, controls) -> bool:
-        # every 2x2 gate lowers into a dense parametric window
+        # every 2x2 gate, and every uncontrolled two-qubit gate (target
+        # a pair), lowers into a dense parametric window
         return True
 
     def _fuse_tick(self) -> None:
@@ -425,7 +426,7 @@ class QEngineTPU(QEngine):
         if plan is not None:
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
                                    width=n, esize=esize, cross=plan["cross"],
-                                   dense=plan["dense"])
+                                   dense=plan["dense"], twoq=plan["twoq"])
         else:
             fu.record_xla_flush(self._tele_name, len(ops), width=n,
                                 esize=esize)
@@ -435,6 +436,11 @@ class QEngineTPU(QEngine):
         """``(program, arguments after the planes)`` of a window that
         merged down to one op: the shared per-gate program families."""
         n, m = self.qubit_count, op.m
+        if op.kind == "u4":
+            if _tele._ENABLED:  # an eager whole-ket two-qubit program
+                _tele.inc(f"gate.{self._tele_name}.4x4.w{n}")
+            return _j_apply_4x4, (gk.mtrx_planes(m, self.dtype),
+                                  n, *op.target)
         if op.kind in ("cphase", "diag"):
             d0, d1 = complex(m[0, 0]), complex(m[1, 1])
             return _j_apply_diag, (d0.real, d0.imag, d1.real, d1.imag,
@@ -475,6 +481,39 @@ class QEngineTPU(QEngine):
         self._state = _j_apply_4x4(self._owned_state(), mp,
                                    self.qubit_count, q1, q2)
         self._drift_tick()
+
+    # ------------------------------------------------------------------
+    # the uncontrolled two-qubit gates: one funnel into the pending
+    # window (ops/fusion.py, kind "u4"); the eager whole-ket programs
+    # above and _k_swap_bits run only where the engine has no fuser,
+    # and gate.tpu.swap / gate.tpu.4x4 count only them
+    # ------------------------------------------------------------------
+
+    def _queue_2q(self, m4, q1: int, q2: int) -> bool:
+        """One op of the pending window, where there is one."""
+        self._check_qubit(q1)
+        self._check_qubit(q2)
+        if q1 == q2:
+            raise ValueError("a two-qubit gate needs two qubits")
+        fuser = self._fuser
+        return fuser is not None and fuser.queue_2q(m4, q1, q2)
+
+    def Swap(self, q1: int, q2: int) -> None:
+        if q1 != q2 and not self._queue_2q(mat.SWAP4, q1, q2):
+            super().Swap(q1, q2)
+
+    def ISwap(self, q1: int, q2: int) -> None:
+        if q1 != q2:
+            self.Apply4x4(mat.ISWAP4, q1, q2)
+
+    def IISwap(self, q1: int, q2: int) -> None:
+        if q1 != q2:
+            self.Apply4x4(mat.IISWAP4, q1, q2)
+
+    def Apply4x4(self, m, q1: int, q2: int) -> None:
+        # SqrtSwap, ISqrtSwap and FSim arrive here too (interface/gates.py)
+        if not self._queue_2q(m, q1, q2):
+            super().Apply4x4(m, q1, q2)
 
     def UCMtrx(self, controls, mtrxs, target, mtrx_skip_powers=(), mtrx_skip_value_mask=0) -> None:
         """Uniformly-controlled gate in one fused kernel (reference kernel

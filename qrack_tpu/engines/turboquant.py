@@ -59,6 +59,7 @@ from ..storage import turboquant as tq
 from .. import matrices as mat
 from .. import telemetry as _tele
 from ..telemetry import roofline as _roofline
+from .qengine import QEngine
 from .tpu import QEngineTPU
 
 
@@ -405,6 +406,14 @@ class QEngineTurboQuant(QEngineTPU):
     """Dense ket resident as rotated b-bit block codes (lossy)."""
 
     _tele_name = "turboquant"
+
+    # the chunk and tile window bodies hold no two-target op: the
+    # two-qubit gates keep the base engine's routes, not QEngineTPU's
+    # funnel into the window
+    Swap = QEngine.Swap
+    ISwap = QEngine.ISwap
+    IISwap = QEngine.IISwap
+    Apply4x4 = QEngine.Apply4x4
 
     def __init__(self, qubit_count: int, init_state: int = 0,
                  bits: int = None, block_pow: int = None,

@@ -36,6 +36,15 @@ _W2 = (X2 + Y2) / math.sqrt(2.0)
 _w_vals, _w_vecs = np.linalg.eigh(_W2)
 SQRTW2 = (_w_vecs * np.sqrt(_w_vals.astype(np.complex128))) @ _w_vecs.conj().T
 
+# the swap family as 4x4s, row and column (bit q2 << 1) | bit q1 (all
+# three are symmetric in their qubits): what an engine that holds a
+# two-qubit gate as one op applies in place of the 2x2 syntheses of
+# interface/gates.py (reference: src/qinterface/gates.cpp:166-247)
+SWAP4 = np.eye(4, dtype=np.complex128)[[0, 2, 1, 3]]
+ISWAP4 = np.array([[1, 0, 0, 0], [0, 0, 1j, 0], [0, 1j, 0, 0], [0, 0, 0, 1]],
+                  dtype=np.complex128)
+IISWAP4 = ISWAP4.conj().T
+
 
 def phase_mtrx(top_left: complex, bottom_right: complex) -> np.ndarray:
     return np.array([[top_left, 0], [0, bottom_right]], dtype=np.complex128)

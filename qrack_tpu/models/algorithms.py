@@ -88,20 +88,16 @@ def shor_order_find(qsim, base: int, to_factor: int, width: int) -> Optional[int
 def random_circuit_sampling(qsim, depth: int, rng, n: Optional[int] = None) -> None:
     """Nearest-neighbor RCS layer structure (reference:
     test/benchmarks.cpp:4141 test_random_circuit_sampling_nn): random
-    single-qubit roots + brick-wall couplers."""
+    single-qubit roots + brick-wall ISwap couplers, the plan of
+    ``models/rcs.rcs_layers`` through the engine's gate methods."""
+    from .rcs import rcs_layers
+
     n = n if n is not None else qsim.GetQubitCount()
-    for d in range(depth):
-        for q in range(n):
-            g = rng.randint(0, 3)
-            if g == 0:
-                qsim.SqrtX(q)
-            elif g == 1:
-                qsim.SqrtY(q)
-            else:
-                qsim.SqrtW(q)
-        off = d & 1
-        for q in range(off, n - 1, 2):
-            qsim.ISwap(q, q + 1)
+    for roots, pairs in rcs_layers(n, depth, rng):
+        for q, g in enumerate(roots):
+            (qsim.SqrtX, qsim.SqrtY, qsim.SqrtW)[g](q)
+        for a, b in pairs:
+            qsim.ISwap(a, b)
 
 
 def quantum_volume(qsim, depth: Optional[int] = None, rng=None) -> int:

@@ -11,17 +11,16 @@ import numpy as np
 from .. import matrices as mat
 from ..utils.rng import QrackRandom
 
-_ISWAP4 = np.array(
-    [[1, 0, 0, 0], [0, 0, 1j, 0], [0, 1j, 0, 0], [0, 0, 0, 1]],
-    dtype=np.complex128,
-)
 _ROOTS = (mat.SQRTX2, mat.SQRTY2, mat.SQRTW2)
 
 
-def rcs_layers(n: int, depth: int, seed: int):
-    """Deterministic gate plan: per layer, a random root per qubit and the
-    brick-wall ISwap pairing (matches models.algorithms.random_circuit_sampling)."""
-    rng = QrackRandom(seed)
+def rcs_layers(n: int, depth: int, rng):
+    """The family's one gate plan: per layer a random root per qubit
+    (0, 1, 2: sqrt X, sqrt Y, sqrt W) and the brick-wall pairing of the
+    couplers.  ``rng`` is a ``QrackRandom`` or the seed of one; the
+    draws come layer by layer, qubit by qubit."""
+    if not hasattr(rng, "randint"):
+        rng = QrackRandom(rng)
     plan = []
     for d in range(depth):
         roots = [rng.randint(0, 3) for _ in range(n)]
@@ -34,9 +33,11 @@ def rcs_layers(n: int, depth: int, seed: int):
 def rcs_qcircuit(n: int, depth: int, seed: int):
     """The RCS gate plan as a ``QCircuit`` gate list — the form the
     noisy trajectory engine lowers (qrack_tpu/noise/trajectories.py).
-    ``QCircuitGate`` is a controlled-1q payload model, so the brick-wall
-    couplers are CZ instead of ISwap: same entangling topology,
-    payload-representable."""
+    ``QCircuitGate`` is a controlled-1q payload model and the trajectory
+    window lowers nothing else, so here the brick-wall couplers are CZ
+    instead of ISwap: same entangling topology, payload-representable.
+    (The dense engine's own window holds an ISwap as one op since PR 36,
+    ``ops/fusion.TwoQubitGate``; a ``QCircuit`` still cannot.)"""
     from ..layers.qcircuit import QCircuit
 
     cz = mat.phase_mtrx(1.0, -1.0)
@@ -56,5 +57,5 @@ def reference_rcs_state(n: int, depth: int, seed: int, engine) -> np.ndarray:
         for q, g in enumerate(roots):
             engine.Mtrx(_ROOTS[g], q)
         for (a, b) in pairs:
-            engine.Apply4x4(_ISWAP4, a, b)
+            engine.Apply4x4(mat.ISWAP4, a, b)
     return np.asarray(engine.GetQuantumState())

@@ -45,6 +45,8 @@ order:
   inv     4  [tr.re,tr.im,bl.re,bl.im]           mask's (local, page)
   gen     8  mtrx_planes (2,2,2),                halves; diag/gen 4:
              row-major                           split_masks' four
+  u4     32  mtrx_planes (2,4,4),       (never controlled)
+             row-major
 
 (``iv`` keeps one dead slot where no op is controlled).  The values are
 rounded to the planes' dtype by numpy, on the host, once; nothing is
@@ -55,6 +57,11 @@ positive controls — all 231 QFT phases): the factor select collapses to
 one combined-mask test, (idx & (tmask|cmask)) == (tmask|cmask).
 Uncontrolled ops hold NO mask slots, so apply_2x2/apply_invert keep
 their static cmask==0 short-circuit inside the trace.
+
+"u4" is the two-target op: any uncontrolled two-qubit gate (Swap, ISwap,
+FSim, Apply4x4 ...) as one 4x4 on ``target = (lo, hi)``, row and column
+index ``(bit hi << 1) | bit lo``.  Its kind never depends on its values
+(a Swap is a "u4" too), so the draws of a random circuit share programs.
 """
 
 from __future__ import annotations
@@ -97,7 +104,8 @@ def window_len() -> int:
 # ---------------------------------------------------------------------------
 
 class FusedOp:
-    """One lowered gate: classification + static placement + payload."""
+    """One lowered gate: classification + static placement + payload.
+    ``target`` is a qubit, or the pair ``(lo, hi)`` of a "u4"."""
 
     __slots__ = ("kind", "target", "cmask", "cval", "m")
 
@@ -121,11 +129,74 @@ def classify(m, cmask: int, cval: int) -> str:
     return "gen"
 
 
+class TwoQubitGate:
+    """The queued record of an uncontrolled two-qubit gate: a 4x4 on
+    ``target = (lo, hi)``, indexed ``(bit hi << 1) | bit lo``.  It stands
+    in the fuser's window beside the ``QCircuitGate``s and answers what
+    the merge walk asks of them."""
+
+    __slots__ = ("target", "m")
+
+    def __init__(self, q1: int, q2: int, m4):
+        m = np.asarray(m4, dtype=np.complex128).reshape(4, 4)
+        if q1 > q2:  # the caller's index is (bit q2 << 1) | bit q1
+            q1, q2 = q2, q1
+            m = m[np.ix_(_SWAP_ROWS, _SWAP_ROWS)]
+        self.target = (q1, q2)
+        self.m = m
+
+    def qubits(self) -> Tuple[int, int]:
+        return self.target
+
+    def can_merge(self, later) -> bool:
+        """A later gate that acts inside this pair composes onto it:
+        another two-qubit gate on the pair, a single-qubit gate on one
+        of the two, or a gate controlled by one on the other.  Decided
+        by the qubits alone, never by a value."""
+        return set(later.qubits()) <= set(self.target)
+
+    def merge(self, later) -> None:
+        self.m = self.embed(later) @ self.m
+
+    def absorb_behind(self, earlier) -> None:
+        """Compose a gate that ran BEFORE this one into it."""
+        self.m = self.m @ self.embed(earlier)
+
+    def embed(self, g) -> np.ndarray:
+        """``g`` (inside this pair) as a 4x4 in this pair's index."""
+        if isinstance(g, TwoQubitGate):
+            return g.m
+        lo, _ = self.target
+        eye = np.eye(2, dtype=np.complex128)
+        out = np.zeros((4, 4), dtype=np.complex128)
+        # per value of the other qubit: its projector beside the payload
+        # that value selects (every value, where g has no control)
+        for v in (0, 1):
+            block = g.payloads.get(v if g.controls else 0, eye)
+            proj = np.zeros((2, 2), dtype=np.complex128)
+            proj[v, v] = 1.0
+            out += (np.kron(proj, block) if g.target == lo
+                    else np.kron(block, proj))
+        return out
+
+    def is_identity(self) -> bool:
+        return bool(np.allclose(self.m, np.eye(4), rtol=0.0, atol=1e-12))
+
+    def clone(self) -> "TwoQubitGate":
+        return TwoQubitGate(*self.target, self.m.copy())
+
+
+_SWAP_ROWS = [0, 2, 1, 3]  # a 4x4's index with its two qubits exchanged
+
+
 def lower_gates(gates) -> List[FusedOp]:
-    """Flatten merged QCircuitGates into op descriptors (payload perms in
+    """Flatten the merged window into op descriptors (payload perms in
     sorted order for a deterministic structure)."""
     ops: List[FusedOp] = []
     for g in gates:
+        if isinstance(g, TwoQubitGate):
+            ops.append(FusedOp("u4", g.target, 0, 0, g.m))
+            continue
         for perm in sorted(g.payloads):
             m = g.payloads[perm]
             cmask = 0
@@ -162,7 +233,7 @@ def structure_of(ops: Sequence[FusedOp]) -> Tuple:
 # ---------------------------------------------------------------------------
 
 _PAYLOAD_SHAPE = {"cphase": (2,), "diag": (2, 2), "inv": (2, 2),
-                  "gen": (2, 2, 2)}
+                  "gen": (2, 2, 2), "u4": (2, 4, 4)}
 
 
 def _payload(kind: str, m) -> tuple:
@@ -211,7 +282,7 @@ def pack_operands(ops: Sequence[FusedOp], dtype, split_at: int = None):
 
 def operand_views(structure: Tuple, iv, fv, split: bool = False) -> List:
     """Per op ``(payload, masks)`` cut from the packed columns by static
-    offset: the payload in its shape ((2,), (2, 2) or (2, 2, 2)), the
+    offset: the payload in its shape (``_PAYLOAD_SHAPE``), the
     masks a tuple of scalars, empty where the op is uncontrolled.
     Columns traced (inside a window body) or numpy (on the host)."""
     from . import pallas_kernels as pk
@@ -271,6 +342,8 @@ def window_fn(n: int, structure: Tuple):
                 elif kind == "inv":
                     planes = gk.apply_invert(planes, p[0, 0], p[0, 1], p[1, 0],
                                              p[1, 1], n, target, cm, cv)
+                elif kind == "u4":
+                    planes = gk.apply_4x4(planes, p, n, *target)
                 else:
                     planes = gk.apply_2x2(planes, p, n, target, cm, cv)
         return planes
@@ -327,9 +400,11 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
 
     Returns ``(plan, fallback_reason)`` — exactly one is non-None.
     ``plan`` is ``{"interpret": bool, "block_pow": int, "sweeps": int,
-    "cross": int, "dense": int}`` (``cross``: the cross-tile segments
-    among the sweeps; ``dense``: the sweeps whose kernel body computes
-    on the dense ``(rows, 128)`` tile, pallas_kernels.dense_tile).
+    "cross": int, "dense": int, "twoq": dict}`` (``cross``: the
+    cross-tile segments a 2x2 leads among the sweeps; ``dense``: the
+    sweeps whose kernel body computes on the dense ``(rows, 128)`` tile,
+    pallas_kernels.dense_tile; ``twoq``: pallas_kernels.twoq_counts,
+    the window's two-target ops and the sweeps that carry them).
 
     The decision inputs are the mode, the backend and the window length
     (the plan's counts depend on the op mix, width and block_pow, the
@@ -364,9 +439,12 @@ def _lowering(structure: Tuple, backend, bp: int, counts):
         return None, "mode_off"
     if backend is None:
         backend = jax.default_backend()
+    from . import pallas_kernels as pk
+
     sweeps, cross, dense = counts(structure, bp)
     plan = {"interpret": backend != "tpu", "block_pow": bp,
-            "sweeps": sweeps, "cross": cross, "dense": dense}
+            "sweeps": sweeps, "cross": cross, "dense": dense,
+            "twoq": pk.twoq_counts(structure, bp)}
     if mode == "on":
         return plan, None
     if backend != "tpu":
@@ -402,11 +480,12 @@ def kernel_window_program(n: int, structure: Tuple, dtype,
 
 def record_kernel_flush(name: str, nops: int, sweeps: int,
                         width=None, esize: int = 4, cross: int = 0,
-                        dense: int = 0) -> None:
+                        dense: int = 0, twoq=None) -> None:
     """A window flushed through the Pallas kernel: count it, the HBM
     sweeps it actually paid (telemetry_report derives sweeps/window),
     how many of them were cross-tile pair segments and how many
-    computed on the dense tile.
+    computed on the dense tile; with ``twoq`` (the plan's), its
+    two-target ops and the sweeps that carried them, by placement.
     Callers that supply the plane width also feed the sweep's planned
     bytes into the roofline ledger (`roofline.tpu.fuse.flush.*`)."""
     if _tele._ENABLED:
@@ -415,6 +494,9 @@ def record_kernel_flush(name: str, nops: int, sweeps: int,
         _tele.inc("fuse.kernel.sweeps", sweeps)
         _tele.inc("fuse.kernel.sweeps.cross", cross)
         _tele.inc("fuse.kernel.sweeps.dense", dense)
+        for key, count in (twoq or {}).items():
+            if count:
+                _tele.inc(f"fuse.kernel.twoq.{key}", count)
         if width is not None:
             _roofline.note_bytes(
                 "tpu.fuse.flush",
@@ -926,8 +1008,7 @@ class GateStreamFuser:
             # the gate is consumed from the driver's stream either way
             # (fused or eager), so the cursor advances unconditionally
             self.lookahead_pos += 1
-        eng = self.engine
-        if not eng._fuse_admit(m, target, controls):
+        if not self.engine._fuse_admit(m, target, controls):
             self.flush("ineligible")
             return False
         from ..layers.qcircuit import QCircuitGate
@@ -936,16 +1017,24 @@ class GateStreamFuser:
             gate = QCircuitGate.controlled(controls, target, m, perm)
         else:
             gate = QCircuitGate.single(target, m)
-        # flush a full window BEFORE admitting the new gate: when the
-        # flush escalates past in-place repair (DispatchGiveUp ->
-        # wrapper-level failover), the failover snapshot re-runs the
-        # kept window and the wrapper replays the TRIGGERING CALL on
-        # the fallback — a gate living in both would apply twice.
-        # Keeping the trigger out of the flushed window makes the two
-        # disjoint, which is the exactly-once property the integrity
-        # replay path (resilience/integrity.py) also leans on.
-        if len(self.gates) >= self.window:
-            self.flush("window_full")
+        self._admit(gate)
+        return True
+
+    def queue_2q(self, m4, q1: int, q2: int) -> bool:
+        """Admit one uncontrolled two-qubit gate as ONE op (a
+        :class:`TwoQubitGate`; index ``(bit q2 << 1) | bit q1``).  Only
+        an engine whose window bodies hold the "u4" kind calls this.  As
+        :meth:`queue`: False after a flush (reason ``twoq_ineligible``,
+        the one flush a two-qubit call can force) where the engine does
+        not admit it."""
+        if not self.engine._fuse_admit(m4, (q1, q2), ()):
+            self.flush("twoq_ineligible")
+            return False
+        self._admit(TwoQubitGate(q1, q2, m4))
+        return True
+
+    def _admit(self, gate) -> None:
+        eng = self.engine
         self._append_merge(gate)
         self._raw += 1
         if _tele._ENABLED:
@@ -957,11 +1046,11 @@ class GateStreamFuser:
         # never flush yet were still requested.  May itself force a
         # flush (a drift check reads the state).
         eng._fuse_tick()
-        return True
 
     def _append_merge(self, gate) -> None:
         # QCircuit.AppendGate's peephole: walk back past disjoint-qubit
-        # gates; compose onto a same-target/controls partner
+        # gates; compose onto a same-target/controls partner, or onto a
+        # two-qubit gate whose pair holds every qubit of this one
         i = len(self.gates) - 1
         gset = set(gate.qubits())
         while i >= 0:
@@ -974,7 +1063,42 @@ class GateStreamFuser:
             if set(g.qubits()) & gset:
                 break
             i -= 1
-        self.gates.append(gate.clone())
+        behind = (self._singles_behind(gate)
+                  if isinstance(gate, TwoQubitGate) else [])
+        # a gate that would grow a full window flushes it BEFORE it is
+        # admitted, and takes nothing out of it first: when the flush
+        # escalates past in-place repair (DispatchGiveUp ->
+        # wrapper-level failover), the failover snapshot re-runs the
+        # kept window and the wrapper replays the TRIGGERING CALL on
+        # the fallback — a gate living in both would apply twice.
+        # Keeping the trigger out of the flushed window makes the two
+        # disjoint, which is the exactly-once property the integrity
+        # replay path (resilience/integrity.py) also leans on.  (A gate
+        # that merged, above, grew nothing and flushed nothing.)
+        if len(self.gates) - len(behind) >= self.window:
+            self.flush("window_full")
+            behind = []
+        gate = gate.clone()
+        for i in behind:
+            gate.absorb_behind(self.gates[i])
+            del self.gates[i]
+        self.gates.append(gate)
+
+    def _singles_behind(self, gate: TwoQubitGate) -> List[int]:
+        """Where the window holds an uncontrolled single-qubit gate that
+        is the last to touch one of ``gate``'s qubits, highest index
+        first: nothing between it and the window's end acts on its
+        qubit, so it commutes up to ``gate`` and composes into it.  By
+        the gates' qubits alone, as every merge."""
+        found = []
+        for q in gate.target:
+            for i in range(len(self.gates) - 1, -1, -1):
+                g = self.gates[i]
+                if q in g.qubits():
+                    if g.qubits() == (q,):
+                        found.append(i)
+                    break
+        return sorted(found, reverse=True)
 
     def flush(self, reason: str = "read") -> None:
         """Lower + dispatch the pending window (guarded site

@@ -26,6 +26,15 @@ Vocabulary (everything the fuser emits):
   sees its own tile and its partner tile and computes its own row of
   the 2x2 mix (inputs are read-only, so the duplicated read is pure).
   This replaces the old ``target < block_pow`` refusal.
+* u4 — the two-target op, a 4x4 on ``target = (lo, hi)``, never
+  controlled: each amplitude reads the four members of its (lo, hi)
+  quad and applies its own row of the matrix (tile_quad_mix).  Three
+  placements.  ``hi < block_pow``: in the tile, both partners by
+  tile_partner, in any segment.  ``lo < block_pow <= hi``: it leads a
+  segment on the pair grid above, the ``lo`` partner taken inside each
+  of the two tiles.  ``lo >= block_pow``: it leads a segment whose grid
+  step sees four tiles (the planes passed four times, the index maps
+  ``i ^ bit(lo)``, ``i ^ bit(hi)`` and both).
 
 ``sweeps == len(segments)``: a window with no cross-tile non-diagonal
 op is exactly one sweep; each cross-tile op opens one more.
@@ -80,16 +89,18 @@ _VMEM_LIMIT_BYTES = 32 << 20
 # floats each op contributes to the packed scalar vector (dense layout
 # order: cphase [f.re,f.im]; diag [d0.re,d0.im,d1.re,d1.im];
 # inv [tr.re,tr.im,bl.re,bl.im]; gen mtrx_planes (2,2,2) row-major)
-_NFLOATS = {"cphase": 2, "diag": 4, "inv": 4, "gen": 8}
+_NFLOATS = {"cphase": 2, "diag": 4, "inv": 4, "gen": 8, "u4": 32}
 
 
-def segment_compatible(kind: str, target: int, block_pow: int) -> bool:
+def segment_compatible(kind: str, target, block_pow: int) -> bool:
     """Can this op join an in-tile segment?  diag/cphase always can
     (high bits resolve against the grid block id); non-diagonal ops
-    need their pair partner inside the tile.  An incompatible op is NOT
-    an error any more — the planner opens a pair-mapped cross-tile
-    segment for it (plan_window), so callers never see the old
-    mid-plan ValueError."""
+    need their pair partner inside the tile, a u4 both of its partners.
+    An incompatible op is NOT an error any more — the planner opens a
+    cross-tile segment for it (plan_window), so callers never see the
+    old mid-plan ValueError."""
+    if kind == "u4":
+        return target[1] < block_pow
     return kind in ("cphase", "diag") or target < block_pow
 
 
@@ -98,9 +109,10 @@ def plan_window(structure: Tuple, block_pow: int) -> List[dict]:
 
     Returns a list of ``{"xgen": slot | None, "ops": [slot, ...]}``
     where each slot is ``(op_index, kind, target, has_ctrl)``.  A
-    cross-tile inv/gen (target >= block_pow) leads its own segment —
-    the pair-mapped grid mixes partner tiles for exactly one op, then
-    the rest of the segment applies in-tile."""
+    cross-tile inv/gen (target >= block_pow) or u4 (hi >= block_pow)
+    leads its own segment — the pair- or quad-mapped grid mixes partner
+    tiles for exactly one op, then the rest of the segment applies
+    in-tile."""
     segs: List[dict] = []
     cur = {"xgen": None, "ops": []}
     for idx, (kind, target, has_ctrl) in enumerate(structure):
@@ -128,11 +140,42 @@ def dense_tile(block_pow: int) -> Optional[Tuple[int, int]]:
 def plan_counts(structure: Tuple, block_pow: int) -> Tuple[int, int, int]:
     """``(sweeps, cross, dense)``: the HBM sweeps the kernel lowering
     pays for this window (the XLA window chain pays ~len(structure)),
-    how many of them are cross-tile pair segments, and how many
-    compute on the dense tile (all, where the block has one)."""
+    how many of them are cross-tile pair segments led by a 2x2, and how
+    many compute on the dense tile (all, where the block has one)."""
     segs = plan_window(structure, block_pow)
-    return (len(segs), sum(seg["xgen"] is not None for seg in segs),
+    return (len(segs),
+            sum(segment_kernel_name(seg, block_pow) == CROSS_KERNEL_NAME
+                for seg in segs),
             len(segs) if dense_tile(block_pow) else 0)
+
+
+def segment_kernel_name(seg: dict, block_pow: int) -> str:
+    """The name a segment's launch has in a device trace: by what leads
+    it, and for an unled one by whether a two-target op rides in it."""
+    lead = seg["xgen"]
+    if lead is None:
+        return (TWOQ_INTILE_KERNEL_NAME
+                if any(slot[1] == "u4" for slot in seg["ops"])
+                else INTILE_KERNEL_NAME)
+    if lead[1] != "u4":
+        return CROSS_KERNEL_NAME
+    return (TWOQ_QUAD_KERNEL_NAME if lead[2][0] >= block_pow
+            else TWOQ_PAIR_KERNEL_NAME)
+
+
+def twoq_counts(structure: Tuple, block_pow: int) -> dict:
+    """A window's two-target ops and the sweeps that carry them:
+    ``ops``, and the launches named ``qrack_window_twoq_intile`` /
+    ``_pair`` / ``_quad`` as ``sweeps.intile`` / ``.pair`` / ``.quad``
+    (the telemetry counters ``fuse.kernel.twoq.*``)."""
+    out = {"ops": sum(kind == "u4" for kind, _, _ in structure),
+           "sweeps.intile": 0, "sweeps.pair": 0, "sweeps.quad": 0}
+    if out["ops"]:
+        for seg in plan_window(structure, block_pow):
+            name = segment_kernel_name(seg, block_pow)
+            if name in _TWOQ_SWEEP_KEY:
+                out[_TWOQ_SWEEP_KEY[name]] += 1
+    return out
 
 
 def _nints(kind: str, has_ctrl: bool, split: bool = False) -> int:
@@ -242,6 +285,41 @@ def tile_local_2x2(v, lidx, hi_id, target, mp, lm, lv, gm, gv):
     return jnp.where(sel, nv, v), hi_ok
 
 
+def tile_quad_mix(members, b1, b2, mp):
+    """Every amplitude's own row of a 4x4 over the four members of its
+    quad.  ``members[x2][x1]`` is the value ``(2, *tile)`` across
+    ``(x2, x1)`` from the amplitude (``[0][0]`` itself); ``b1`` / ``b2``
+    are its own low / high target bits, per element or one scalar for
+    the tile; ``mp[plane][row][col]`` are the matrix's scalars, row and
+    column ``(bit hi << 1) | bit lo``.  The coefficient of the member
+    across ``x`` is ``m[r, r ^ x]`` with ``r`` the amplitude's own row
+    (gatekernels.apply_4x4's arithmetic, in its order)."""
+    def own(plane, x):
+        at = [mp[plane][r][r ^ x] for r in range(4)]
+        return jnp.where(b2, jnp.where(b1, at[3], at[2]),
+                         jnp.where(b1, at[1], at[0]))
+
+    re = im = None
+    for x2 in (0, 1):
+        for x1 in (0, 1):
+            v = members[x2][x1]
+            cre, cim = own(0, (x2 << 1) | x1), own(1, (x2 << 1) | x1)
+            tre = v[0] * cre - v[1] * cim
+            tim = v[0] * cim + v[1] * cre
+            re = tre if re is None else re + tre
+            im = tim if im is None else im + tim
+    return jnp.stack([re, im])
+
+
+def tile_local_4x4(v, lidx, lo, hi, mp):
+    """The two-target op with both partners inside the tile."""
+    p1 = tile_partner(v, lidx, lo)
+    members = ((v, p1),
+               (tile_partner(v, lidx, hi), tile_partner(p1, lidx, hi)))
+    return tile_quad_mix(members, (lidx & (1 << lo)) != 0,
+                         (lidx & (1 << hi)) != 0, mp)
+
+
 def tile_local_invert(v, lidx, hi_id, target,
                       trre, trim, blre, blim, lm, lv, gm, gv):
     """Anti-diagonal 2x2 (X/Y-like) with the pair inside the tile."""
@@ -264,6 +342,14 @@ def tile_local_invert(v, lidx, hi_id, target,
 # stack, metadata= rides the custom call's frontend attributes always
 INTILE_KERNEL_NAME = "qrack_window_intile"
 CROSS_KERNEL_NAME = "qrack_window_cross"
+# the launches that carry a two-target op: an unled segment with one
+# in its tile, and the segments one leads on the pair and quad grids
+TWOQ_INTILE_KERNEL_NAME = "qrack_window_twoq_intile"
+TWOQ_PAIR_KERNEL_NAME = "qrack_window_twoq_pair"
+TWOQ_QUAD_KERNEL_NAME = "qrack_window_twoq_quad"
+_TWOQ_SWEEP_KEY = {TWOQ_INTILE_KERNEL_NAME: "sweeps.intile",
+                   TWOQ_PAIR_KERNEL_NAME: "sweeps.pair",
+                   TWOQ_QUAD_KERNEL_NAME: "sweeps.quad"}
 
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 # packed scalar operands: SMEM on the TPU, honoured by the interpreter
@@ -308,6 +394,8 @@ def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
                                  fv_ref[foff, 0], fv_ref[foff + 1, 0],
                                  fv_ref[foff + 2, 0], fv_ref[foff + 3, 0],
                                  cm & lbits, cv & lbits, cm >> bp, cv >> bp)
+    elif kind == "u4":
+        v = tile_local_4x4(v, lidx, *target, _u4_scalars(fv_ref, foff))
     else:
         mp = [[[fv_ref[foff + 4 * plane + 2 * row + col, 0]
                 for col in range(2)]
@@ -316,6 +404,14 @@ def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
         v, _ = tile_local_2x2(v, lidx, blk, target, mp,
                               cm & lbits, cv & lbits, cm >> bp, cv >> bp)
     return v
+
+
+def _u4_scalars(fv_ref, foff):
+    """``mp[plane][row][col]`` of a u4's 32 floats (mtrx_planes flat)."""
+    return [[[fv_ref[foff + 16 * plane + 4 * row + col, 0]
+              for col in range(4)]
+             for row in range(4)]
+            for plane in range(2)]
 
 
 def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
@@ -346,30 +442,74 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
                 v = jax.lax.optimization_barrier(v)
         return v.reshape(2, block)
 
-    if xgen is None:
-        def kernel(iv_ref, fv_ref, in_ref, out_ref):
-            out_ref[...] = in_tile_ops(load(in_ref), pl.program_id(0),
-                                       iv_ref, fv_ref)
+    def launch(kernel, partner_bits=()):
+        """run(planes, iv, fv): the kernel over every tile, the planes
+        passed once more for each partner tile ``i ^ bits`` a grid step
+        reads beside its own."""
+        name = segment_kernel_name(seg, bp)
+        partner_specs = [pl.BlockSpec((2, block), lambda i, x=x: (0, i ^ x))
+                         for x in partner_bits]
 
         def run(planes, iv, fv):
             return pl.pallas_call(
                 kernel,
                 out_shape=jax.ShapeDtypeStruct((2, 1 << n), planes.dtype),
                 grid=(nblk,),
-                in_specs=[iv_spec, fv_spec, tile_spec],
+                in_specs=[iv_spec, fv_spec, tile_spec] + partner_specs,
                 out_specs=tile_spec,
                 compiler_params=_COMPILER_PARAMS,
                 interpret=interpret,
-                name=INTILE_KERNEL_NAME,
-                metadata={"qrack_kernel": INTILE_KERNEL_NAME},
-            )(iv, fv, planes)
+                name=name,
+                metadata={"qrack_kernel": name},
+            )(iv, fv, planes, *([planes] * len(partner_bits)))
 
         return run
 
-    # cross-tile segment: partner-pair grid for the leading inv/gen
+    if xgen is None:
+        def kernel(iv_ref, fv_ref, in_ref, out_ref):
+            out_ref[...] = in_tile_ops(load(in_ref), pl.program_id(0),
+                                       iv_ref, fv_ref)
+
+        return launch(kernel)
+
     idx, kind, target, has_ctrl = xgen
-    h = target - bp
     foff_x, ioff_x = slots[idx]
+
+    if kind == "u4":
+        # the two-target op above the tile: the quad's members are this
+        # tile, its partner tiles, and the low partner inside each where
+        # the low target is in the tile
+        lo, hi = target
+        h2 = 1 << (hi - bp)
+
+        if lo < bp:
+            def kernel(iv_ref, fv_ref, in_ref, pa_ref, out_ref):
+                blk = pl.program_id(0)
+                lidx = _tile_index(tile)
+                mine, other = load(in_ref), load(pa_ref)
+                members = ((mine, tile_partner(mine, lidx, lo)),
+                           (other, tile_partner(other, lidx, lo)))
+                nv = tile_quad_mix(members, (lidx & (1 << lo)) != 0,
+                                   (blk & h2) != 0,
+                                   _u4_scalars(fv_ref, foff_x))
+                out_ref[...] = in_tile_ops(nv, blk, iv_ref, fv_ref)
+
+            return launch(kernel, (h2,))
+
+        h1 = 1 << (lo - bp)
+
+        def kernel(iv_ref, fv_ref, in_ref, p1_ref, p2_ref, p12_ref, out_ref):
+            blk = pl.program_id(0)
+            members = ((load(in_ref), load(p1_ref)),
+                       (load(p2_ref), load(p12_ref)))
+            nv = tile_quad_mix(members, (blk & h1) != 0, (blk & h2) != 0,
+                               _u4_scalars(fv_ref, foff_x))
+            out_ref[...] = in_tile_ops(nv, blk, iv_ref, fv_ref)
+
+        return launch(kernel, (h1, h2, h1 | h2))
+
+    # cross-tile segment: partner-pair grid for the leading inv/gen
+    h = target - bp
 
     def kernel(iv_ref, fv_ref, in_ref, pa_ref, out_ref):
         blk = pl.program_id(0)
@@ -410,21 +550,7 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
             nv = jnp.where(sel, nv, mine)
         out_ref[...] = in_tile_ops(nv, blk, iv_ref, fv_ref)
 
-    def run(planes, iv, fv):
-        return pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((2, 1 << n), planes.dtype),
-            grid=(nblk,),
-            in_specs=[iv_spec, fv_spec, tile_spec,
-                      pl.BlockSpec((2, block), lambda i: (0, i ^ (1 << h)))],
-            out_specs=tile_spec,
-            compiler_params=_COMPILER_PARAMS,
-            interpret=interpret,
-            name=CROSS_KERNEL_NAME,
-            metadata={"qrack_kernel": CROSS_KERNEL_NAME},
-        )(iv, fv, planes, planes)
-
-    return run
+    return launch(kernel, (1 << h,))
 
 
 def make_window_fn(n: int, structure: Tuple,

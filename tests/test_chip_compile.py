@@ -56,7 +56,8 @@ def one_chip(topo):
 def _ops(structure):
     """Placeholder ops of a window structure: the fuser's own packer
     then gives the two operand columns' shapes, so they cannot drift."""
-    return [fu.FusedOp(kind, target, int(has_ctrl), int(has_ctrl), np.eye(2))
+    return [fu.FusedOp(kind, target, int(has_ctrl), int(has_ctrl),
+                       np.eye(4 if kind == "u4" else 2))
             for kind, target, has_ctrl in structure]
 
 
@@ -201,6 +202,53 @@ def test_tfim_last_window_sharded_kernel(topo):
     # (the step's sixth window, launches between exchanges, reads 3.63)
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= 7 * KET_BYTES // 2 + SLACK
+
+
+def _u4(lo, hi):
+    return ("u4", (lo, hi), False)
+
+
+# the windows of a random-circuit sample at w28 that hold each segment
+# shape a two-target op has (benchmarks/tests/test_rcs.py has the whole
+# plan): in the tile, leading the pair grid, leading the four-tile grid
+RCS_WINDOWS = {
+    # couplers with their roots composed in, on the quad grid, the last
+    # with the next cycle's in-tile couplers riding behind it
+    "intile-and-quad": tuple(_u4(a, a + 1) for a in (8, 10, 12, 14))
+    + tuple(_u4(a, a + 1) for a in range(16, 28, 2))
+    + tuple(_u4(a, a + 1) for a in (1, 3, 5, 7, 9, 11)),
+    # bare cross-tile roots, then the coupler across the tile's edge
+    "cross-pair-quad": tuple(("gen", t, False) for t in range(21, 28))
+    + tuple(_u4(a, a + 1) for a in (1, 3, 5, 7, 9, 11, 13))
+    + (_u4(15, 16), _u4(17, 18)),
+    # sixteen ops in one tile, eight of them 4x4: the most arithmetic
+    "intile-16": tuple(_u4(a, a + 1) for a in range(0, 16, 2))
+    + tuple(("gen", t, False) for t in range(8)),
+}
+
+
+@pytest.mark.parametrize("window", sorted(RCS_WINDOWS))
+def test_two_qubit_window_kernel(one_chip, window):
+    """Each new segment shape compiles for the chip within 60 s (1.5 to
+    5 s when written, PR 36) and holds at most two kets in flight
+    beside the donated one."""
+    structure = RCS_WINDOWS[window]
+    plan, why = fu.kernel_lowering(W, structure, backend="tpu")
+    assert why is None
+    expected = {"intile-and-quad": (1, 0, 6), "cross-pair-quad": (0, 1, 1),
+                "intile-16": (1, 0, 0)}[window]
+    assert tuple(plan["twoq"][f"sweeps.{k}"]
+                 for k in ("intile", "pair", "quad")) == expected
+    t0 = time.perf_counter()
+    compiled = _compile(pk.make_window_fn(W, structure),
+                        _dense_args(structure, one_chip))
+    assert time.perf_counter() - t0 < 60
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= plan["sweeps"]
+    for name, count in zip((pk.TWOQ_INTILE_KERNEL_NAME, pk.TWOQ_PAIR_KERNEL_NAME,
+                            pk.TWOQ_QUAD_KERNEL_NAME), expected):
+        assert (name in text) == bool(count)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * KET_BYTES + SLACK
 
 
 def test_kernel_launches_carry_their_names(one_chip):
