@@ -20,21 +20,40 @@ Vocabulary (everything the fuser emits):
   reads its partner 2^target amplitudes away through two rotations
   (tile_partner); controls anywhere (runtime mask split).
 * inv / gen with target >= block_pow — CROSS-TILE: the planner starts a
-  new segment led by the op, and the segment's grid maps block PAIRS:
-  the planes array is passed twice, the second BlockSpec index-mapping
-  ``i -> i ^ (1 << (target - block_pow))``, so each program instance
-  sees its own tile and its partner tile and computes its own row of
-  the 2x2 mix (inputs are read-only, so the duplicated read is pure).
-  This replaces the old ``target < block_pow`` refusal.
+  new segment led by the op, and the segment's grid walks an ORBIT at a
+  time.  An orbit is the set of tiles the lead mixes, here the two
+  tiles ``{base, base | 1 << (target - block_pow)}`` (orbit_tile); the
+  grid is ``(orbits + 1, members)``, the member innermost.  A step moves
+  one tile in and one tile out, as an unled step does: step ``(o, j)``
+  is handed tile ``j`` of orbit ``o``, casts it to the dense tile
+  (below) into one half of a VMEM scratch, and computes tile ``j`` of
+  orbit ``o - 1`` from the other half, which that orbit's steps filled:
+  its own row of the 2x2 mix over the "bit 0" and "bit 1" tiles.  So a
+  led sweep reads the ket once, writes it once and casts one tile in
+  and one out a step, evenly, whatever the lead mixes (the read is
+  clamped on the added last orbit; the first orbit's steps write blocks
+  that the second's then write with what belongs there).  The tile id
+  that high-bit masks and the riding in-tile ops read is
+  ``orbit_tile(lead bits, o - 1, j)``, not a grid index.
 * u4 — the two-target op, a 4x4 on ``target = (lo, hi)``, never
   controlled: each amplitude reads the four members of its (lo, hi)
   quad and applies its own row of the matrix (tile_quad_mix).  Three
   placements.  ``hi < block_pow``: in the tile, both partners by
   tile_partner, in any segment.  ``lo < block_pow <= hi``: it leads a
-  segment on the pair grid above, the ``lo`` partner taken inside each
-  of the two tiles.  ``lo >= block_pow``: it leads a segment whose grid
-  step sees four tiles (the planes passed four times, the index maps
-  ``i ^ bit(lo)``, ``i ^ bit(hi)`` and both).
+  segment over the two-tile orbits above, the ``lo`` partner taken
+  inside each of the two tiles.  ``lo >= block_pow``: it leads a segment
+  whose orbits are the four tiles over both bits.  tile_quad_mix sums a
+  quad's members in the order the amplitude meets them, itself first:
+  member ``r`` of an orbit reads the scratch's tile ``r ^ x`` for ``x``
+  across.
+
+What is NOT the way to one read: a view of the ket that puts the lead's
+bit on an axis of its own, ``(2, A, 2, B * 2^block_pow)``, so that one
+block holds both tiles.  The planes are ``f32[2, 2^n]`` tiled
+``(2, 128)`` on the chip, plane-interleaved every 128 amplitudes; the
+view's physical order differs, so XLA pays a copy of the ket (6.5 ms at
+w28) per launch for it, and a view with a short minor axis is padded
+(32 GiB at w28: PERF.md section 6, PR 25).
 
 ``sweeps == len(segments)``: a window with no cross-tile non-diagonal
 op is exactly one sweep; each cross-tile op opens one more.
@@ -76,14 +95,15 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_POW = 16
 _LANE_POW = 7  # a vreg is 8 sublanes of 128 lanes
 
-# A pair-grid segment holds two input tiles and one output tile, each
-# double-buffered and padded from 2 to 8 sublanes (12 MiB at block_pow
-# 16), beside the body's temporaries.  On the flat tile a controlled
-# cross-tile gen with five cphases behind it asked for 17.04 MiB against
-# the compiler's default 16 MiB scoped limit (v5e, w28 over 4 pages); on
-# the dense tile the same body, and a 16-op one, compile inside 13 MiB
-# (described v5e, PR 29).  The limit stays as headroom: the v5e has
-# 128 MiB of VMEM.
+# A segment holds one input block and one output block, each
+# double-buffered (2 MiB at block_pow 16), beside the body's
+# temporaries and, where an op leads it, two orbits of cast tiles (2 or
+# 4 MiB).  On the flat tile a controlled cross-tile gen with five
+# cphases behind it asked for 17.04 MiB against the compiler's default
+# 16 MiB scoped limit (v5e, w28 over 4 pages, the grid that read a
+# partner block a step); on the dense tile the same body, and a 16-op
+# one, compile inside 13 MiB (described v5e, PR 29).  The limit stays
+# as headroom: the v5e has 128 MiB of VMEM.
 _VMEM_LIMIT_BYTES = 32 << 20
 
 # floats each op contributes to the packed scalar vector (dense layout
@@ -110,9 +130,9 @@ def plan_window(structure: Tuple, block_pow: int) -> List[dict]:
     Returns a list of ``{"xgen": slot | None, "ops": [slot, ...]}``
     where each slot is ``(op_index, kind, target, has_ctrl)``.  A
     cross-tile inv/gen (target >= block_pow) or u4 (hi >= block_pow)
-    leads its own segment — the pair- or quad-mapped grid mixes partner
-    tiles for exactly one op, then the rest of the segment applies
-    in-tile."""
+    leads its own segment — the grid walks the lead's orbits of two or
+    four tiles and mixes them for exactly one op, then the rest of the
+    segment applies in-tile."""
     segs: List[dict] = []
     cur = {"xgen": None, "ops": []}
     for idx, (kind, target, has_ctrl) in enumerate(structure):
@@ -343,7 +363,7 @@ def tile_local_invert(v, lidx, hi_id, target,
 INTILE_KERNEL_NAME = "qrack_window_intile"
 CROSS_KERNEL_NAME = "qrack_window_cross"
 # the launches that carry a two-target op: an unled segment with one
-# in its tile, and the segments one leads on the pair and quad grids
+# in its tile, and the segments one leads over orbits of two and four tiles
 TWOQ_INTILE_KERNEL_NAME = "qrack_window_twoq_intile"
 TWOQ_PAIR_KERNEL_NAME = "qrack_window_twoq_pair"
 TWOQ_QUAD_KERNEL_NAME = "qrack_window_twoq_quad"
@@ -414,19 +434,33 @@ def _u4_scalars(fv_ref, foff):
             for plane in range(2)]
 
 
+def orbit_tile(lead_bits: Tuple[int, ...], orbit, member):
+    """The tile id of a leading op's ``orbit``-th orbit's ``member``-th
+    tile.  ``lead_bits`` are the positions, in the tile id and ascending,
+    of the lead's targets above the tile: the orbit index is the tile id
+    with those bits taken out (a zero is put back in at each), and bit
+    ``p`` of the member index goes to position ``lead_bits[p]``.  Plain
+    shifts and masks: it serves the BlockSpecs' index maps, the kernel
+    bodies and, on Python ints, the tests."""
+    for h in lead_bits:
+        orbit = ((orbit >> h) << (h + 1)) | (orbit & ((1 << h) - 1))
+    for p, h in enumerate(lead_bits):
+        orbit = orbit | (((member >> p) & 1) << h)
+    return orbit
+
+
 def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
     """One pl.pallas_call for one segment: run(planes, iv, fv)."""
     block = 1 << bp
     nblk = 1 << (n - bp)
     lbits = block - 1
     xgen = seg["xgen"]
-    iv_spec = fv_spec = _SCALAR_SPEC
-    tile_spec = pl.BlockSpec((2, block), lambda i: (0, i))
 
     # the tile the body computes on: (rows, 128) full vregs where the
-    # block has them, else the (block,) row the refs hold.  A cross-tile
-    # segment casts its two inputs, ahead of the mix: fewer instructions
-    # than casting the mixed value (PERF.md section 6, PR 29)
+    # block has them, else the (block,) row the refs hold.  A led
+    # segment casts its inputs, ahead of the mix (fewer instructions
+    # than casting the mixed value: PERF.md section 6, PR 29), each
+    # tile once, into a scratch (led_kernel)
     tile = dense_tile(bp) or (block,)
 
     def load(ref):
@@ -442,26 +476,44 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
                 v = jax.lax.optimization_barrier(v)
         return v.reshape(2, block)
 
-    def launch(kernel, partner_bits=()):
-        """run(planes, iv, fv): the kernel over every tile, the planes
-        passed once more for each partner tile ``i ^ bits`` a grid step
-        reads beside its own."""
+    def launch(kernel, lead_bits=()):
+        """run(planes, iv, fv): the kernel over every tile, one tile in
+        and one tile out a grid step.  An unled segment's step is its
+        tile.  A led one's grid is (orbit, member), the member
+        innermost, and runs one orbit longer than the ket has: step
+        (o, j) reads tile j of orbit o and writes tile j of orbit
+        o - 1 (led_kernel), the read clamped at the last orbit and the
+        write at the first, whose blocks the next orbit's steps write
+        again with what belongs there."""
         name = segment_kernel_name(seg, bp)
-        partner_specs = [pl.BlockSpec((2, block), lambda i, x=x: (0, i ^ x))
-                         for x in partner_bits]
+        m = 1 << len(lead_bits)
+        if lead_bits:
+            last = nblk // m - 1
+            grid = (nblk // m + 1, m)
+            in_spec = pl.BlockSpec((2, block), lambda o, j: (0, orbit_tile(
+                lead_bits, jnp.minimum(o, last), j)))
+            out_spec = pl.BlockSpec((2, block), lambda o, j: (0, orbit_tile(
+                lead_bits, jnp.maximum(o - 1, 0), j)))
+        else:
+            grid = (nblk,)
+            in_spec = out_spec = pl.BlockSpec((2, block), lambda i: (0, i))
 
         def run(planes, iv, fv):
+            # two orbits of cast tiles: the one read and the one written
+            scratch = [pltpu.VMEM((2, m, 2) + tile, planes.dtype)] \
+                if lead_bits else []
             return pl.pallas_call(
                 kernel,
                 out_shape=jax.ShapeDtypeStruct((2, 1 << n), planes.dtype),
-                grid=(nblk,),
-                in_specs=[iv_spec, fv_spec, tile_spec] + partner_specs,
-                out_specs=tile_spec,
+                grid=grid,
+                in_specs=[_SCALAR_SPEC, _SCALAR_SPEC, in_spec],
+                out_specs=out_spec,
+                scratch_shapes=scratch,
                 compiler_params=_COMPILER_PARAMS,
                 interpret=interpret,
                 name=name,
                 metadata={"qrack_kernel": name},
-            )(iv, fv, planes, *([planes] * len(partner_bits)))
+            )(iv, fv, planes)
 
         return run
 
@@ -472,55 +524,65 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
 
         return launch(kernel)
 
+    def led_kernel(mix, lead_bits):
+        """The kernel of a led segment from its lead's ``mix(tiles,
+        member, blk, iv_ref, fv_ref)``, ``tiles(k)`` the orbit's
+        ``k``-th tile.  Step (o, j) casts the tile it is handed, tile j
+        of orbit o, into one half of a VMEM scratch, and computes tile j
+        of orbit o - 1 from the other half, which that orbit's steps
+        filled: every step loads one tile, casts one in and one out and
+        stores one, as an unled step does, whatever the lead mixes."""
+        orbits = nblk >> len(lead_bits)
+
+        def kernel(iv_ref, fv_ref, in_ref, out_ref, orbit_ref):
+            o, member = pl.program_id(0), pl.program_id(1)
+
+            @pl.when(o < orbits)
+            def _():
+                # + 0.0: arithmetic on the cast value makes Mosaic cast
+                # it in registers and store full vregs; a cast that
+                # feeds a store alone becomes one sublane-strided store
+                # per row, twice a register cast's time (PERF.md s.6,
+                # PR 37).  It turns a -0.0 into 0.0 and nothing else
+                orbit_ref[o & 1, member] = load(in_ref) + 0.0
+
+            @pl.when(o > 0)
+            def _():
+                blk = orbit_tile(lead_bits, o - 1, member)
+                nv = mix(lambda k: orbit_ref[(o - 1) & 1, k], member, blk,
+                         iv_ref, fv_ref)
+                out_ref[...] = in_tile_ops(nv, blk, iv_ref, fv_ref)
+
+        return launch(kernel, lead_bits)
+
     idx, kind, target, has_ctrl = xgen
     foff_x, ioff_x = slots[idx]
 
     if kind == "u4":
-        # the two-target op above the tile: the quad's members are this
-        # tile, its partner tiles, and the low partner inside each where
-        # the low target is in the tile
+        # the two-target op above the tile: the quad's members are the
+        # orbit's tiles in the order this member meets them, itself
+        # first (the order tile_quad_mix sums in), and the low partner
+        # inside each where the low target is in the tile
         lo, hi = target
-        h2 = 1 << (hi - bp)
+        lead_bits = tuple(t - bp for t in target if t >= bp)
 
-        if lo < bp:
-            def kernel(iv_ref, fv_ref, in_ref, pa_ref, out_ref):
-                blk = pl.program_id(0)
+        def mix(tiles, member, blk, iv_ref, fv_ref):
+            seen = [tiles(member ^ x) for x in range(1 << len(lead_bits))]
+            if lo < bp:
                 lidx = _tile_index(tile)
-                mine, other = load(in_ref), load(pa_ref)
-                members = ((mine, tile_partner(mine, lidx, lo)),
-                           (other, tile_partner(other, lidx, lo)))
-                nv = tile_quad_mix(members, (lidx & (1 << lo)) != 0,
-                                   (blk & h2) != 0,
-                                   _u4_scalars(fv_ref, foff_x))
-                out_ref[...] = in_tile_ops(nv, blk, iv_ref, fv_ref)
+                members = tuple((v, tile_partner(v, lidx, lo)) for v in seen)
+                b1, b2 = (lidx & (1 << lo)) != 0, member != 0
+            else:
+                members = (seen[:2], seen[2:])
+                b1, b2 = (member & 1) != 0, (member & 2) != 0
+            return tile_quad_mix(members, b1, b2, _u4_scalars(fv_ref, foff_x))
 
-            return launch(kernel, (h2,))
+        return led_kernel(mix, lead_bits)
 
-        h1 = 1 << (lo - bp)
-
-        def kernel(iv_ref, fv_ref, in_ref, p1_ref, p2_ref, p12_ref, out_ref):
-            blk = pl.program_id(0)
-            members = ((load(in_ref), load(p1_ref)),
-                       (load(p2_ref), load(p12_ref)))
-            nv = tile_quad_mix(members, (blk & h1) != 0, (blk & h2) != 0,
-                               _u4_scalars(fv_ref, foff_x))
-            out_ref[...] = in_tile_ops(nv, blk, iv_ref, fv_ref)
-
-        return launch(kernel, (h1, h2, h1 | h2))
-
-    # cross-tile segment: partner-pair grid for the leading inv/gen
-    h = target - bp
-
-    def kernel(iv_ref, fv_ref, in_ref, pa_ref, out_ref):
-        blk = pl.program_id(0)
-        b = (blk >> h) & 1
-        mine = load(in_ref)
-        other = load(pa_ref)
-        # target-bit-0 / target-bit-1 operands of the 2x2, from my side
-        lo_r = jnp.where(b == 0, mine[0], other[0])
-        lo_i = jnp.where(b == 0, mine[1], other[1])
-        hi_r = jnp.where(b == 0, other[0], mine[0])
-        hi_i = jnp.where(b == 0, other[1], mine[1])
+    # cross-tile segment: the leading inv/gen mixes the orbit's two tiles
+    def mix(tiles, b, blk, iv_ref, fv_ref):
+        # the target-bit-0 / target-bit-1 operands of the 2x2
+        lo, hi = tiles(0), tiles(1)
         if kind == "gen":
             # my row of the matrix: row b -> (m[b,0], m[b,1]);
             # fv holds mtrx_planes flat: [re00,re01,re10,re11,im...]
@@ -533,13 +595,13 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
             m1i = jnp.where(b == 0, fv_ref[foff_x + 5, 0],
                             fv_ref[foff_x + 7, 0])
         else:  # inv rows: (0, tr) and (bl, 0); fv holds [tr.re,tr.im,bl...]
-            zero = jnp.zeros((), mine.dtype)
+            zero = jnp.zeros((), lo.dtype)
             m0r = jnp.where(b == 0, zero, fv_ref[foff_x + 2, 0])
             m0i = jnp.where(b == 0, zero, fv_ref[foff_x + 3, 0])
             m1r = jnp.where(b == 0, fv_ref[foff_x + 0, 0], zero)
             m1i = jnp.where(b == 0, fv_ref[foff_x + 1, 0], zero)
-        nr = m0r * lo_r - m0i * lo_i + m1r * hi_r - m1i * hi_i
-        nim = m0r * lo_i + m0i * lo_r + m1r * hi_i + m1i * hi_r
+        nr = m0r * lo[0] - m0i * lo[1] + m1r * hi[0] - m1i * hi[1]
+        nim = m0r * lo[1] + m0i * lo[0] + m1r * hi[1] + m1i * hi[0]
         nv = jnp.stack([nr, nim])
         if has_ctrl:
             cm = iv_ref[ioff_x, 0]
@@ -547,10 +609,10 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
             lidx = _tile_index(tile)
             sel = (((lidx & (cm & lbits)) == (cv & lbits))
                    & ((blk & (cm >> bp)) == (cv >> bp)))
-            nv = jnp.where(sel, nv, mine)
-        out_ref[...] = in_tile_ops(nv, blk, iv_ref, fv_ref)
+            nv = jnp.where(sel, nv, tiles(b))
+        return nv
 
-    return launch(kernel, (1 << h,))
+    return led_kernel(mix, (target - bp,))
 
 
 def make_window_fn(n: int, structure: Tuple,

@@ -27,6 +27,7 @@ from qrack_tpu.fleet import (AdoptionStalled, AutoscaleConfig, Autoscaler,
 from qrack_tpu.fleet import heartbeat as hb
 from qrack_tpu.fleet import rpc
 from qrack_tpu.layers.qcircuit import QCircuit
+from qrack_tpu import resilience
 from qrack_tpu.resilience import faults
 from qrack_tpu.resilience.probe import reap_child
 from qrack_tpu.utils.rng import QrackRandom
@@ -37,6 +38,7 @@ def _clean_fleet():
     faults.clear()
     yield
     faults.clear()
+    resilience.disable()    # faults.inject switches it on; clear() leaves it
     tele.disable()
     tele.reset()
 

@@ -572,3 +572,234 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
         dense += plan["dense"]
         with_ops += sum(bool(seg["ops"]) for seg in segments)
     assert (dense, with_ops) == (sweeps, carry_ops)
+
+
+# ---------------------------------------------------------------------------
+# led segments: the grid walks an orbit at a time (PR 37).  A led
+# segment's grid is (orbit, member); a step reads one tile of its orbit
+# into a VMEM scratch and writes one tile of the orbit before, computed
+# from the scratch; the body computes what the parent's did, in its
+# order.  Held here bit for bit against numpy's float32, one IEEE
+# operation at a time, and (the property the gain rests on) by reading
+# the index maps.
+# ---------------------------------------------------------------------------
+
+def _exact(fn, *args):
+    """``fn(*args)`` compiled with XLA's CPU backend at optimization
+    level 0.  At its default level that backend contracts ``a * b + c``
+    into one rounding where its fusions happen to allow it, so two
+    bodies with the same arithmetic differ in a last bit here and there
+    (29 of 116 led windows, new grid against old, and none at level 0
+    or with FMA off: PR 37); at level 0 every product and sum rounds on
+    its own, which is numpy's arithmetic and the TPU's."""
+    import jax
+
+    return np.asarray(jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_backend_optimization_level": 0})(*args))
+
+
+def lead_in_numpy(ket, op, n):
+    """A leading op on the whole ``(2, 2^n)`` float32 ket, in the order
+    the kernel computes it: a 2 x 2 as its own row over the (bit 0,
+    bit 1) pair, column 0 first; a 4 x 4 as its own row over the quad's
+    members in the order the amplitude meets them, itself first
+    (``tile_quad_mix``)."""
+    idx = np.arange(1 << n)
+    m = np.asarray(op.m)
+    re, im = m.real.astype(np.float32), m.imag.astype(np.float32)
+    if op.kind == "u4":
+        lo, hi = op.target
+        row = (((idx >> hi) & 1) << 1) | ((idx >> lo) & 1)
+        acc = None
+        for x in range(4):
+            v = ket[:, idx ^ ((x & 1) << lo) ^ ((x >> 1) << hi)]
+            cre, cim = re[row, row ^ x], im[row, row ^ x]
+            term = (v[0] * cre - v[1] * cim, v[0] * cim + v[1] * cre)
+            acc = term if acc is None else (acc[0] + term[0], acc[1] + term[1])
+        return np.stack(acc)
+    bit = 1 << op.target
+    b = (idx >> op.target) & 1
+    lo, hi = ket[:, idx & ~bit], ket[:, idx | bit]
+    m0r, m0i, m1r, m1i = re[b, 0], im[b, 0], re[b, 1], im[b, 1]
+    nv = np.stack([m0r * lo[0] - m0i * lo[1] + m1r * hi[0] - m1i * hi[1],
+                   m0r * lo[1] + m0i * lo[0] + m1r * hi[1] + m1i * hi[0]])
+    return np.where((idx & op.cmask) == op.cval, nv, ket)
+
+
+def _su(rng, k):
+    q, _ = np.linalg.qr(rng.normal(size=(k, k)) + 1j * rng.normal(size=(k, k)))
+    return q
+
+
+def riders(n, bp):
+    """In-tile ops to ride behind a lead, each reading the tile id: a
+    cphase controlled on the two highest qubits above the tile (one,
+    where there is one), a diag on the top qubit, an inv controlled on
+    the lowest bit above the tile; a gen and a u4 between them."""
+    rng = np.random.default_rng(n * 31 + bp)
+    high = (1 << (n - 1)) | (1 << max(n - 2, bp))
+    return [fu.FusedOp("gen", 3, 1 << 1, 1 << 1, _su(rng, 2)),
+            fu.FusedOp("cphase", 2, high, high, _DENSE_MATRICES["cphase"]),
+            fu.FusedOp("diag", n - 1, 1 << 5, 0, _DENSE_MATRICES["diag"]),
+            fu.FusedOp("u4", (0, 4), 0, 0, _su(rng, 4)),
+            fu.FusedOp("inv", 6, 1 << bp, 1 << bp, _DENSE_MATRICES["inv"])]
+
+
+def led_segment_against_numpy(n, bp, lead, behind, seed):
+    """``(got, want)``: the kernel window ``[lead] + riders`` under the
+    interpreter, and the lead in numpy with the riders applied by the
+    unled kernel, whose tile id is its grid step."""
+    import jax.numpy as jnp
+
+    def window(ops):
+        fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
+                               interpret=True)
+        return lambda ket: _exact(fn, jnp.asarray(ket),
+                                  *fu.pack_operands(ops, jnp.float32))
+
+    behind = riders(n, bp) if behind else []
+    assert pk.plan_window(fu.structure_of([lead] + behind), bp)[0]["xgen"][0] == 0
+    rng = np.random.default_rng(seed)
+    ket = rng.standard_normal((2, 1 << n)).astype(np.float32)
+    ket /= np.sqrt((ket ** 2).sum(dtype=np.float32))
+    want = lead_in_numpy(ket, lead, n)
+    if behind:
+        assert len(pk.plan_window(fu.structure_of(behind), bp)) == 1
+        want = window(behind)(want)
+    return window([lead] + behind)(ket), want
+
+
+# (width, block_pow): 2, 4 and 16 tiles, on the flat tile and the dense
+ORBIT_SHAPES = [(8, 7), (9, 7), (11, 7), (11, 10), (12, 10), (14, 10)]
+
+
+def _led_2x2_cases():
+    cases = []
+    for n, bp in ORBIT_SHAPES:
+        for target in sorted({bp, n - 1}):
+            # controls: a bit inside the tile, and above it the highest
+            # qubit that is not the target (none where the tile id is
+            # the target bit alone); gen's is an anti-control in the tile
+            above = [q for q in range(bp, n) if q != target][-1:]
+            cmask = (1 << 1) | sum(1 << q for q in above)
+            for kind in ("gen", "inv"):
+                for ctrl in (False, True):
+                    for behind in (False, True):
+                        cases.append(pytest.param(
+                            n, bp, kind, target, cmask if ctrl else 0, behind,
+                            id=f"w{n}-bp{bp}-{'c' if ctrl else ''}{kind}{target}"
+                               + ("-riders" if behind else "-bare")))
+    return cases
+
+
+@pytest.mark.parametrize("n,bp,kind,target,cmask,behind", _led_2x2_cases())
+def test_led_2x2_segment_is_numpy_bit_for_bit(n, bp, kind, target, cmask,
+                                              behind):
+    cval = cmask & ~2 if kind == "gen" else cmask
+    lead = fu.FusedOp(kind, target, cmask, cval, _DENSE_MATRICES[kind])
+    got, want = led_segment_against_numpy(n, bp, lead, behind, seed=n + target)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+
+def grids_of(fn, *args):
+    """``(grid, input index maps, output index map)`` of every
+    pallas_call ``fn`` traces to; a map takes the grid indices to a
+    block's column (every block here is ``(2, 2^block_pow)``, row 0),
+    the two scalar columns left out."""
+    import jax
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["grid_mapping"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    def column(mapping):
+        closed = mapping.index_map_jaxpr
+
+        def at(*ids):
+            row, col = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *ids)
+            assert int(row) == 0
+            return int(col)
+        return at
+
+    out = []
+    for gm in calls(jax.make_jaxpr(fn)(*args).jaxpr):
+        maps = [column(bm) for bm in gm.block_mappings[2:]]
+        assert gm.num_outputs == 1
+        out.append((tuple(gm.grid), maps[:-1], maps[-1]))
+    return out
+
+
+def _u4(lo, hi):
+    return fu.FusedOp("u4", (lo, hi), 0, 0, np.eye(4))
+
+
+_ORBIT_LEADS = [
+    pytest.param(8, 7, fu.FusedOp("gen", 7, 0, 0, np.eye(2)), (0,),
+                 id="2tiles-gen"),
+    pytest.param(9, 7, fu.FusedOp("inv", 8, 1, 1, np.eye(2)), (1,),
+                 id="4tiles-cinv"),
+    pytest.param(9, 7, _u4(7, 8), (0, 1), id="4tiles-quad"),
+    pytest.param(12, 8, fu.FusedOp("gen", 10, 0, 0, np.eye(2)), (2,),
+                 id="16tiles-gen"),
+    pytest.param(12, 8, _u4(3, 11), (3,), id="16tiles-pair-u4"),
+    pytest.param(12, 8, _u4(9, 11), (1, 3), id="16tiles-quad"),
+    pytest.param(14, 10, _u4(10, 13), (0, 3), id="16tiles-dense-quad"),
+]
+
+
+@pytest.mark.parametrize("n,bp,lead,lead_bits", _ORBIT_LEADS)
+def test_a_led_segment_moves_one_tile_in_and_one_out_a_step(n, bp, lead,
+                                                            lead_bits):
+    """What the gain rests on, held without a chip: a led launch has one
+    input and one output block a step, as an unled one has; over the
+    grid it reads every tile once, an orbit at a time, and writes every
+    tile once, an orbit behind (the read clamped on the added last
+    orbit, the write on the first, whose blocks are written again by
+    the steps that own them)."""
+    import jax.numpy as jnp
+
+    ops = [lead, fu.FusedOp("cphase", 2, 1 << (n - 1), 1 << (n - 1),
+                            _DENSE_MATRICES["cphase"])]
+    fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
+                           interpret=True)
+    (grid, (read,), write), = grids_of(fn, jnp.zeros((2, 1 << n), jnp.float32),
+                                       *fu.pack_operands(ops, jnp.float32))
+    nblk, m = 1 << (n - bp), 1 << len(lead_bits)
+    orbits = nblk // m
+    assert grid == (orbits + 1, m)
+    mask = sum(1 << h for h in lead_bits)
+    for orbit in range(orbits):
+        tiles = [read(orbit, member) for member in range(m)]
+        assert tiles == [pk.orbit_tile(lead_bits, orbit, member)
+                         for member in range(m)]
+        # what the next orbit's steps write, each its own
+        assert [write(orbit + 1, member) for member in range(m)] == tiles
+        # the tiles the lead mixes: one value of the other bits, every
+        # value of its own
+        assert len({t & ~mask for t in tiles}) == 1
+        assert sorted(t & mask for t in tiles) == sorted(
+            sum(((k >> p) & 1) << h for p, h in enumerate(lead_bits))
+            for k in range(m))
+    steps = [(o, j) for o in range(orbits + 1) for j in range(m)]
+    assert sorted(read(o, j) for o, j in steps[:nblk]) == list(range(nblk))
+    assert sorted(write(o, j) for o, j in steps[m:]) == list(range(nblk))
+    # the clamped ends stay inside the ket and inside their own orbit
+    assert [read(orbits, j) for j in range(m)] \
+        == [read(orbits - 1, j) for j in range(m)]
+    assert [write(0, j) for j in range(m)] == [write(1, j) for j in range(m)]
+
+
+def test_an_unled_segment_keeps_its_grid():
+    import jax.numpy as jnp
+
+    ops = [fu.FusedOp("gen", 3, 0, 0, np.eye(2))]
+    fn = pk.make_window_fn(12, fu.structure_of(ops), block_pow=8,
+                           interpret=True)
+    (grid, (tile,), out), = grids_of(fn, jnp.zeros((2, 1 << 12), jnp.float32),
+                                     *fu.pack_operands(ops, jnp.float32))
+    assert grid == (16,)
+    assert [tile(i) for i in range(16)] == [out(i) for i in range(16)] \
+        == list(range(16))

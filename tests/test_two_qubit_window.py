@@ -23,6 +23,8 @@ from qrack_tpu.ops import fusion as fu
 from qrack_tpu.ops import pallas_kernels as pk
 from qrack_tpu.utils.rng import QrackRandom
 
+from test_pallas_window import ORBIT_SHAPES, led_segment_against_numpy
+
 
 @pytest.fixture(autouse=True)
 def _clean(monkeypatch):
@@ -374,3 +376,31 @@ def test_rcs_family_against_the_cpu_engine(kernel_on, n):
     want = rcs.reference_rcs_state(
         n, 6, 21, QEngineCPU(n, rng=QrackRandom(3), rand_global_phase=False))
     assert np.max(np.abs(q.GetQuantumState() - want)) < 5e-6
+
+
+# -- a u4 that leads its segment, on the orbit grid (PR 37) ------------------
+
+def _led_u4_cases():
+    cases = []
+    for n, bp in ORBIT_SHAPES:
+        pairs = {(5, bp), (2, n - 1)}                    # the pair grid
+        if n - bp >= 2:                                  # four tiles
+            pairs |= {(bp, bp + 1), (bp, n - 1), (n - 2, n - 1)}
+        for lo, hi in sorted(pairs):
+            for behind in (False, True):
+                cases.append(pytest.param(
+                    n, bp, lo, hi, behind,
+                    id=f"w{n}-bp{bp}-{'pair' if lo < bp else 'quad'}{lo}.{hi}"
+                       + ("-riders" if behind else "-bare")))
+    return cases
+
+
+@pytest.mark.parametrize("n,bp,lo,hi,behind", _led_u4_cases())
+def test_led_u4_segment_is_numpy_bit_for_bit(n, bp, lo, hi, behind):
+    """The two-target lead on the pair grid and on four tiles, alone and
+    with in-tile ops that read the tile id behind it: the float32 bits of
+    ``tile_quad_mix``'s order in numpy (tests/test_pallas_window.py)."""
+    lead = fu.FusedOp("u4", (lo, hi), 0, 0,
+                      _su(np.random.default_rng(lo * 16 + hi), 4))
+    got, want = led_segment_against_numpy(n, bp, lead, behind, seed=lo + hi)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
