@@ -647,10 +647,14 @@ def plan_remaps(ops: Sequence[FusedOp], L: int, qmap: Sequence[int],
     hot = sorted(((worth(q, new_qmap[q]), q) for q in range(n)
                   if new_qmap[q] >= L and (win[q] or look[q])),
                  key=lambda t: (-t[0], t[1]))
+    # equally cold victims: the highest local position first.  The top k
+    # local bits are the batched exchange's carriers (sharded.
+    # plan_exchange), so a victim there needs no pass over the page to
+    # get onto one and none to get off it afterwards
     cold = sorted(((win[q] * GEN_GLOBAL_COST + min(look[q],
                                                    REMAP_PAIR_COST), q)
                    for q in range(n) if new_qmap[q] < L),
-                  key=lambda t: (t[0], t[1]))
+                  key=lambda t: (t[0], -new_qmap[t[1]]))
     best_k, best_net = 0, 0.0
     for k in range(1, min(len(hot), len(cold)) + 1):
         gbits = [new_qmap[q] - L for _, q in hot[:k]]

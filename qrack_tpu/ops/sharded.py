@@ -222,21 +222,20 @@ def mixed_swap(local, npg: int, L: int, lpos: int, gpos: int):
     g-bit (those amplitudes don't move) and ships the other half to its
     bit-flipped partner — whose shipped half is exactly the slab this
     page needs.  One ppermute, half a page per payload: the same traffic
-    bound as a paged-target 2x2, but a pure relabeling (no arithmetic)."""
-    pid = page_id()
-    b = (pid >> gpos) & 1
-    lo = 1 << lpos
-    hi = local.shape[-1] // (2 * lo)
-    arr = local.reshape(local.shape[0], hi, 2, lo)
-    a0 = arr[:, :, 0, :]
-    a1 = arr[:, :, 1, :]
-    keep = jnp.where(b == 0, a0, a1)   # l-bit == own g-bit: stays
-    away = jnp.where(b == 0, a1, a0)   # l-bit != g-bit: belongs to partner
-    perm = [(j, j ^ (1 << gpos)) for j in range(npg)]
-    got = exchange(away, perm)
-    s0 = jnp.where(b == 0, keep, got)
-    s1 = jnp.where(b == 0, got, keep)
-    return jnp.stack([s0, s1], axis=2).reshape(local.shape)
+    bound as a paged-target 2x2, but a pure relabeling (no arithmetic).
+
+    The halves are the two contiguous halves of the page (the top local
+    bit), so a lower ``lpos`` first trades places with the top bit in
+    the page and trades back afterwards: a ``(planes, hi, 2, lo)`` view
+    of the page is what the TPU compiler took 935 s over at a 2 GiB
+    page (PERF.md §6, PR 30 and PR 38)."""
+    top = L - 1
+    if lpos != top:
+        local = gk.swap_bits(local, L, lpos, top)
+    local = batched_mixed_swap(local, npg, 1, (gpos,))
+    if lpos != top:
+        local = gk.swap_bits(local, L, lpos, top)
+    return local
 
 
 # ---------------------------------------------------------------------------
@@ -314,6 +313,22 @@ def plan_exchange(L: int, g: int, swaps):
     ordered = [by_dest.pop(t, None) for t in crossers]
     leftovers = iter(c for c in cross_in if c in by_dest.values())
     cross_in = [c if c is not None else next(leftovers) for c in ordered]
+    # which pair rides which carrier is free: a crossing content that
+    # already sits on a carrier bit keeps it, so a victim among the top
+    # k local bits costs no pass over the page before the exchange (and
+    # none after it, where the swap is a plain transposition)
+    slots = [None] * k
+    rest = []
+    for pair in zip(cross_in, crossers):
+        j = pair[0] - (L - k)
+        if 0 <= j < k and slots[j] is None:
+            slots[j] = pair
+        else:
+            rest.append(pair)
+    rest = iter(rest)
+    slots = [pair if pair is not None else next(rest) for pair in slots]
+    cross_in = [c for c, _ in slots]
+    crossers = [t for _, t in slots]
     # pre-shuffle: crossing local contents onto the carrier (top-k) bits,
     # everything else staying put where possible
     A = {c: carriers[j] for j, c in enumerate(cross_in)}
@@ -360,6 +375,15 @@ def page_perm_of(page_dest, g: int):
     return perm
 
 
+def _pick(index, choices):
+    """``choices[index]`` for a traced scalar ``index``: whole-array
+    selects over static operands, no traced slice start."""
+    out = choices[0]
+    for s in range(1, len(choices)):
+        out = jnp.where(index == s, choices[s], out)
+    return out
+
+
 def batched_mixed_swap(local, npg: int, k: int, gpos):
     """k disjoint mixed transpositions — carrier local bits [L-k, L)
     against page bits ``gpos`` — as one batched exchange: for every
@@ -367,25 +391,31 @@ def batched_mixed_swap(local, npg: int, k: int, gpos):
     sub-block its XOR-d partner needs, in one ppermute.  The d=0
     diagonal never moves, so total traffic is (1 - 2^-k) state volumes
     and all 2^k - 1 transfers are independent (one collective round on
-    hardware that overlaps them, vs k serialized half-buffer swaps)."""
+    hardware that overlaps them, vs k serialized half-buffer swaps).
+
+    The sub-blocks are static slices of the minor axis, chosen by
+    selects on this page's bits and joined with ``concatenate``: no
+    ``(planes, 2^k, -1)`` view and no slice or update at a traced index
+    (as ``apply_global_2x2``: PERF.md §6, PR 35 and PR 38)."""
     pid = page_id()
     nsub = 1 << k
-    sub = local.reshape(local.shape[0], nsub, -1)
+    size = local.shape[-1] // nsub
+    sub = [local[:, s * size:(s + 1) * size] for s in range(nsub)]
     b = jnp.zeros((), pid.dtype)
     for j, gp in enumerate(gpos):
         b = b | (((pid >> gp) & 1) << j)
-    out = sub
+    got = [None] * nsub   # got[d]: what the XOR-d partner sent
     for d in range(1, nsub):
         pd = 0
         for j, gp in enumerate(gpos):
             if (d >> j) & 1:
                 pd |= 1 << gp
         perm = [(j2, j2 ^ pd) for j2 in range(npg)]
-        payload = jax.lax.dynamic_index_in_dim(sub, b ^ d, axis=1,
-                                               keepdims=True)
-        got = exchange(payload, perm)
-        out = jax.lax.dynamic_update_slice_in_dim(out, got, b ^ d, axis=1)
-    return out.reshape(local.shape)
+        got[d] = exchange(_pick(b ^ d, sub), perm)
+    # slot s keeps its own block on the page whose bits are s and else
+    # holds what the partner at offset s ^ b sent
+    out = [_pick(b ^ s, [sub[s]] + got[1:]) for s in range(nsub)]
+    return jnp.concatenate(out, axis=-1)
 
 
 def apply_remap(local, npg: int, L: int, swaps, batched: bool = True):

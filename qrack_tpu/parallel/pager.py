@@ -27,7 +27,7 @@ jax.distributed processes; the kernels are unchanged.
 from __future__ import annotations
 
 import math
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -121,6 +121,17 @@ def _host_read(x) -> np.ndarray:
     if _res._ACTIVE:
         return _res.call_guarded("pager.device_get", _host_read_raw, (x,))
     return _host_read_raw(x)
+
+
+class WindowPlan(NamedTuple):
+    """What :meth:`QPager._plan_window` decides about one window."""
+    swaps: tuple          # the planner's physical transpositions (prologue)
+    new_qmap: Sequence    # the placement table after them
+    tops: Sequence        # the window's ops on that table
+    batched: bool         # the prologue's lowering
+    structure: Optional[tuple]  # None: one op, the shared eager programs
+    kernel: Optional[dict]      # the per-page kernel lowering, or None
+    why: Optional[str]          # why not, where it is None
 
 
 class QPager(QEngine):
@@ -249,6 +260,13 @@ class QPager(QEngine):
     def _map_nonid(self) -> bool:
         return any(q != p for q, p in enumerate(self._qmap))
 
+    def placement(self) -> Tuple[int, ...]:
+        """The placement table: the bit position, in the planes the
+        pager holds, of each logical qubit.  A read of the table alone:
+        it flushes no pending window (whose prologue may still move the
+        table) and undoes no remap, so what runs next is unchanged."""
+        return tuple(self._qmap)
+
     def _map_index(self, idx: int) -> int:
         """Logical basis index -> physical basis index (exact at any
         width: pure Python ints)."""
@@ -356,6 +374,13 @@ class QPager(QEngine):
             if sum(1 for p1, p2 in swaps if max(p1, p2) >= L) >= 2:
                 _tele.inc("remap.pager.batched")
             _tele.inc("exchange.pager.collective_bytes", frac * nb)
+            # the prologue by what its lowering sends: k pairs across the
+            # page boundary in one batch, then whole pages where a
+            # permutation of the page bits is left over
+            plan = shb.plan_exchange(L, self.g_bits, swaps)
+            _tele.inc(f"remap.pager.prologues.k{plan.k}")
+            if plan.page_dest is not None:
+                _tele.inc("remap.pager.page_perms")
         self._tele_exchange("remap", frac * nb)
 
     def _unmap(self) -> None:
@@ -703,6 +728,34 @@ class QPager(QEngine):
         self._settle()
         self._dispatch_ops(ops)
 
+    def _plan_window(self, ops, lookahead=None) -> WindowPlan:
+        """Everything about one window of LOGICAL ops that is decided
+        before its operands are packed, from the table and the ops
+        alone: the planner's swaps, the ops on the table after them and
+        the program's structure and kernel lowering."""
+        from ..ops import fusion as fu
+
+        L = self.local_bits
+        swaps = ()
+        new_qmap = self._qmap
+        batched = self._collective_batched()
+        if self._remap_active():
+            with _tele.span("remap.plan"):
+                swaps, new_qmap = fu.plan_remaps(
+                    ops, L, self._qmap, lookahead,
+                    weights=self._exchange_weights, batched=batched)
+        tops = (fu.translate_ops(ops, new_qmap)
+                if (swaps or self._map_nonid()) else ops)
+        # merged down to one op on the current placement: the shared
+        # eager programs already exist and are cheaper than a fresh
+        # one-op window structure
+        if len(tops) == 1 and not swaps:
+            return WindowPlan(swaps, new_qmap, tops, batched, None, None, None)
+        structure = fu.sharded_structure_of(tops)
+        kernel, why = fu.sharded_kernel_lowering(L, structure)
+        return WindowPlan(swaps, new_qmap, tops, batched, structure, kernel,
+                          why)
+
     def _dispatch_ops(self, ops, lookahead=None) -> int:
         """Lower + dispatch one window of LOGICAL ops: plan placement
         swaps against the window + lookahead, translate ops onto the
@@ -715,22 +768,10 @@ class QPager(QEngine):
 
         L = self.local_bits
         with _tele.span("fuse.lower"):
-            swaps = ()
-            new_qmap = self._qmap
-            batched = self._collective_batched()
-            if self._remap_active():
-                swaps, new_qmap = fu.plan_remaps(
-                    ops, L, self._qmap, lookahead,
-                    weights=self._exchange_weights, batched=batched)
-            tops = (fu.translate_ops(ops, new_qmap)
-                    if (swaps or self._map_nonid()) else ops)
-            # merged down to one op on the current placement: the shared
-            # eager programs already exist and are cheaper than a fresh
-            # one-op window structure
-            one_op = len(tops) == 1 and not swaps
+            swaps, new_qmap, tops, batched, structure, plan, why = \
+                self._plan_window(ops, lookahead)
+            one_op = structure is None
             if not one_op:
-                structure = fu.sharded_structure_of(tops)
-                plan, why = fu.sharded_kernel_lowering(L, structure)
                 prog = self._p_fuse_window(structure, kernel_plan=plan,
                                            remap=swaps, batched=batched)
         with _tele.span("fuse.operands"):

@@ -47,3 +47,62 @@ def rand_state(n: int, seed: int) -> np.ndarray:
     g = np.random.Generator(np.random.PCG64(seed))
     v = g.normal(size=1 << n) + 1j * g.normal(size=1 << n)
     return (v / np.linalg.norm(v)).astype(np.complex128)
+
+
+def trotter_step_gates(n: int, dt: float = 0.1, j: float = 1.0, h: float = 1.0):
+    """One first-order Trotter step of the open transverse-field Ising
+    chain as ``(controls, matrix, target)`` gate calls, in the order of
+    ``models/algorithms.trotter_qcircuit`` (and of the benchmark's
+    ``circuits/tfim.gates``): CNOT, RZ(2 j dt), CNOT on every bond, then
+    RX(2 h dt) on every qubit: 4 n - 3 calls."""
+    x2 = np.array([[0, 1], [1, 0]], dtype=np.complex128)
+    tz, tx = 2.0 * j * dt, 2.0 * h * dt
+    rz = np.diag([np.exp(-0.5j * tz), np.exp(0.5j * tz)])
+    c, s = np.cos(tx / 2), np.sin(tx / 2)
+    rx = np.array([[c, -1j * s], [-1j * s, c]], dtype=np.complex128)
+    out = []
+    for i in range(n - 1):
+        out += [((i,), x2, i + 1), ((), rz, i + 1), ((i,), x2, i + 1)]
+    out += [((), rx, q) for q in range(n)]
+    return out
+
+
+def issue(q, gates) -> None:
+    """A gate list through an engine's gate methods."""
+    for controls, matrix, target in gates:
+        if controls:
+            q.MCMtrx(controls, matrix, target)
+        else:
+            q.Mtrx(matrix, target)
+
+
+def plan_only_pager(n: int, n_pages: int = 4, **kwargs):
+    """A ``QPager`` with no planes, at any width the host can name: the
+    real gate funnel, ``GateStreamFuser``, remap planner and kernel
+    lowering decide each window (``QPager._plan_window``) and
+    ``q.windows`` records what they decided; nothing is allocated and no
+    program is built.  A read is ``GetAmplitude``, which flushes."""
+    from qrack_tpu.parallel.pager import QPager
+
+    class PlanOnlyPager(QPager):
+        def __init__(self):
+            self.windows = []
+            super().__init__(n, n_pages=n_pages, rand_global_phase=False,
+                             **kwargs)
+
+        def SetPermutation(self, perm, phase=None):
+            self._state = None  # drops a pending window, as the pager's does
+            self._map_reset()
+
+        def GetAmplitude(self, perm):
+            self._settle()
+            return 0j
+
+        def _dispatch_ops(self, ops, lookahead=None):
+            window = self._plan_window(ops, lookahead)
+            self.windows.append(window)
+            if window.structure is not None:
+                self._map_assign(window.new_qmap)
+            return 1
+
+    return PlanOnlyPager()

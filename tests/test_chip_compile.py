@@ -1,5 +1,6 @@
 """Compile the main path's window programs for a described TPU v5e at
-w28 (a 2 GiB float32 ket), without a chip.
+w28 (a 2 GiB float32 ket; the pager's at w30, a 2 GiB page), without a
+chip.
 
 Nothing here runs: each case hands the chip's own compiler the shapes
 and asserts that it accepts them (section 2 of the on-chip-measurement
@@ -157,12 +158,14 @@ def test_tfim_last_window_kernel(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * KET_BYTES + SLACK
 
 
-def _compile_sharded(topo, structure, n, npg=4):
-    """The pager's per-page kernel body of a window on a 2x2 mesh."""
+def _compile_sharded(topo, structure, n, npg=4, remap=(), batched=True):
+    """The pager's per-page kernel body of a window on a 2x2 mesh, with
+    the planner's transpositions ``remap`` as its prologue."""
     L = n - 2
     mesh = Mesh(np.array(topo.devices[:npg]), ("pages",))
     ops = _ops(structure)
-    body = fu.sharded_kernel_window_body(L, npg, fu.sharded_structure_of(ops))
+    body = fu.sharded_kernel_window_body(L, npg, fu.sharded_structure_of(ops),
+                                         remap=remap, batched=batched)
     args = _args(fu.pack_operands(ops, jnp.float32, split_at=L),
                  NamedSharding(mesh, P(None, "pages")),
                  NamedSharding(mesh, P()), n=n)
@@ -200,6 +203,77 @@ def test_tfim_last_window_sharded_kernel(topo):
     assert compiled.as_text().count("tpu_custom_call") >= 3
     # a page is a w28 ket here: three and a half by the compiler's count
     # (the step's sixth window, launches between exchanges, reads 3.63)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 7 * KET_BYTES // 2 + SLACK
+
+
+# every window of the paged Trotter step at w30 that begins with a remap
+# prologue, on the pager's default placement: step 0 moves the table
+# (its sixth and last windows), every later step runs the same four
+# (windows five to eight), all of two pairs across the page boundary.
+# Beside them what other circuits make the planner emit: one pair whose
+# victim sits on a sublane bit, two pairs with victims on lane bits
+# (shuffles of the whole page before and after the exchange), one pair
+# and two pairs on the carrier bits themselves (no shuffle at all)
+REMAP_PROGRAMS = ("step0-w6", "step0-w8", "settled-w5", "settled-w6",
+                  "settled-w7", "settled-w8", "k1-sublane", "k2-lanes",
+                  "k1-carrier", "k2-carriers")
+PAGED_W = W + 2
+
+
+@pytest.fixture(scope="module")
+def remap_programs():
+    """``name -> (structure, swaps)``: the planner replayed on the host
+    through the pager's own gate funnel, no ket allocated."""
+    from helpers import issue, plan_only_pager, trotter_step_gates
+
+    q = plan_only_pager(PAGED_W)
+    gates = trotter_step_gates(PAGED_W)
+    steps = []
+    for _ in range(3):
+        q.windows.clear()
+        issue(q, gates)
+        q.GetAmplitude(0)
+        steps.append(list(q.windows))
+    # the settled step: step 2 plans what step 1 planned, table and all
+    assert [(w.structure, w.swaps) for w in steps[1]] \
+        == [(w.structure, w.swaps) for w in steps[2]]
+    named = {f"step0-w{i + 1}": w for i, w in enumerate(steps[0]) if w.swaps}
+    named.update({f"settled-w{i + 1}": w for i, w in enumerate(steps[1])
+                  if w.swaps})
+    out = {name: (w.structure, w.swaps) for name, w in named.items()}
+    local = tuple(("gen", t, False) for t in (3, 12, 20, 27))
+    out["k1-sublane"] = (local, ((7, 29),))
+    out["k2-lanes"] = (local, ((0, 28), (1, 29)))
+    out["k1-carrier"] = (local, ((27, 28),))
+    out["k2-carriers"] = (local, ((26, 28), (27, 29)))
+    assert sorted(out) == sorted(REMAP_PROGRAMS)
+    return out
+
+
+@pytest.mark.parametrize("batched", [True, False], ids=["batched", "pairs"])
+@pytest.mark.parametrize("name", REMAP_PROGRAMS)
+def test_remap_prologue_sharded_kernel(topo, remap_programs, name, batched):
+    """A remap prologue ahead of the window's launches, at a 2 GiB page,
+    in both lowerings: seconds to compile (935 s while ``mixed_swap``
+    and ``batched_mixed_swap`` viewed the page as ``(planes, hi, 2, lo)``
+    and ``(planes, 2^k, -1)``: PERF.md §6, PR 30; 1.5 to 3.7 s with the
+    sub-blocks sliced off the minor axis, PR 38) and at most three and
+    a half pages of temporaries beside the donated page."""
+    from qrack_tpu.ops import sharded as shb
+
+    structure, swaps = remap_programs[name]
+    plan = shb.plan_exchange(PAGED_W - 2, 2, swaps)
+    expected = {"k1-sublane": (1, ((7, 27),)), "k2-lanes": (2, ((0, 26), (1, 27))),
+                "k1-carrier": (1, ()), "k2-carriers": (2, ())}.get(name)
+    if expected is not None:
+        assert (plan.k, plan.pre) == expected and plan.post == plan.pre
+    else:
+        assert plan.k == 2 and plan.page_dest is None
+    t0 = time.perf_counter()
+    compiled = _compile_sharded(topo, structure, PAGED_W, remap=swaps,
+                                batched=batched)
+    assert time.perf_counter() - t0 < 120
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= 7 * KET_BYTES // 2 + SLACK
 
