@@ -484,7 +484,11 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
         (o, j) reads tile j of orbit o and writes tile j of orbit
         o - 1 (led_kernel), the read clamped at the last orbit and the
         write at the first, whose blocks the next orbit's steps write
-        again with what belongs there."""
+        again with what belongs there.  The result is aliased to the
+        planes: a donated ket is swept in place (a tile is fetched at
+        least ``m`` steps before the step that writes it, and the
+        pipeline fetches one step ahead), one that is not donated is
+        copied first by XLA and comes back unchanged."""
         name = segment_kernel_name(seg, bp)
         m = 1 << len(lead_bits)
         if lead_bits:
@@ -509,6 +513,7 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
                 in_specs=[_SCALAR_SPEC, _SCALAR_SPEC, in_spec],
                 out_specs=out_spec,
                 scratch_shapes=scratch,
+                input_output_aliases={2: 0},  # the planes, after iv and fv
                 compiler_params=_COMPILER_PARAMS,
                 interpret=interpret,
                 name=name,

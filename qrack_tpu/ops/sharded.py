@@ -65,37 +65,43 @@ def apply_global_2x2(local, mp, npg: int, gpos: int, lmask, lval, gmask, gval):
         out = gk.cmul(dd_re, dd_im, local) + gk.cmul(od_re, od_im, other)
         ok = (pid & gmask) == gval
         return jnp.where(ok, out, local)
-    perm = [(j, j ^ (1 << gpos)) for j in range(npg)]
+    bit = 1 << gpos
+    perm = [(j, j ^ bit) for j in range(npg)]
     pid = page_id()
-    b = (pid >> gpos) & 1
+    low = ((pid >> gpos) & 1) == 0   # this page is the pair's a side
     half_n = local.shape[-1] // 2
     # the halves are slices of the minor axis, never a (planes, 2, half)
     # view: behind a kernel launch the TPU compiler lays that view out
     # tile by tile (1172 s for a window of five ops at a 2 GiB page,
     # 2 s sliced: PERF.md §6, PR 35)
     h0, h1 = local[:, :half_n], local[:, half_n:]
-    keep = jnp.where(b == 0, h0, h1)
-    away = jnp.where(b == 0, h1, h0)
-    got = exchange(away, perm)       # half-page payload
+    keep = jnp.where(low, h0, h1)
+    got = exchange(jnp.where(low, h1, h0), perm)   # half-page payload
     # this page now holds complete (a, b) pairs for local indices with
-    # top bit == b: a = partner-0 amplitude, b = partner-1 amplitude
-    a_amp = jnp.where(b == 0, keep, got)
-    b_amp = jnp.where(b == 0, got, keep)
+    # top bit == b: a = partner-0 amplitude, b = partner-1 amplitude.
+    # ``keep`` is the a side on a low page and the b side on a high one,
+    # so the page picks its four coefficients, not its operands: the
+    # sums are the (a, b) form's, term for term, with no page-sized
+    # select of (keep, got) into (a, b) and of (a', b') into (mine,
+    # theirs) for the compiler to keep beside them (PERF.md §6, PR 39)
     re, im = mp[0], mp[1]
-    a_out = gk.cmul(re[0, 0], im[0, 0], a_amp) + gk.cmul(re[0, 1], im[0, 1], b_amp)
-    b_out = gk.cmul(re[1, 0], im[1, 0], a_amp) + gk.cmul(re[1, 1], im[1, 1], b_amp)
+
+    def coef(on_low, on_high):
+        return (jnp.where(low, re[on_low], re[on_high]),
+                jnp.where(low, im[on_low], im[on_high]))
+
+    mine = gk.cmul(*coef((0, 0), (1, 1)), keep) \
+        + gk.cmul(*coef((0, 1), (1, 0)), got)
+    theirs = gk.cmul(*coef((1, 0), (0, 1)), keep) \
+        + gk.cmul(*coef((1, 1), (0, 0)), got)
     # control masks: same local index for both outputs, page id differs
-    idx = gk.iota_for(keep) + jnp.where(b == 0, 0, half_n)
-    p0 = pid & ~(1 << gpos)
-    p1 = pid | (1 << gpos)
+    idx = gk.iota_for(keep) + jnp.where(low, 0, half_n)
     lok = (idx & lmask) == lval
-    a_out = jnp.where(lok & ((p0 & gmask) == gval), a_out, a_amp)
-    b_out = jnp.where(lok & ((p1 & gmask) == gval), b_out, b_amp)
-    mine = jnp.where(b == 0, a_out, b_out)
-    theirs = jnp.where(b == 0, b_out, a_out)
+    mine = jnp.where(lok & ((pid & gmask) == gval), mine, keep)
+    theirs = jnp.where(lok & (((pid ^ bit) & gmask) == gval), theirs, got)
     back = exchange(theirs, perm)    # half-page payload
-    lo = jnp.where(b == 0, mine, back)
-    hi = jnp.where(b == 0, back, mine)
+    lo = jnp.where(low, mine, back)
+    hi = jnp.where(low, back, mine)
     return jnp.concatenate([lo, hi], axis=-1)
 
 

@@ -6,6 +6,8 @@ vectorized index algebra) so engine bugs can't hide in shared code.
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
 
@@ -74,6 +76,34 @@ def issue(q, gates) -> None:
             q.MCMtrx(controls, matrix, target)
         else:
             q.Mtrx(matrix, target)
+
+
+@contextlib.contextmanager
+def benchmark_plans(width: int = 28):
+    """``windows(family)``: the windows the fuser plans for one
+    application of a dense cell's family at ``width``, from the
+    benchmark's own gate lists (``benchmarks/tests/structure.py``), no
+    ket allocated.  The benchmark's modules are imported for the
+    ``with`` alone."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    before, path = set(sys.modules), list(sys.path)
+    sys.path[:0] = [os.path.join(bench, "tests"), bench]
+    try:
+        import families
+        import structure
+
+        yield lambda name: structure.plan_application(
+            families.family(name), width, families.PARAMS[name])
+    finally:
+        sys.path[:] = path
+        for name in set(sys.modules) - before:
+            if name in ("families", "structure", "harness") \
+                    or name.startswith("bench_"):
+                del sys.modules[name]
 
 
 def plan_only_pager(n: int, n_pages: int = 4, **kwargs):

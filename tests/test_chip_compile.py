@@ -5,7 +5,12 @@ chip.
 Nothing here runs: each case hands the chip's own compiler the shapes
 and asserts that it accepts them (section 2 of the on-chip-measurement
 guide).  The interpret-mode parity tests cannot see what this sees: a
-view Mosaic cannot lay out, a temporary that does not fit 16 GiB.
+view Mosaic cannot lay out, a temporary that does not fit 16 GiB, a
+copy of the ket that XLA puts beside a launch.  A kernel window sweeps
+its donated ket in place (every launch aliases its planes to its
+result, PR 39): its program holds no ket of temporaries and no ket-sized
+``copy``, which the dense cases assert; the pager's programs keep the
+exchange's buffers.
 
 The topology is described inside a module-scoped fixture, never at
 import: only the worker that is handed this file loads the TPU library.
@@ -76,10 +81,12 @@ def _dense_args(structure, sharding):
 
 
 def _compile(fn, args):
-    """Compiled for the described chip; the compiler's seconds (Mosaic's,
-    for a kernel window: XLA's own part is the ket's copies) go to the
-    test's output, where ``pytest -rP`` or a failure shows them, so that
-    a change which multiplies them is seen without a chip."""
+    """Compiled for the described chip, the planes donated as the engine
+    and the pager donate them; the compiler's seconds (Mosaic's, for a
+    kernel window: XLA's own part is the operands' slices and nothing of
+    the ket's size) go to the test's output, where ``pytest -rP`` or a
+    failure shows them, so that a change which multiplies them is seen
+    without a chip."""
     lowered = jax.jit(fn, donate_argnums=(0,)).lower(*args)
     t0 = time.perf_counter()
     compiled = lowered.compile()
@@ -87,6 +94,19 @@ def _compile(fn, args):
           f"{getattr(fn, '__name__', fn)} "
           f"temp_bytes={compiled.memory_analysis().temp_size_in_bytes}")
     return compiled
+
+
+def _launches(compiled):
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def _in_place(compiled):
+    """No ket of temporaries and no ket-sized copy: every launch of the
+    program writes the donated ket it reads."""
+    copies = re.findall(r"f32\[2,%d\]\S* copy(?:-start)?\(" % (1 << W),
+                        compiled.as_text())
+    return (not copies
+            and compiled.memory_analysis().temp_size_in_bytes <= SLACK)
 
 
 # the Trotter step's last window at w28 (RX on 15-27: 13 planned sweeps
@@ -132,7 +152,7 @@ def test_kernel_window(one_chip, structure):
     compiled = _compile(pk.make_window_fn(W, structure),
                         _dense_args(structure, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes <= KET_BYTES + SLACK
+    assert _in_place(compiled)
 
 
 def test_qft_window_xla(one_chip):
@@ -144,18 +164,19 @@ def test_qft_window_kernel(one_chip):
     compiled = _compile(pk.make_window_fn(W, QFT16),
                         _dense_args(QFT16, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes <= KET_BYTES + SLACK
+    assert _in_place(compiled)
 
 
 def test_tfim_last_window_kernel(one_chip):
-    """13 launches in one program: two kets in flight beside the
-    donated one, whatever the count of launches."""
+    """13 launches in one program, each on the result of the one
+    before: none beside the donated ket (two kets in flight until
+    PR 39)."""
     plan, why = fu.kernel_lowering(W, TFIM_LAST, backend="tpu")
     assert why is None and (plan["sweeps"], plan["cross"]) == (13, 12)
     compiled = _compile(pk.make_window_fn(W, TFIM_LAST),
                         _dense_args(TFIM_LAST, one_chip))
-    assert compiled.as_text().count("tpu_custom_call") >= 13
-    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * KET_BYTES + SLACK
+    assert _launches(compiled) == 13
+    assert _in_place(compiled)
 
 
 def _compile_sharded(topo, structure, n, npg=4, remap=(), batched=True):
@@ -184,10 +205,15 @@ def test_sharded_kernel_window_four_pages(topo):
     low-lane gen."""
     compiled = _compile_sharded(
         topo, (("gen", 27, False), ("gen", 3, False)), W)
-    # bytes of one device: three pages, the exchange's (the sent copy,
-    # the partner's page, the mixed one); the kernel's tile adds none
+    # bytes of one device: two and a half pages by the compiler's count,
+    # the exchange's (the partner's half, the eight half-planes of the
+    # pair arithmetic).  Three until PR 39; the alias alone read three
+    # and a half, the donated page's buffer standing idle while both
+    # halves of the mixed page (the launch's input, bound to that
+    # buffer) were made beside it: the exchange now picks coefficients
+    # by page instead of operands and keeps no (a, b) halves
     assert compiled.memory_analysis().temp_size_in_bytes \
-        <= 3 * KET_BYTES // 4 + SLACK
+        <= 5 * KET_BYTES // 8 + SLACK
 
 
 def test_tfim_last_window_sharded_kernel(topo):
@@ -201,10 +227,35 @@ def test_tfim_last_window_sharded_kernel(topo):
     # with a (planes, 2, half) view of a launch's result (PR 35)
     assert time.perf_counter() - t0 < 120
     assert compiled.as_text().count("tpu_custom_call") >= 3
-    # a page is a w28 ket here: three and a half by the compiler's count
-    # (the step's sixth window, launches between exchanges, reads 3.63)
+    # a page is a w28 ket here: two and a half by the compiler's count
+    # (three and a half until PR 39; the step's sixth window, launches
+    # between controlled exchanges, reads 3.06, 3.63 before)
     assert compiled.memory_analysis().temp_size_in_bytes \
-        <= 7 * KET_BYTES // 2 + SLACK
+        <= 5 * KET_BYTES // 2 + SLACK
+
+
+def test_tfim_sixth_window_sharded_kernel(topo):
+    """The paged Trotter step's sixth window at w30 on the fixed
+    placement: launches between the step's four controlled exchanges
+    (the CNOTs onto 28 and 29), the paged program the compiler counts
+    the most memory for.  3.63 pages of temporaries before PR 39, 4.13
+    with the alias alone (the launches' results chain through the
+    donated page's buffer, which took one of the exchange's halves
+    before), 3.06 since the exchange keeps no (a, b) halves."""
+    from helpers import issue, plan_only_pager, trotter_step_gates
+
+    q = plan_only_pager(W + 2, remap="off")
+    issue(q, trotter_step_gates(W + 2))
+    q.GetAmplitude(0)
+    structure = q.windows[5].structure
+    controlled = [op for op in structure if op[1] >= W and op[0] == "gen"]
+    assert len(q.windows) == 8 and len(controlled) == 4
+    plan, why = fu.sharded_kernel_lowering(W, structure, backend="tpu")
+    assert why is None and plan["sweeps"] == 8
+    compiled = _compile_sharded(topo, structure, W + 2)
+    assert _launches(compiled) == 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 13 * KET_BYTES // 4 + SLACK
 
 
 # every window of the paged Trotter step at w30 that begins with a remap
@@ -258,8 +309,9 @@ def test_remap_prologue_sharded_kernel(topo, remap_programs, name, batched):
     in both lowerings: seconds to compile (935 s while ``mixed_swap``
     and ``batched_mixed_swap`` viewed the page as ``(planes, hi, 2, lo)``
     and ``(planes, 2^k, -1)``: PERF.md §6, PR 30; 1.5 to 3.7 s with the
-    sub-blocks sliced off the minor axis, PR 38) and at most three and
-    a half pages of temporaries beside the donated page."""
+    sub-blocks sliced off the minor axis, PR 38) and at most three
+    pages of temporaries beside the donated page (three and a half
+    until the launches wrote in place, PR 39)."""
     from qrack_tpu.ops import sharded as shb
 
     structure, swaps = remap_programs[name]
@@ -275,7 +327,7 @@ def test_remap_prologue_sharded_kernel(topo, remap_programs, name, batched):
                                 batched=batched)
     assert time.perf_counter() - t0 < 120
     assert compiled.memory_analysis().temp_size_in_bytes \
-        <= 7 * KET_BYTES // 2 + SLACK
+        <= 3 * KET_BYTES + SLACK
 
 
 def _u4(lo, hi):
@@ -304,8 +356,7 @@ RCS_WINDOWS = {
 @pytest.mark.parametrize("window", sorted(RCS_WINDOWS))
 def test_two_qubit_window_kernel(one_chip, window):
     """Each new segment shape compiles for the chip within 60 s (1.5 to
-    5 s when written, PR 36) and holds at most two kets in flight
-    beside the donated one."""
+    5 s when written, PR 36) and sweeps the donated ket in place."""
     structure = RCS_WINDOWS[window]
     plan, why = fu.kernel_lowering(W, structure, backend="tpu")
     assert why is None
@@ -318,11 +369,69 @@ def test_two_qubit_window_kernel(one_chip, window):
                         _dense_args(structure, one_chip))
     assert time.perf_counter() - t0 < 60
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= plan["sweeps"]
+    assert _launches(compiled) == plan["sweeps"]
     for name, count in zip((pk.TWOQ_INTILE_KERNEL_NAME, pk.TWOQ_PAIR_KERNEL_NAME,
                             pk.TWOQ_QUAD_KERNEL_NAME), expected):
         assert (name in text) == bool(count)
-    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * KET_BYTES + SLACK
+    assert _in_place(compiled)
+
+
+# the window programs of one application of the three dense cells at
+# w28, by the families' own gate lists: QFT's 26 windows, the Trotter
+# step's 7, and the 12 structures of a random circuit's 14 windows.  One
+# structure of each plan shape is compiled (27 of the 45: all 45 took the
+# file from 62 to 144 s): dropped are 18 of QFT's one-sweep windows of
+# 16 in-tile ops, windows 7-12, 14-16, 18, 19, 21, 22 and 24 in the
+# shape of window 6, and 17, 20, 23 and 25 in that of window 13
+CELL_WINDOWS = [("qft", i) for i in (0, 1, 2, 3, 4, 5, 12, 25)] \
+    + [("tfim", i) for i in range(7)] + [("rcs", i) for i in range(12)]
+
+
+def _plan_shape(structure):
+    """What a window's program is made of, the targets left out: each
+    segment's kernel, its lead and the kinds that ride behind it."""
+    return tuple(
+        (pk.segment_kernel_name(seg, pk.DEFAULT_BLOCK_POW),
+         seg["xgen"] and (seg["xgen"][1], seg["xgen"][3]),
+         tuple(sorted((kind, ctrl) for _, kind, _, ctrl in seg["ops"])))
+        for seg in pk.plan_window(structure, pk.DEFAULT_BLOCK_POW))
+
+
+@pytest.fixture(scope="module")
+def cell_windows():
+    """``family -> its distinct window structures``, in the order the
+    fuser flushes them (``helpers.benchmark_plans``)."""
+    from helpers import benchmark_plans
+
+    with benchmark_plans(W) as windows:
+        out = {family: list(dict.fromkeys(
+                   w["structure"] for w in windows(family)
+                   if w["path"] == "kernel"))
+               for family in ("qft", "tfim", "rcs")}
+    assert {f: len(structures) for f, structures in out.items()} \
+        == {"qft": 26, "tfim": 7, "rcs": 12}
+    assert {_plan_shape(s) for structures in out.values() for s in structures} \
+        == {_plan_shape(out[f][i]) for f, i in CELL_WINDOWS}
+    return out
+
+
+@pytest.mark.parametrize("family,index", CELL_WINDOWS,
+                         ids=[f"{f}-w{i + 1:02d}" for f, i in CELL_WINDOWS])
+def test_cell_window_sweeps_its_ket_in_place(one_chip, cell_windows, family,
+                                             index):
+    """What ``xla.ms_per_circuit`` lost with PR 39, held without a chip:
+    a window program of a dense cell is its planned launches and
+    nothing of the ket's size beside them (a one-sweep window held one
+    ``copy`` of ``f32[2,268435456]`` back into the donated ket, 6.53 ms
+    on the chip: 21 of QFT's 26 windows, three of the Trotter step's 7,
+    one of a random circuit's)."""
+    structure = cell_windows[family][index]
+    plan, why = fu.kernel_lowering(W, structure, backend="tpu")
+    assert why is None
+    compiled = _compile(pk.make_window_fn(W, structure),
+                        _dense_args(structure, one_chip))
+    assert _launches(compiled) == plan["sweeps"]
+    assert _in_place(compiled)
 
 
 def test_kernel_launches_carry_their_names(one_chip):

@@ -389,3 +389,47 @@ def test_pager_devices_env_selection():
                    n_pages=1)
     finally:
         set_config(pager_devices="")
+
+
+@pytest.mark.parametrize("controlled", [False, True], ids=["bare", "controlled"])
+@pytest.mark.parametrize("npg,gpos", [(2, 0), (4, 0), (4, 1), (8, 1), (8, 2)])
+def test_pair_exchange_against_float64(npg, gpos, controlled):
+    """``apply_global_2x2`` alone, every page picking its coefficients by
+    its side of the pair: a random 2x2 on a paged target, bare and under
+    an in-page and a page-level control, against numpy's float64 on the
+    whole ket (PR 39: the exchange keeps no (a, b) halves)."""
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from qrack_tpu.ops import sharded as shb
+
+    L = 5
+    rng = np.random.default_rng(100 * npg + 10 * gpos + controlled)
+    m = rng.standard_normal((2, 2)) + 1j * rng.standard_normal((2, 2))
+    ket = rng.standard_normal(npg << L) + 1j * rng.standard_normal(npg << L)
+    lmask = lval = gmask = gval = 0
+    if controlled:
+        lmask, lval = 0b10010, 0b10000
+        gmask = (npg - 1) & ~(1 << gpos)
+        gval = gmask & 0b101
+    mesh = Mesh(np.array(jax.devices()[:npg]), ("pages",))
+    fn = jax.jit(jax.shard_map(
+        lambda local, mp: shb.apply_global_2x2(local, mp, npg, gpos, lmask,
+                                               lval, gmask, gval),
+        mesh=mesh, in_specs=(P(None, "pages"), P()),
+        out_specs=P(None, "pages"), check_vma=False))
+    planes = np.stack([ket.real, ket.imag]).astype(np.float32)
+    mp = np.stack([m.real, m.imag]).astype(np.float32)
+    out = np.asarray(fn(planes, mp))
+    ket32 = planes[0].astype(np.float64) + 1j * planes[1]
+    m32 = mp[0].astype(np.float64) + 1j * mp[1]
+    want = ket32.copy()
+    bit = 1 << (L + gpos)
+    for i in range(npg << L):
+        if i & bit or (i & lmask) != lval or ((i >> L) & gmask) != gval:
+            continue
+        a, b = ket32[i], ket32[i | bit]
+        want[i] = m32[0, 0] * a + m32[0, 1] * b
+        want[i | bit] = m32[1, 0] * a + m32[1, 1] * b
+    assert np.any(want != ket32) and (not controlled or np.any(want == ket32))
+    np.testing.assert_allclose(out[0] + 1j * out[1], want, atol=2e-6)

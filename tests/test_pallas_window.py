@@ -526,30 +526,13 @@ def test_flat_tile_below_block_pow_10():
 
 
 @pytest.fixture
-def benchmark_plans(monkeypatch):
-    """``benchmarks/tests/structure.py``: the windows the fuser plans for
-    one application of a cell's family at w28, no ket allocated.  The
-    benchmark's modules are imported for this test alone."""
-    import os
-    import sys
+def benchmark_plans():
+    """``helpers.benchmark_plans``: the windows of one application of a
+    cell's family at w28."""
+    from helpers import benchmark_plans
 
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks")
-    before = set(sys.modules)
-    monkeypatch.syspath_prepend(bench)
-    monkeypatch.syspath_prepend(os.path.join(bench, "tests"))
-    import families
-    import structure
-
-    def windows(name):
-        return structure.plan_application(families.family(name), 28,
-                                          families.PARAMS[name])
-
-    yield windows
-    for name in set(sys.modules) - before:
-        if name in ("families", "structure", "harness") \
-                or name.startswith("bench_"):
-            del sys.modules[name]
+    with benchmark_plans() as windows:
+        yield windows
 
 
 @pytest.mark.parametrize("family,sweeps,carry_ops", [("qft", 37, 37),
@@ -584,9 +567,9 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
 # the index maps.
 # ---------------------------------------------------------------------------
 
-def _exact(fn, *args):
+def _exact(fn, *args, donate=False):
     """``fn(*args)`` compiled with XLA's CPU backend at optimization
-    level 0.  At its default level that backend contracts ``a * b + c``
+    level 0, the first argument donated or kept.  At its default level that backend contracts ``a * b + c``
     into one rounding where its fusions happen to allow it, so two
     bodies with the same arithmetic differ in a last bit here and there
     (29 of 116 led windows, new grid against old, and none at level 0
@@ -594,8 +577,34 @@ def _exact(fn, *args):
     its own, which is numpy's arithmetic and the TPU's."""
     import jax
 
-    return np.asarray(jax.jit(fn).lower(*args).compile(
+    jitted = jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return np.asarray(jitted.lower(*args).compile(
         compiler_options={"xla_backend_optimization_level": 0})(*args))
+
+
+def random_ket(rng, n):
+    """A normalised ``(2, 2^n)`` float32 ket."""
+    ket = rng.standard_normal((2, 1 << n)).astype(np.float32)
+    return ket / np.sqrt((ket ** 2).sum(dtype=np.float32))
+
+
+def run_window(n, bp, ops, ket, donate):
+    """The kernel window of ``ops`` under the interpreter on a device
+    copy of the numpy ``ket``.  Every launch aliases its planes to its
+    result (PR 39): donated, the copy is consumed; kept, it has to come
+    back as it went in (XLA copies it ahead of the first launch)."""
+    import jax.numpy as jnp
+
+    fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
+                           interpret=True)
+    planes = jnp.array(ket, copy=True)
+    got = _exact(fn, planes, *fu.pack_operands(ops, jnp.float32),
+                 donate=donate)
+    if donate:
+        assert planes.is_deleted()
+    else:
+        assert np.array_equal(np.asarray(planes), ket)
+    return got
 
 
 def lead_in_numpy(ket, op, n):
@@ -645,28 +654,19 @@ def riders(n, bp):
             fu.FusedOp("inv", 6, 1 << bp, 1 << bp, _DENSE_MATRICES["inv"])]
 
 
-def led_segment_against_numpy(n, bp, lead, behind, seed):
+def led_segment_against_numpy(n, bp, lead, behind, seed, donate=False):
     """``(got, want)``: the kernel window ``[lead] + riders`` under the
-    interpreter, and the lead in numpy with the riders applied by the
-    unled kernel, whose tile id is its grid step."""
-    import jax.numpy as jnp
-
-    def window(ops):
-        fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
-                               interpret=True)
-        return lambda ket: _exact(fn, jnp.asarray(ket),
-                                  *fu.pack_operands(ops, jnp.float32))
-
+    interpreter, its ket donated or kept (``run_window``), and the lead
+    in numpy with the riders applied by the unled kernel, whose tile id
+    is its grid step."""
     behind = riders(n, bp) if behind else []
     assert pk.plan_window(fu.structure_of([lead] + behind), bp)[0]["xgen"][0] == 0
-    rng = np.random.default_rng(seed)
-    ket = rng.standard_normal((2, 1 << n)).astype(np.float32)
-    ket /= np.sqrt((ket ** 2).sum(dtype=np.float32))
+    ket = random_ket(np.random.default_rng(seed), n)
     want = lead_in_numpy(ket, lead, n)
     if behind:
         assert len(pk.plan_window(fu.structure_of(behind), bp)) == 1
-        want = window(behind)(want)
-    return window([lead] + behind)(ket), want
+        want = run_window(n, bp, behind, want, donate)
+    return run_window(n, bp, [lead] + behind, ket, donate), want
 
 
 # (width, block_pow): 2, 4 and 16 tiles, on the flat tile and the dense
@@ -692,13 +692,33 @@ def _led_2x2_cases():
     return cases
 
 
+DONATE = pytest.mark.parametrize("donate", [False, True],
+                                 ids=["kept", "donated"])
+
+
+@DONATE
 @pytest.mark.parametrize("n,bp,kind,target,cmask,behind", _led_2x2_cases())
 def test_led_2x2_segment_is_numpy_bit_for_bit(n, bp, kind, target, cmask,
-                                              behind):
+                                              behind, donate):
     cval = cmask & ~2 if kind == "gen" else cmask
     lead = fu.FusedOp(kind, target, cmask, cval, _DENSE_MATRICES[kind])
-    got, want = led_segment_against_numpy(n, bp, lead, behind, seed=n + target)
+    got, want = led_segment_against_numpy(n, bp, lead, behind, seed=n + target,
+                                          donate=donate)
     assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+
+def launches_of(fn, *args):
+    """The equation of every pallas_call ``fn`` traces to, in order."""
+    import jax
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    return list(calls(jax.make_jaxpr(fn)(*args).jaxpr))
 
 
 def grids_of(fn, *args):
@@ -707,13 +727,6 @@ def grids_of(fn, *args):
     block's column (every block here is ``(2, 2^block_pow)``, row 0),
     the two scalar columns left out."""
     import jax
-
-    def calls(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                yield eqn.params["grid_mapping"]
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from calls(sub)
 
     def column(mapping):
         closed = mapping.index_map_jaxpr
@@ -725,7 +738,7 @@ def grids_of(fn, *args):
         return at
 
     out = []
-    for gm in calls(jax.make_jaxpr(fn)(*args).jaxpr):
+    for gm in (eqn.params["grid_mapping"] for eqn in launches_of(fn, *args)):
         maps = [column(bm) for bm in gm.block_mappings[2:]]
         assert gm.num_outputs == 1
         out.append((tuple(gm.grid), maps[:-1], maps[-1]))
@@ -803,3 +816,119 @@ def test_an_unled_segment_keeps_its_grid():
     assert grid == (16,)
     assert [tile(i) for i in range(16)] == [out(i) for i in range(16)] \
         == list(range(16))
+
+
+# ---------------------------------------------------------------------------
+# in place (PR 39): every launch aliases its planes to its result, so a
+# window program that was handed a donated ket writes no second one.
+# What no interpreter shows is the order of the chip's DMAs (PERF.md
+# section 6, PR 39: the probe on the chip); what it does show is here:
+# the alias on every grid, the bits, and a kept ket left as it was.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,bp,ops,grids", [
+    pytest.param(12, 8, [fu.FusedOp("gen", 3, 0, 0, np.eye(2))], [1],
+                 id="unled"),
+    pytest.param(12, 8, [fu.FusedOp("inv", 10, 1, 1, np.eye(2)),
+                         fu.FusedOp("gen", 2, 0, 0, np.eye(2))], [2],
+                 id="pair"),
+    pytest.param(12, 8, [_u4(3, 11)], [2], id="pair-u4"),
+    pytest.param(14, 10, [_u4(10, 13)], [2], id="four-tiles"),
+    # a window of three launches: each takes the one before's result
+    pytest.param(12, 8, [fu.FusedOp("gen", 5, 0, 0, np.eye(2)),
+                         fu.FusedOp("diag", 5, 2, 2, np.eye(2)),
+                         fu.FusedOp("gen", 9, 0, 0, np.eye(2)), _u4(8, 11)],
+                 [1, 2, 2], id="unled-pair-quad"),
+])
+def test_every_launch_aliases_its_planes_to_its_result(n, bp, ops, grids):
+    import jax.numpy as jnp
+
+    fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
+                           interpret=True)
+    assert fn.sweeps == len(grids)
+    launches = launches_of(fn, jnp.zeros((2, 1 << n), jnp.float32),
+                           *fu.pack_operands(ops, jnp.float32))
+    assert [len(eqn.params["grid_mapping"].grid) for eqn in launches] == grids
+    for eqn in launches:
+        # iv, fv, planes -> the one result, of the planes' shape and type
+        assert tuple(eqn.params["input_output_aliases"]) == ((2, 0),)
+        planes, = eqn.invars[2:]
+        out, = eqn.outvars
+        assert planes.aval.shape == out.aval.shape == (2, 1 << n)
+        assert planes.aval.dtype == out.aval.dtype
+
+
+def unled_in_numpy(ket, op, n):
+    """An op whose pair lies inside the tile (or which has none) on the
+    whole ``(2, 2^n)`` float32 ket in the kernel's order: ``tile_cphase``,
+    ``tile_diag``, ``tile_local_invert``, ``tile_local_2x2`` (its own
+    entry of the matrix first, then the partner's)."""
+    idx = np.arange(1 << n)
+    m = np.asarray(op.m)
+    re, im = m.real.astype(np.float32), m.imag.astype(np.float32)
+    b = (idx >> op.target) & 1
+    v, o = ket, ket[:, idx ^ (1 << op.target)]
+    if op.kind in ("cphase", "diag"):
+        if op.kind == "cphase":
+            sel = (idx & (op.cmask | (1 << op.target))) \
+                == (op.cmask | (1 << op.target))
+            fre, fim = re[1, 1], im[1, 1]
+        else:
+            sel = (idx & op.cmask) == op.cval
+            fre, fim = re[b, b], im[b, b]
+        fre = np.where(sel, fre, np.float32(1))
+        fim = np.where(sel, fim, np.float32(0))
+        return np.stack([v[0] * fre - v[1] * fim, v[0] * fim + v[1] * fre])
+    if op.kind == "inv":
+        fre, fim = re[b, 1 - b], im[b, 1 - b]
+        nv = np.stack([fre * o[0] - fim * o[1], fre * o[1] + fim * o[0]])
+    else:
+        dre, dim, ore, oim = re[b, b], im[b, b], re[b, 1 - b], im[b, 1 - b]
+        nv = np.stack([dre * v[0] - dim * v[1] + ore * o[0] - oim * o[1],
+                       dre * v[1] + dim * v[0] + ore * o[1] + oim * o[0]])
+    return np.where((idx & op.cmask) == op.cval, nv, ket)
+
+
+def _unled_cases():
+    """The cases of ``_dense_cases`` at w12 that no op leads: every kind
+    with its target on a lane or a sublane bit, and the two diagonal
+    kinds with theirs above the tile, under each placement of controls."""
+    def unled(n, bp, kind, target, *_):
+        return n == 12 and not (kind in ("inv", "gen") and target >= bp)
+
+    return [c for c in _dense_cases() if unled(*c.values)]
+
+
+@DONATE
+@pytest.mark.parametrize("n,bp,kind,target,cmask,cval,behind", _unled_cases())
+def test_unled_segment_is_numpy_bit_for_bit(n, bp, kind, target, cmask, cval,
+                                            behind, donate):
+    op = fu.FusedOp(kind, target, cmask, cval, _DENSE_MATRICES[kind])
+    assert pk.plan_window(fu.structure_of([op]), bp)[0]["xgen"] is None
+    ket = random_ket(np.random.default_rng(target + cmask), n)
+    got = run_window(n, bp, [op], ket, donate)
+    want = unled_in_numpy(ket, op, n)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+
+@DONATE
+def test_a_window_of_many_launches_in_place(donate):
+    """Led and unled launches in one program, each on the result of the
+    one before: the bits of the same ops a launch at a time."""
+    n, bp = 12, 8
+    rng = np.random.default_rng(39)
+    ops = [fu.FusedOp("gen", 9, 0, 0, _su(rng, 2)),
+           fu.FusedOp("u4", (8, 11), 0, 0, _su(rng, 4)),
+           fu.FusedOp("gen", 5, 0, 0, _su(rng, 2)),
+           fu.FusedOp("inv", 10, 1 << 5, 1 << 5, _DENSE_MATRICES["inv"]),
+           fu.FusedOp("u4", (2, 9), 0, 0, _su(rng, 4))]
+    segments = pk.plan_window(fu.structure_of(ops), bp)
+    assert len(segments) >= 3
+    ket = random_ket(rng, n)
+    want, at = ket, 0
+    for seg in segments:
+        count = len(seg["ops"]) + (seg["xgen"] is not None)
+        want = run_window(n, bp, ops[at:at + count], want, donate)
+        at += count
+    assert at == len(ops)
+    assert np.array_equal(run_window(n, bp, ops, ket, donate), want)

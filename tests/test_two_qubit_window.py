@@ -23,7 +23,7 @@ from qrack_tpu.ops import fusion as fu
 from qrack_tpu.ops import pallas_kernels as pk
 from qrack_tpu.utils.rng import QrackRandom
 
-from test_pallas_window import ORBIT_SHAPES, led_segment_against_numpy
+from test_pallas_window import DONATE, ORBIT_SHAPES, led_segment_against_numpy
 
 
 @pytest.fixture(autouse=True)
@@ -395,12 +395,14 @@ def _led_u4_cases():
     return cases
 
 
+@DONATE
 @pytest.mark.parametrize("n,bp,lo,hi,behind", _led_u4_cases())
-def test_led_u4_segment_is_numpy_bit_for_bit(n, bp, lo, hi, behind):
+def test_led_u4_segment_is_numpy_bit_for_bit(n, bp, lo, hi, behind, donate):
     """The two-target lead on the pair grid and on four tiles, alone and
     with in-tile ops that read the tile id behind it: the float32 bits of
     ``tile_quad_mix``'s order in numpy (tests/test_pallas_window.py)."""
     lead = fu.FusedOp("u4", (lo, hi), 0, 0,
                       _su(np.random.default_rng(lo * 16 + hi), 4))
-    got, want = led_segment_against_numpy(n, bp, lead, behind, seed=lo + hi)
+    got, want = led_segment_against_numpy(n, bp, lead, behind, seed=lo + hi,
+                                          donate=donate)
     assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
