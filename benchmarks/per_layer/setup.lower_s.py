@@ -1,0 +1,10 @@
+"""Seconds of ``compile.lower`` under the program's spans before the
+window: JAX lowering jaxprs to MLIR modules.  Counted where the stage is
+outermost (``setup_spans``)."""
+
+import setup_spans
+
+
+def read(ctx):
+    found = setup_spans.load(ctx)
+    return None if found is None else found.stage_s("compile.lower")

@@ -239,10 +239,11 @@ def create_quantum_interface(layers: Union[str, Sequence[str]], qubit_count: int
     opts = {k: kwargs.pop(k) for k in ("noise", "model", "devices",
                                        "n_pages", "dtype")
             if k in kwargs}
-    if _tele._ENABLED:
-        _tele.inc("factory.create_interface")
-    factory = build_factory(tuple(layers), **opts)
-    return factory(qubit_count, init_state=init_state, **kwargs)
+    # the stack's construction: device discovery, a pager's mesh, the
+    # first fill (the span's count is the number of stacks built)
+    with _tele.span("factory.create_interface"):
+        factory = build_factory(tuple(layers), **opts)
+        return factory(qubit_count, init_state=init_state, **kwargs)
 
 
 def create_arranged_layers_full(nw: bool = False, md: bool = False, sd: bool = True,

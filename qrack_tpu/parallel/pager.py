@@ -118,9 +118,10 @@ def _host_read(x) -> np.ndarray:
     out_shardings P()): when the mesh spans jax.distributed processes
     the array is not fully addressable, but any process-local shard of
     a replicated array holds the whole value."""
-    if _res._ACTIVE:
-        return _res.call_guarded("pager.device_get", _host_read_raw, (x,))
-    return _host_read_raw(x)
+    with _tele.span("engine.read"):
+        if _res._ACTIVE:
+            return _res.call_guarded("pager.device_get", _host_read_raw, (x,))
+        return _host_read_raw(x)
 
 
 class WindowPlan(NamedTuple):
@@ -1619,17 +1620,18 @@ class QPager(QEngine):
                     jax.device_get(st[:, offset:offset + length]),
                     dtype=np.float64)
 
-            if _res._ACTIVE:  # site "pager.device_get": the completion sync
-                planes = _res.call_guarded("pager.device_get", read,
-                                           (self._state,))
-                from ..resilience import integrity as _integ
+            with _tele.span("engine.read"):
+                if _res._ACTIVE:  # site "pager.device_get": the completion sync
+                    planes = _res.call_guarded("pager.device_get", read,
+                                               (self._state,))
+                    from ..resilience import integrity as _integ
 
-                if _integ.enabled():
-                    # boundary invariant piggybacked on the fetched
-                    # window — no extra HBM sweep (docs/INTEGRITY.md)
-                    _integ.check_host("pager.device_get", planes)
-                return planes
-            return read(self._state)
+                    if _integ.enabled():
+                        # boundary invariant piggybacked on the fetched
+                        # window — no extra HBM sweep (docs/INTEGRITY.md)
+                        _integ.check_host("pager.device_get", planes)
+                    return planes
+                return read(self._state)
         from .cluster import replicate_program
 
         prog = _program(self._key("replicate", length),
@@ -1676,8 +1678,10 @@ class QPager(QEngine):
 
             return jax.jit(f, out_shardings=sh)
 
-        prog = _program(self._key("setperm", n), build)
-        self._state = prog(perm, jnp.asarray([ph.real, ph.imag], dtype=self.dtype))
+        with _tele.span("engine.set_permutation"):  # build, fill, put
+            prog = _program(self._key("setperm", n), build)
+            self._state = prog(
+                perm, jnp.asarray([ph.real, ph.imag], dtype=self.dtype))
         self._map_reset()
         self.running_norm = 1.0
 

@@ -26,7 +26,10 @@ Three surfaces:
   of the same ``.xplane.pb`` as the device's operations, on their
   clock.  Each recorded span has a process-wide ``id`` and the
   ``parent`` id of the span that encloses it on its thread
-  (:func:`self_seconds` is duration minus what children cover).  Spans
+  (:func:`self_seconds` is duration minus what children cover); the
+  annotation carries the ``id`` as a statistic of its event, so a
+  reader of the ``.xplane.pb`` finds a span's ring entry and, from the
+  pairs, the offset between this module's clock and the trace's.  Spans
   and events carry the thread's current distributed-trace id
   (:func:`set_trace` / :func:`current_trace`) so per-process traces can
   be correlated across a fleet; ring timestamps are relative to the
@@ -50,13 +53,18 @@ Compile-cache accounting comes from two helpers:
 ``_PROGRAMS`` dicts (parallel/pager.py, engines/turboquant.py), and
 :func:`instrument_jit`, a thin wrapper over module-level ``jax.jit``
 programs (engines/tpu.py) that classifies each call as hit or miss via
-the jitted function's ``_cache_size()``.
+the jitted function's ``_cache_size()``.  What a miss costs is JAX's to
+say: once telemetry is on, JAX's own ``jax.monitoring`` events of its
+compile pipeline are recorded as the spans ``compile.trace``,
+``compile.lower``, ``compile.backend`` and ``compile.cache_load``,
+children of whatever span is open on the thread that compiles.
 """
 
 from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -113,6 +121,7 @@ def enable() -> None:
     Arms the atexit JSONL dump if QRACK_TPU_TELEMETRY_OUT is set."""
     global _ENABLED
     _ENABLED = True
+    _listen()
     from . import export
 
     export.arm_atexit()
@@ -259,13 +268,16 @@ _SPAN_IDS = itertools.count(1)
 _ANNOTATION = None
 
 
-def _annotation(name: str):
+def _annotation(name: str, span_id: int):
     global _ANNOTATION
     if _ANNOTATION is None:
         from jax.profiler import TraceAnnotation
 
         _ANNOTATION = TraceAnnotation
-    return _ANNOTATION("qrack." + name)
+        _listen()  # the environment gate, before jax was imported
+    # the id is a statistic of the host-plane event: what ties a ring
+    # entry (perf_counter) to the trace's clock
+    return _ANNOTATION("qrack." + name, id=span_id)
 
 
 def _record(entry: dict) -> None:
@@ -290,9 +302,10 @@ def _record(entry: dict) -> None:
 class _Span:
     __slots__ = ("name", "t0", "depth", "trace", "id", "parent", "_ann")
 
-    def __init__(self, name: str, trace=None):
+    def __init__(self, name: str, trace=None, annotate: bool = True):
         self.name = name
         self.trace = trace
+        self._ann = None if annotate else _NULL_SPAN
 
     def __enter__(self):
         stack = getattr(_TLS, "stack", None)
@@ -305,7 +318,8 @@ class _Span:
         # under an open jax.profiler trace the span is an event of the
         # host plane too, on the device trace's clock; without one the
         # annotation costs a flag test
-        self._ann = _annotation(self.name)
+        if self._ann is None:
+            self._ann = _annotation(self.name, self.id)
         self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
@@ -356,13 +370,15 @@ def self_seconds(entries) -> Dict[int, float]:
 
 
 def record_span(name: str, start_s: float, dur_s: float,
-                trace=None) -> None:
+                trace=None, parent: Optional[int] = None,
+                depth: int = 0) -> None:
     """Append an already-measured interval to the trace ring and span
     aggregates — for callers that own their own stopwatch (e.g. the
     executor re-emitting a job's t_submit->t_done serve latency so the
     merged fleet timeline carries one bar per job and the raw durations
     can cross-check the bucketed histogram gauges).  `start_s` is a
-    ``time.perf_counter()`` reading from THIS process."""
+    ``time.perf_counter()`` reading from THIS process; `parent` and
+    `depth` place the interval under a span that encloses it."""
     if not _ENABLED:
         return
     if trace is None:
@@ -372,13 +388,87 @@ def record_span(name: str, start_s: float, dur_s: float,
         "ts_s": start_s - _EPOCH,
         "dur_s": dur_s,
         "tid": threading.get_ident(),
-        "depth": 0,
+        "depth": depth,
         "id": next(_SPAN_IDS),
-        "parent": None,
+        "parent": parent,
     }
     if trace is not None:
         entry["trace"] = trace
     _record(entry)
+
+
+# ---------------------------------------------------------------------------
+# JAX's compile pipeline as spans
+# ---------------------------------------------------------------------------
+
+# jax/_src/dispatch.py brackets each stage with a context manager that
+# fires the event twice through jax.monitoring: as a scalar (its start)
+# on entry and as a duration on exit.  Begin and end are a span's enter
+# and exit, so a stage nests under the program span that is open on the
+# thread and holds whatever compiles inside it.
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+# fired on exit alone, inside the backend stage
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_LISTENING = False
+
+
+def _listen() -> None:
+    """Register the two compile listeners, once a process and only once
+    jax is there: this module imports none (a first enabled span does)."""
+    global _LISTENING
+    if _LISTENING or "jax" not in sys.modules:
+        return
+    _LISTENING = True
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_on_compile_begin)
+    monitoring.register_event_duration_secs_listener(_on_compile_end)
+
+
+def _on_compile_begin(event: str, _start, **_kw) -> None:
+    # the thread's open stages, innermost last; None stands for a trace
+    # inside a trace (a jit that calls a jit), which counts once: in the
+    # outer one.  While a stage is open its inner ones are followed
+    # whatever the gate says, so that every begin meets its end.
+    open_stages = getattr(_TLS, "compile", None)
+    if not (_ENABLED or open_stages):
+        return
+    name = _COMPILE_STAGES.get(event)
+    if name is None:
+        return
+    if open_stages is None:
+        open_stages = _TLS.compile = []
+    nested = name == "compile.trace" and any(
+        s is not None and s.name == name for s in open_stages)
+    # ring and aggregates only: a profiler trace keeps its own account
+    # of what JAX does
+    open_stages.append(
+        None if nested else _Span(name, annotate=False).__enter__())
+
+
+def _on_compile_end(event: str, duration: float, **_kw) -> None:
+    open_stages = getattr(_TLS, "compile", None)
+    if not (_ENABLED or open_stages):
+        return
+    name = _COMPILE_STAGES.get(event)
+    if name is not None and open_stages:
+        stage = open_stages.pop()
+        if stage is not None:
+            stage.__exit__(None, None, None)
+        return
+    if name is None:
+        if event != _CACHE_LOAD_EVENT:
+            return
+        name = "compile.cache_load"
+    # no begin was seen (the cache's load has none; telemetry came on
+    # inside a stage): an interval that ended now and lasted `duration`
+    stack = getattr(_TLS, "stack", None) or ()
+    record_span(name, time.perf_counter() - duration, duration,
+                parent=stack[-1] if stack else None, depth=len(stack))
 
 
 # ---------------------------------------------------------------------------
@@ -599,6 +689,8 @@ from .export import (  # noqa: E402  (cycle-safe: export imports nothing above l
 )
 from .blackbox import FlightRecorder, read_blackbox  # noqa: E402
 
+if _ENABLED:
+    _listen()
 # arm the atexit JSONL dump when the env gate + out path are both set
 if _ENABLED and os.environ.get("QRACK_TPU_TELEMETRY_OUT"):
     from .export import arm_atexit as _arm

@@ -73,9 +73,10 @@ def test_gate_counters_per_stack(stack):
         # phases force the dense engines underneath
         q.QFT(0, n)
     q.GetQuantumState()
-    counters = tele.snapshot()["counters"]
+    snap = tele.snapshot()
+    counters = snap["counters"]
     assert any(k.startswith("gate.") for k in counters), counters
-    assert counters.get("factory.create_interface") == 1
+    assert snap["spans"]["factory.create_interface"]["count"] == 1
 
 
 def test_qft20_optimal_counts_three_layers():

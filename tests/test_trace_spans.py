@@ -1,7 +1,9 @@
 """Spans at the layer boundaries of the dense path (docs/OBSERVABILITY.md
 "Spans inside the fuser and the engine"): ids and parents, the three
 spans beneath every ``fuse.flush``, the counters that ride with them,
-the scopes a device trace finds the programs by, and the disabled path.
+the scopes a device trace finds the programs by, and the disabled path;
+JAX's compile stages as children of the span that is open, and the
+``id`` that ties the ring's clock to a profiler trace's.
 
 The names a compiled kernel carries are held where the chip's compiler
 is (tests/test_chip_compile.py)."""
@@ -152,6 +154,240 @@ def test_an_enabled_span_is_an_event_of_an_open_profiler_trace(tmp_path):
              if plane.name.startswith("/host:")
              for line in plane.lines for ev in line.events}
     assert "qrack.fuse.flush" in names
+
+
+# -- JAX's compile stages under the span that is open ------------------------------
+
+STAGES = ("compile.trace", "compile.lower", "compile.backend")
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _nested_jits():
+    """A jit that calls a jit, new functions every time: never cached."""
+    inner = jax.jit(lambda x: x * 2.0 + 1.0)
+    return jax.jit(lambda x: inner(x) + inner(x + 1.0))
+
+
+def _named(name):
+    return [e for e in _recorded() if e["name"] == name]
+
+
+def _inside(child, parent, slack=1e-3):
+    # a stage's end is JAX's own reading of another clock
+    return (child["ts_s"] >= parent["ts_s"] - slack
+            and child["ts_s"] + child["dur_s"]
+            <= parent["ts_s"] + parent["dur_s"] + slack)
+
+
+def test_a_first_call_leaves_its_stages_under_the_open_span():
+    tele.enable()
+    fn, x = _nested_jits(), np.ones(8, np.float32)  # numpy: no eager program
+    with tele.span("program.first_call"):
+        fn(x).block_until_ready()
+    with tele.span("program.second_call"):
+        fn(x).block_until_ready()
+    first, second = _named("program.first_call")[0], \
+        _named("program.second_call")[0]
+    stages = [e for e in _recorded() if e["name"] in STAGES]
+    # the inner jit's trace is part of the outer one's: counted once
+    assert sorted(e["name"] for e in stages) == sorted(STAGES)
+    for e in stages:
+        assert e["parent"] == first["id"] and e["depth"] == first["depth"] + 1
+        assert _inside(e, first)
+    assert sum(e["dur_s"] for e in stages) <= first["dur_s"]
+    assert not [e for e in _recorded() if e["parent"] == second["id"]]
+    agg = tele.snapshot()["spans"]
+    assert [agg[n]["count"] for n in STAGES] == [1, 1, 1]
+
+
+def test_an_eager_program_inside_a_trace_is_the_traces_child():
+    """What compiles while a function is traced (an operation evaluated
+    at trace time) nests under that trace, so outermost stages never sum
+    to more than the span around them."""
+    tele.enable()
+
+    def fn(x):
+        with jax.ensure_compile_time_eval():  # eager, while tracing
+            c = jnp.arange(8, dtype=jnp.float32) * 3.0
+        return x + c
+
+    with tele.span("program.call"):
+        jax.jit(fn)(np.ones(8, np.float32)).block_until_ready()
+    call = _named("program.call")[0]
+    by_id = {e["id"]: e for e in _recorded()}
+    outer = [e for e in _recorded() if e["parent"] == call["id"]]
+    assert sorted(e["name"] for e in outer) == sorted(STAGES)
+    trace = [e for e in outer if e["name"] == "compile.trace"][0]
+    inner = [e for e in _recorded() if e["parent"] == trace["id"]]
+    assert inner and {e["name"] for e in inner} <= set(STAGES)
+    assert all(_inside(e, trace) for e in inner)
+    assert sum(e["dur_s"] for e in outer) <= call["dur_s"]
+    own = tele.self_seconds(_recorded())
+    assert all(v >= -1e-3 for v in own.values())
+    assert all(by_id[e["parent"]]["name"] != e["name"] for e in inner)
+
+
+def test_a_stage_under_no_span_has_no_parent():
+    tele.enable()
+    _nested_jits()(np.ones(4, np.float32)).block_until_ready()
+    stages = [e for e in _recorded() if e["name"] in STAGES]
+    assert sorted(e["name"] for e in stages) == sorted(STAGES)
+    assert all(e["parent"] is None and e["depth"] == 0 for e in stages)
+
+
+def test_the_caches_load_is_a_child_of_the_backend_stage():
+    """The persistent cache fires its retrieval's duration inside the
+    backend stage, with no begin of its own (jax/_src/compiler.py)."""
+    from jax import monitoring
+
+    tele.enable()
+    with tele.span("program.call"):
+        monitoring.record_scalar(BACKEND_EVENT, 0.0, fun_name="f")
+        monitoring.record_event_duration_secs(CACHE_LOAD_EVENT, 1e-4)
+        monitoring.record_event_duration_secs(BACKEND_EVENT, 2e-4,
+                                              fun_name="f")
+    call, backend, load = (_named(n)[0] for n in (
+        "program.call", "compile.backend", "compile.cache_load"))
+    assert backend["parent"] == call["id"]
+    assert load["parent"] == backend["id"] and load["depth"] == 2
+    assert load["dur_s"] == 1e-4
+    # an end whose begin was never seen: an interval that ended now
+    monitoring.record_event_duration_secs(BACKEND_EVENT, 0.5, fun_name="g")
+    late = _named("compile.backend")[-1]
+    assert late["parent"] is None and late["dur_s"] == 0.5
+
+
+def test_telemetry_off_the_listeners_record_nothing():
+    tele.enable()   # registers the listeners, once a process
+    tele.disable()
+    _nested_jits()(np.ones(4, np.float32)).block_until_ready()
+    assert tele.span("fuse.flush") is tele._NULL_SPAN
+    assert _recorded() == [] and tele.snapshot()["spans"] == {}
+    assert not getattr(tele._TLS, "compile", None)
+
+
+def test_a_stage_begun_while_on_meets_its_end_while_off():
+    from jax import monitoring
+
+    tele.enable()
+    with tele.span("program.call"):
+        monitoring.record_scalar(BACKEND_EVENT, 0.0, fun_name="f")
+        tele.disable()
+        monitoring.record_event_duration_secs(BACKEND_EVENT, 1e-4,
+                                              fun_name="f")
+    assert tele._TLS.compile == [] and tele._TLS.stack == []
+    assert [e["name"] for e in _recorded()] == ["compile.backend",
+                                                "program.call"]
+
+
+def test_record_span_takes_a_parent_and_a_depth():
+    import time
+
+    tele.enable()
+    with tele.span("outer"):
+        tele.record_span("measured", time.perf_counter() - 0.25, 0.25,
+                         parent=tele._TLS.stack[-1], depth=1)
+    outer, measured = _named("outer")[0], _named("measured")[0]
+    assert (measured["parent"], measured["depth"]) == (outer["id"], 1)
+    tele.record_span("alone", time.perf_counter(), 0.5)
+    assert (_named("alone")[0]["parent"], _named("alone")[0]["depth"]) \
+        == (None, 0)
+
+
+def _stack_tpu_qft():
+    from qrack_tpu import create_quantum_interface
+
+    q = create_quantum_interface("tpu", W, rng=QrackRandom(7),
+                                 rand_global_phase=False)
+    return _qft(q)
+
+
+def _stack_pager_trotter_step():
+    from helpers import issue, trotter_step_gates
+    from qrack_tpu import create_quantum_interface
+
+    q = create_quantum_interface("pager", 14, n_pages=4, rng=QrackRandom(3),
+                                 rand_global_phase=False)
+    q.SetPermutation(0b10110011101011)
+    issue(q, trotter_step_gates(14))
+    return q.GetAmplitude(0b10110011101011)
+
+
+@pytest.mark.parametrize("drive", [_stack_tpu_qft, _stack_pager_trotter_step],
+                         ids=["tpu-qft-w12", "pager-trotter-w14"])
+def test_every_compile_stage_of_a_stack_is_under_a_program_span(drive):
+    from qrack_tpu.parallel import pager
+
+    fu.PROGRAMS.clear()
+    pager._PROGRAMS.clear()  # the stack's programs compile in this test
+    tele.enable()
+    drive()
+    rec = _recorded()
+    by_id = {e["id"]: e for e in rec}
+    stages = [e for e in rec if e["name"].startswith("compile.")]
+    assert {"compile.trace", "compile.lower", "compile.backend"} \
+        <= {e["name"] for e in stages}
+    for e in stages:
+        while e["name"].startswith("compile."):
+            assert e["parent"] is not None, e
+            e = by_id[e["parent"]]
+    # the engine's names, whatever the engine
+    names = {e["name"] for e in rec}
+    assert {"engine.read", "engine.set_permutation",
+            "factory.create_interface"} <= names
+    create = _named("factory.create_interface")
+    assert len(create) == 1
+    fills = _named("engine.set_permutation")
+    assert fills[0]["parent"] == create[0]["id"]      # the constructor's
+    assert fills[-1]["parent"] is None                # the caller's
+    own = tele.self_seconds(rec)
+    assert all(v >= -1e-3 for v in own.values())
+
+
+# -- the ring on a profiler trace's clock ----------------------------------------------
+
+def _host_events(directory):
+    """(name, id statistic, start_ns) of the ``qrack.*`` host events."""
+    from jax.profiler import ProfileData
+
+    found = [os.path.join(d, f) for d, _, fs in os.walk(directory)
+             for f in fs if f.endswith(".xplane.pb")]
+    return [(ev.name, dict(ev.stats).get("id"), ev.start_ns)
+            for plane in ProfileData.from_file(found[0]).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("qrack.")]
+
+
+def test_a_traced_span_carries_its_id_and_ties_the_two_clocks(tmp_path):
+    tele.enable()
+    with tele.span("engine.set_permutation"):   # before the trace opens
+        jnp.zeros(4).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            with tele.span("fuse.flush"):
+                with tele.span("fuse.dispatch"):
+                    jnp.zeros(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    by_id = {e["id"]: e for e in _recorded()}
+    events = _host_events(tmp_path)
+    assert sorted(n for n, _, _ in events) == sorted(
+        ["qrack.fuse.flush", "qrack.fuse.dispatch"] * 3)
+    # every traced event has its ring twin, by id and by name
+    for name, span_id, _ in events:
+        assert name == "qrack." + by_id[span_id]["name"]
+    offsets = [start / 1e9 - by_id[i]["ts_s"] for _, i, start in events]
+    assert max(offsets) - min(offsets) < 1e-3
+    offset = sorted(offsets)[len(offsets) // 2]
+    # the ring's entries on the trace's clock: the one recorded before
+    # the trace opened lies ahead of the first traced event
+    before = _named("engine.set_permutation")[0]
+    assert before["id"] not in {i for _, i, _ in events}
+    first_traced = min(start for _, _, start in events) / 1e9
+    assert before["ts_s"] + before["dur_s"] + offset < first_traced
 
 
 # -- the disabled path ---------------------------------------------------------
