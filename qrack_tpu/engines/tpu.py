@@ -426,7 +426,9 @@ class QEngineTPU(QEngine):
         if plan is not None:
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
                                    width=n, esize=esize, cross=plan["cross"],
-                                   dense=plan["dense"], twoq=plan["twoq"])
+                                   dense=plan["dense"], twoq=plan["twoq"],
+                                   diag_runs=lambda: fu.diag_run_counts(
+                                       ops, plan["block_pow"]))
         else:
             fu.record_xla_flush(self._tele_name, len(ops), width=n,
                                 esize=esize)

@@ -15,7 +15,14 @@ Vocabulary (everything the fuser emits):
 * cphase / diag — ANY target and controls.  The combined/control mask
   splits at runtime inside the kernel into a tile-local part tested
   against the in-tile index and a high part tested against the grid
-  block id, so high targets cost one scalar compare per tile.
+  block id, so high targets cost one scalar compare per tile.  Two or
+  more of them in a row are a RUN, applied as the one diagonal operator
+  they are (_apply_run): the ops with no high part are multiplied
+  together once a launch into a phase tile in VMEM, which every step
+  multiplies by once, and an op with a high part is applied only on the
+  tiles whose id admits it, in a pass over the value held in VMEM.
+  Which of the two an op is follows from its runtime masks, so the
+  program key stays the structure.
 * inv / gen with target < block_pow — in-tile pair mix: each element
   reads its partner 2^target amplitudes away through two rotations
   (tile_partner); controls anywhere (runtime mask split).
@@ -85,6 +92,7 @@ grid step, so it is a CORRECTNESS harness, not a fast path
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Tuple
 
 import jax
@@ -261,6 +269,9 @@ def tile_diag(v, lidx, hi_id, target, L,
                       v[0] * f_im + v[1] * f_re]), hi_ok
 
 
+_TILE_DIAGONAL = {"cphase": tile_cphase, "diag": tile_diag}
+
+
 def tile_partner(v, lidx, target):
     """``v[:, i ^ (1 << target)]`` on one tile, as two rotations and a
     select on the target bit.  The tile is ``v.shape[1:]``: flat
@@ -387,35 +398,69 @@ def _tile_index(tile: Tuple[int, ...]):
     return jax.lax.broadcasted_iota(jnp.int32, shape, 0) * shape[1] + lane
 
 
-def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
-    """Apply one in-tile window op to the loaded tile value.  Masks are
-    runtime scalars; the lo/hi split happens here (dense widths are
-    int32-safe: engines/tpu.py MAX_DENSE_QB)."""
-    idx, kind, target, has_ctrl = slot
-    foff, ioff = slots[idx]
+def _static_pick(values, at):
+    """``values[at]`` of a static list of ints under 256 at a traced
+    index: four to a word, so that the pick is a select among the words
+    and a shift, not a select an entry."""
+    words = [sum(v << (8 * j) for j, v in enumerate(values[w:w + 4]))
+             for w in range(0, len(values), 4)]
+    word = jnp.int32(words[0])
+    for later, value in enumerate(words[1:], 1):
+        word = jnp.where(at >> 2 == later, jnp.int32(value), word)
+    return (word >> ((at & 3) << 3)) & 255
+
+
+def _slot_masks(slot, slots, iv_ref, at=0):
+    """An op's runtime ``(cmask, cval)``, zeros where it is uncontrolled;
+    with ``at``, those of the op that many alike slots further on."""
+    idx, kind, _, has_ctrl = slot
+    if not has_ctrl:
+        return jnp.int32(0), jnp.int32(0)
+    ioff = slots[idx][1] + at * _nints(kind, True)
+    return iv_ref[ioff, 0], iv_ref[ioff + 1, 0]
+
+
+def _diag_operands(stretch, at, slots, iv_ref, fv_ref, bp):
+    """The arguments of tile_cphase / tile_diag after ``(v, lidx,
+    hi_id)`` for the ``at``-th slot of ``stretch``: consecutive cphase
+    slots alike in having controls, whose operands lie a fixed stride
+    apart in both columns, so that ``at`` may be a loop's traced index
+    (_apply_run), or one diag slot (tile_diag wants its target static).
+    Masks are runtime scalars; the lo/hi split happens here (dense
+    widths are int32-safe: engines/tpu.py MAX_DENSE_QB)."""
+    idx, kind, target, _ = stretch[0]
+    foff = slots[idx][0] + at * _NFLOATS[kind]
     lbits = (1 << bp) - 1
-    if has_ctrl:
-        cm = iv_ref[ioff, 0]
-        cv = iv_ref[ioff + 1, 0]
-    else:
-        cm = jnp.int32(0)
-        cv = jnp.int32(0)
+    cm, cv = _slot_masks(stretch[0], slots, iv_ref, at)
     if kind == "cphase":
-        comb = jnp.int32(1 << target) | cm
-        v, _ = tile_cphase(v, lidx, blk, comb & lbits, comb >> bp,
-                           fv_ref[foff, 0], fv_ref[foff + 1, 0])
-    elif kind == "diag":
-        v, _ = tile_diag(v, lidx, blk, target, bp,
-                         fv_ref[foff, 0], fv_ref[foff + 1, 0],
-                         fv_ref[foff + 2, 0], fv_ref[foff + 3, 0],
-                         cm & lbits, cv & lbits, cm >> bp, cv >> bp)
-    elif kind == "inv":
+        targets = [slot[2] for slot in stretch]
+        tbit = (jnp.int32(1 << targets[at]) if isinstance(at, int)
+                else jnp.int32(1) << _static_pick(targets, at))
+        comb = tbit | cm
+        return (comb & lbits, comb >> bp,
+                fv_ref[foff, 0], fv_ref[foff + 1, 0])
+    return (target, bp, fv_ref[foff, 0], fv_ref[foff + 1, 0],
+            fv_ref[foff + 2, 0], fv_ref[foff + 3, 0],
+            cm & lbits, cv & lbits, cm >> bp, cv >> bp)
+
+
+def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
+    """Apply one in-tile window op to the loaded tile value."""
+    idx, kind, target, has_ctrl = slot
+    foff = slots[idx][0]
+    lbits = (1 << bp) - 1
+    if kind in _TILE_DIAGONAL:
+        v, _ = _TILE_DIAGONAL[kind](
+            v, lidx, blk, *_diag_operands([slot], 0, slots, iv_ref, fv_ref, bp))
+        return v
+    if kind == "u4":
+        return tile_local_4x4(v, lidx, *target, _u4_scalars(fv_ref, foff))
+    cm, cv = _slot_masks(slot, slots, iv_ref)
+    if kind == "inv":
         v, _ = tile_local_invert(v, lidx, blk, target,
                                  fv_ref[foff, 0], fv_ref[foff + 1, 0],
                                  fv_ref[foff + 2, 0], fv_ref[foff + 3, 0],
                                  cm & lbits, cv & lbits, cm >> bp, cv >> bp)
-    elif kind == "u4":
-        v = tile_local_4x4(v, lidx, *target, _u4_scalars(fv_ref, foff))
     else:
         mp = [[[fv_ref[foff + 4 * plane + 2 * row + col, 0]
                 for col in range(2)]
@@ -424,6 +469,167 @@ def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
         v, _ = tile_local_2x2(v, lidx, blk, target, mp,
                               cm & lbits, cv & lbits, cm >> bp, cv >> bp)
     return v
+
+
+def diag_runs(ops) -> List[Tuple[int, int]]:
+    """``[(start, stop), ...]``: the maximal runs of two or more
+    consecutive diagonal ops (cphase, diag) among a segment's in-tile
+    slots.  Diagonal ops commute, so a run is one diagonal operator and
+    the body applies it as one (_apply_run); a diagonal op that stands
+    alone keeps _apply_slot."""
+    runs = []
+    start = None
+    for at, slot in enumerate(list(ops) + [None]):
+        if slot is not None and slot[1] in _TILE_DIAGONAL:
+            if start is None:
+                start = at
+            continue
+        if start is not None and at - start >= 2:
+            runs.append((start, at))
+        start = None
+    return runs
+
+
+def in_tile(kind: str, target: int, cmask: int, cval: int, bp: int) -> bool:
+    """Do all the bits a diagonal op reads lie inside the tile?  Then
+    its factor is the same on every tile of a launch, and a run folds it
+    into its phase tile.  On Python ints, for the host's counters; the
+    kernel decides the same from its runtime masks (_apply_run)."""
+    if kind == "cphase":
+        return ((1 << target) | cmask) >> bp == 0
+    return target < bp and (cmask | cval) >> bp == 0
+
+
+def diag_run_counts(structure: Tuple, masks, bp: int) -> Tuple[int, int, int]:
+    """``(runs, ops, tile_ops)``: the runs of diagonal ops the kernel
+    lowers to a phase tile for this window, the ops inside them, and
+    those of them whose factor goes into the tile (in_tile).  ``masks``
+    are the ops' ``(cmask, cval)`` as the kernel reads them: the
+    telemetry counters ``fuse.kernel.diag_runs``, ``.diag_run.ops`` and
+    ``.diag_run.tile_ops``."""
+    runs = ops = tile_ops = 0
+    for seg in plan_window(structure, bp):
+        for start, stop in diag_runs(seg["ops"]):
+            runs += 1
+            ops += stop - start
+            tile_ops += sum(in_tile(kind, target, *masks[idx], bp)
+                            for idx, kind, target, _ in seg["ops"][start:stop])
+    return runs, ops, tile_ops
+
+
+# rows of the dense tile a step of a run's passes takes: eight vreg pairs
+_RUN_ROWS = 64
+
+
+def _for_tile_chunks(tile: Tuple[int, ...], body) -> None:
+    """``body(rows, lidx)`` for every chunk of a tile, in a loop: ``rows``
+    slices the first axis of a ref of the tile's shape and ``lidx`` is
+    the chunk's in-tile index.  A run's passes go chunk by chunk because
+    the TPU's scheduler keeps the order it is given: a pass written on
+    the whole tile is emitted operation by operation over its 64 vreg
+    pairs, and the 128 live vregs go through the one vector-store slot
+    between any two operations (635 bundles for one cphase whose
+    arithmetic fills 176: PERF.md section 6, PR 42).  The loop is
+    rolled: unrolled it schedules tighter (188 bundles a cphase against
+    284), and a window program takes seconds to trace and lower.  A
+    flat tile is one chunk."""
+    if len(tile) == 1:
+        body(slice(None), _tile_index(tile))
+        return
+    rows = min(tile[0], _RUN_ROWS)
+    lidx = _tile_index((rows, tile[1]))
+
+    def step(at, carry):
+        start = pl.multiple_of(at * rows, rows)
+        body(pl.ds(start, rows), lidx + start * tile[1])
+        return carry
+
+    jax.lax.fori_loop(0, tile[0] // rows, step, 0)
+
+
+def _run_stretches(run) -> List[list]:
+    """A run's slots as _diag_operands takes them: consecutive cphase
+    slots alike in having controls together, a diag alone."""
+    stretches = []
+    for slot in run:
+        last = stretches[-1][-1] if stretches else None
+        if last and slot[1] == last[1] == "cphase" and slot[3] == last[3]:
+            stretches[-1].append(slot)
+        else:
+            stretches.append([slot])
+    return stretches
+
+
+def _apply_run(v, blk, run, slots, iv_ref, fv_ref, bp, first, run_ref,
+               table):
+    """A run of diagonal ops on the tile value ``v``, as one operator.
+
+    The run's factor at amplitude ``(blk, lidx)`` is ``R(blk, lidx) *
+    T(lidx)``.  ``T`` is the product of the ops that read no bit above
+    the tile: the same for every tile, so it is built once a launch, at
+    ``first`` (the launch's first computing step), in ``run_ref[table]``
+    from a tile of ``1 + 0i``.  ``R`` is the rest: each op with a high
+    part, by the code it has alone, in a pass over the value held in
+    ``run_ref[0]``, and only on a tile whose id admits it; on any other
+    tile its factor is exactly ``1 + 0i`` and the step branches past it.
+    An op is one or the other by its runtime masks, never both, so it is
+    traced once, under one ``pl.when``: onto the table at ``first``, or
+    onto the value where the tile admits it.  The step ends with the
+    one multiply of the value by the table, complete by then on the
+    first step too.  The ops of a stretch (_run_stretches) are one
+    traced body in a loop over their operands' offsets: what a window
+    program costs to trace and lower is part of every set-up (PERF.md
+    section 6, PR 42)."""
+    tile = v.shape[1:]
+
+    def a_pass(at, kind, args):
+        """The op on ``run_ref[at]``, in place."""
+        def chunk(rows, lidx):
+            at_rows = (at, slice(None), rows)
+            run_ref[at_rows] = _TILE_DIAGONAL[kind](run_ref[at_rows], lidx,
+                                                    blk, *args)[0]
+        _for_tile_chunks(tile, chunk)
+
+    def for_ops(stretch):
+        """Each op of the stretch onto the table or onto the value."""
+        kind = stretch[0][1]
+
+        def op(at, carry=0):
+            args = _diag_operands(stretch, at, slots, iv_ref, fv_ref, bp)
+            if kind == "cphase":
+                high, admits = args[1], (blk & args[1]) == args[1]   # chi
+            else:
+                high = jnp.int32(stretch[0][2] >= bp) | args[-2] | args[-1]
+                admits = (blk & args[-2]) == args[-1]                # gm, gv
+            # on the table an op has no high part: any tile id admits it
+            pl.when(jnp.where(high == 0, first, admits))(functools.partial(
+                a_pass, jnp.where(high == 0, table, 0), kind, args))
+            return carry
+
+        if len(stretch) == 1:
+            op(0)
+        else:
+            jax.lax.fori_loop(0, len(stretch), op, 0)
+
+    @pl.when(first)
+    def _():
+        run_ref[table] = jnp.stack([jnp.ones(tile, v.dtype),
+                                    jnp.zeros(tile, v.dtype)])
+
+    # + 0.0: a cast that feeds a store alone is made by strided stores
+    # (led_kernel); it turns a -0.0 into 0.0 and nothing else
+    run_ref[0] = v + 0.0
+    for stretch in _run_stretches(run):
+        for_ops(stretch)
+
+    def by_table(rows, _):
+        t_re, t_im = run_ref[table, 0, rows], run_ref[table, 1, rows]
+        re, im = run_ref[0, 0, rows], run_ref[0, 1, rows]
+        run_ref[0, 0, rows] = re * t_re - im * t_im
+        run_ref[0, 1, rows] = re * t_im + im * t_re
+
+    _for_tile_chunks(tile, by_table)
+    return run_ref[0]
 
 
 def _u4_scalars(fv_ref, foff):
@@ -466,12 +672,32 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
     def load(ref):
         return ref[...].reshape((2,) + tile)
 
-    def in_tile_ops(v, blk, iv_ref, fv_ref):
+    # the segment's in-tile ops, a run of diagonal ones as one piece, and
+    # the runs' VMEM scratch after whatever the kernel has of its own:
+    # tiles of the body's shape, the first for the value and one phase
+    # tile a run (_apply_run).  A segment without a run has none, and
+    # its body is its ops one after the other
+    ops = seg["ops"]
+    runs = dict(diag_runs(ops))
+    run_scratch = [(1 + len(runs), 2) + tile] if runs else []
+
+    def in_tile_ops(v, blk, iv_ref, fv_ref, first, run_refs):
         """The segment's in-tile ops on a loaded (or mixed) tile value,
-        back in the refs' ``(2, block)`` shape."""
+        back in the refs' ``(2, block)`` shape.  ``first()``: is this
+        the launch's first computing step (asked where a run builds its
+        table); ``run_refs``: the refs of ``run_scratch``."""
         lidx = _tile_index(tile)
-        for slot in seg["ops"]:
-            v = _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp)
+        at = table = 0
+        while at < len(ops):
+            if at in runs:
+                table += 1
+                v = _apply_run(v, blk, ops[at:runs[at]], slots, iv_ref,
+                               fv_ref, bp, first(), *run_refs, table)
+                at = runs[at]
+            else:
+                v = _apply_slot(v, lidx, blk, ops[at], slots, iv_ref, fv_ref,
+                                bp)
+                at += 1
             if interpret:  # XLA lowers the body: see tile_partner
                 v = jax.lax.optimization_barrier(v)
         return v.reshape(2, block)
@@ -504,8 +730,8 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
 
         def run(planes, iv, fv):
             # two orbits of cast tiles: the one read and the one written
-            scratch = [pltpu.VMEM((2, m, 2) + tile, planes.dtype)] \
-                if lead_bits else []
+            scratch = [pltpu.VMEM(shape, planes.dtype) for shape in
+                       ([(2, m, 2) + tile] if lead_bits else []) + run_scratch]
             return pl.pallas_call(
                 kernel,
                 out_shape=jax.ShapeDtypeStruct((2, 1 << n), planes.dtype),
@@ -523,9 +749,10 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
         return run
 
     if xgen is None:
-        def kernel(iv_ref, fv_ref, in_ref, out_ref):
-            out_ref[...] = in_tile_ops(load(in_ref), pl.program_id(0),
-                                       iv_ref, fv_ref)
+        def kernel(iv_ref, fv_ref, in_ref, out_ref, *run_refs):
+            out_ref[...] = in_tile_ops(load(in_ref), pl.program_id(0), iv_ref,
+                                       fv_ref, lambda: pl.program_id(0) == 0,
+                                       run_refs)
 
         return launch(kernel)
 
@@ -539,7 +766,7 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
         stores one, as an unled step does, whatever the lead mixes."""
         orbits = nblk >> len(lead_bits)
 
-        def kernel(iv_ref, fv_ref, in_ref, out_ref, orbit_ref):
+        def kernel(iv_ref, fv_ref, in_ref, out_ref, orbit_ref, *run_refs):
             o, member = pl.program_id(0), pl.program_id(1)
 
             @pl.when(o < orbits)
@@ -556,7 +783,9 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
                 blk = orbit_tile(lead_bits, o - 1, member)
                 nv = mix(lambda k: orbit_ref[(o - 1) & 1, k], member, blk,
                          iv_ref, fv_ref)
-                out_ref[...] = in_tile_ops(nv, blk, iv_ref, fv_ref)
+                out_ref[...] = in_tile_ops(
+                    nv, blk, iv_ref, fv_ref,
+                    lambda: (o == 1) & (member == 0), run_refs)
 
         return launch(kernel, lead_bits)
 

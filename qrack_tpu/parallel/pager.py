@@ -800,7 +800,9 @@ class QPager(QEngine):
         if plan is not None:
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
                                    width=self.qubit_count,
-                                   cross=plan["cross"], dense=plan["dense"])
+                                   cross=plan["cross"], dense=plan["dense"],
+                                   diag_runs=lambda: fu.diag_run_counts(
+                                       tops, plan["block_pow"], split_at=L))
         else:
             fu.record_kernel_fallback(why)
             fu.record_xla_flush(self._tele_name, len(ops),

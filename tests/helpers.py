@@ -83,7 +83,8 @@ def benchmark_plans(width: int = 28):
     """``windows(family)``: the windows the fuser plans for one
     application of a dense cell's family at ``width``, from the
     benchmark's own gate lists (``benchmarks/tests/structure.py``), no
-    ket allocated.  The benchmark's modules are imported for the
+    ket allocated; each with the ``ops`` it lowered, whose masks say
+    where a control sits.  The benchmark's modules are imported for the
     ``with`` alone."""
     import os
     import sys
@@ -96,8 +97,23 @@ def benchmark_plans(width: int = 28):
         import families
         import structure
 
-        yield lambda name: structure.plan_application(
-            families.family(name), width, families.PARAMS[name])
+        from qrack_tpu.ops import fusion as fu
+
+        class WithOps(structure.PlanOnlyEngine):
+            def _fuse_flush(self, gates):
+                dispatched = super()._fuse_flush(gates)
+                self.windows[-1]["ops"] = fu.lower_gates(gates)
+                return dispatched
+
+        def windows(name):
+            planner, structure.PlanOnlyEngine = structure.PlanOnlyEngine, WithOps
+            try:
+                return structure.plan_application(
+                    families.family(name), width, families.PARAMS[name])
+            finally:
+                structure.PlanOnlyEngine = planner
+
+        yield windows
     finally:
         sys.path[:] = path
         for name in set(sys.modules) - before:

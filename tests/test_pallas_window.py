@@ -535,17 +535,26 @@ def benchmark_plans():
         yield windows
 
 
-@pytest.mark.parametrize("family,sweeps,carry_ops", [("qft", 37, 37),
-                                                     ("tfim", 41, 17)])
+@pytest.mark.parametrize("family,sweeps,carry_ops,diag_runs", [
+    ("qft", 37, 37, (47, 373, 119)), ("tfim", 41, 17, (0, 0, 0)),
+    ("rcs", 102, 20, (0, 0, 0))])
 def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
-                                     carry_ops):
+                                     carry_ops, diag_runs):
     """What ``fuse.kernel.sweeps.dense`` reads in a traced run of each
     cell: every planned kernel segment of an application at w28.  Of
     TFIM's 36 cross-tile segments 12 carry an in-tile op behind the mix
     (11 a ``diag``, one a window's 15 ``gen``), 12 are the controlled
     ``inv`` alone, whose select is what the dense tile shortens, and 12
-    the last window's bare ``gen`` (its 13th, on qubit 15, is in-tile)."""
+    the last window's bare ``gen`` (its 13th, on qubit 15, is in-tile).
+
+    And what ``fuse.kernel.diag_runs`` / ``.diag_run.ops`` /
+    ``.diag_run.tile_ops`` read there (PR 42): QFT's 378 ``cphase`` sit
+    in 47 runs of two or more but for five, and 119 of those in runs
+    have both bits in the tile; no segment of a Trotter step or of a
+    random circuit holds two diagonal ops in a row, so their bodies are
+    the ops one after the other, as before."""
     dense = with_ops = 0
+    runs = (0, 0, 0)
     for w in benchmark_plans(family):
         if w["path"] != "kernel":
             continue
@@ -554,7 +563,33 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
         assert plan["dense"] == plan["sweeps"] == len(segments)
         dense += plan["dense"]
         with_ops += sum(bool(seg["ops"]) for seg in segments)
+        counts = fu.diag_run_counts(w["ops"], plan["block_pow"])
+        assert counts[0] == sum(len(pk.diag_runs(seg["ops"]))
+                                for seg in segments)
+        runs = tuple(a + b for a, b in zip(runs, counts))
     assert (dense, with_ops) == (sweeps, carry_ops)
+    assert runs == diag_runs
+
+
+@pytest.mark.parametrize("kwargs", [{"remap": "off"}, {}],
+                         ids=["tfim_w30.pager4_noremap", "tfim_w30.pager4"])
+def test_paged_cells_hold_no_diag_run(kwargs):
+    """The per-page kernel runs of the paged Trotter step at w30, on the
+    fixed placement and on the pager's own through its settled steps:
+    no run of two diagonal ops in any segment."""
+    from helpers import issue, plan_only_pager, trotter_step_gates
+
+    q = plan_only_pager(30, **kwargs)
+    for _ in range(6):
+        q.windows.clear()
+        issue(q, trotter_step_gates(30))
+        q.GetAmplitude(0)
+        assert len(q.windows) == 8
+        for w in q.windows:
+            plan, _ = fu.sharded_kernel_lowering(q.local_bits, w.structure,
+                                                 backend="tpu")
+            assert fu.diag_run_counts(w.tops, plan["block_pow"],
+                                      split_at=q.local_bits) == (0, 0, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -932,3 +967,417 @@ def test_a_window_of_many_launches_in_place(donate):
         at += count
     assert at == len(ops)
     assert np.array_equal(run_window(n, bp, ops, ket, donate), want)
+
+
+# ---------------------------------------------------------------------------
+# a run of diagonal ops costs one phase tile (PR 42).  Two or more
+# consecutive cphase / diag of a segment are one diagonal operator: an
+# op with a high part is applied by its own code, and only on the tiles
+# whose id admits it; the ops that read no bit above the tile are
+# multiplied together once a launch, on a tile of 1 + 0i, into a VMEM
+# scratch that every step multiplies its value by, once.  The product is associated differently from
+# the parent's (the factor takes the roundings the amplitude took), so
+# the kernel is held bit for bit to numpy float32 in the NEW order, and
+# to the parent's order within the roundings both make.
+# ---------------------------------------------------------------------------
+
+def _cmul(v, f):
+    return np.stack([v[0] * f[0] - v[1] * f[1], v[0] * f[1] + v[1] * f[0]])
+
+
+def run_in_numpy(ket, run, n, bp):
+    """A run of diagonal ops on the whole ``(2, 2^n)`` float32 ket in
+    the kernel's order (``_apply_run``): the ops with a bit above the
+    tile, in their order (on a tile that does not admit one its factor
+    is ``1 + 0i``, and multiplying by that changes no bit); then one
+    multiply by the phase tile, built from the ops with every bit in
+    the tile, in their order, on a tile of ``1 + 0i``."""
+    table = np.stack([np.ones(1 << bp, np.float32),
+                      np.zeros(1 << bp, np.float32)])
+    for op in run:
+        if pk.in_tile(op.kind, op.target, op.cmask, op.cval, bp):
+            table = unled_in_numpy(table, op, bp)
+        else:
+            ket = unled_in_numpy(ket, op, n)
+    return _cmul(ket, np.tile(table, (1, 1 << (n - bp))))
+
+
+def segment_in_numpy(ket, ops, n, bp):
+    """The in-tile ops of one segment in the kernel's order: a run of
+    two or more diagonal ops through ``run_in_numpy``, any other op
+    through ``unled_in_numpy``."""
+    runs = dict(pk.diag_runs([(i, op.kind, op.target, op.cmask != 0)
+                              for i, op in enumerate(ops)]))
+    at = 0
+    while at < len(ops):
+        if at in runs:
+            ket = run_in_numpy(ket, ops[at:runs[at]], n, bp)
+            at = runs[at]
+        else:
+            ket = unled_in_numpy(ket, ops[at], n)
+            at += 1
+    return ket
+
+
+def _phase(angle):
+    return np.diag([1.0, np.exp(1j * angle)])
+
+
+def _two_phases(a0, a1):
+    return np.diag([np.exp(1j * a0), np.exp(1j * a1)])
+
+
+def diagonal_ops(n, bp):
+    """Every class of diagonal op by where its bits lie against the
+    tile, by name; ``n - bp >= 2``.  The last four hold an anti-control
+    (``cval != cmask``)."""
+    top, low = n - 1, 1 << bp
+    ops = {
+        "cphase.tile": ("cphase", 5, 1 << 2, 1 << 2, _phase(0.37)),
+        "cphase.tile.bare": ("cphase", 3, 0, 0, _phase(-0.91)),
+        "cphase.mixed": ("cphase", top, 1 << 3, 1 << 3, _phase(0.71)),
+        "cphase.mixed2": ("cphase", bp, 1 << 6, 1 << 6, _phase(-1.3)),
+        "cphase.high": ("cphase", top, low, low, _phase(1.13)),
+        "cphase.high.bare": ("cphase", top, 0, 0, _phase(0.29)),
+        "cphase.tile.2c": ("cphase", 7, 0b11, 0b11, _phase(2.1)),
+        "cphase.mixed.2c": ("cphase", 4, low | 2, low | 2, _phase(-0.43)),
+        "diag.tile.bare": ("diag", 4, 0, 0, _two_phases(-0.21, 0.53)),
+        "diag.high.bare": ("diag", top, 0, 0, _two_phases(0.8, -0.33)),
+        "diag.tile": ("diag", 6, 1 << 1, 1 << 1, _two_phases(0.15, 0.95)),
+        "diag.high": ("diag", top, 1 << 5, 1 << 5, _two_phases(-0.6, 0.4)),
+        "diag.tile.anti": ("diag", 1, 1 << 4, 0, _two_phases(0.5, -1.7)),
+        "diag.mixed.anti": ("diag", 2, low | 1, 1, _two_phases(1.9, 0.07)),
+        "diag.high.anti": ("diag", bp, (low << 1) | 8, 8,
+                           _two_phases(-0.77, 0.66)),
+        "diag.mixed.anti2": ("diag", 0, (low << 1) | 4, low << 1,
+                             _two_phases(0.23, -0.12)),
+    }
+    out = {}
+    for name, (kind, target, cmask, cval, m) in ops.items():
+        assert fu.classify(m, cmask, cval) == kind, name
+        out[name] = fu.FusedOp(kind, target, cmask, cval, m)
+    return out
+
+
+# (name, the run's ops): runs of 2, 3 and 16, a run that is all table
+# (no op may read the tile id: no value scratch), one that is all rest
+DIAG_RUNS = {
+    "2": ("cphase.tile", "cphase.mixed"),
+    "2-table": ("cphase.tile.bare", "diag.tile.bare"),
+    "2-rest": ("cphase.high", "diag.high.bare"),
+    "3": ("diag.tile", "cphase.high", "diag.mixed.anti"),
+    "3-anti": ("diag.tile.anti", "diag.high.anti", "diag.mixed.anti2"),
+    "16": tuple(diagonal_ops(12, 10)),
+}
+RUN_SHAPES = [(10, 8), (12, 10), (18, 16)]
+
+
+@functools.lru_cache(maxsize=None)
+def _diag_run_case(n, bp, name):
+    """``(ket, ops, got)`` of a run under the interpreter, once."""
+    ops = [diagonal_ops(n, bp)[key] for key in DIAG_RUNS[name]]
+    structure = fu.structure_of(ops)
+    segment, = pk.plan_window(structure, bp)
+    assert pk.diag_runs(segment["ops"]) == [(0, len(ops))]
+    ket = random_ket(np.random.default_rng(n * 100 + len(name)), n)
+    return ket, ops, run_window(n, bp, ops, ket, donate=False)
+
+
+_RUN_CASES = pytest.mark.parametrize(
+    "n,bp,name", [pytest.param(n, bp, name, id=f"w{n}-bp{bp}-run{name}")
+                  for n, bp in RUN_SHAPES for name in DIAG_RUNS])
+
+
+@_RUN_CASES
+def test_diag_run_is_numpy_bit_for_bit_in_the_new_order(n, bp, name):
+    ket, ops, got = _diag_run_case(n, bp, name)
+    want = run_in_numpy(ket, ops, n, bp)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+    assert got.dtype == np.float32
+
+
+@_RUN_CASES
+def test_diag_run_is_the_parents_order_within_its_roundings(n, bp, name):
+    """Against the ops one after the other (the parent's order): both
+    orders round once an op, so an amplitude moves by at most two ulp
+    of its magnitude for every op of the run."""
+    ket, ops, got = _diag_run_case(n, bp, name)
+    want = ket
+    for op in ops:
+        want = unled_in_numpy(want, op, n)
+    ulp = np.spacing(np.hypot(want[0], want[1]))
+    assert np.all(np.abs(got - want) <= 2 * len(ops) * ulp)
+    # and no op was dropped: each moves the ket by far more than that
+    for skip in range(len(ops)):
+        less = ket
+        for op in ops[:skip] + ops[skip + 1:]:
+            less = unled_in_numpy(less, op, n)
+        assert np.max(np.abs(got - less)) > 1e-3 * np.max(np.abs(want))
+
+
+@pytest.mark.parametrize("n,bp", RUN_SHAPES, ids=lambda v: str(v))
+def test_a_stretch_of_cphase_is_one_loop_over_its_operands(n, bp):
+    """Eleven controlled ``cphase`` in a row, an uncontrolled one and a
+    ``diag`` after them: the first eleven are one stretch, one traced
+    body that reads each op's masks and phase at its offset in the
+    columns and its target from a packed constant (targets out of
+    order, across the words' boundaries), the other two a stretch
+    each.  Bit for bit the run's numpy model."""
+    top = n - 1
+    targets = [5, top, 3, bp, 7, 0, top - 1, top, 2, 6, 4]
+    controls = [1, 2, top, 6, bp, 3, 1, bp, top - 1, 0, 7]
+    ops = [fu.FusedOp("cphase", t, 1 << c, 1 << c, _phase(0.2 + 0.37 * k))
+           for k, (t, c) in enumerate(zip(targets, controls))]
+    named = diagonal_ops(n, bp)
+    ops += [named["cphase.high.bare"], named["diag.mixed.anti"]]
+    segment, = pk.plan_window(fu.structure_of(ops), bp)
+    assert [len(s) for s in pk._run_stretches(segment["ops"])] == [11, 1, 1]
+    ket = random_ket(np.random.default_rng(n), n)
+    got = run_window(n, bp, ops, ket, donate=False)
+    want = run_in_numpy(ket, ops, n, bp)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+
+def test_two_runs_split_by_a_gen_in_one_segment():
+    """Each run has its own phase tile; the gen between them reads the
+    first run's result and the second run the gen's."""
+    n, bp = 12, 10
+    named = diagonal_ops(n, bp)
+    ops = [named[k] for k in ("cphase.tile", "diag.high", "cphase.mixed")] \
+        + [fu.FusedOp("gen", 3, 1 << 1, 1 << 1, _DENSE_MATRICES["gen"])] \
+        + [named[k] for k in ("diag.tile.anti", "cphase.tile.2c")] \
+        + [fu.FusedOp("inv", 6, 0, 0, _DENSE_MATRICES["inv"]),
+           named["cphase.high"]]                     # a diagonal op alone
+    segment, = pk.plan_window(fu.structure_of(ops), bp)
+    assert pk.diag_runs(segment["ops"]) == [(0, 3), (4, 6)]
+    ket = random_ket(np.random.default_rng(42), n)
+    got = run_window(n, bp, ops, ket, donate=True)
+    want = segment_in_numpy(ket, ops, n, bp)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+
+def _run_leads():
+    rng = np.random.default_rng(7)
+    return [
+        pytest.param(12, 10, fu.FusedOp("gen", 11, 0, 0, _su(rng, 2)),
+                     id="gen"),
+        pytest.param(12, 10, fu.FusedOp("gen", 10, (1 << 11) | 2, 1 << 11,
+                                        _su(rng, 2)), id="cgen"),
+        pytest.param(11, 8, fu.FusedOp("inv", 9, 1 << 3, 1 << 3,
+                                       _DENSE_MATRICES["inv"]), id="cinv"),
+        pytest.param(12, 10, fu.FusedOp("u4", (4, 11), 0, 0, _su(rng, 4)),
+                     id="u4-pair"),
+        pytest.param(12, 10, fu.FusedOp("u4", (10, 11), 0, 0, _su(rng, 4)),
+                     id="u4-quad"),
+        pytest.param(18, 16, fu.FusedOp("gen", 17, 0, 0, _su(rng, 2)),
+                     id="gen-bp16"),
+    ]
+
+
+@DONATE
+@pytest.mark.parametrize("n,bp,lead", _run_leads())
+def test_a_run_behind_a_lead_is_numpy_bit_for_bit(n, bp, lead, donate):
+    """The phases that ride behind a led 2 x 2 or u4: the table is built
+    at the launch's first computing step, orbit 1 member 0, and the
+    tile id the others read is the orbit's, not a grid index."""
+    named = diagonal_ops(n, bp)
+    behind = [named[k] for k in ("cphase.tile", "cphase.mixed", "diag.high",
+                                 "cphase.high", "diag.mixed.anti")]
+    segment, = pk.plan_window(fu.structure_of([lead] + behind), bp)
+    assert segment["xgen"][0] == 0
+    assert pk.diag_runs(segment["ops"]) == [(0, len(behind))]
+    ket = random_ket(np.random.default_rng(n + bp), n)
+    got = run_window(n, bp, [lead] + behind, ket, donate)
+    want = run_in_numpy(lead_in_numpy(ket, lead, n), behind, n, bp)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+
+def _page_ops(ops, L, pid):
+    """The ops of a per-page kernel run as page ``pid`` sees them
+    (``fusion._sharded_run_structure`` / ``_sharded_run_operands``):
+    local masks, a test on page bits folded into the payload (the
+    identity where this page misses it), an op on a page bit a ``diag``
+    on bit 0 whose two factors are equal."""
+    lbits = (1 << L) - 1
+    out = []
+    for op in ops:
+        m = np.asarray(op.m)
+        if op.kind == "cphase":
+            hit = (pid & ((op.cmask | (1 << op.target)) >> L)) \
+                == (op.cmask | (1 << op.target)) >> L
+        else:
+            hit = (pid & (op.cmask >> L)) == op.cval >> L
+        if op.kind in ("cphase", "diag") and op.target >= L:
+            d = m[1, 1] if (pid >> (op.target - L)) & 1 or op.kind == "cphase" \
+                else m[0, 0]
+            kind, target, m = "diag", 0, np.diag([d, d])
+        else:
+            kind, target = op.kind, op.target
+        if not hit:
+            m = np.eye(2)
+        out.append(fu.FusedOp(kind, target, op.cmask & lbits,
+                              op.cval & lbits, m))
+    return out
+
+
+@pytest.mark.parametrize("bp", [8, 10])
+def test_the_per_page_kernel_runs_a_diag_run_in_the_new_order(bp):
+    """``sharded_kernel_window_body`` on four pages: every op of a run
+    is controlled by its local masks, page-level tests are in the
+    payload, and an op goes into the phase tile exactly when its local
+    mask has no bit above the tile."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    n, L, npg = 12, 10, 4
+    page = 1 << L
+    ops = [
+        fu.FusedOp("cphase", 5, 1 << 2, 1 << 2, _phase(0.37)),       # tile
+        fu.FusedOp("cphase", 11, 1 << 3, 1 << 3, _phase(0.71)),      # page target
+        fu.FusedOp("cphase", 4, (1 << 10) | (1 << 8) | 2,
+                   (1 << 10) | (1 << 8) | 2, _phase(-0.4)),
+        fu.FusedOp("diag", 11, 1 << 9, 1 << 9, _two_phases(0.2, -0.5)),
+        fu.FusedOp("diag", 6, (1 << 11) | 1, 1, _two_phases(0.5, -1.7)),
+        fu.FusedOp("cphase", 9, 1 << 10, 1 << 10, _phase(1.2)),      # above tile
+        fu.FusedOp("gen", 3, 1 << 10, 1 << 10, _DENSE_MATRICES["gen"]),
+        fu.FusedOp("diag", 2, 0, 0, _two_phases(-0.21, 0.53)),
+        fu.FusedOp("cphase", 10, 1 << 11, 1 << 11, _phase(0.9)),     # pages only
+    ]
+    structure = fu.sharded_structure_of(ops)
+    (kind, run), = fu._sharded_segments(structure, L)
+    assert kind == "run"
+    segment, = pk.plan_window(fu._sharded_run_structure(run, L), bp)
+    assert pk.diag_runs(segment["ops"]) == [(0, 6), (7, 9)]
+    expected = {8: (2, 8, 5), 10: (2, 8, 8)}[bp]
+    assert fu.diag_run_counts(ops, bp, split_at=L) == expected
+    body = fu.sharded_kernel_window_body(L, npg, structure, block_pow=bp,
+                                         interpret=True)
+    mesh = Mesh(np.array(jax.devices()[:npg]), ("pages",))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, "pages"), P(), P()),
+                       out_specs=P(None, "pages"), check_vma=False)
+    ket = random_ket(np.random.default_rng(bp), n)
+    got = _exact(fn, jnp.asarray(ket),
+                 *fu.pack_operands(ops, jnp.float32, split_at=L))
+    for pid in range(npg):
+        local = ket[:, pid * page:(pid + 1) * page]
+        want = segment_in_numpy(local, _page_ops(ops, L, pid), L, bp)
+        assert np.array_equal(got[:, pid * page:(pid + 1) * page], want), pid
+
+
+def _scratch_and_conds(fn, *args):
+    """``[(scratch operands, cond equations)]`` of every launch."""
+    import jax
+
+    def conds(jaxpr):
+        total = 0
+        for eqn in jaxpr.eqns:
+            total += eqn.primitive.name == "cond"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                total += conds(sub)
+        return total
+
+    return [(eqn.params["grid_mapping"].num_scratch_operands,
+             conds(eqn.params["jaxpr"])) for eqn in launches_of(fn, *args)]
+
+
+@pytest.mark.parametrize("family", ["tfim", "rcs"])
+def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
+                                                 monkeypatch):
+    """The bypass, held without a chip: a structure that holds no run
+    of diagonal ops lowers to the jaxpr it has with the run path taken
+    out, and that is the parent's form of the body: an unled launch with
+    no scratch and no ``pl.when``, a led one with its orbit scratch and
+    the two of its grid (read in, compute out)."""
+    import jax
+    import jax.numpy as jnp
+
+    structures = list(dict.fromkeys(
+        w["structure"] for w in benchmark_plans(family)
+        if w["path"] == "kernel"))
+    assert len(structures) == {"tfim": 7, "rcs": 12}[family]
+    planes = jax.ShapeDtypeStruct((2, 1 << 28), jnp.float32)
+    for structure in structures:
+        ops = [fu.FusedOp(kind, target, int(ctrl), int(ctrl),
+                          np.eye(4 if kind == "u4" else 2))
+               for kind, target, ctrl in structure]
+        args = (planes, *fu.pack_operands(ops, jnp.float32))
+        with monkeypatch.context() as patch:
+            patch.setattr(pk, "diag_runs", lambda ops: [])
+            without = str(jax.make_jaxpr(pk.make_window_fn(28, structure))(*args))
+        fn = pk.make_window_fn(28, structure)
+        assert str(jax.make_jaxpr(fn)(*args)) == without
+        segments = pk.plan_window(structure, fn.block_pow)
+        assert _scratch_and_conds(fn, *args) \
+            == [(1, 2) if seg["xgen"] else (0, 0) for seg in segments]
+
+
+def test_a_window_with_a_run_holds_its_scratch_in_vmem():
+    """One scratch of tiles: the value's and a phase tile a run; the
+    planes stay the launch's one result
+    (``test_every_launch_aliases_its_planes_to_its_result``).  A
+    ``pl.when`` for the table's start, and one for each stretch of a run
+    (consecutive ``cphase`` alike in having controls, or one ``diag``:
+    one traced body in a loop over its ops, onto the table at the first
+    step where the op has no high part, onto the value where it has one
+    and the tile admits it)."""
+    import jax
+    import jax.numpy as jnp
+
+    n, bp = 12, 10
+    named = diagonal_ops(n, bp)
+    gen = fu.FusedOp("gen", 3, 0, 0, _DENSE_MATRICES["gen"])
+    table_only = [named["cphase.tile.bare"], named["diag.tile.bare"]]
+    mixed = [named["cphase.tile"], named["cphase.mixed"]]
+    lead = fu.FusedOp("gen", 11, 0, 0, _DENSE_MATRICES["gen"])
+    for ops, expected in [
+            (table_only, [(1, 3)]),
+            (mixed, [(1, 2)]),
+            (mixed + [gen] + table_only, [(1, 5)]),
+            ([lead] + mixed, [(2, 4)]),              # + the orbits, the grid's two
+            ([named["cphase.mixed"], gen, named["cphase.tile"]], [(0, 0)])]:
+        fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
+                               interpret=True)
+        args = (jnp.zeros((2, 1 << n), jnp.float32),
+                *fu.pack_operands(ops, jnp.float32))
+        assert _scratch_and_conds(fn, *args) == expected
+        for eqn in launches_of(fn, *args):
+            assert tuple(eqn.params["input_output_aliases"]) == ((2, 0),)
+
+
+@pytest.mark.parametrize("stack,kw", [("tpu", {}), ("pager", {"n_pages": 4})],
+                         ids=["tpu", "pager"])
+def test_diag_run_counters_read_what_the_kernel_lowered(stack, kw, monkeypatch):
+    """``fuse.kernel.diag_runs`` / ``.diag_run.ops`` / ``.diag_run.tile_ops``
+    of a QFT through the engine's gate calls, beside ``fuse.kernel.ops``
+    and ``.sweeps``, which the run lowering leaves as they were; the ket
+    is the CPU engine's."""
+    n, bp = 12, 8
+    monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
+    monkeypatch.setattr(pk, "DEFAULT_BLOCK_POW", bp)
+    tele.enable()
+    q = create_quantum_interface(stack, n, rng=QrackRandom(5),
+                                 rand_global_phase=False, **kw)
+    o = QEngineCPU(n, rng=QrackRandom(5), rand_global_phase=False)
+    for e in (q, o):
+        e.SetPermutation(0b101101110011)
+        e.QFT(0, n)
+    assert _fidelity(q.GetQuantumState(), o.GetQuantumState()) > 1 - 1e-6
+    c = tele.snapshot(include_events=False)["counters"]
+    runs, in_runs, in_tile = (c["fuse.kernel.diag_runs"],
+                              c["fuse.kernel.diag_run.ops"],
+                              c["fuse.kernel.diag_run.tile_ops"])
+    assert 0 < runs and 2 * runs <= in_runs <= c["fuse.kernel.ops"]
+    assert 0 < in_tile < in_runs
+    if stack == "tpu":
+        # the replay of the same gate list, window by window
+        from helpers import benchmark_plans
+
+        with benchmark_plans(n) as windows:
+            want = (0, 0, 0)
+            for w in windows("qft"):
+                if w["path"] == "kernel":
+                    want = tuple(a + b for a, b in zip(
+                        want, fu.diag_run_counts(w["ops"], bp)))
+        assert (runs, in_runs, in_tile) == want
