@@ -113,6 +113,35 @@ _j_uc_2x2 = _jit("uc_2x2", gk.uc_2x2, static_argnums=(2, 3, 4), donate_argnums=(
 _j_copy = _jit("copy_planes", jnp.copy)
 
 
+def qrack_fill(planes, perm, phase, n, dtype):
+    """|perm> times `phase` as (2, 2^n) planes: one write of the ket.
+    `planes` is the ket to write over — donated, never read, the result
+    takes its buffer — or None, and then the one ket is allocated here.
+    `perm` and `phase` are runtime operands: a new basis state never
+    retraces.  The name is the compiled module's (``jit_qrack_fill``).
+    Zeros and an update in place: a select on an iota keeps a predicate
+    of the ket's length beside the ket (1 GiB at w30, by the compiler)."""
+    return jax.lax.dynamic_update_slice(
+        jnp.zeros((2, 1 << n), dtype), phase.astype(dtype)[:, None],
+        (jnp.zeros_like(perm), perm))
+
+
+# keyed by width and plane type (and by whether a ket is handed in);
+# keep_unused: the donated ket is a parameter the result can alias
+_j_fill = _jit("fill", qrack_fill, static_argnums=(3, 4),
+               donate_argnums=(0,), keep_unused=True)
+
+# A fresh ket is allocated behind a spacer of this many bytes, let go
+# at once.  An engine is as a rule the first thing a process puts on
+# the chip, and with the ket at the very start of HBM the cross-tile
+# sweeps run 1.3 % slower than behind a freed stretch, into which the
+# windows' small operand buffers then go: a Trotter step at w28 419.8 ms
+# with no spacer, 415.6 to 417.5 with one of 64 KiB to 1.5 MiB, 417.0 to
+# 417.4 with any of 2 MiB to 12 GiB (PERF.md §6, PR 43).  It has to be
+# the result of a program: a pad put from the host changed nothing.
+_KET_STAGGER_BYTES = 4 << 20
+
+
 # ---------------------------------------------------------------------------
 # plane pin registry (serve/prefix_cache.py): buffers whose identity is
 # registered here were handed out as SHARED refs (a cache entry plus any
@@ -230,10 +259,13 @@ class QEngineTPU(QEngine):
         # read-modify-writes never hit this: their RHS read flushed the
         # window first, and the flush's own write-back is re-entrant
         # (_flushing) so it passes straight through.
+        self._drop_overwritten()
+        self._state_raw = planes
+
+    def _drop_overwritten(self) -> None:
         f = self._fuser
         if f is not None and f.gates and not f._flushing:
             f.drop("overwritten")
-        self._state_raw = planes
 
     def _owned_state(self):
         """The resident planes as a DONATABLE buffer.  When the serving
@@ -384,17 +416,20 @@ class QEngineTPU(QEngine):
 
     def _fuse_flush(self, gates) -> int:
         """Lower the pending window into ONE parametric program dispatch
-        (guarded site tpu.fuse.flush).  A window that merged down to a
-        single op reuses the shared per-gate program families instead of
-        minting a one-op window program.  Three host spans split the
-        flush (docs/OBSERVABILITY.md): lower, operands, dispatch."""
+        (guarded site tpu.fuse.flush).  Where the kernel lowers windows
+        a window of one op is a kernel window too (one sweep in place:
+        the eager program of a lone gate may hold two kets beside the
+        donated one); elsewhere it reuses the shared per-gate program
+        families instead of minting a one-op chain program.  Three host
+        spans split the flush (docs/OBSERVABILITY.md): lower, operands,
+        dispatch."""
         from ..ops import fusion as fu
 
         n = self.qubit_count
-        plan = None
+        plan = prog = None
         with _tele.span("fuse.lower"):
             ops = fu.lower_gates(gates)
-            if len(ops) > 1:
+            if ops:
                 structure = fu.structure_of(ops)
                 plan, why = fu.kernel_lowering(n, structure)
                 if plan is not None:
@@ -402,25 +437,26 @@ class QEngineTPU(QEngine):
                         n, structure, self.dtype,
                         interpret=plan["interpret"],
                         block_pow=plan["block_pow"])
-                else:
+                elif len(ops) > 1:
                     fu.record_kernel_fallback(why)
                     prog = fu.dense_window_program(n, structure, self.dtype)
         if not ops:
             return 0
+        eager = prog is None  # a lone op where no kernel lowers windows
         with _tele.span("fuse.operands"):
-            if len(ops) > 1:
+            if eager:
+                prog, operands = self._one_op_program(ops[0])
+            else:
                 # two host columns, whatever the window holds: the
                 # dispatch puts them on the device with the program
                 operands = fu.pack_operands(ops, self.dtype)
-            else:
-                prog, operands = self._one_op_program(ops[0])
         with _tele.span("fuse.dispatch"):
             self._state = prog(self._owned_state(), *operands)
         if _tele._ENABLED:
             # a window issues one put per operand column and its program
             _tele.inc(f"fuse.{self._tele_name}.programs",
-                      len(operands) + 1 if len(ops) > 1 else 1)
-        if len(ops) == 1:
+                      1 if eager else len(operands) + 1)
+        if eager:
             return 1
         esize = jnp.dtype(self.dtype).itemsize
         if plan is not None:
@@ -664,13 +700,35 @@ class QEngineTPU(QEngine):
         )
 
     def SetPermutation(self, perm: int, phase=None) -> None:
+        """One program writes one ket (``jit_qrack_fill``): over the ket
+        the engine owns, donated and aliased to the result, or a fresh
+        one where it owns none (construction; planes the prefix cache
+        pinned as shared are let go, never donated).  The old ket is
+        never alive beside the new: a w30 ket is half the chip."""
         ph = self._rand_phase() if phase is None else complex(phase)
-        with _tele.span("engine.set_permutation"):  # fill, scatter, put
-            st = jnp.zeros((2, 1 << self.qubit_count), dtype=self.dtype)
-            st = st.at[:, perm].set(
-                jnp.asarray([ph.real, ph.imag], dtype=self.dtype))
-            self._state = self._put(st)
+        self._drop_overwritten()  # a blind overwrite, as the _state setter's
+        with _tele.span("engine.set_permutation"):
+            st, self._state_raw = self._state_raw, None
+            args = (np.int32(perm), np.asarray([ph.real, ph.imag],
+                                               dtype=self.dtype))
+            if st is None or planes_pinned(st) or st.is_deleted():
+                _tele.inc("engine.fill.fresh")
+                st = None  # let go before the new ket is allocated
+                self._state_raw = self._fresh_ket(args)
+            else:
+                _tele.inc("engine.fill.in_place")
+                self._state_raw = _j_fill(st, *args, self.qubit_count,
+                                          self.dtype)
         self.running_norm = 1.0
+
+    def _fresh_ket(self, args):
+        """The fill where the engine owns no ket: the one ket allocated,
+        behind a spacer that is let go when this returns."""
+        spacer = jnp.zeros((_KET_STAGGER_BYTES,), jnp.uint8,  # noqa: F841
+                           device=self._device)
+        if self._device is not None:  # the ket goes where its operands are
+            args = jax.device_put(args, self._device)
+        return _j_fill(None, *args, self.qubit_count, self.dtype)
 
     def Clone(self) -> "QEngineTPU":
         c = QEngineTPU(

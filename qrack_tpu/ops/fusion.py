@@ -417,13 +417,13 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
     * mode auto — TPU-class backends only (reason ``cpu_backend``
       elsewhere: the CPU XLA chain is measured compute-bound at these
       widths, so a single-sweep lowering cannot beat it and interpret
-      certainly cannot).  On TPU every window of two or more ops
-      takes the kernel, a window of bare cross-tile gen (as many
-      segments as ops) included: on the chip a chain op costs three
-      passes over the ket behind its barrier (31.9 ms at w28) where a
-      one-op kernel sweep costs 7.6-11.7 ms (PERF.md §6, PR 35).
-      Single-op windows fall back with ``single_op`` (the eager
-      per-gate programs already pay one sweep).
+      certainly cannot).  On TPU every window takes the kernel, a
+      window of bare cross-tile gen (as many segments as ops) and a
+      window of one op included: on the chip a chain op costs three
+      passes over the ket behind its barrier (31.9 ms at w28) and up
+      to two kets of temporaries, where a one-op kernel sweep costs
+      7.6-11.7 ms in place (PERF.md §6, PR 35, PR 43: a w30 ket has no
+      room for a second).
     """
     from . import pallas_kernels as pk
 
@@ -449,8 +449,6 @@ def _lowering(structure: Tuple, backend, bp: int, counts):
         return plan, None
     if backend != "tpu":
         return None, "cpu_backend"
-    if len(structure) <= 1:
-        return None, "single_op"
     return plan, None
 
 

@@ -75,9 +75,9 @@ def _args(operands, state_sharding, operand_sharding, n=W):
         for o in operands]
 
 
-def _dense_args(structure, sharding):
+def _dense_args(structure, sharding, n=W):
     return _args(fu.pack_operands(_ops(structure), jnp.float32),
-                 sharding, sharding)
+                 sharding, sharding, n=n)
 
 
 def _compile(fn, args):
@@ -100,10 +100,10 @@ def _launches(compiled):
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
-def _in_place(compiled):
+def _in_place(compiled, n=W):
     """No ket of temporaries and no ket-sized copy: every launch of the
     program writes the donated ket it reads."""
-    copies = re.findall(r"f32\[2,%d\]\S* copy(?:-start)?\(" % (1 << W),
+    copies = re.findall(r"f32\[2,%d\]\S* copy(?:-start)?\(" % (1 << n),
                         compiled.as_text())
     return (not copies
             and compiled.memory_analysis().temp_size_in_bytes <= SLACK)
@@ -478,6 +478,99 @@ def test_qft_run_window_kernel(one_chip, cell_windows, name):
     assert _launches(compiled) == len(expected)
     assert compiled.memory_analysis().temp_size_in_bytes == 0
     assert _in_place(compiled)
+
+
+# -- w30: the widest ket one chip holds (PR 43) --------------------------------
+# An 8 GiB ket fits a 16 GB chip only while no program of the path holds
+# a second: the fill, each of QFT(0, 30)'s 30 windows (29 of sixteen ops
+# and the last H alone, a kernel window of one since the rule
+# ``single_op`` went) and the read.  SLACK stays the w28 cases' 32 MiB.
+W30 = 30
+KET30_BYTES = 2 * 4 << W30
+QFT30_WINDOWS = 30
+
+
+@pytest.fixture(scope="module")
+def qft30_windows():
+    """The structures of QFT(0, 30)'s windows in the order the fuser
+    flushes them, from the benchmark's own gate list, no ket allocated."""
+    from helpers import benchmark_plans
+
+    with benchmark_plans(W30) as windows:
+        out = [w["structure"] for w in windows("qft")]
+    assert len(out) == QFT30_WINDOWS
+    assert [len(s) for s in out] == [16] * 29 + [1]
+    assert out[-1] == (("gen", 0, False),)
+    return out
+
+
+@pytest.mark.parametrize("owned", [True, False], ids=["in-place", "fresh"])
+def test_fill_writes_one_ket_w30(one_chip, owned):
+    """``SetPermutation``'s program as the engine jits it: with a ket
+    handed in the result takes its buffer and nothing of the ket's size
+    stands beside it (64 000 bytes of temporaries when written); with
+    none it allocates the one ket.  Zeros and a two-element update: a
+    select on an iota held a predicate of 1 GiB."""
+    from qrack_tpu.engines import tpu
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    planes = shape((2, 1 << W30), jnp.float32) if owned else None
+    t0 = time.perf_counter()
+    compiled = tpu._j_fill.lower(
+        planes, shape((), jnp.int32), shape((2,), jnp.float32), W30,
+        jnp.dtype("float32")).compile()
+    memory = compiled.memory_analysis()
+    print(f"compile_s={time.perf_counter() - t0:.2f} qrack_fill "
+          f"temp_bytes={memory.temp_size_in_bytes}")
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_qrack_fill")
+    assert memory.output_size_in_bytes == KET30_BYTES
+    assert memory.alias_size_in_bytes == (KET30_BYTES if owned else 0)
+    assert memory.temp_size_in_bytes <= SLACK
+    assert _in_place(compiled, W30)
+    if owned:
+        assert "input_output_alias={ {}: (0, {}, may-alias) }" in text
+
+
+@pytest.mark.parametrize("index", range(QFT30_WINDOWS),
+                         ids=[f"w{i + 1:02d}" for i in range(QFT30_WINDOWS)])
+def test_qft_w30_window_sweeps_its_ket_in_place(one_chip, qft30_windows, index):
+    """Every window of ``qft_w30.library``'s application, the lone last
+    ``H`` among them: its planned launches (2^14 tiles a plane, led
+    segments on targets 16 to 29), the result aliased to the donated
+    ket, no temporary and no copy of the ket's size."""
+    structure = qft30_windows[index]
+    plan, why = fu.kernel_lowering(W30, structure, backend="tpu")
+    assert why is None and not plan["interpret"]
+    compiled = _compile(pk.make_window_fn(W30, structure),
+                        _dense_args(structure, one_chip, n=W30))
+    assert _launches(compiled) == plan["sweeps"]
+    assert compiled.memory_analysis().alias_size_in_bytes == KET30_BYTES
+    assert _in_place(compiled, W30)
+
+
+def test_qft_w30_plans_43_sweeps(qft30_windows):
+    plans = [fu.kernel_lowering(W30, s, backend="tpu")[0]
+             for s in qft30_windows]
+    assert [p["sweeps"] for p in plans] == [5, 3, 3, 2, 3, 2, 2] + [1] * 23
+    assert sum(p["cross"] for p in plans) == 14
+
+
+def test_amplitude_read_w30_holds_no_ket(one_chip):
+    """``GetAmplitude``'s slice of the planes (an eager
+    ``dynamic_slice``: the index is an operand, a new ``perm`` compiles
+    nothing) reads two elements and holds nothing of the ket's size."""
+    def read(planes, perm):
+        return planes[:, perm]
+
+    args = [jax.ShapeDtypeStruct((2, 1 << W30), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)]
+    compiled = jax.jit(read).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= SLACK
+    assert memory.output_size_in_bytes < 4096
 
 
 def test_kernel_launches_carry_their_names(one_chip):

@@ -334,8 +334,20 @@ def test_tpu_takes_the_kernel_for_bare_cross_tile_windows(lowering, n, targets,
 @pytest.mark.parametrize("lower", [fu.kernel_lowering,
                                    fu.sharded_kernel_lowering],
                          ids=["dense", "paged"])
+def test_a_lone_op_is_a_kernel_window_on_the_tpu(lower):
+    """The rule ``single_op`` went with PR 43: the eager program of a
+    lone gate may hold two kets beside the donated one, a one-op sweep
+    holds none."""
+    plan, why = lower(24, _gen_structure((20,)), backend="tpu")
+    assert why is None and not plan["interpret"]
+    assert (plan["sweeps"], plan["cross"]) == (1, 1)
+
+
+@pytest.mark.parametrize("lower", [fu.kernel_lowering,
+                                   fu.sharded_kernel_lowering],
+                         ids=["dense", "paged"])
 @pytest.mark.parametrize("mode,backend,targets,reason", [
-    (None, "tpu", (20,), "single_op"),
+    (None, "cpu", (20,), "cpu_backend"),
     (None, "cpu", (20, 21), "cpu_backend"),
     ("off", "tpu", (20, 21), "mode_off"),
 ])
