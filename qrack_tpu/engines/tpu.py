@@ -463,7 +463,7 @@ class QEngineTPU(QEngine):
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
                                    width=n, esize=esize, cross=plan["cross"],
                                    dense=plan["dense"], twoq=plan["twoq"],
-                                   diag_runs=lambda: fu.diag_run_counts(
+                                   lowered=lambda: fu.count_kernel_window(
                                        ops, plan["block_pow"]))
         else:
             fu.record_xla_flush(self._tele_name, len(ops), width=n,

@@ -476,40 +476,56 @@ def kernel_window_program(n: int, structure: Tuple, dtype,
     return PROGRAMS.get_or_build(key, timed_build(build))
 
 
-def diag_run_counts(ops: Sequence[FusedOp], block_pow: int,
-                    split_at: int = None) -> Tuple[int, int, int]:
-    """``pallas_kernels.diag_run_counts`` of a flushed window, from the
-    masks the host packed: the dense layout's, or with ``split_at`` the
-    local halves each per-page kernel run reads (page-level tests are in
-    its payload, _sharded_run_operands).  Host work only."""
+# what the kernel lowered for a flushed window, by counter under
+# ``fuse.kernel.``: pallas_kernels.diag_run_counts, then .stretch_counts
+KERNEL_WINDOW_COUNTERS = ("diag_runs", "diag_run.ops", "diag_run.tile_ops",
+                          "stretches", "stretch.ops", "stretch.passes",
+                          "whole_tile_ops")
+
+
+def count_kernel_window(ops: Sequence[FusedOp], block_pow: int,
+                        split_at: int = None) -> dict:
+    """``{counter: count}`` over KERNEL_WINDOW_COUNTERS for a flushed
+    window: ``pallas_kernels.diag_run_counts`` (the runs of diagonal ops
+    the kernel applies through a phase tile) and ``.stretch_counts``
+    (the stretches of in-tile ops between runs that it applies chunk by
+    chunk, their ops and passes, and the ops it still applies on a whole
+    tile), from the structure and the masks the host packed: the dense
+    layout's, or with ``split_at`` those of each per-page kernel run
+    (local halves of the masks: page-level tests are in its payload,
+    _sharded_run_operands).  Host work only."""
     from . import pallas_kernels as pk
 
+    def counts(structure, masks):
+        return (pk.diag_run_counts(structure, masks, block_pow)
+                + pk.stretch_counts(structure, block_pow))
+
     if split_at is None:
-        return pk.diag_run_counts(structure_of(ops),
-                                  [(op.cmask, op.cval) for op in ops],
-                                  block_pow)
-    lbits = (1 << split_at) - 1
-    total = (0, 0, 0)
-    for kind, run in _sharded_segments(sharded_structure_of(ops), split_at):
-        if kind == "run":
-            masks = [(ops[idx].cmask & lbits, ops[idx].cval & lbits)
-                     for idx, _, _, _ in run]
-            total = tuple(map(sum, zip(total, pk.diag_run_counts(
-                _sharded_run_structure(run, split_at), masks, block_pow))))
-    return total
+        total = counts(structure_of(ops), [(op.cmask, op.cval) for op in ops])
+    else:
+        lbits = (1 << split_at) - 1
+        total = (0,) * len(KERNEL_WINDOW_COUNTERS)
+        for kind, run in _sharded_segments(sharded_structure_of(ops), split_at):
+            if kind == "run":
+                masks = [(ops[idx].cmask & lbits, ops[idx].cval & lbits)
+                         for idx, _, _, _ in run]
+                total = tuple(map(sum, zip(total, counts(
+                    _sharded_run_structure(run, split_at), masks))))
+    return dict(zip(KERNEL_WINDOW_COUNTERS, total))
 
 
 def record_kernel_flush(name: str, nops: int, sweeps: int,
                         width=None, esize: int = 4, cross: int = 0,
-                        dense: int = 0, twoq=None, diag_runs=None) -> None:
+                        dense: int = 0, twoq=None, lowered=None) -> None:
     """A window flushed through the Pallas kernel: count it, the HBM
     sweeps it actually paid (telemetry_report derives sweeps/window),
     how many of them were cross-tile pair segments and how many
     computed on the dense tile; with ``twoq`` (the plan's), its
     two-target ops and the sweeps that carried them, by placement; with
-    ``diag_runs`` (a call that gives :func:`diag_run_counts`, made only
-    while telemetry is on), the runs of diagonal ops the kernel applied
-    through a phase tile.
+    ``lowered`` (a call that gives :func:`count_kernel_window`, made
+    only while telemetry is on), the runs of diagonal ops the kernel
+    applied through a phase tile and the stretches it applied chunk by
+    chunk.
     Callers that supply the plane width also feed the sweep's planned
     bytes into the roofline ledger (`roofline.tpu.fuse.flush.*`)."""
     if _tele._ENABLED:
@@ -521,12 +537,10 @@ def record_kernel_flush(name: str, nops: int, sweeps: int,
         for key, count in (twoq or {}).items():
             if count:
                 _tele.inc(f"fuse.kernel.twoq.{key}", count)
-        if diag_runs is not None:
-            runs, in_runs, in_tile = diag_runs()
-            if runs:
-                _tele.inc("fuse.kernel.diag_runs", runs)
-                _tele.inc("fuse.kernel.diag_run.ops", in_runs)
-                _tele.inc("fuse.kernel.diag_run.tile_ops", in_tile)
+        if lowered is not None:
+            for key, count in lowered().items():
+                if count:
+                    _tele.inc(f"fuse.kernel.{key}", count)
         if width is not None:
             _roofline.note_bytes(
                 "tpu.fuse.flush",

@@ -22,7 +22,8 @@ Vocabulary (everything the fuser emits):
   multiplies by once, and an op with a high part is applied only on the
   tiles whose id admits it, in a pass over the value held in VMEM.
   Which of the two an op is follows from its runtime masks, so the
-  program key stays the structure.
+  program key stays the structure.  One that stands alone is an op of
+  a stretch (below) like any other.
 * inv / gen with target < block_pow — in-tile pair mix: each element
   reads its partner 2^target amplitudes away through two rotations
   (tile_partner); controls anywhere (runtime mask split).
@@ -81,6 +82,58 @@ a shard; Mosaic wants rows a multiple of 8) the body keeps the flat
 tile, the one-axis case of the same tile_* functions.  Telemetry counts
 the sweeps that computed dense as ``fuse.kernel.sweeps.dense``.
 
+Stretch, pass, chunk.  A segment's in-tile ops are its runs of
+diagonal ops and the STRETCHES between them (segment_pieces): a stretch
+is a maximal sequence of ops that are in no run.  A body written on the
+whole ``(2, 512, 128)`` tile is emitted operation by operation over the
+tile's 64 vreg pairs, the TPU's scheduler keeps that order, and the 128
+live vregs go through the one vector-store slot between any two
+operations (PERF.md section 6, PR 42).  So on a dense tile of more than
+64 rows a stretch is applied in PASSES (stretch_passes), of two kinds.
+
+An op that takes its partner by lane rotation (a non-diagonal op with a
+target on bits 0 to 6: rolls_lanes) stays on the whole tile's value,
+with the diagonal ops that stand behind it: a lane rotation goes
+through the XLU and comes back some hundred cycles later, which the
+tile's 64 vreg pairs hide and a chunk's eight cannot (a lane ``gen``
+costs 1.6 ms a sweep at w28 on the whole tile and 3.4 ms chunk by
+chunk: PERF.md section 6, PR 44).
+
+Every other op, whose partner is a sublane away or in another vreg, is
+applied chunk by chunk: the segment's value lives in a VMEM scratch
+tile (the one a run's passes work on, 512 KiB at ``block_pow`` 16), a
+pass is one rolled loop over the tile's CHUNKS (_for_tile_chunks), and
+in its body every op of the pass is applied to the chunk while registers
+hold it, one after the other, by the tile_* function it has on a whole
+tile, and the chunk is stored once.  A chunk is eight vregs a plane (64
+rows; four, 32 rows, in a pass with a u4, whose quad keeps four members
+live).  It holds the seven lane bits and the three sublane bits of the
+in-tile index and three (two) of the six bits above them, any three: the
+targets from bit 10 on of the pass's non-diagonal ops, whose partners
+are other vregs, which the chunk takes from wherever those bits put
+them; the loop's counter runs over the other bits.  A stretch whose ops
+ask for more such bits than a chunk holds is split, in order, into
+passes that do not; diagonal ops and controls read the index (``lidx``,
+the element's own in the tile whatever rows a chunk holds) and go with
+any pass.  The value goes to the scratch ahead of the first run or
+chunked pass and comes back to registers where an op wants the whole
+tile.
+
+On a dense tile a target's own bit is a matter of which lane, sublane
+or vreg (own_bit): a bit below the vreg is read once for all vregs, a
+bit from the vreg on picks vregs and costs nothing, and the partner
+across it is the other vregs (tile_partner).  Every amplitude's
+arithmetic and its order are the same on the whole tile and in a chunk:
+only the order in which amplitudes are visited differs.  The flat tile,
+and a dense one of at most 64 rows, is one chunk: its stretches are
+their ops on the tile's value.  What a loop costs to trace and lower is
+part of every set-up, and a line traced inside a loop's body costs
+several times what it costs outside one in the benchmark's process: an
+op's scalars are read ahead of its pass's loop (_slot_operands), and a
+diagonal op that is a segment's only op stays on the whole tile.
+Telemetry: ``fuse.kernel.stretches``, ``.stretch.ops``,
+``.stretch.passes``, ``fuse.kernel.whole_tile_ops`` (stretch_counts).
+
 Scalar operands ride in two packed SMEM refs (floats and int32 masks),
 a (K, 1) column each — TPU SMEM wants 2-D refs — packed on the host
 (fusion.pack_operands) at the offsets of _operand_slots.
@@ -93,6 +146,7 @@ grid step, so it is a CORRECTNESS harness, not a fast path
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import List, Optional, Tuple
 
 import jax
@@ -102,6 +156,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_POW = 16
 _LANE_POW = 7  # a vreg is 8 sublanes of 128 lanes
+_VREG_POW = _LANE_POW + 3  # the dense tile's index: lane, sublane, vreg
 
 # A segment holds one input block and one output block, each
 # double-buffered (2 MiB at block_pow 16), beside the body's
@@ -160,7 +215,7 @@ def dense_tile(block_pow: int) -> Optional[Tuple[int, int]]:
     None where it keeps the flat one: Mosaic takes the cast of the
     loaded ``(2, block)`` value when rows is a multiple of the eight
     sublanes."""
-    if block_pow < _LANE_POW + 3:
+    if block_pow < _VREG_POW:
         return None
     return (1 << (block_pow - _LANE_POW), 1 << _LANE_POW)
 
@@ -272,14 +327,57 @@ def tile_diag(v, lidx, hi_id, target, L,
 _TILE_DIAGONAL = {"cphase": tile_cphase, "diag": tile_diag}
 
 
-def tile_partner(v, lidx, target):
+def own_bit(lidx, target, at=None):
+    """``pick(one, zero)``: for every element of a tile ``one`` where
+    the ``target`` bit of its in-tile index ``lidx`` is set and ``zero``
+    where it is not; each a scalar or a value of the tile's shape.  It
+    is ``jnp.where`` on that bit, written so that the TPU's compiler
+    sees what the dense tile makes of it: ``at`` is the bit of the
+    value's own index that holds the target (tile_partner's), and a
+    value of ``(rows, lanes)`` is whole vregs of eight rows.  A bit
+    below the vreg reads the same in every vreg, so it is read from the
+    first one's index, repeated: the compare, and every select among
+    scalars on it, is then one vreg's work for the whole value and not
+    one a vreg (the compiler merges equal operations on equal
+    operands).  A bit from the vreg on is the same all through a vreg,
+    and which vregs have it set is known here: the pick is those vregs
+    of ``one`` and the others of ``zero``, no select at all."""
+    at = target if at is None else at
+    rows = lidx.shape[0] if lidx.ndim == 2 else 0
+    if rows > 8 and at >= _VREG_POW:
+        half = 1 << (at - _LANE_POW)  # rows that share the bit
+
+        def pick(one, zero):
+            # a scalar fills its vregs, a value gives its own
+            sides = [side if jnp.ndim(side) else
+                     jnp.full((half, lidx.shape[1]), side) for side in (zero, one)]
+            return jnp.concatenate([
+                side if side.shape[0] == half else
+                jax.lax.slice_in_dim(side, r, r + half)
+                for r in range(0, rows, half)
+                for side in [sides[(r // half) & 1]]])
+
+        return pick
+    if rows > 8:
+        lidx = jnp.concatenate([jax.lax.slice_in_dim(lidx, 0, 8)] * (rows // 8))
+    bit = (lidx & (1 << target)) != 0
+    return lambda one, zero: jnp.where(bit, one, zero)
+
+
+def tile_partner(v, lidx, target, at=None):
     """``v[:, i ^ (1 << target)]`` on one tile, as two rotations and a
     select on the target bit.  The tile is ``v.shape[1:]``: flat
     ``(block,)``, or ``(rows, lanes)`` with index ``row * lanes + lane``.
     A target below the lane power is a lane roll by ``2^target``; one
-    above it a sublane roll by ``2^(target - lane power)`` rows, which
-    from eight rows on moves whole vregs and costs nothing.  The flat
-    tile is the case of one axis: every in-tile target is a lane roll.
+    above it a sublane roll by ``2^(target - lane power)`` rows; from
+    eight rows on the partner is another vreg, and the value's vregs are
+    taken in the partners' order, which costs nothing.  The flat tile is
+    the case of one axis: every in-tile target is a lane roll.
+
+    ``v`` may be a chunk of the tile whose rows are not consecutive
+    there (_for_tile_chunks): ``at`` is then the bit of the chunk's own
+    index that holds the target, which the rotations go by, while the
+    select reads ``lidx``, the index in the tile, as every mask does.
 
     The flat tile stays flat: Mosaic refuses the (2, high, 2, low) view
     for low < 128 lanes, and XLA pads it 128/low-fold.
@@ -288,27 +386,32 @@ def tile_partner(v, lidx, target):
     instead (the interpreter; a chunk body outside any kernel) the
     caller puts an optimization barrier between ops, or XLA fuses a run
     of k ops by recomputing each input at every read, 3^k-fold."""
+    at = target if at is None else at
     lane_pow = (v.shape[-1] - 1).bit_length()
-    if target < lane_pow:
-        axis, dist = v.ndim - 1, 1 << target
+    if at < lane_pow:
+        axis, dist = v.ndim - 1, 1 << at
     else:
-        axis, dist = v.ndim - 2, 1 << (target - lane_pow)
+        axis, dist = v.ndim - 2, 1 << (at - lane_pow)
+    if v.ndim == 3 and dist >= 8 and axis == 1:
+        return jnp.concatenate(
+            [jax.lax.slice_in_dim(v, r ^ dist, (r ^ dist) + dist, axis=1)
+             for r in range(0, v.shape[1], dist)], axis=1)
     down = pltpu.roll(v, dist, axis)                   # v[i - 2^target]
     up = pltpu.roll(v, v.shape[axis] - dist, axis)     # v[i + 2^target]
-    return jnp.where((lidx & (1 << target)) != 0, down, up)
+    return own_bit(lidx, target, at)(down, up)
 
 
-def tile_local_2x2(v, lidx, hi_id, target, mp, lm, lv, gm, gv):
+def tile_local_2x2(v, lidx, hi_id, target, mp, lm, lv, gm, gv, at=None):
     """Generic 2x2 with the pair inside the tile (target < tile pow);
     mp indexes like mtrx_planes (2, 2, 2) [plane, row, col] but may be
-    a nested list of traced scalars."""
-    o = tile_partner(v, lidx, target)
-    bit = (lidx & (1 << target)) != 0
+    a nested list of traced scalars.  ``at``: tile_partner's."""
+    o = tile_partner(v, lidx, target, at)
+    pick = own_bit(lidx, target, at)
     # my own row of the matrix: (diagonal, off-diagonal) entry
-    dre = jnp.where(bit, mp[0][1][1], mp[0][0][0])
-    dim = jnp.where(bit, mp[1][1][1], mp[1][0][0])
-    ore = jnp.where(bit, mp[0][1][0], mp[0][0][1])
-    oim = jnp.where(bit, mp[1][1][0], mp[1][0][1])
+    dre = pick(mp[0][1][1], mp[0][0][0])
+    dim = pick(mp[1][1][1], mp[1][0][0])
+    ore = pick(mp[0][1][0], mp[0][0][1])
+    oim = pick(mp[1][1][0], mp[1][0][1])
     nv = jnp.stack([dre * v[0] - dim * v[1] + ore * o[0] - oim * o[1],
                     dre * v[1] + dim * v[0] + ore * o[1] + oim * o[0]])
     hi_ok = (hi_id & gm) == gv
@@ -320,15 +423,15 @@ def tile_quad_mix(members, b1, b2, mp):
     """Every amplitude's own row of a 4x4 over the four members of its
     quad.  ``members[x2][x1]`` is the value ``(2, *tile)`` across
     ``(x2, x1)`` from the amplitude (``[0][0]`` itself); ``b1`` / ``b2``
-    are its own low / high target bits, per element or one scalar for
-    the tile; ``mp[plane][row][col]`` are the matrix's scalars, row and
+    pick by its own low / high target bit (own_bit's ``pick(one,
+    zero)``; for a bit that is one scalar for the tile, ``jnp.where`` on
+    it); ``mp[plane][row][col]`` are the matrix's scalars, row and
     column ``(bit hi << 1) | bit lo``.  The coefficient of the member
     across ``x`` is ``m[r, r ^ x]`` with ``r`` the amplitude's own row
     (gatekernels.apply_4x4's arithmetic, in its order)."""
     def own(plane, x):
         at = [mp[plane][r][r ^ x] for r in range(4)]
-        return jnp.where(b2, jnp.where(b1, at[3], at[2]),
-                         jnp.where(b1, at[1], at[0]))
+        return b2(b1(at[3], at[2]), b1(at[1], at[0]))
 
     re = im = None
     for x2 in (0, 1):
@@ -342,22 +445,24 @@ def tile_quad_mix(members, b1, b2, mp):
     return jnp.stack([re, im])
 
 
-def tile_local_4x4(v, lidx, lo, hi, mp):
-    """The two-target op with both partners inside the tile."""
-    p1 = tile_partner(v, lidx, lo)
-    members = ((v, p1),
-               (tile_partner(v, lidx, hi), tile_partner(p1, lidx, hi)))
-    return tile_quad_mix(members, (lidx & (1 << lo)) != 0,
-                         (lidx & (1 << hi)) != 0, mp)
+def tile_local_4x4(v, lidx, lo, hi, mp, at=(None, None)):
+    """The two-target op with both partners inside the tile; ``at``:
+    tile_partner's, for ``lo`` and for ``hi``."""
+    p1 = tile_partner(v, lidx, lo, at[0])
+    members = ((v, p1), (tile_partner(v, lidx, hi, at[1]),
+                         tile_partner(p1, lidx, hi, at[1])))
+    return tile_quad_mix(members, own_bit(lidx, lo, at[0]),
+                         own_bit(lidx, hi, at[1]), mp)
 
 
 def tile_local_invert(v, lidx, hi_id, target,
-                      trre, trim, blre, blim, lm, lv, gm, gv):
-    """Anti-diagonal 2x2 (X/Y-like) with the pair inside the tile."""
-    o = tile_partner(v, lidx, target)
-    bit = (lidx & (1 << target)) != 0
-    fre = jnp.where(bit, blre, trre)
-    fim = jnp.where(bit, blim, trim)
+                      trre, trim, blre, blim, lm, lv, gm, gv, at=None):
+    """Anti-diagonal 2x2 (X/Y-like) with the pair inside the tile;
+    ``at``: tile_partner's."""
+    o = tile_partner(v, lidx, target, at)
+    pick = own_bit(lidx, target, at)
+    fre = pick(blre, trre)
+    fim = pick(blim, trim)
     nv = jnp.stack([fre * o[0] - fim * o[1], fre * o[1] + fim * o[0]])
     hi_ok = (hi_id & gm) == gv
     sel = ((lidx & lm) == lv) & hi_ok
@@ -420,20 +525,20 @@ def _slot_masks(slot, slots, iv_ref, at=0):
     return iv_ref[ioff, 0], iv_ref[ioff + 1, 0]
 
 
-def _diag_operands(stretch, at, slots, iv_ref, fv_ref, bp):
+def _diag_operands(group, at, slots, iv_ref, fv_ref, bp):
     """The arguments of tile_cphase / tile_diag after ``(v, lidx,
-    hi_id)`` for the ``at``-th slot of ``stretch``: consecutive cphase
+    hi_id)`` for the ``at``-th slot of ``group``: consecutive cphase
     slots alike in having controls, whose operands lie a fixed stride
     apart in both columns, so that ``at`` may be a loop's traced index
     (_apply_run), or one diag slot (tile_diag wants its target static).
     Masks are runtime scalars; the lo/hi split happens here (dense
     widths are int32-safe: engines/tpu.py MAX_DENSE_QB)."""
-    idx, kind, target, _ = stretch[0]
+    idx, kind, target, _ = group[0]
     foff = slots[idx][0] + at * _NFLOATS[kind]
     lbits = (1 << bp) - 1
-    cm, cv = _slot_masks(stretch[0], slots, iv_ref, at)
+    cm, cv = _slot_masks(group[0], slots, iv_ref, at)
     if kind == "cphase":
-        targets = [slot[2] for slot in stretch]
+        targets = [slot[2] for slot in group]
         tbit = (jnp.int32(1 << targets[at]) if isinstance(at, int)
                 else jnp.int32(1) << _static_pick(targets, at))
         comb = tbit | cm
@@ -444,31 +549,51 @@ def _diag_operands(stretch, at, slots, iv_ref, fv_ref, bp):
             cm & lbits, cv & lbits, cm >> bp, cv >> bp)
 
 
-def _apply_slot(v, lidx, blk, slot, slots, iv_ref, fv_ref, bp):
-    """Apply one in-tile window op to the loaded tile value."""
-    idx, kind, target, has_ctrl = slot
+def _slot_operands(slot, slots, iv_ref, fv_ref, bp):
+    """What one in-tile op's tile_* function takes after ``(v, lidx,
+    hi_id)``: its static target, its scalars read from the two columns
+    and its masks split at the tile's edge.  Read outside any loop: a
+    pass reads its ops' operands ahead of its loop over chunks (a read
+    of a scalar ref traced inside a loop's body costs seven times what
+    it costs outside one: PERF.md section 6, PR 44)."""
+    idx, kind, target, _ = slot
     foff = slots[idx][0]
     lbits = (1 << bp) - 1
     if kind in _TILE_DIAGONAL:
-        v, _ = _TILE_DIAGONAL[kind](
-            v, lidx, blk, *_diag_operands([slot], 0, slots, iv_ref, fv_ref, bp))
-        return v
+        return _diag_operands([slot], 0, slots, iv_ref, fv_ref, bp)
     if kind == "u4":
-        return tile_local_4x4(v, lidx, *target, _u4_scalars(fv_ref, foff))
+        return (*target, _u4_scalars(fv_ref, foff))
     cm, cv = _slot_masks(slot, slots, iv_ref)
+    masks = (cm & lbits, cv & lbits, cm >> bp, cv >> bp)
     if kind == "inv":
-        v, _ = tile_local_invert(v, lidx, blk, target,
-                                 fv_ref[foff, 0], fv_ref[foff + 1, 0],
-                                 fv_ref[foff + 2, 0], fv_ref[foff + 3, 0],
-                                 cm & lbits, cv & lbits, cm >> bp, cv >> bp)
-    else:
-        mp = [[[fv_ref[foff + 4 * plane + 2 * row + col, 0]
-                for col in range(2)]
-               for row in range(2)]
-              for plane in range(2)]
-        v, _ = tile_local_2x2(v, lidx, blk, target, mp,
-                              cm & lbits, cv & lbits, cm >> bp, cv >> bp)
-    return v
+        return (target, fv_ref[foff, 0], fv_ref[foff + 1, 0],
+                fv_ref[foff + 2, 0], fv_ref[foff + 3, 0], *masks)
+    mp = [[[fv_ref[foff + 4 * plane + 2 * row + col, 0]
+            for col in range(2)]
+           for row in range(2)]
+          for plane in range(2)]
+    return (target, mp, *masks)
+
+
+def _apply_slot(v, lidx, blk, slot, operands, held=()):
+    """One in-tile window op on ``v``, from its _slot_operands: the
+    tile's value, or a chunk of it that holds the bits ``held`` of the
+    tile's index above the vreg (_for_tile_chunks) and with them the
+    op's partners.  ``lidx`` is the in-tile index of every element of
+    ``v``: the masks and the target's own bit are read there, whatever
+    rows ``v`` holds."""
+    _, kind, target, _ = slot
+    if kind in _TILE_DIAGONAL:
+        return _TILE_DIAGONAL[kind](v, lidx, blk, *operands)[0]
+
+    def at(t):
+        """tile_partner's ``at``: where a chunk holds the bit ``t``."""
+        return _VREG_POW + held.index(t) if t in held else None
+
+    if kind == "u4":
+        return tile_local_4x4(v, lidx, *operands, at=tuple(map(at, target)))
+    apply = tile_local_invert if kind == "inv" else tile_local_2x2
+    return apply(v, lidx, blk, *operands, at=at(target))[0]
 
 
 def diag_runs(ops) -> List[Tuple[int, int]]:
@@ -476,7 +601,7 @@ def diag_runs(ops) -> List[Tuple[int, int]]:
     consecutive diagonal ops (cphase, diag) among a segment's in-tile
     slots.  Diagonal ops commute, so a run is one diagonal operator and
     the body applies it as one (_apply_run); a diagonal op that stands
-    alone keeps _apply_slot."""
+    alone is an op of a stretch (segment_pieces)."""
     runs = []
     start = None
     for at, slot in enumerate(list(ops) + [None]):
@@ -517,119 +642,310 @@ def diag_run_counts(structure: Tuple, masks, bp: int) -> Tuple[int, int, int]:
     return runs, ops, tile_ops
 
 
-# rows of the dense tile a step of a run's passes takes: eight vreg pairs
+# rows of the dense tile a step of a pass takes: eight vreg pairs of the
+# value (a run's passes, and a stretch's with nothing but 2x2 ops), and
+# four where the pass holds a u4, which keeps its quad's four members
+# live (on 64 rows they alone are the 64 vregs the core has)
 _RUN_ROWS = 64
+_U4_ROWS = 32
 
 
-def _for_tile_chunks(tile: Tuple[int, ...], body) -> None:
-    """``body(rows, lidx)`` for every chunk of a tile, in a loop: ``rows``
-    slices the first axis of a ref of the tile's shape and ``lidx`` is
-    the chunk's in-tile index.  A run's passes go chunk by chunk because
-    the TPU's scheduler keeps the order it is given: a pass written on
-    the whole tile is emitted operation by operation over its 64 vreg
-    pairs, and the 128 live vregs go through the one vector-store slot
-    between any two operations (635 bundles for one cphase whose
-    arithmetic fills 176: PERF.md section 6, PR 42).  The loop is
-    rolled: unrolled it schedules tighter (188 bundles a cphase against
-    284), and a window program takes seconds to trace and lower.  A
-    flat tile is one chunk."""
+def chunked(tile: Tuple[int, ...]) -> bool:
+    """Does a pass over this tile take it in more than one chunk?  A
+    flat tile and a dense one of at most _RUN_ROWS rows are one chunk:
+    their ops outside a run are applied on the tile's value, one after
+    the other."""
+    return len(tile) == 2 and tile[0] > _RUN_ROWS
+
+
+def _partner_vregs(slot) -> Tuple[int, ...]:
+    """The targets of a slot whose partner is another vreg of the dense
+    tile (bits from _VREG_POW on): the bits a chunk has to hold for the
+    op to find its partners in it.  A diagonal op has no partner."""
+    _, kind, target, _ = slot
+    if kind in _TILE_DIAGONAL:
+        return ()
+    return tuple(t for t in (target if kind == "u4" else (target,))
+                 if t >= _VREG_POW)
+
+
+def rolls_lanes(slot) -> bool:
+    """Does the op take a partner by lane rotation (a non-diagonal op
+    with a target below the lane power)?  A lane rotation goes through
+    the XLU and comes back some hundred cycles later: a chunk's few vreg
+    pairs cannot hide that, the tile's 64 can, so such an op is applied
+    on the whole tile's value (3.4 ms a lane ``gen`` a sweep at w28 in a
+    pass of 64-row chunks, the same in chunks of 32; 1.6 ms on the whole
+    tile: PERF.md section 6, PR 44)."""
+    _, kind, target, _ = slot
+    return kind not in _TILE_DIAGONAL and any(
+        t < _LANE_POW for t in (target if kind == "u4" else (target,)))
+
+
+def stretch_passes(stretch, tile: Tuple[int, ...]) -> List[Tuple[list, tuple]]:
+    """``[(slots, held), ...]``: a stretch of in-tile slots split, in
+    their order, into the passes that apply it on a chunked dense tile.
+
+    ``held`` None: the slots are applied on the whole tile's value, one
+    after the other.  Those are the ops that roll lanes (rolls_lanes),
+    with the diagonal ops that stand behind one of them (or ahead of the
+    stretch's first), and a diagonal op that is a segment's only op.
+
+    ``held`` a tuple: the slots are one loop over chunks; every op of
+    the pass is applied to a chunk while registers hold it, so the chunk
+    has to hold every op's partners.  A chunk of ``2^c`` vregs a plane
+    holds the seven lane bits, the three sublane bits and ``c`` bits of
+    the tile's index above them, any ``c``: its vregs are taken where
+    those bits say (_for_tile_chunks).  ``held`` are those bits,
+    ascending: the targets from _VREG_POW on of the pass's non-diagonal
+    ops, filled up with the lowest bits left.  The split is greedy: a
+    pass ends ahead of the op that would ask for more bits than its
+    chunk has (three on _RUN_ROWS rows; two on the _U4_ROWS a pass with
+    a u4 takes).  Diagonal ops, and any op's controls, read the index
+    and go with any pass."""
+    top = (tile[0] * tile[1] - 1).bit_length()
+
+    def room(slots):
+        rows = _U4_ROWS if any(s[1] == "u4" for s in slots) else _RUN_ROWS
+        return (rows >> 3).bit_length() - 1
+
+    def a_pass(slots, want):
+        spare = [t for t in range(_VREG_POW, top) if t not in want]
+        return slots, tuple(sorted(
+            want | set(spare[:room(slots) - len(want)])))
+
+    # whole or in chunks: a diagonal op goes as the op ahead of it does,
+    # the stretch's first ones as the first op that is not diagonal; one
+    # that is all the stretch (its segment's only op, or it would be in a
+    # run) stays whole: in chunks it gains a tenth of a millisecond a
+    # sweep and costs a loop to trace and lower
+    whole = [rolls_lanes(s) if s[1] not in _TILE_DIAGONAL else None
+             for s in stretch]
+    known = [w for w in whole if w is not None]
+    last = known[0] if known else True
+    for at, w in enumerate(whole):
+        whole[at] = last = last if w is None else w
+
+    passes = []
+    for wide, group in itertools.groupby(zip(whole, stretch), lambda p: p[0]):
+        group = [slot for _, slot in group]
+        if wide:
+            passes.append((group, None))
+            continue
+        cur, want = [], set()
+        for slot in group:
+            more = want | set(_partner_vregs(slot))
+            if cur and len(more) > room(cur + [slot]):
+                passes.append(a_pass(cur, want))
+                cur, more = [], set(_partner_vregs(slot))
+            cur, want = cur + [slot], more
+        passes.append(a_pass(cur, want))
+    return passes
+
+
+def segment_pieces(ops, tile: Tuple[int, ...]) -> List[Tuple[str, list, list]]:
+    """A segment's in-tile slots as the body applies them, in order:
+    ``("run", slots, [])`` for a run of diagonal ops (diag_runs) and
+    ``("stretch", slots, passes)`` for a STRETCH, a maximal sequence of
+    slots between runs, with its stretch_passes where the tile is
+    chunked and one pass on the whole tile where it is one chunk."""
+    pieces, at = [], 0
+    for start, stop in diag_runs(ops) + [(len(ops), len(ops))]:
+        if start > at:
+            stretch = list(ops[at:start])
+            pieces.append(("stretch", stretch, stretch_passes(stretch, tile)
+                           if chunked(tile) else [(stretch, None)]))
+        if stop > start:
+            pieces.append(("run", list(ops[start:stop]), []))
+        at = stop
+    return pieces
+
+
+def stretch_counts(structure: Tuple, bp: int) -> Tuple[int, int, int, int]:
+    """``(stretches, ops, passes, whole_tile_ops)``: the stretches the
+    kernel applies chunk by chunk for this window (in part, at least),
+    the ops it applies so, the loops over chunks it lowers for them
+    (stretch_passes), and the in-tile ops outside runs that it applies
+    on the whole tile's value: those that roll lanes, and all of them
+    where the tile is one chunk (the flat tile; a dense one of at most
+    _RUN_ROWS rows).  The telemetry counters ``fuse.kernel.stretches``,
+    ``.stretch.ops``, ``.stretch.passes`` and
+    ``fuse.kernel.whole_tile_ops``."""
+    tile = dense_tile(bp) or (1 << bp,)
+    stretches = ops = passes = whole = 0
+    for seg in plan_window(structure, bp):
+        for kind, slots, split in segment_pieces(seg["ops"], tile):
+            if kind != "stretch":
+                continue
+            chunks = [in_pass for in_pass, held in split if held is not None]
+            stretches += bool(chunks)
+            passes += len(chunks)
+            ops += sum(map(len, chunks))
+            whole += len(slots) - sum(map(len, chunks))
+    return stretches, ops, passes, whole
+
+
+def _for_tile_chunks(tile: Tuple[int, ...], body, held=None) -> None:
+    """``body(pieces, lidx)`` for every chunk of a tile, in a loop.  A
+    CHUNK is the part of the dense tile a pass holds in registers at a
+    time: ``pieces`` are the slices of a tile-shaped ref's first axis
+    that make it up, in the chunk's own order (_chunk_of, _chunk_to),
+    and ``lidx`` is the in-tile index of its every element.  With no
+    ``held`` a chunk is _RUN_ROWS consecutive rows, one piece.  With
+    ``held``, the bits of the tile's index above the vreg that the
+    chunk is to hold (stretch_passes; ascending), the chunk's vreg
+    ``j`` is the tile's vreg whose index has bit ``k`` of ``j`` at
+    ``held[k]`` and the loop's counter spread over the other bits
+    (orbit_tile): where ``held`` are the lowest bits its rows are
+    consecutive, else it is pieces of whole vregs, and a target
+    ``held[k]`` sits on bit ``_VREG_POW + k`` of the chunk's own index
+    (tile_partner's ``at``).
+
+    Passes go chunk by chunk because the TPU's scheduler keeps the
+    order it is given: a pass written on the whole tile is emitted
+    operation by operation over its 64 vreg pairs, and the 128 live
+    vregs go through the one vector-store slot between any two
+    operations (635 bundles for one cphase whose arithmetic fills 176:
+    PERF.md section 6, PR 42).  The loop is rolled: unrolled it
+    schedules tighter (188 bundles a cphase against 284), and a window
+    program takes seconds to trace and lower.  A flat tile is one
+    chunk."""
     if len(tile) == 1:
-        body(slice(None), _tile_index(tile))
+        body([slice(None)], _tile_index(tile))
         return
-    rows = min(tile[0], _RUN_ROWS)
+    if held is None:
+        rows = min(tile[0], _RUN_ROWS)
+        held = tuple(range(_VREG_POW, _VREG_POW + (rows >> 3).bit_length() - 1))
+    vregs = tuple(t - _VREG_POW for t in held)
+    rows = 8 << len(vregs)
+    # the lowest bits are one piece of consecutive rows; every bit above
+    # them doubles the pieces
+    joined = sum(v == k for k, v in enumerate(vregs))
+    size = 8 << joined
+    offsets = [8 * orbit_tile(vregs[joined:], 0, j)
+               for j in range(1 << (len(vregs) - joined))]
     lidx = _tile_index((rows, tile[1]))
+    if len(offsets) > 1:
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, tile[1]), 0)
+        lidx = lidx & (size * tile[1] - 1)
+        for k, v in enumerate(vregs[joined:], joined):
+            lidx = lidx | (((row >> (3 + k)) & 1) << (_VREG_POW + v))
 
     def step(at, carry):
-        start = pl.multiple_of(at * rows, rows)
-        body(pl.ds(start, rows), lidx + start * tile[1])
+        first = at * rows if len(offsets) == 1 else 8 * orbit_tile(vregs, at, 0)
+        start = pl.multiple_of(first, size)
+        body([pl.ds(start + off if off else start, size) for off in offsets],
+             lidx + start * tile[1])
         return carry
 
-    jax.lax.fori_loop(0, tile[0] // rows, step, 0)
+    if tile[0] == rows:
+        body([pl.ds(off, size) for off in offsets], lidx)
+    else:
+        jax.lax.fori_loop(0, tile[0] // rows, step, 0)
 
 
-def _run_stretches(run) -> List[list]:
+def _chunk_of(ref, at, pieces):
+    """The chunk ``pieces`` of the tile ``ref[at]``, ``(2, rows, lanes)``
+    (a flat tile's whole ``(2, block)``)."""
+    parts = [ref[(at, slice(None), rows)] for rows in pieces]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _chunk_to(ref, at, pieces, v) -> None:
+    """Store ``v`` as the chunk ``pieces`` of the tile ``ref[at]``."""
+    if len(pieces) == 1:
+        ref[(at, slice(None), pieces[0])] = v
+        return
+    size = v.shape[1] // len(pieces)
+    for j, rows in enumerate(pieces):
+        ref[(at, slice(None), rows)] = jax.lax.slice_in_dim(
+            v, j * size, (j + 1) * size, axis=1)
+
+
+def _run_groups(run) -> List[list]:
     """A run's slots as _diag_operands takes them: consecutive cphase
     slots alike in having controls together, a diag alone."""
-    stretches = []
+    groups = []
     for slot in run:
-        last = stretches[-1][-1] if stretches else None
+        last = groups[-1][-1] if groups else None
         if last and slot[1] == last[1] == "cphase" and slot[3] == last[3]:
-            stretches[-1].append(slot)
+            groups[-1].append(slot)
         else:
-            stretches.append([slot])
-    return stretches
+            groups.append([slot])
+    return groups
 
 
-def _apply_run(v, blk, run, slots, iv_ref, fv_ref, bp, first, run_ref,
-               table):
-    """A run of diagonal ops on the tile value ``v``, as one operator.
+def _apply_run(v, blk, run, slots, iv_ref, fv_ref, bp, first, run_ref, table):
+    """A run of diagonal ops on the tile value held in ``run_ref[0]``,
+    in place, as one operator; ``v`` is the value where the scratch does
+    not hold it yet (the segment begins with the run), else None.
 
     The run's factor at amplitude ``(blk, lidx)`` is ``R(blk, lidx) *
     T(lidx)``.  ``T`` is the product of the ops that read no bit above
     the tile: the same for every tile, so it is built once a launch, at
     ``first`` (the launch's first computing step), in ``run_ref[table]``
     from a tile of ``1 + 0i``.  ``R`` is the rest: each op with a high
-    part, by the code it has alone, in a pass over the value held in
-    ``run_ref[0]``, and only on a tile whose id admits it; on any other
-    tile its factor is exactly ``1 + 0i`` and the step branches past it.
+    part, by the code it has alone, in a pass over the value, and only
+    on a tile whose id admits it; on any other tile its factor is
+    exactly ``1 + 0i`` and the step branches past it.
     An op is one or the other by its runtime masks, never both, so it is
     traced once, under one ``pl.when``: onto the table at ``first``, or
-    onto the value where the tile admits it.  The step ends with the
+    onto the value where the tile admits it.  The run ends with the
     one multiply of the value by the table, complete by then on the
-    first step too.  The ops of a stretch (_run_stretches) are one
+    first step too.  The ops of a group (_run_groups) are one
     traced body in a loop over their operands' offsets: what a window
     program costs to trace and lower is part of every set-up (PERF.md
     section 6, PR 42)."""
-    tile = v.shape[1:]
+    tile, dtype = run_ref.shape[2:], run_ref.dtype
 
     def a_pass(at, kind, args):
         """The op on ``run_ref[at]``, in place."""
-        def chunk(rows, lidx):
-            at_rows = (at, slice(None), rows)
-            run_ref[at_rows] = _TILE_DIAGONAL[kind](run_ref[at_rows], lidx,
-                                                    blk, *args)[0]
+        def chunk(pieces, lidx):
+            _chunk_to(run_ref, at, pieces, _TILE_DIAGONAL[kind](
+                _chunk_of(run_ref, at, pieces), lidx, blk, *args)[0])
         _for_tile_chunks(tile, chunk)
 
-    def for_ops(stretch):
-        """Each op of the stretch onto the table or onto the value."""
-        kind = stretch[0][1]
+    def for_ops(group):
+        """Each op of the group onto the table or onto the value."""
+        kind = group[0][1]
 
         def op(at, carry=0):
-            args = _diag_operands(stretch, at, slots, iv_ref, fv_ref, bp)
+            args = _diag_operands(group, at, slots, iv_ref, fv_ref, bp)
             if kind == "cphase":
                 high, admits = args[1], (blk & args[1]) == args[1]   # chi
             else:
-                high = jnp.int32(stretch[0][2] >= bp) | args[-2] | args[-1]
+                high = jnp.int32(group[0][2] >= bp) | args[-2] | args[-1]
                 admits = (blk & args[-2]) == args[-1]                # gm, gv
             # on the table an op has no high part: any tile id admits it
             pl.when(jnp.where(high == 0, first, admits))(functools.partial(
                 a_pass, jnp.where(high == 0, table, 0), kind, args))
             return carry
 
-        if len(stretch) == 1:
+        if len(group) == 1:
             op(0)
         else:
-            jax.lax.fori_loop(0, len(stretch), op, 0)
+            jax.lax.fori_loop(0, len(group), op, 0)
 
     @pl.when(first)
     def _():
-        run_ref[table] = jnp.stack([jnp.ones(tile, v.dtype),
-                                    jnp.zeros(tile, v.dtype)])
+        run_ref[table] = jnp.stack([jnp.ones(tile, dtype),
+                                    jnp.zeros(tile, dtype)])
 
-    # + 0.0: a cast that feeds a store alone is made by strided stores
-    # (led_kernel); it turns a -0.0 into 0.0 and nothing else
-    run_ref[0] = v + 0.0
-    for stretch in _run_stretches(run):
-        for_ops(stretch)
+    if v is not None:
+        # + 0.0: a cast that feeds a store alone is made by strided stores
+        # (led_kernel); it turns a -0.0 into 0.0 and nothing else
+        run_ref[0] = v + 0.0
+    for group in _run_groups(run):
+        for_ops(group)
 
-    def by_table(rows, _):
+    def by_table(pieces, _):
+        rows, = pieces
         t_re, t_im = run_ref[table, 0, rows], run_ref[table, 1, rows]
         re, im = run_ref[0, 0, rows], run_ref[0, 1, rows]
         run_ref[0, 0, rows] = re * t_re - im * t_im
         run_ref[0, 1, rows] = re * t_im + im * t_re
 
     _for_tile_chunks(tile, by_table)
-    return run_ref[0]
 
 
 def _u4_scalars(fv_ref, foff):
@@ -672,34 +988,73 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
     def load(ref):
         return ref[...].reshape((2,) + tile)
 
-    # the segment's in-tile ops, a run of diagonal ones as one piece, and
-    # the runs' VMEM scratch after whatever the kernel has of its own:
-    # tiles of the body's shape, the first for the value and one phase
-    # tile a run (_apply_run).  A segment without a run has none, and
-    # its body is its ops one after the other
-    ops = seg["ops"]
-    runs = dict(diag_runs(ops))
-    run_scratch = [(1 + len(runs), 2) + tile] if runs else []
+    # the segment's in-tile ops as the body applies them (segment_pieces:
+    # runs of diagonal ops, and the stretches between them in their
+    # passes), and their VMEM scratch after whatever the kernel has of
+    # its own: tiles of the body's shape, the first for the value and
+    # one phase tile a run (_apply_run).  A segment with no run and no
+    # pass has none, and its body is its ops one after the other on the
+    # tile's value
+    pieces = segment_pieces(seg["ops"], tile)
+    runs = sum(kind == "run" for kind, _, _ in pieces)
+    run_scratch = ([(1 + runs, 2) + tile]
+                   if any(kind == "run" or any(held is not None
+                                               for _, held in passes)
+                          for kind, _, passes in pieces) else [])
 
     def in_tile_ops(v, blk, iv_ref, fv_ref, first, run_refs):
         """The segment's in-tile ops on a loaded (or mixed) tile value,
-        back in the refs' ``(2, block)`` shape.  ``first()``: is this
-        the launch's first computing step (asked where a run builds its
-        table); ``run_refs``: the refs of ``run_scratch``."""
+        back in the refs' ``(2, block)`` shape.  A run, and a stretch in
+        passes, work in place on the value held in VMEM, in the
+        scratch's first tile: it is stored there ahead of the first of
+        them and loaded behind the last.  A PASS is one rolled loop
+        over the tile's chunks (_for_tile_chunks) in whose body every op
+        of the pass is applied to the chunk in registers, one after the
+        other, by the code it has on a whole tile (_apply_slot), and
+        the chunk is stored once: the ops of a pass cost one load and
+        one store of the tile together.  Where the tile is one chunk a
+        stretch is its ops on the tile's value (the flat tile's body).
+        ``first()``: is this the launch's first computing step (asked
+        where a run builds its table); ``run_refs``: the refs of
+        ``run_scratch``."""
         lidx = _tile_index(tile)
-        at = table = 0
-        while at < len(ops):
-            if at in runs:
+        held = False  # is the value in the scratch, or in ``v``
+        table = 0
+
+        def operands(slot):
+            return _slot_operands(slot, slots, iv_ref, fv_ref, bp)
+
+        def on_value(v, lidx, applied, bits=()):
+            for slot, args in applied:
+                v = _apply_slot(v, lidx, blk, slot, args, bits)
+                if interpret:  # XLA lowers the body: see tile_partner
+                    v = jax.lax.optimization_barrier(v)
+            return v
+
+        for kind, in_piece, passes in pieces:
+            if kind == "run":
                 table += 1
-                v = _apply_run(v, blk, ops[at:runs[at]], slots, iv_ref,
-                               fv_ref, bp, first(), *run_refs, table)
-                at = runs[at]
-            else:
-                v = _apply_slot(v, lidx, blk, ops[at], slots, iv_ref, fv_ref,
-                                bp)
-                at += 1
-            if interpret:  # XLA lowers the body: see tile_partner
-                v = jax.lax.optimization_barrier(v)
+                _apply_run(None if held else v, blk, in_piece, slots, iv_ref,
+                           fv_ref, bp, first(), *run_refs, table)
+                held = True
+            for in_pass, bits in passes:
+                if bits is None:  # on the whole tile's value
+                    if held:
+                        v, held = run_refs[0][0], False
+                    v = on_value(v, lidx, ((slot, operands(slot))
+                                           for slot in in_pass))
+                    continue
+                if not held:
+                    run_refs[0][0] = v + 0.0  # + 0.0: see _apply_run
+                    held = True
+                applied = [(slot, operands(slot)) for slot in in_pass]
+
+                def chunk(rows, lidx, applied=applied, bits=bits):
+                    _chunk_to(run_refs[0], 0, rows, on_value(
+                        _chunk_of(run_refs[0], 0, rows), lidx, applied, bits))
+                _for_tile_chunks(tile, chunk, bits)
+        if held:
+            v = run_refs[0][0]
         return v.reshape(2, block)
 
     def launch(kernel, lead_bits=()):
@@ -805,10 +1160,13 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
             if lo < bp:
                 lidx = _tile_index(tile)
                 members = tuple((v, tile_partner(v, lidx, lo)) for v in seen)
-                b1, b2 = (lidx & (1 << lo)) != 0, member != 0
+                b1, b2 = own_bit(lidx, lo), member != 0
             else:
                 members = (seen[:2], seen[2:])
                 b1, b2 = (member & 1) != 0, (member & 2) != 0
+            # a bit that is one scalar for the tile picks by jnp.where
+            b1, b2 = (b if callable(b) else functools.partial(jnp.where, b)
+                      for b in (b1, b2))
             return tile_quad_mix(members, b1, b2, _u4_scalars(fv_ref, foff_x))
 
         return led_kernel(mix, lead_bits)

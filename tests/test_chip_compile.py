@@ -480,6 +480,76 @@ def test_qft_run_window_kernel(one_chip, cell_windows, name):
     assert _in_place(compiled)
 
 
+# the cells' windows whose launches hold a stretch of in-tile ops (PR 44;
+# the indices are cell_windows'): the random circuit's sixteen gen on
+# qubits 0-15 (seven on the whole tile, nine in two passes) and its
+# window of 15 u4 and one gen (six u4 in the tile, two of them on lane
+# bits; six leading four tiles, the last with a gen and three u4 behind
+# it, all on lane bits: no pass, no scratch but the orbits), the Trotter
+# step's first window (inv and diag alternating on qubits 1-6: every op
+# rolls lanes or stands behind one that does) and its inv-led launch with
+# 15 gen riding.  Each launch: (scratch operands, passes of its stretch)
+STRETCH_WINDOWS = {
+    "rcs-16gen": ("rcs", 0, [(1, 2)]),
+    "rcs-15u4+gen": ("rcs", 5, [(1, 3)] + [(1, 0)] * 6),
+    "tfim-16op": ("tfim", 0, [(0, 0)]),
+    "tfim-inv-led-15gen": ("tfim", 5, [(2, 2)]),
+}
+
+
+def _stretch_launches(fn, args, structure, expected):
+    """Each launch's scratch is float32 tiles that, with its blocks (one
+    in, one out, each double-buffered), stay under a quarter of the VMEM
+    the launch asks for; a stretch is one loop a pass."""
+    from test_pallas_window import _scratch_conds_loops, launches_of
+
+    bp = fn.block_pow
+    block = 2 * 4 << bp
+    found = []
+    for eqn, seg, (_, _, loops) in zip(
+            launches_of(fn, *args), pk.plan_window(structure, bp),
+            _scratch_conds_loops(fn, *args)):
+        count = eqn.params["grid_mapping"].num_scratch_operands
+        scratch = [v.aval for v in eqn.params["jaxpr"].invars[-count:]] \
+            if count else []
+        assert all(a.dtype == jnp.float32 for a in scratch)
+        vmem = 4 * block + sum(4 * int(np.prod(a.shape)) for a in scratch)
+        assert vmem <= pk._VMEM_LIMIT_BYTES // 4
+        pieces = pk.segment_pieces(seg["ops"], pk.dense_tile(bp))
+        passes = sum(held is not None for _, _, split in pieces
+                     for _, held in split)
+        if not pk.diag_runs(seg["ops"]):
+            assert loops == passes
+        found.append((count, passes))
+    assert found == expected
+
+
+@pytest.mark.parametrize("name", sorted(STRETCH_WINDOWS))
+def test_stretch_window_kernel(one_chip, cell_windows, name):
+    """The value a stretch's passes work on is a VMEM scratch tile of
+    the launch (512 KiB), beside a led launch's two orbits; the program
+    holds nothing of the ket's size beside the donated ket, its result
+    takes the ket's buffer, and the compiler takes a fraction of a
+    second (the backend alone, this sandbox, parent / change:
+    rcs-15u4+gen 2.06 / 0.51 s, rcs-16gen 1.70 / 0.21, tfim-16op 1.28 /
+    0.20, tfim-inv-led-15gen 1.96 / 0.32, the w30 QFT window below 0.26
+    / 0.15; the three families' 45 programs 32.6 / 13.6 s: a pass is a
+    loop, its body a chunk's code and not a tile's)."""
+    family, index, expected = STRETCH_WINDOWS[name]
+    structure = cell_windows[family][index]
+    fn = pk.make_window_fn(W, structure)
+    args = _dense_args(structure, one_chip)
+    _stretch_launches(fn, args, structure, expected)
+    t0 = time.perf_counter()
+    compiled = _compile(fn, args)
+    assert time.perf_counter() - t0 < 60
+    assert _launches(compiled) == len(expected)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes == KET_BYTES
+    assert _in_place(compiled)
+
+
 # -- w30: the widest ket one chip holds (PR 43) --------------------------------
 # An 8 GiB ket fits a 16 GB chip only while no program of the path holds
 # a second: the fill, each of QFT(0, 30)'s 30 windows (29 of sixteen ops
@@ -548,6 +618,26 @@ def test_qft_w30_window_sweeps_its_ket_in_place(one_chip, qft30_windows, index):
                         _dense_args(structure, one_chip, n=W30))
     assert _launches(compiled) == plan["sweeps"]
     assert compiled.memory_analysis().alias_size_in_bytes == KET30_BYTES
+    assert _in_place(compiled, W30)
+
+
+def test_qft_w30_stretch_window_kernel(one_chip, qft30_windows):
+    """A window of ``qft_w30.library`` with a ``gen`` between two runs
+    of ``cphase`` (its seventh: eight ``cphase``, the ``gen`` on qubit
+    14, seven more): the stretch of one op is one pass on the tile the
+    runs hold the value in, one scratch of three tiles."""
+    structure = qft30_windows[8]
+    kinds = [kind for kind, _, _ in structure]
+    assert sorted(set(kinds)) == ["cphase", "gen"] and kinds.count("gen") == 1
+    assert all(t < pk.DEFAULT_BLOCK_POW for k, t, _ in structure if k == "gen")
+    fn = pk.make_window_fn(W30, structure)
+    args = _dense_args(structure, one_chip, n=W30)
+    _stretch_launches(fn, args, structure, [(1, 1)])
+    assert pk.stretch_counts(structure, fn.block_pow) == (1, 1, 1, 0)
+    compiled = _compile(fn, args)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes == KET30_BYTES
     assert _in_place(compiled, W30)
 
 

@@ -547,11 +547,23 @@ def benchmark_plans():
         yield windows
 
 
-@pytest.mark.parametrize("family,sweeps,carry_ops,diag_runs", [
-    ("qft", 37, 37, (47, 373, 119)), ("tfim", 41, 17, (0, 0, 0)),
-    ("rcs", 102, 20, (0, 0, 0))])
+def lowered_counts(ops, bp, split_at=None):
+    """``fusion.count_kernel_window`` as ``(runs' three, stretches'
+    four)``: what ``fuse.kernel.diag_runs`` / ``.diag_run.ops`` /
+    ``.diag_run.tile_ops`` and ``fuse.kernel.stretches`` /
+    ``.stretch.ops`` / ``.stretch.passes`` / ``.whole_tile_ops`` read."""
+    counts = fu.count_kernel_window(ops, bp, split_at=split_at)
+    assert tuple(counts) == fu.KERNEL_WINDOW_COUNTERS
+    values = tuple(counts.values())
+    return values[:3], values[3:]
+
+
+@pytest.mark.parametrize("family,sweeps,carry_ops,diag_runs,stretches", [
+    ("qft", 37, 37, (47, 373, 119), (9, 10, 9, 11)),
+    ("tfim", 41, 17, (0, 0, 0), (4, 36, 6, 37)),
+    ("rcs", 102, 20, (0, 0, 0), (13, 68, 33, 58))])
 def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
-                                     carry_ops, diag_runs):
+                                     carry_ops, diag_runs, stretches):
     """What ``fuse.kernel.sweeps.dense`` reads in a traced run of each
     cell: every planned kernel segment of an application at w28.  Of
     TFIM's 36 cross-tile segments 12 carry an in-tile op behind the mix
@@ -563,10 +575,21 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
     ``.diag_run.tile_ops`` read there (PR 42): QFT's 378 ``cphase`` sit
     in 47 runs of two or more but for five, and 119 of those in runs
     have both bits in the tile; no segment of a Trotter step or of a
-    random circuit holds two diagonal ops in a row, so their bodies are
-    the ops one after the other, as before."""
+    random circuit holds two diagonal ops in a row.
+
+    And ``fuse.kernel.stretches`` / ``.stretch.ops`` / ``.stretch.passes``
+    / ``.whole_tile_ops`` (PR 44): every in-tile op outside a run is in
+    a stretch: the random circuit's 60 ``u4`` and 66 ``gen`` in 20
+    launches, the Trotter step's 73 ops in 17 (11 of them a lone
+    ``diag`` behind a led ``inv``), QFT's 16 ``gen`` and 5 lone
+    ``cphase`` in 19.  The ops that take a partner by lane rotation (a
+    target on qubits 0 to 6) are applied on the whole tile with the
+    diagonal ops behind them, and so is a diagonal op that is its
+    segment's only op: 58 / 37 / 11 of them; the others chunk by chunk,
+    68 / 36 / 10, a pass where a chunk holds every op's partners (all
+    of QFT's) and more where it does not."""
     dense = with_ops = 0
-    runs = (0, 0, 0)
+    runs, found = (0, 0, 0), (0, 0, 0, 0)
     for w in benchmark_plans(family):
         if w["path"] != "kernel":
             continue
@@ -575,12 +598,17 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
         assert plan["dense"] == plan["sweeps"] == len(segments)
         dense += plan["dense"]
         with_ops += sum(bool(seg["ops"]) for seg in segments)
-        counts = fu.diag_run_counts(w["ops"], plan["block_pow"])
-        assert counts[0] == sum(len(pk.diag_runs(seg["ops"]))
-                                for seg in segments)
-        runs = tuple(a + b for a, b in zip(runs, counts))
+        in_runs, in_stretches = lowered_counts(w["ops"], plan["block_pow"])
+        assert in_runs[0] == sum(len(pk.diag_runs(seg["ops"]))
+                                 for seg in segments)
+        # every in-tile op is in a run or in a stretch
+        assert in_runs[1] + in_stretches[1] + in_stretches[3] \
+            == sum(len(seg["ops"]) for seg in segments)
+        runs = tuple(a + b for a, b in zip(runs, in_runs))
+        found = tuple(a + b for a, b in zip(found, in_stretches))
     assert (dense, with_ops) == (sweeps, carry_ops)
     assert runs == diag_runs
+    assert found == stretches
 
 
 @pytest.mark.parametrize("kwargs", [{"remap": "off"}, {}],
@@ -588,7 +616,10 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
 def test_paged_cells_hold_no_diag_run(kwargs):
     """The per-page kernel runs of the paged Trotter step at w30, on the
     fixed placement and on the pager's own through its settled steps:
-    no run of two diagonal ops in any segment."""
+    no run of two diagonal ops in any segment, and every in-tile op in
+    a stretch: 36 of a step's 75 applied chunk by chunk in 6 passes,
+    the 25 on qubits 0 to 6 and the 14 lone ``diag`` on the whole
+    tile."""
     from helpers import issue, plan_only_pager, trotter_step_gates
 
     q = plan_only_pager(30, **kwargs)
@@ -597,11 +628,15 @@ def test_paged_cells_hold_no_diag_run(kwargs):
         issue(q, trotter_step_gates(30))
         q.GetAmplitude(0)
         assert len(q.windows) == 8
+        stretches = (0, 0, 0, 0)
         for w in q.windows:
             plan, _ = fu.sharded_kernel_lowering(q.local_bits, w.structure,
                                                  backend="tpu")
-            assert fu.diag_run_counts(w.tops, plan["block_pow"],
-                                      split_at=q.local_bits) == (0, 0, 0)
+            in_runs, in_stretches = lowered_counts(
+                w.tops, plan["block_pow"], split_at=q.local_bits)
+            assert in_runs == (0, 0, 0)
+            stretches = tuple(a + b for a, b in zip(stretches, in_stretches))
+        assert stretches == (4, 36, 6, 39)
 
 
 # ---------------------------------------------------------------------------
@@ -1026,9 +1061,16 @@ def segment_in_numpy(ket, ops, n, bp):
             ket = run_in_numpy(ket, ops[at:runs[at]], n, bp)
             at = runs[at]
         else:
-            ket = unled_in_numpy(ket, ops[at], n)
+            ket = op_in_numpy(ket, ops[at], n)
             at += 1
     return ket
+
+
+def op_in_numpy(ket, op, n):
+    """One in-tile op in the kernel's order: a ``u4`` as its own row
+    over its quad, itself first (``lead_in_numpy``: the same sum
+    wherever its targets sit), any other by ``unled_in_numpy``."""
+    return (lead_in_numpy if op.kind == "u4" else unled_in_numpy)(ket, op, n)
 
 
 def _phase(angle):
@@ -1143,7 +1185,7 @@ def test_a_stretch_of_cphase_is_one_loop_over_its_operands(n, bp):
     named = diagonal_ops(n, bp)
     ops += [named["cphase.high.bare"], named["diag.mixed.anti"]]
     segment, = pk.plan_window(fu.structure_of(ops), bp)
-    assert [len(s) for s in pk._run_stretches(segment["ops"])] == [11, 1, 1]
+    assert [len(s) for s in pk._run_groups(segment["ops"])] == [11, 1, 1]
     ket = random_ket(np.random.default_rng(n), n)
     got = run_window(n, bp, ops, ket, donate=False)
     want = run_in_numpy(ket, ops, n, bp)
@@ -1209,7 +1251,7 @@ def _page_ops(ops, L, pid):
     (``fusion._sharded_run_structure`` / ``_sharded_run_operands``):
     local masks, a test on page bits folded into the payload (the
     identity where this page misses it), an op on a page bit a ``diag``
-    on bit 0 whose two factors are equal."""
+    on bit 0 whose two factors are equal, an ``inv`` a ``gen``."""
     lbits = (1 << L) - 1
     out = []
     for op in ops:
@@ -1224,7 +1266,8 @@ def _page_ops(ops, L, pid):
                 else m[0, 0]
             kind, target, m = "diag", 0, np.diag([d, d])
         else:
-            kind, target = op.kind, op.target
+            # the sharded layout holds an inv as the gen it is
+            kind, target = {"inv": "gen"}.get(op.kind, op.kind), op.target
         if not hit:
             m = np.eye(2)
         out.append(fu.FusedOp(kind, target, op.cmask & lbits,
@@ -1262,7 +1305,7 @@ def test_the_per_page_kernel_runs_a_diag_run_in_the_new_order(bp):
     segment, = pk.plan_window(fu._sharded_run_structure(run, L), bp)
     assert pk.diag_runs(segment["ops"]) == [(0, 6), (7, 9)]
     expected = {8: (2, 8, 5), 10: (2, 8, 8)}[bp]
-    assert fu.diag_run_counts(ops, bp, split_at=L) == expected
+    assert lowered_counts(ops, bp, split_at=L)[0] == expected
     body = fu.sharded_kernel_window_body(L, npg, structure, block_pow=bp,
                                          interpret=True)
     mesh = Mesh(np.array(jax.devices()[:npg]), ("pages",))
@@ -1278,30 +1321,279 @@ def test_the_per_page_kernel_runs_a_diag_run_in_the_new_order(bp):
         assert np.array_equal(got[:, pid * page:(pid + 1) * page], want), pid
 
 
-def _scratch_and_conds(fn, *args):
-    """``[(scratch operands, cond equations)]`` of every launch."""
+# ---------------------------------------------------------------------------
+# every in-tile op walks the tile chunk by chunk (PR 44).  The ops of a
+# segment that are in no run are a stretch; on a tile of more than 64
+# rows the stretch's value lives in a VMEM scratch tile and a pass over
+# it is one rolled loop over chunks in whose body the pass's ops are
+# applied one after the other.  A chunk holds the lane bits, the sublane
+# bits and two or three bits from the vreg on, those its ops' partners
+# sit across; a stretch that needs more is split into passes.  Every
+# amplitude's arithmetic and its order are those of the whole-tile body:
+# bit for bit numpy's float32, one op after the other.
+# ---------------------------------------------------------------------------
+
+# (width, block_pow): 128 rows, two chunks of 64 and four of 32; the
+# cells' own 512 rows
+CHUNK_SHAPES = [(16, 14), (18, 16)]
+
+
+def _stretches(n, bp):
+    """``name -> ops`` of one unled segment with no run in it."""
+    rng = np.random.default_rng(n + bp)
+    top, low = n - 1, 1 << bp
+
+    def gen(target, cmask=0, cval=0):
+        return fu.FusedOp("gen", target, cmask, cval, _su(rng, 2))
+
+    def u4(lo, hi):
+        return fu.FusedOp("u4", (lo, hi), 0, 0, _su(rng, 4))
+
+    def inv(target, cmask, cval):
+        return fu.FusedOp("inv", target, cmask, cval, _DENSE_MATRICES["inv"])
+
+    named = diagonal_ops(n, bp)
+    out = {
+        # lane targets first (on the whole tile), then sublane and vreg
+        # targets in no order; a control in the tile, one above it, an
+        # anti-control on a vreg bit
+        "16gen": [gen(t) for t in (0, 3)] + [gen(6, low, low)]
+        + [gen(t) for t in (1, 5, 2, 4, 13, 7, 10, 9)]
+        + [gen(12, 1 << 2, 1 << 2), gen(11, 1 << 12, 0)]
+        + [gen(t) for t in (8, 13, 12)],
+        # the same kinds of target taking turns: the value goes between
+        # the scratch and the whole tile at every turn
+        "turns": [gen(t) for t in (0, 13, 7, 3, 10, 9)]
+        + [gen(12, 1 << 2, 1 << 2), gen(6, low, low), gen(11, 1 << 12, 0)]
+        + [gen(t) for t in (1, 8, 5, 13, 2, 4, 12)],
+        # lo on a lane bit and hi on a vreg bit (whole tile); both on
+        # vreg bits; lo on a sublane bit; a diagonal op alone between
+        "u4": [u4(3, 12), named["diag.high"], u4(10, 11), u4(8, 13), gen(5),
+               u4(0, 1)],
+        # controlled inv, the control above the tile, on a vreg bit, on
+        # a lane bit beside an anti-control
+        "cinv": [inv(11, 1 << top, 1 << top), inv(4, 1 << 12, 1 << 12),
+                 named["cphase.mixed"], inv(13, (1 << 3) | low, 1 << 3),
+                 inv(9, low << 1, 0)],
+        # more partners' bits from the vreg on than a chunk holds, out of
+        # order: the passes' chunks are pieces of whole vregs
+        "split": [gen(t) for t in range(bp - 1, 9, -2)]
+        + [named["diag.tile.anti"]] + [gen(t) for t in range(10, bp, 2)]
+        + [u4(10, bp - 1), named["cphase.high"], u4(11, 12), gen(13)],
+    }
+    for ops in out.values():
+        segment, = pk.plan_window(fu.structure_of(ops), bp)
+        assert segment["xgen"] is None and not pk.diag_runs(segment["ops"])
+    return out
+
+
+def _stretch_cases():
+    return [pytest.param(n, bp, name, id=f"w{n}-bp{bp}-{name}")
+            for n, bp in CHUNK_SHAPES
+            for name in (("16gen", "turns", "u4", "cinv", "split")
+                         if bp < 16 else ("16gen", "split"))]
+
+
+@DONATE
+@pytest.mark.parametrize("n,bp,name", _stretch_cases())
+def test_a_stretch_is_numpy_bit_for_bit(n, bp, name, donate):
+    ops = _stretches(n, bp)[name]
+    structure = fu.structure_of(ops)
+    stretches, in_chunks, passes, whole = pk.stretch_counts(structure, bp)
+    assert (stretches, in_chunks + whole) == (1, len(ops))
+    # (ops on the whole tile, passes)
+    assert (whole, passes) == {"16gen": (7, 2), "turns": (7, 6), "u4": (4, 2),
+                               "cinv": (2, 2), "split": (0, 5)}[name]
+    ket = random_ket(np.random.default_rng(n * 7 + len(name)), n)
+    got = run_window(n, bp, ops, ket, donate)
+    want = ket
+    for op in ops:
+        want = op_in_numpy(want, op, n)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+    assert got.dtype == np.float32
+
+
+def test_a_pass_holds_its_ops_partners():
+    """``stretch_passes``: a pass's chunk holds three bits from the vreg
+    on (two where a ``u4`` is in the pass), its non-diagonal ops'
+    targets there first and the lowest bits left after them; a pass ends
+    ahead of the op that would ask for one more, and ahead of an op that
+    rolls lanes, which is applied on the whole tile (``held`` None)."""
+    tile = pk.dense_tile(16)
+    slots = [(i, kind, target, False) for i, (kind, target) in enumerate([
+        ("gen", 8), ("gen", 15), ("diag", 14), ("gen", 10), ("inv", 15),
+        ("gen", 12),                     # 15, 10, 12: full
+        ("gen", 13), ("cphase", 11),     # a new pass: 13 so far
+        ("u4", (9, 14)),                 # with a u4 a pass holds two: 13, 14
+        ("u4", (14, 15)), ("gen", 7),    # 15 would be a third
+        ("u4", (11, 12)),
+        ("gen", 2), ("diag", 3), ("u4", (5, 12)),   # these roll lanes
+        ("gen", 9)])]
+    passes = pk.stretch_passes(slots, tile)
+    assert [(len(ops), held) for ops, held in passes] \
+        == [(6, (10, 12, 15)), (3, (13, 14)), (2, (14, 15)), (1, (11, 12)),
+            (3, None), (1, (10, 11, 12))]
+    assert [slot for ops, _ in passes for slot in ops] == slots
+    assert [pk.rolls_lanes(slot) for slot in slots[12:]] \
+        == [True, False, True, False]
+    # a diagonal op goes as the op ahead of it, the first as the next
+    assert pk.stretch_passes(slots[13:15], tile) == [(slots[13:15], None)]
+    assert pk.stretch_passes(slots[13:14], tile) == [(slots[13:14], None)]
+    assert pk.stretch_passes(slots[6:8], tile) \
+        == [(slots[6:8], (10, 11, 13))]
+    # a pass that asks for fewer bits than its chunk holds takes the
+    # lowest ones left
+    assert pk.stretch_passes(slots[6:7], tile) == [(slots[6:7], (10, 11, 13))]
+    assert not pk.chunked(pk.dense_tile(13)) and not pk.chunked((512,))
+    assert pk.segment_pieces(slots, pk.dense_tile(13)) \
+        == [("stretch", slots, [(slots, None)])]
+
+
+def _stretch_leads():
+    rng = np.random.default_rng(44)
+    return [
+        pytest.param(16, 14, fu.FusedOp("gen", 15, 0, 0, _su(rng, 2)),
+                     id="gen"),
+        pytest.param(16, 14, fu.FusedOp("inv", 14, (1 << 15) | 2, (1 << 15) | 2,
+                                        _DENSE_MATRICES["inv"]), id="cinv"),
+        pytest.param(16, 14, fu.FusedOp("u4", (4, 15), 0, 0, _su(rng, 4)),
+                     id="u4-pair"),
+        pytest.param(16, 14, fu.FusedOp("u4", (11, 14), 0, 0, _su(rng, 4)),
+                     id="u4-pair-vreg"),
+        pytest.param(16, 14, fu.FusedOp("u4", (14, 15), 0, 0, _su(rng, 4)),
+                     id="u4-quad"),
+    ]
+
+
+@DONATE
+@pytest.mark.parametrize("n,bp,lead", _stretch_leads())
+def test_a_stretch_behind_a_lead_is_numpy_bit_for_bit(n, bp, lead, donate):
+    """The mix of a led step goes into the scratch as it is, and the
+    tile id the stretch's masks read is the orbit's."""
+    gen, cphase, diag, u4, inv = riders(n, bp)
+    behind = [gen, cphase, u4, diag, inv] + _stretches(n, bp)["split"][:5]
+    segment, = pk.plan_window(fu.structure_of([lead] + behind), bp)
+    assert segment["xgen"][0] == 0 and not pk.diag_runs(segment["ops"])
+    ket = random_ket(np.random.default_rng(n + bp), n)
+    got = run_window(n, bp, [lead] + behind, ket, donate)
+    want = lead_in_numpy(ket, lead, n)
+    for op in behind:
+        want = op_in_numpy(want, op, n)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+
+@DONATE
+def test_a_stretch_between_two_runs_shares_the_values_tile(donate):
+    """Run, stretch, run, stretch in one unled segment on a tile of two
+    chunks: the value is stored once, ahead of the first run, and loaded
+    once, behind the last stretch."""
+    n, bp = 16, 14
+    named = diagonal_ops(n, bp)
+    stretch = _stretches(n, bp)
+    ops = [named[k] for k in ("cphase.tile", "diag.high", "cphase.mixed")] \
+        + stretch["16gen"][7:14] \
+        + [named[k] for k in ("diag.tile.anti", "cphase.tile.2c")] \
+        + stretch["u4"][:3]
+    segment, = pk.plan_window(fu.structure_of(ops), bp)
+    assert pk.diag_runs(segment["ops"]) == [(0, 3), (10, 12)]
+    assert [(kind, len(slots), len(passes)) for kind, slots, passes in
+            pk.segment_pieces(segment["ops"], pk.dense_tile(bp))] \
+        == [("run", 3, 0), ("stretch", 7, 2), ("run", 2, 0), ("stretch", 3, 2)]
+    # the last stretch begins with a u4 that rolls lanes and a diagonal
+    # op behind it: on the whole tile, then one pass
+    assert pk.stretch_counts(fu.structure_of(ops), bp) == (2, 8, 3, 2)
+    ket = random_ket(np.random.default_rng(4), n)
+    got = run_window(n, bp, ops, ket, donate)
+    want = segment_in_numpy(ket, ops, n, bp)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+
+@DONATE
+def test_the_per_page_kernel_walks_a_stretch_chunk_by_chunk(donate):
+    """``sharded_kernel_window_body`` on four pages of four tiles of two
+    chunks: the stretch's masks are each page's local halves, the
+    page-level tests are in the payloads."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    n, L, npg, bp = 18, 16, 4, 14
+    page = 1 << L
+    rng = np.random.default_rng(18)
+    ops = [
+        fu.FusedOp("gen", 12, 1 << 17, 1 << 17, _su(rng, 2)),    # page control
+        fu.FusedOp("gen", 3, 1 << 13, 0, _su(rng, 2)),
+        fu.FusedOp("inv", 10, (1 << 16) | (1 << 15), 1 << 15,    # page anti-control
+                   _DENSE_MATRICES["inv"]),
+        fu.FusedOp("diag", 17, 1 << 11, 1 << 11, _two_phases(0.2, -0.5)),
+        fu.FusedOp("gen", 6, 1 << 14, 1 << 14, _su(rng, 2)),     # above the tile
+        fu.FusedOp("gen", 11, 0, 0, _su(rng, 2)),
+        fu.FusedOp("gen", 13, 0, 0, _su(rng, 2)),
+        fu.FusedOp("cphase", 2, 1 << 16, 1 << 16, _phase(0.9)),
+        fu.FusedOp("inv", 9, 0, 0, _DENSE_MATRICES["inv"]),
+    ]
+    structure = fu.sharded_structure_of(ops)
+    (kind, run), = fu._sharded_segments(structure, L)
+    assert kind == "run"
+    segment, = pk.plan_window(fu._sharded_run_structure(run, L), bp)
+    assert not pk.diag_runs(segment["ops"])
+    assert lowered_counts(ops, bp, split_at=L) == ((0, 0, 0), (1, 7, 3, 2))
+    body = fu.sharded_kernel_window_body(L, npg, structure, block_pow=bp,
+                                         interpret=True)
+    mesh = Mesh(np.array(jax.devices()[:npg]), ("pages",))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, "pages"), P(), P()),
+                       out_specs=P(None, "pages"), check_vma=False)
+    ket = random_ket(np.random.default_rng(bp), n)
+    got = _exact(fn, jnp.array(ket, copy=True),
+                 *fu.pack_operands(ops, jnp.float32, split_at=L),
+                 donate=donate)
+    for pid in range(npg):
+        local = ket[:, pid * page:(pid + 1) * page]
+        want = segment_in_numpy(local, _page_ops(ops, L, pid), L, bp)
+        assert np.array_equal(got[:, pid * page:(pid + 1) * page], want), pid
+
+
+def _scratch_conds_loops(fn, *args):
+    """``[(scratch operands, cond equations, loops)]`` of every launch;
+    a rolled loop is a ``scan`` (``fori_loop`` over a static range)."""
     import jax
 
-    def conds(jaxpr):
+    def count(jaxpr, name):
         total = 0
         for eqn in jaxpr.eqns:
-            total += eqn.primitive.name == "cond"
+            total += eqn.primitive.name == name
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                total += conds(sub)
+                total += count(sub, name)
         return total
 
     return [(eqn.params["grid_mapping"].num_scratch_operands,
-             conds(eqn.params["jaxpr"])) for eqn in launches_of(fn, *args)]
+             count(eqn.params["jaxpr"], "cond"),
+             count(eqn.params["jaxpr"], "scan"))
+            for eqn in launches_of(fn, *args)]
+
+
+def _placeholder_ops(structure):
+    return [fu.FusedOp(kind, target, int(ctrl), int(ctrl),
+                       np.eye(4 if kind == "u4" else 2))
+            for kind, target, ctrl in structure]
 
 
 @pytest.mark.parametrize("family", ["tfim", "rcs"])
 def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
                                                  monkeypatch):
-    """The bypass, held without a chip: a structure that holds no run
-    of diagonal ops lowers to the jaxpr it has with the run path taken
-    out, and that is the parent's form of the body: an unled launch with
-    no scratch and no ``pl.when``, a led one with its orbit scratch and
-    the two of its grid (read in, compute out)."""
+    """The bypass since PR 44, held without a chip: a segment with no op
+    behind its lead lowers to the launch it has with the chunk path
+    taken out, and that is the parent's: its orbit scratch, the two
+    ``pl.when`` of its grid (read in, compute out) and no loop.  So
+    does a segment whose ops all roll lanes (the Trotter step's first
+    window) or whose one op is diagonal (the step's eleven ``diag``
+    behind a led ``inv``): they stay on the whole tile's value.  Every
+    other segment
+    of the two families holds its value in one more scratch tile and
+    applies the ops that roll no lane in one rolled loop a pass
+    (``stretch_passes``); no segment here holds a run, so those loops
+    are all its loops."""
     import jax
     import jax.numpy as jnp
 
@@ -1310,19 +1602,129 @@ def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
         if w["path"] == "kernel"))
     assert len(structures) == {"tfim": 7, "rcs": 12}[family]
     planes = jax.ShapeDtypeStruct((2, 1 << 28), jnp.float32)
+    bare = whole = chunked = 0
     for structure in structures:
-        ops = [fu.FusedOp(kind, target, int(ctrl), int(ctrl),
-                          np.eye(4 if kind == "u4" else 2))
-               for kind, target, ctrl in structure]
-        args = (planes, *fu.pack_operands(ops, jnp.float32))
+        args = (planes, *fu.pack_operands(_placeholder_ops(structure),
+                                          jnp.float32))
         with monkeypatch.context() as patch:
-            patch.setattr(pk, "diag_runs", lambda ops: [])
-            without = str(jax.make_jaxpr(pk.make_window_fn(28, structure))(*args))
+            patch.setattr(pk, "chunked", lambda tile: False)
+            unchunked = [str(eqn.params["jaxpr"]) for eqn in launches_of(
+                pk.make_window_fn(28, structure), *args)]
         fn = pk.make_window_fn(28, structure)
-        assert str(jax.make_jaxpr(fn)(*args)) == without
+        tile = pk.dense_tile(fn.block_pow)
+        launches = launches_of(fn, *args)
+        shapes = _scratch_conds_loops(fn, *args)
         segments = pk.plan_window(structure, fn.block_pow)
-        assert _scratch_and_conds(fn, *args) \
-            == [(1, 2) if seg["xgen"] else (0, 0) for seg in segments]
+        assert len(segments) == len(launches) == len(unchunked)
+        for seg, eqn, was, shape in zip(segments, launches, unchunked, shapes):
+            led = seg["xgen"] is not None
+            pieces = pk.segment_pieces(seg["ops"], tile)
+            loops = sum(held is not None
+                        for _, _, passes in pieces for _, held in passes)
+            assert shape == (led + bool(loops), 2 * led, loops)
+            assert (str(eqn.params["jaxpr"]) == was) == (not loops)
+            if not seg["ops"]:
+                bare += 1
+                assert shape == (1, 2, 0)
+            elif loops:
+                chunked += 1
+                (kind, slots, passes), = pieces
+                assert kind == "stretch"
+                assert all(pk.rolls_lanes(slot) or slot[1] in ("cphase", "diag")
+                           for ops, held in passes if held is None
+                           for slot in ops)
+            else:
+                whole += 1
+    # the distinct structures' segments: 36 of the step's 41 launches
+    # are led, 24 of them bare; a sample's 14 windows are 12 structures
+    assert (bare, whole, chunked) \
+        == {"tfim": (24, 13, 4), "rcs": (71, 6, 11)}[family]
+
+
+def test_a_window_of_one_op_is_one_pass():
+    """A window of one op is a stretch of one op like any other.
+    QFT(0, 30)'s last window, the lone ``H`` on qubit 0, rolls lanes and
+    stays on the whole tile's value: no scratch, no loop.  A lone ``H``
+    on qubit 12 is one pass over the value's scratch tile."""
+    import jax
+    import jax.numpy as jnp
+
+    for target, shape, counts in [(0, (0, 0, 0), (0, 0, 0, 1)),
+                                  (12, (1, 0, 1), (1, 1, 1, 0))]:
+        structure = (("gen", target, False),)
+        fn = pk.make_window_fn(30, structure)
+        args = (jax.ShapeDtypeStruct((2, 1 << 30), jnp.float32),
+                *fu.pack_operands(_placeholder_ops(structure), jnp.float32))
+        assert _scratch_conds_loops(fn, *args) == [shape]
+        assert pk.stretch_counts(structure, fn.block_pow) == counts
+
+
+def _chunked_cases():
+    """``(ops, [(scratch operands, conds, loops)], scratch tiles)`` on a
+    tile of two chunks (w16, ``block_pow`` 14: 128 rows)."""
+    n, bp = 16, 14
+    named = diagonal_ops(n, bp)
+    gen = [fu.FusedOp("gen", t, 0, 0, _DENSE_MATRICES["gen"])
+           for t in range(bp)]
+    inv = fu.FusedOp("inv", 6, 1 << 15, 1 << 15, _DENSE_MATRICES["inv"])
+    mixed = [named["cphase.tile"], named["cphase.mixed"]]
+    table_only = [named["cphase.tile.bare"], named["diag.tile.bare"]]
+    leads = {"gen": fu.FusedOp("gen", 15, 0, 0, _DENSE_MATRICES["gen"]),
+             "cinv": fu.FusedOp("inv", 14, 1 << 3, 1 << 3,
+                                _DENSE_MATRICES["inv"]),
+             "u4-pair": _u4(4, 15), "u4-quad": _u4(14, 15)}
+    cases = [
+        # a stretch without a run: the value's tile alone, one loop for
+        # the op that rolls no lane behind the two that do
+        ("stretch", [gen[3], inv, gen[12]], [(1, 0, 1)], 1),
+        ("lanes-only", [gen[3], inv, named["diag.high"]], [(0, 0, 0)], 0),
+        # a diagonal op that is the segment's only op stays on the value
+        ("lone-diag", [named["diag.high"]], [(0, 0, 0)], 0),
+        # four partners' bits from the vreg on are one more than a
+        # chunk of 64 rows holds: two passes
+        ("split", [gen[10], gen[11], gen[12], gen[13]], [(1, 0, 2)], 1),
+        # a u4 takes 32 rows a chunk, two bits: (9, 10) and (11, 12) ask
+        # for three
+        ("split-u4", [_u4(9, 10), _u4(11, 12)], [(1, 0, 2)], 1),
+        # a stretch between two runs works on the tile the runs hold the
+        # value in: the runs' five conds and six loops (the first run's
+        # two like cphase are one loop over one traced pass, the second
+        # run's two unlike ops a pass each, and a multiply by the table
+        # each), and the stretch's one
+        ("between-runs", mixed + [gen[12]] + table_only, [(1, 5, 7)], 3),
+        # an op that rolls lanes between them is applied on the value,
+        # loaded behind the first run and stored again by the second
+        ("lanes-between-runs", mixed + [gen[3]] + table_only, [(1, 5, 6)], 3),
+        ("run-then-stretch", mixed + [gen[3], gen[13]], [(1, 2, 4)], 2),
+    ]
+    for name, lead in leads.items():
+        # behind a lead: the orbits, the value's tile, the grid's two conds
+        cases.append((f"led-{name}", [lead, gen[3], inv, gen[11]],
+                      [(2, 2, 1)], 1))
+    return [pytest.param(ops, shapes, tiles, id=name)
+            for name, ops, shapes, tiles in cases]
+
+
+@pytest.mark.parametrize("ops,shapes,tiles", _chunked_cases())
+def test_a_stretch_holds_its_value_in_the_scratch(ops, shapes, tiles):
+    """On a chunked tile a segment with in-tile ops has one scratch of
+    tiles, the value's first and a phase tile a run, whatever mix of
+    runs and stretches it holds; a stretch adds one loop a pass of ops
+    that roll no lane and no ``pl.when``, and a segment whose every op
+    rolls lanes has no scratch at all."""
+    import jax.numpy as jnp
+
+    n, bp = 16, 14
+    fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
+                           interpret=True)
+    args = (jnp.zeros((2, 1 << n), jnp.float32),
+            *fu.pack_operands(ops, jnp.float32))
+    assert _scratch_conds_loops(fn, *args) == shapes
+    eqn, = launches_of(fn, *args)
+    assert tuple(eqn.params["input_output_aliases"]) == ((2, 0),)
+    if tiles:
+        assert eqn.params["jaxpr"].invars[-1].aval.shape \
+            == (tiles, 2) + pk.dense_tile(bp)
 
 
 def test_a_window_with_a_run_holds_its_scratch_in_vmem():
@@ -1333,7 +1735,10 @@ def test_a_window_with_a_run_holds_its_scratch_in_vmem():
     (consecutive ``cphase`` alike in having controls, or one ``diag``:
     one traced body in a loop over its ops, onto the table at the first
     step where the op has no high part, onto the value where it has one
-    and the tile admits it)."""
+    and the tile admits it).  The tile here is one chunk (eight rows):
+    a pass is its body, not a loop, the only loops are those over a
+    run's like ops, and the ops outside runs are applied on the tile's
+    value (the last case: no scratch at all)."""
     import jax
     import jax.numpy as jnp
 
@@ -1344,28 +1749,35 @@ def test_a_window_with_a_run_holds_its_scratch_in_vmem():
     mixed = [named["cphase.tile"], named["cphase.mixed"]]
     lead = fu.FusedOp("gen", 11, 0, 0, _DENSE_MATRICES["gen"])
     for ops, expected in [
-            (table_only, [(1, 3)]),
-            (mixed, [(1, 2)]),
-            (mixed + [gen] + table_only, [(1, 5)]),
-            ([lead] + mixed, [(2, 4)]),              # + the orbits, the grid's two
-            ([named["cphase.mixed"], gen, named["cphase.tile"]], [(0, 0)])]:
+            (table_only, [(1, 3, 0)]),
+            (mixed, [(1, 2, 1)]),
+            (mixed + [gen] + table_only, [(1, 5, 1)]),
+            ([lead] + mixed, [(2, 4, 1)]),           # + the orbits, the grid's two
+            ([named["cphase.mixed"], gen, named["cphase.tile"]], [(0, 0, 0)])]:
         fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
                                interpret=True)
         args = (jnp.zeros((2, 1 << n), jnp.float32),
                 *fu.pack_operands(ops, jnp.float32))
-        assert _scratch_and_conds(fn, *args) == expected
+        assert _scratch_conds_loops(fn, *args) == expected
+        assert pk.stretch_counts(fu.structure_of(ops), bp)[:3] == (0, 0, 0)
         for eqn in launches_of(fn, *args):
             assert tuple(eqn.params["input_output_aliases"]) == ((2, 0),)
 
 
+@pytest.mark.parametrize("n,bp", [(12, 8), (16, 14)],
+                         ids=["flat-tile", "two-chunks"])
 @pytest.mark.parametrize("stack,kw", [("tpu", {}), ("pager", {"n_pages": 4})],
                          ids=["tpu", "pager"])
-def test_diag_run_counters_read_what_the_kernel_lowered(stack, kw, monkeypatch):
+def test_diag_run_counters_read_what_the_kernel_lowered(stack, kw, n, bp,
+                                                        monkeypatch):
     """``fuse.kernel.diag_runs`` / ``.diag_run.ops`` / ``.diag_run.tile_ops``
-    of a QFT through the engine's gate calls, beside ``fuse.kernel.ops``
-    and ``.sweeps``, which the run lowering leaves as they were; the ket
-    is the CPU engine's."""
-    n, bp = 12, 8
+    and ``fuse.kernel.stretches`` / ``.stretch.ops`` / ``.stretch.passes``
+    / ``.whole_tile_ops`` of a QFT through the engine's gate calls,
+    beside ``fuse.kernel.ops`` and ``.sweeps``, which neither lowering
+    moves; the ket is the CPU engine's.  On the flat tile every op
+    outside a run is applied on the whole tile and counted so; on a tile
+    of two chunks only those that roll lanes are, the others chunk by
+    chunk."""
     monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
     monkeypatch.setattr(pk, "DEFAULT_BLOCK_POW", bp)
     tele.enable()
@@ -1381,15 +1793,35 @@ def test_diag_run_counters_read_what_the_kernel_lowered(stack, kw, monkeypatch):
                               c["fuse.kernel.diag_run.ops"],
                               c["fuse.kernel.diag_run.tile_ops"])
     assert 0 < runs and 2 * runs <= in_runs <= c["fuse.kernel.ops"]
-    assert 0 < in_tile < in_runs
+    # a page of one tile has no bit above the tile to read
+    tiles = 1 << (n - (2 if stack == "pager" else 0) - bp)
+    assert 0 < in_tile and (in_tile < in_runs if tiles > 1
+                            else in_tile == in_runs)
+    stretches, in_stretches, passes, whole = (
+        c.get(f"fuse.kernel.{key}", 0)
+        for key in ("stretches", "stretch.ops", "stretch.passes",
+                    "whole_tile_ops"))
+    if bp < 10:
+        assert (stretches, in_stretches, passes) == (0, 0, 0) and whole > 0
+    else:
+        # QFT's H on qubits 0 to 6 roll lanes: on the whole tile, with
+        # a lone cphase behind one of them and those that stand alone
+        assert 7 <= whole <= 12 and 0 < stretches <= passes <= in_stretches
     if stack == "tpu":
-        # the replay of the same gate list, window by window
+        # the replay of the same gate list, window by window; every op
+        # of the engine's one shard is in a run, in a stretch or applied
+        # on the whole tile, but those that lead a segment
         from helpers import benchmark_plans
 
         with benchmark_plans(n) as windows:
-            want = (0, 0, 0)
+            want, led = ((0, 0, 0), (0, 0, 0, 0)), 0
             for w in windows("qft"):
                 if w["path"] == "kernel":
-                    want = tuple(a + b for a, b in zip(
-                        want, fu.diag_run_counts(w["ops"], bp)))
-        assert (runs, in_runs, in_tile) == want
+                    want = tuple(tuple(a + b for a, b in zip(have, more))
+                                 for have, more in zip(
+                                     want, lowered_counts(w["ops"], bp)))
+                    led += sum(seg["xgen"] is not None for seg in
+                               pk.plan_window(w["structure"], bp))
+        assert ((runs, in_runs, in_tile),
+                (stretches, in_stretches, passes, whole)) == want
+        assert in_runs + in_stretches + whole + led == c["fuse.kernel.ops"]
