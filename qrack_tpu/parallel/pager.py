@@ -18,8 +18,19 @@ include/qpager.hpp:31; src/qpager.cpp). Mapping (SURVEY.md §2.3):
   CombineEngines for indivisible ops           host-staged fallback
     (src/qpager.cpp:316-367, :595)             (guarded by width)
 
-Masks are always split into (local, page) parts, so no kernel ever
-builds a >int32 global index — widths beyond 31 qubits stay exact.
+Masks are always split into (local, page) parts, and a basis state is
+split on the host into (page, offset in the page): the fill
+(SetPermutation), the one-amplitude read and write (GetAmplitude,
+SetAmplitude), the gate programs and the fused windows build no index
+of the global axis, so they are exact at any width the pages hold (a
+page is at most 2^30 amplitudes: w31 on two pages, w32 on four).  What
+still indexes the global axis with int32: ``_k_gather`` without a
+``split=`` form and ``_k_out_of_place`` (a gather or scatter over an
+axis of 2^31: OverflowError at w31), and, sound at w31 and no wider,
+``_global_iota`` (``_k_phase_fn`` without a ``split=`` form) and every
+window of more than one amplitude read or written at a global offset
+(``_fetch`` with ``length > 1``, ``SetAmplitudePage``): PERF.md §7 has
+the table.
 Multi-host DCN scale-out composes by constructing the Mesh over
 jax.distributed processes; the kernels are unchanged.
 """
@@ -615,6 +626,83 @@ class QPager(QEngine):
             ))
 
         return _program(self._key("ssd"), build)
+
+    # -- one basis state or one amplitude, by (page, offset in the page):
+    #    nothing of the ket's length is an index, so these are exact at
+    #    any width (an int32 holds every offset of a page) --
+
+    def _split_index(self, phys: int):
+        """A PHYSICAL basis index as the programs below take it."""
+        L = self.local_bits
+        return np.int32(phys >> L), np.int32(phys & ((1 << L) - 1))
+
+    def _p_page_fill(self, owned: bool):
+        """|perm> times a phase, one write of every page (module
+        ``jit_qrack_page_fill``): each page writes zeros, then the
+        amplitude at its offset where the page id is its own and a zero
+        there where it is not.  ``owned``: the first operand is the ket
+        the pager holds, donated and never read, and the result takes
+        its buffers; else the one ket is allocated here.  As
+        ``engines/tpu.qrack_fill``: zeros and an update in place, no
+        select on an iota of the page's length."""
+        L, mesh, dtype = self.local_bits, self.mesh, self.dtype
+
+        def build():
+            def qrack_page_fill(*operands):
+                page, off, amp = operands[-3:]
+                mine = jax.lax.axis_index("pages") == page
+                amp = jnp.where(mine, amp, jnp.zeros((), dtype))[:, None]
+                return jax.lax.dynamic_update_slice(
+                    jnp.zeros((2, 1 << L), dtype), amp,
+                    (jnp.zeros_like(off), off))
+
+            # keep_unused: the donated ket is a parameter the result
+            # can alias
+            return jax.jit(jax.shard_map(
+                qrack_page_fill, mesh=mesh,
+                in_specs=_state_specs(3) if owned else (P(),) * 3,
+                out_specs=P(None, "pages")),
+                donate_argnums=(0,) if owned else (), keep_unused=True)
+
+        return _program(self._key("pagefill", str(dtype), owned), build)
+
+    def _p_page_read(self):
+        """One amplitude, replicated: the page that holds it gives it,
+        every other page a zero, summed over the mesh (legal on a mesh
+        that spans processes, and a completion barrier of every page)."""
+        mesh = self.mesh
+
+        def build():
+            def qrack_page_read(local, page, off):
+                amp = jax.lax.dynamic_slice(
+                    local, (jnp.zeros_like(off), off), (2, 1))
+                mine = jax.lax.axis_index("pages") == page
+                return jax.lax.psum(
+                    jnp.where(mine, amp, jnp.zeros((), local.dtype)), "pages")
+
+            return jax.jit(jax.shard_map(
+                qrack_page_read, mesh=mesh, in_specs=_state_specs(2),
+                out_specs=P()))
+
+        return _program(self._key("pageread"), build)
+
+    def _p_page_write(self):
+        """One amplitude written in place on the page that holds it."""
+        mesh = self.mesh
+
+        def build():
+            def qrack_page_write(local, page, off, amp):
+                at = (jnp.zeros_like(off), off)
+                mine = jax.lax.axis_index("pages") == page
+                old = jax.lax.dynamic_slice(local, at, (2, 1))
+                return jax.lax.dynamic_update_slice(
+                    local, jnp.where(mine, amp[:, None], old), at)
+
+            return jax.jit(jax.shard_map(
+                qrack_page_write, mesh=mesh, in_specs=_state_specs(3),
+                out_specs=P(None, "pages")), donate_argnums=(0,))
+
+        return _program(self._key("pagewrite"), build)
 
     # ------------------------------------------------------------------
     # kernel contract
@@ -1616,30 +1704,36 @@ class QPager(QEngine):
             itemsize = jnp.dtype(self.dtype).itemsize
             _tele.inc("exchange.pager.host_fetch")
             _tele.inc("exchange.pager.host_fetch_bytes", 2 * length * itemsize)
-        if self._state.is_fully_addressable:
+        if length == 1:  # by (page, offset): no index of the global axis
+            prog, at = self._p_page_read(), self._split_index(offset)
+
+            def read(st):
+                return np.asarray(_host_read_raw(prog(st, *at)),
+                                  dtype=np.float64)
+        elif self._state.is_fully_addressable:
             def read(st):
                 return np.asarray(
                     jax.device_get(st[:, offset:offset + length]),
                     dtype=np.float64)
+        else:
+            from .cluster import replicate_program
 
-            with _tele.span("engine.read"):
-                if _res._ACTIVE:  # site "pager.device_get": the completion sync
-                    planes = _res.call_guarded("pager.device_get", read,
-                                               (self._state,))
-                    from ..resilience import integrity as _integ
+            prog = _program(self._key("replicate", length),
+                            lambda: replicate_program(self.mesh, length))
+            return np.asarray(_host_read(prog(self._state, offset)),
+                              dtype=np.float64)
+        with _tele.span("engine.read"):
+            if _res._ACTIVE:  # site "pager.device_get": the completion sync
+                planes = _res.call_guarded("pager.device_get", read,
+                                           (self._state,))
+                from ..resilience import integrity as _integ
 
-                    if _integ.enabled():
-                        # boundary invariant piggybacked on the fetched
-                        # window — no extra HBM sweep (docs/INTEGRITY.md)
-                        _integ.check_host("pager.device_get", planes)
-                    return planes
-                return read(self._state)
-        from .cluster import replicate_program
-
-        prog = _program(self._key("replicate", length),
-                        lambda: replicate_program(self.mesh, length))
-        return np.asarray(_host_read(prog(self._state, offset)),
-                          dtype=np.float64)
+                if _integ.enabled():
+                    # boundary invariant piggybacked on the fetched
+                    # window — no extra HBM sweep (docs/INTEGRITY.md)
+                    _integ.check_host("pager.device_get", planes)
+                return planes
+            return read(self._state)
 
     def GetQuantumState(self) -> np.ndarray:
         planes = self._fetch(0, 1 << self.qubit_count)
@@ -1660,30 +1754,36 @@ class QPager(QEngine):
         amp = complex(amp)
         self._settle()
         perm = self._map_index(perm) if self._map_nonid() else perm
+        self._state = self._p_page_write()(
+            self._state, *self._split_index(perm),
+            np.asarray([amp.real, amp.imag], dtype=self.dtype))
 
-        sh = self.sharding
-
-        def build():
-            return jax.jit(lambda s, p, v: s.at[:, p].set(v), out_shardings=sh)
-
-        prog = _program(self._key("setamp"), build)
-        self._state = prog(self._state, perm,
-                           jnp.asarray([amp.real, amp.imag], dtype=self.dtype))
+    def _ket_is_mine_to_overwrite(self, st) -> bool:
+        """The planes the fill may take: those of this mesh, width and
+        plane type (a re-paged or re-typed pager fills a fresh ket)."""
+        return (st is not None and not st.is_deleted()
+                and st.shape == (2, 1 << self.qubit_count)
+                and st.dtype == self.dtype and st.sharding == self.sharding)
 
     def SetPermutation(self, perm: int, phase=None) -> None:
+        """One program writes every page once (``jit_qrack_page_fill``):
+        over the ket the pager owns, donated and aliased to the result,
+        or a fresh one where it owns none (construction).  The old ket
+        is never alive beside the new: a w31 page is a quarter of a
+        chip.  ``perm`` goes in as (page, offset), split on the host."""
         ph = self._rand_phase() if phase is None else complex(phase)
-        n, dtype, sh = self.qubit_count, self.dtype, self.sharding
-
-        def build():
-            def f(p, v):
-                return jnp.zeros((2, 1 << n), dtype=dtype).at[:, p].set(v)
-
-            return jax.jit(f, out_shardings=sh)
-
-        with _tele.span("engine.set_permutation"):  # build, fill, put
-            prog = _program(self._key("setperm", n), build)
-            self._state = prog(
-                perm, jnp.asarray([ph.real, ph.imag], dtype=self.dtype))
+        # a blind overwrite: the setter drops a pending window unflushed
+        st, self._state = self._state_raw, None
+        operands = (*self._split_index(perm),
+                    np.asarray([ph.real, ph.imag], dtype=self.dtype))
+        with _tele.span("engine.set_permutation"):
+            if self._ket_is_mine_to_overwrite(st):
+                _tele.inc("pager.fill.in_place")
+                self._state_raw = self._p_page_fill(True)(st, *operands)
+            else:
+                _tele.inc("pager.fill.fresh")
+                st = None  # let go before the new ket is allocated
+                self._state_raw = self._p_page_fill(False)(*operands)
         self._map_reset()
         self.running_norm = 1.0
 

@@ -1,6 +1,6 @@
 """Compile the main path's window programs for a described TPU v5e at
-w28 (a 2 GiB float32 ket; the pager's at w30, a 2 GiB page), without a
-chip.
+w28 (a 2 GiB float32 ket; the pager's at w30, a 2 GiB page, and at w31,
+a 4 GiB page: the cell ``qft_w31.pager4``), without a chip.
 
 Nothing here runs: each case hands the chip's own compiler the shapes
 and asserts that it accepts them (section 2 of the on-chip-measurement
@@ -179,7 +179,8 @@ def test_tfim_last_window_kernel(one_chip):
     assert _in_place(compiled)
 
 
-def _compile_sharded(topo, structure, n, npg=4, remap=(), batched=True):
+def _compile_sharded(topo, structure, n, npg=4, remap=(), batched=True,
+                     exchanges=True):
     """The pager's per-page kernel body of a window on a 2x2 mesh, with
     the planner's transpositions ``remap`` as its prologue."""
     L = n - 2
@@ -195,7 +196,7 @@ def _compile_sharded(topo, structure, n, npg=4, remap=(), batched=True):
                        out_specs=P(None, "pages"), check_vma=False)
     compiled = _compile(fn, args)
     text = compiled.as_text()
-    assert "collective-permute" in text
+    assert ("collective-permute" in text) == exchanges
     assert "tpu_custom_call" in text
     return compiled
 
@@ -661,6 +662,107 @@ def test_amplitude_read_w30_holds_no_ket(one_chip):
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= SLACK
     assert memory.output_size_in_bytes < 4096
+
+
+# -- the first ket no one chip holds: ``qft_w31.pager4`` (PR 45) -------------
+# A w31 ket is 16 GiB, 4 GiB a page on the 2x2 mesh: whatever a program
+# of the application keeps beside the page has to fit the 15.75 GiB the
+# runtime gives a chip.  The fill, the one-amplitude read, and three of
+# QFT(0, 31)'s 31 windows: the first (the planner's first prologue, two
+# pairs whose victims sit below the carrier bits: a shuffle of the page
+# before and after the exchange), the second (the last prologue, on the
+# carrier bits themselves) and the third (no exchange: launches alone).
+W31 = 31
+PAGE31_BYTES = (2 * 4 << W31) // 4
+HBM_BYTES = int(15.75 * 2 ** 30)
+QFT31_WINDOWS = {"w01-prologue-shuffled": 0, "w02-prologue-carriers": 1,
+                 "w03-plain": 2}
+
+
+@pytest.fixture(scope="module")
+def pager31(topo):
+    """A pager on the described chips with no planes (its programs are
+    built, never run) after one application of the cell: ``windows``
+    holds what ``_plan_window`` decided for each of the 31."""
+    from helpers import plan_only_pager
+
+    q = plan_only_pager(W31, devices=list(topo.devices[:4]))
+    q.SetPermutation(5)
+    q.QFT(0, W31)
+    q.GetAmplitude(3)
+    assert len(q.windows) == 31
+    assert [bool(w.swaps) for w in q.windows] == [True, True] + [False] * 29
+    return q
+
+
+def _page_shapes(q):
+    from jax.sharding import NamedSharding as NS
+
+    rep = NS(q.mesh, P())
+    return (jax.ShapeDtypeStruct((2, 1 << W31), jnp.float32,
+                                 sharding=q.sharding),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((2,), jnp.float32, sharding=rep))
+
+
+@pytest.mark.parametrize("owned", [True, False], ids=["in-place", "fresh"])
+def test_page_fill_writes_one_page_w31(pager31, owned):
+    """``QPager.SetPermutation``'s program: with a ket handed in every
+    page's result takes its buffer (one write, no read) and under 1 MiB
+    stands beside the 4 GiB page; with none it allocates the one ket."""
+    ket, i32, amp = _page_shapes(pager31)
+    t0 = time.perf_counter()
+    compiled = pager31._p_page_fill(owned).lower(
+        *((ket,) if owned else ()), i32, i32, amp).compile()
+    memory = compiled.memory_analysis()
+    print(f"compile_s={time.perf_counter() - t0:.2f} qrack_page_fill "
+          f"temp_bytes={memory.temp_size_in_bytes}")
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_qrack_page_fill")
+    assert memory.output_size_in_bytes == PAGE31_BYTES
+    assert memory.alias_size_in_bytes == (PAGE31_BYTES if owned else 0)
+    assert memory.temp_size_in_bytes < 1 << 20
+    assert not re.findall(r"f32\[2,%d\]\S* copy(?:-start)?\(" % (1 << 29), text)
+    if owned:
+        assert "input_output_alias={ {}: (0, {}, may-alias) }" in text
+
+
+def test_page_read_w31_holds_no_page(pager31):
+    """``GetAmplitude``'s program: (page, offset) in, two elements summed
+    over the mesh out; nothing of a page's size beside the page."""
+    ket, i32, _ = _page_shapes(pager31)
+    compiled = pager31._p_page_read().lower(ket, i32, i32).compile()
+    memory = compiled.memory_analysis()
+    assert compiled.as_text().startswith("HloModule jit_qrack_page_read")
+    assert memory.temp_size_in_bytes < 1 << 20
+    assert memory.output_size_in_bytes < 4096
+
+
+@pytest.mark.parametrize("name", sorted(QFT31_WINDOWS))
+def test_qft_w31_window_fits_beside_its_page(topo, pager31, name):
+    """Each compiles in seconds and page + temporaries stay under what
+    the runtime gives a chip."""
+    window = pager31.windows[QFT31_WINDOWS[name]]
+    plan, why = fu.sharded_kernel_lowering(W31 - 2, window.structure,
+                                           backend="tpu")
+    assert why is None and not plan["interpret"]
+    if window.swaps:
+        from qrack_tpu.ops import sharded as shb
+
+        exchange = shb.plan_exchange(W31 - 2, 2, window.swaps)
+        assert exchange.k == 2 and exchange.page_dest is None
+        assert bool(exchange.pre) == (name == "w01-prologue-shuffled")
+    t0 = time.perf_counter()
+    compiled = _compile_sharded(topo, window.structure, W31,
+                                remap=window.swaps, batched=window.batched,
+                                exchanges=bool(window.swaps))
+    assert time.perf_counter() - t0 < 120
+    assert _launches(compiled) == plan["sweeps"]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == PAGE31_BYTES
+    assert PAGE31_BYTES + memory.temp_size_in_bytes < HBM_BYTES
+    if not window.swaps:  # launches alone sweep the page in place
+        assert memory.temp_size_in_bytes <= SLACK
 
 
 def test_kernel_launches_carry_their_names(one_chip):
