@@ -11,7 +11,7 @@ from the same transform, arXiv:2304.14969).  This module makes fusion
 the *default* execution mode of the dense engines:
 
 * :class:`GateStreamFuser` — a bounded pending window of gate
-  descriptors (``QRACK_TPU_FUSE_WINDOW``, default 16) attached to an
+  descriptors (``QRACK_TPU_FUSE_WINDOW``, default 32) attached to an
   engine.  Gate ops append instead of dispatching; every read/boundary
   (Prob*/M*/device_get/checkpoint capture/failover snapshot/serror
   batch edge) lands on the engine's ``_state`` property, whose getter
@@ -81,7 +81,14 @@ from .. import resilience as _res
 from ..utils.bits import control_offset
 from . import gatekernels as gk
 
-DEFAULT_WINDOW = 16
+# merged ops a pending window holds.  32 since PR 46 (16 was PR 5's, for
+# the XLA chain on a CPU): wide enough that a cycle of a random circuit's
+# roots waits for its couplers and composes into them on the host, and
+# that a launch carries two of a QFT's runs; not wider, because a
+# window's float operands reach the kernel as one (N, 1) column in SMEM
+# (512 bytes an entry) and 64 ``u4`` are the whole of a v5e's 1 MiB
+# (tests/test_chip_compile.py; PERF.md section 7)
+DEFAULT_WINDOW = 32
 
 # structure-keyed parametric window programs, shared by the engine
 # fusers AND QCircuit.RunFused (layers/qcircuit.py) — same structure,
@@ -431,9 +438,22 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
                      pk.plan_counts)
 
 
-def _lowering(structure: Tuple, backend, bp: int, counts):
+# rows the two packed operand columns of a window may have where Mosaic
+# compiles it: an (N, 1) column in SMEM pads every entry to 512 bytes
+# and a v5e core has 1 MiB, so the chip's compiler refuses 2048 rows (64
+# ``u4``: RESOURCE_EXHAUSTED, tests/test_chip_compile.py) and takes the
+# 1024 of the default bound's widest window.  Three quarters of SMEM:
+# only a ``QRACK_TPU_FUSE_WINDOW`` above 32 reaches it
+SMEM_OPERAND_ROWS = 1536
+
+
+def _lowering(structure: Tuple, backend, bp: int, counts,
+              split: bool = False):
     """The choice both lowerings share; ``counts(structure, bp)`` gives
-    the plan's ``(sweeps, cross, dense)``."""
+    the plan's ``(sweeps, cross, dense)``.  A window whose operand
+    columns (``split``: in the sharded layout) would not fit a chip's
+    SMEM takes the chain (reason ``smem_operands``) where the chip's
+    compiler would refuse its program."""
     mode = kernel_mode()
     if mode == "off":
         return None, "mode_off"
@@ -441,6 +461,10 @@ def _lowering(structure: Tuple, backend, bp: int, counts):
         backend = jax.default_backend()
     from . import pallas_kernels as pk
 
+    if backend == "tpu":
+        _, floats, ints = pk._operand_slots(structure, split)
+        if floats + ints > SMEM_OPERAND_ROWS:
+            return None, "smem_operands"
     sweeps, cross, dense = counts(structure, bp)
     plan = {"interpret": backend != "tpu", "block_pow": bp,
             "sweeps": sweeps, "cross": cross, "dense": dense,
@@ -956,7 +980,8 @@ def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):
     from . import pallas_kernels as pk
 
     return _lowering(structure, backend, min(pk.DEFAULT_BLOCK_POW, L),
-                     lambda st, bp: sharded_kernel_counts(st, L, bp))
+                     lambda st, bp: sharded_kernel_counts(st, L, bp),
+                     split=True)
 
 
 def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
@@ -1012,7 +1037,7 @@ class GateStreamFuser:
     faults.suspended(), which re-runs the flush."""
 
     __slots__ = ("engine", "window", "gates", "_raw", "_flushing",
-                 "lookahead", "lookahead_pos")
+                 "lookahead", "lookahead_pos", "_head")
 
     def __init__(self, engine, window: int):
         self.engine = engine
@@ -1027,6 +1052,9 @@ class GateStreamFuser:
         # planning accuracy, never correctness.
         self.lookahead = None
         self.lookahead_pos = 0
+        # the gate that forced a "paged_target" flush, as the planner's
+        # first lookahead entry while that flush runs
+        self._head = ()
 
     @property
     def pending(self) -> bool:
@@ -1042,10 +1070,12 @@ class GateStreamFuser:
 
     def lookahead_rest(self):
         """Entries beyond the pending window (the window itself is
-        scored from its lowered ops)."""
+        scored from its lowered ops): the driver's stream or, where
+        there is none, the gate that is closing the window
+        (:meth:`_heads_a_window`)."""
         la = self.lookahead
         if not la:
-            return None
+            return self._head or None
         return la[self.lookahead_pos:] or None
 
     def queue(self, controls, m, target: int, perm: int) -> bool:
@@ -1126,11 +1156,49 @@ class GateStreamFuser:
         if len(self.gates) - len(behind) >= self.window:
             self.flush("window_full")
             behind = []
+        elif self.gates and self._heads_a_window(gate):
+            self._head = (("gen", gate.target),)
+            try:
+                self.flush("paged_target")
+            finally:
+                self._head = ()
         gate = gate.clone()
         for i in behind:
             gate.absorb_behind(self.gates[i])
             del self.gates[i]
         self.gates.append(gate)
+
+    def _heads_a_window(self, gate) -> bool:
+        """True where ``gate`` is non-diagonal, its target sits on a
+        page bit of the engine's placement table (QPager with the remap
+        planner on; no other engine has a table), the pending window
+        holds no such gate on that qubit yet and no driver primed a
+        lookahead (a stream of bare gate calls: with one, the planner
+        sees past the window and is left to it).  A remap prologue runs
+        at a window's head alone (:func:`plan_remaps`), so a gate that
+        needs one opens a window instead of landing wherever the count
+        puts it.  The window it opens is young when its prologue is
+        planned, so the carrier bits are cold and serve as victims (no
+        pass over the page before or after the exchange); the gate never
+        stays on its page bit (a full-state pair exchange) because a
+        window most of which lies before it left no qubit cold; and
+        window edges follow the circuit, so a periodic stream repeats
+        its plan from its second period on (PERF.md section 6, PR 46).
+        The window it closes is planned with this gate as its lookahead:
+        a prologue that window needs anyway takes this qubit along."""
+        qmap = getattr(self.engine, "_qmap", None)
+        if (qmap is None or self.lookahead is not None
+                or isinstance(gate, TwoQubitGate)):
+            return False
+        eng, target = self.engine, gate.target
+        if qmap[target] < eng.local_bits or not eng._remap_active():
+            return False
+
+        def hits(g):
+            return (not isinstance(g, TwoQubitGate) and g.target == target
+                    and not all(mat.is_phase(m) for m in g.payloads.values()))
+
+        return hits(gate) and not any(hits(g) for g in self.gates)
 
     def _singles_behind(self, gate: TwoQubitGate) -> List[int]:
         """Where the window holds an uncontrolled single-qubit gate that

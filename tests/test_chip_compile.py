@@ -12,6 +12,12 @@ result, PR 39): its program holds no ket of temporaries and no ket-sized
 ``copy``, which the dense cases assert; the pager's programs keep the
 exchange's buffers.
 
+The plans are the fuser's at its bound of 32 ops a window (16 until
+PR 46): a QFT's window holds two of its runs (``gen``, fifteen
+``cphase``, twice), a random circuit's is 32 ``u4``, the widest operand
+column the kernel's SMEM holds (64 are refused: the last case of the
+w28 part).
+
 The topology is described inside a module-scoped fixture, never at
 import: only the worker that is handed this file loads the TPU library.
 """
@@ -115,12 +121,21 @@ def _in_place(compiled, n=W):
 TFIM_LAST = tuple(("gen", t, False) for t in range(15, 28))
 TFIM_LAST_PAGED = tuple(("gen", t, False) for t in range(25, 30))
 
-QFT16 = (("gen", 27, False),) + tuple(
-    ("cphase", 26 - k, True) for k in range(15))
+
+
+def _qft_runs(top, runs=2):
+    """``runs`` times a ``gen`` and fifteen ``cphase`` below it, the
+    ``gen`` one qubit lower each time: what a QFT's window of 32 holds
+    (one run was PR 5's window of 16)."""
+    return tuple(op for r in range(runs) for op in (
+        (("gen", top - r, False),)
+        + tuple(("cphase", (top - r - 1 - k) % top, True) for k in range(15))))
+
+
+QFT32 = _qft_runs(27)
 # the same window with every target inside the tile: what most of a
 # QFT's sweeps are, and the body with the most arithmetic a tile
-QFT16_INTILE = (("gen", 15, False),) + tuple(
-    ("cphase", 14 - k, True) for k in range(15))
+QFT32_INTILE = _qft_runs(15)
 
 
 @pytest.mark.parametrize("kind,target", [
@@ -142,7 +157,7 @@ def test_xla_one_op_window(one_chip, kind, target):
     # the pager's per-page run at w28 / 4 pages that asked for 17 MiB of
     # VMEM: a controlled cross-tile gen with five cphases behind it
     (("gen", 17, True),) + (("cphase", 18, True),) * 5,
-    QFT16_INTILE,
+    QFT32_INTILE,
     # a controlled cross-tile gen whose mixed value goes on through
     # lane, sublane and whole-vreg pair ops and cphases
     (("gen", 21, True), ("gen", 3, False), ("cphase", 20, True),
@@ -156,15 +171,22 @@ def test_kernel_window(one_chip, structure):
 
 
 def test_qft_window_xla(one_chip):
-    compiled = _compile(fu.window_fn(W, QFT16), _dense_args(QFT16, one_chip))
+    compiled = _compile(fu.window_fn(W, QFT32), _dense_args(QFT32, one_chip))
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * KET_BYTES + SLACK
 
 
-def test_qft_window_kernel(one_chip):
-    compiled = _compile(pk.make_window_fn(W, QFT16),
-                        _dense_args(QFT16, one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
-    assert _in_place(compiled)
+@pytest.mark.parametrize("n", [W, 30], ids=["w28", "w30"])
+def test_qft_window_kernel(one_chip, n):
+    """The widest QFT window the bound of 32 builds: two led launches,
+    each a ``gen`` above the tile with its run of fifteen ``cphase``
+    behind it, at w28 and on the 8 GiB ket of w30."""
+    structure = _qft_runs(n - 1)
+    assert len(structure) == fu.DEFAULT_WINDOW == 32
+    compiled = _compile(pk.make_window_fn(n, structure),
+                        _dense_args(structure, one_chip, n=n))
+    assert _launches(compiled) == 2
+    assert compiled.memory_analysis().alias_size_in_bytes == 2 * 4 << n
+    assert _in_place(compiled, n)
 
 
 def test_tfim_last_window_kernel(one_chip):
@@ -235,41 +257,44 @@ def test_tfim_last_window_sharded_kernel(topo):
         <= 5 * KET_BYTES // 2 + SLACK
 
 
-def test_tfim_sixth_window_sharded_kernel(topo):
-    """The paged Trotter step's sixth window at w30 on the fixed
-    placement: launches between the step's four controlled exchanges
-    (the CNOTs onto 28 and 29), the paged program the compiler counts
-    the most memory for.  3.63 pages of temporaries before PR 39, 4.13
-    with the alias alone (the launches' results chain through the
-    donated page's buffer, which took one of the exchange's halves
-    before), 3.06 since the exchange keeps no (a, b) halves."""
+def test_tfim_third_window_sharded_kernel(topo):
+    """The paged Trotter step's third window at w30 on the fixed
+    placement (its sixth of 16 ops until PR 46, with the fifth's led
+    launches ahead of it now): launches between the step's four
+    controlled exchanges (the CNOTs onto 28 and 29), the paged program
+    the compiler counts the most memory for.  3.63 pages of temporaries
+    before PR 39, 4.13 with the alias alone (the launches' results chain
+    through the donated page's buffer, which took one of the exchange's
+    halves before), 3.06 since the exchange keeps no (a, b) halves."""
     from helpers import issue, plan_only_pager, trotter_step_gates
 
     q = plan_only_pager(W + 2, remap="off")
     issue(q, trotter_step_gates(W + 2))
     q.GetAmplitude(0)
-    structure = q.windows[5].structure
+    structure = q.windows[2].structure
     controlled = [op for op in structure if op[1] >= W and op[0] == "gen"]
-    assert len(q.windows) == 8 and len(controlled) == 4
+    assert len(q.windows) == 4 and len(controlled) == 4
     plan, why = fu.sharded_kernel_lowering(W, structure, backend="tpu")
-    assert why is None and plan["sweeps"] == 8
+    assert why is None and plan["sweeps"] == 19
     compiled = _compile_sharded(topo, structure, W + 2)
-    assert _launches(compiled) == 4
+    assert _launches(compiled) == 15
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= 13 * KET_BYTES // 4 + SLACK
 
 
 # every window of the paged Trotter step at w30 that begins with a remap
-# prologue, on the pager's default placement: step 0 moves the table
-# (its sixth and last windows), every later step runs the same four
-# (windows five to eight), all of two pairs across the page boundary.
-# Beside them what other circuits make the planner emit: one pair whose
-# victim sits on a sublane bit, two pairs with victims on lane bits
-# (shuffles of the whole page before and after the exchange), one pair
-# and two pairs on the carrier bits themselves (no shuffle at all)
-REMAP_PROGRAMS = ("step0-w6", "step0-w8", "settled-w5", "settled-w6",
-                  "settled-w7", "settled-w8", "k1-sublane", "k2-lanes",
-                  "k1-carrier", "k2-carriers")
+# prologue, on the pager's default placement.  A gate that needs one
+# heads its window (``GateStreamFuser._heads_a_window``, PR 46), so
+# these windows are short and their victims sit on the carrier bits:
+# step 0 brings 28 and 29 in ahead of the bond onto 28; from step 1 on
+# every step runs the same two programs twice each (a bond's three ops,
+# a lone RX), all of two pairs.  Beside them what other circuits make
+# the planner emit: one pair whose victim sits on a sublane bit, two
+# pairs with victims on lane bits (shuffles of the whole page before and
+# after the exchange), one pair and two pairs on the carrier bits
+# themselves (no shuffle at all)
+REMAP_PROGRAMS = ("step0-bond", "settled-bond", "settled-rx", "k1-sublane",
+                  "k2-lanes", "k1-carrier", "k2-carriers")
 PAGED_W = W + 2
 
 
@@ -282,18 +307,20 @@ def remap_programs():
     q = plan_only_pager(PAGED_W)
     gates = trotter_step_gates(PAGED_W)
     steps = []
-    for _ in range(3):
+    for _ in range(4):
         q.windows.clear()
         issue(q, gates)
         q.GetAmplitude(0)
-        steps.append(list(q.windows))
-    # the settled step: step 2 plans what step 1 planned, table and all
-    assert [(w.structure, w.swaps) for w in steps[1]] \
-        == [(w.structure, w.swaps) for w in steps[2]]
-    named = {f"step0-w{i + 1}": w for i, w in enumerate(steps[0]) if w.swaps}
-    named.update({f"settled-w{i + 1}": w for i, w in enumerate(steps[1])
-                  if w.swaps})
-    out = {name: (w.structure, w.swaps) for name, w in named.items()}
+        steps.append([(w.structure, w.swaps) for w in q.windows])
+    # the settled step: every step from the second on plans the same,
+    # table and all
+    assert steps[1] == steps[2] == steps[3]
+    settled = sorted({w for w in steps[1] if w[1]}, key=lambda w: -len(w[0]))
+    first = [w for w in steps[0] if w[1] and w not in steps[1]]
+    assert [len(w[0]) for w in settled] == [3, 1] and len(first) == 1
+    assert sum(bool(w[1]) for w in steps[1]) == 4
+    out = {"step0-bond": first[0], "settled-bond": settled[0],
+           "settled-rx": settled[1]}
     local = tuple(("gen", t, False) for t in (3, 12, 20, 27))
     out["k1-sublane"] = (local, ((7, 29),))
     out["k2-lanes"] = (local, ((0, 28), (1, 29)))
@@ -322,7 +349,9 @@ def test_remap_prologue_sharded_kernel(topo, remap_programs, name, batched):
     if expected is not None:
         assert (plan.k, plan.pre) == expected and plan.post == plan.pre
     else:
-        assert plan.k == 2 and plan.page_dest is None
+        # the step's prologues ride the carrier bits: no pass over the
+        # page before the exchange or after
+        assert plan.k == 2 and plan.page_dest is None and not plan.pre
     t0 = time.perf_counter()
     compiled = _compile_sharded(topo, structure, PAGED_W, remap=swaps,
                                 batched=batched)
@@ -335,9 +364,11 @@ def _u4(lo, hi):
     return ("u4", (lo, hi), False)
 
 
-# the windows of a random-circuit sample at w28 that hold each segment
-# shape a two-target op has (benchmarks/tests/test_rcs.py has the whole
-# plan): in the tile, leading the pair grid, leading the four-tile grid
+# windows that hold each segment shape a two-target op has: in the tile,
+# leading the pair grid, leading the four-tile grid (a random-circuit
+# sample's own four windows of 32 ``u4`` are ``CELL_WINDOWS``' below; these
+# are what its stream flushed at the bound of 16, and what a circuit
+# whose roots do not all merge still builds)
 RCS_WINDOWS = {
     # couplers with their roots composed in, on the quad grid, the last
     # with the next cycle's in-tile couplers riding behind it
@@ -351,6 +382,12 @@ RCS_WINDOWS = {
     # sixteen ops in one tile, eight of them 4x4: the most arithmetic
     "intile-16": tuple(_u4(a, a + 1) for a in range(0, 16, 2))
     + tuple(("gen", t, False) for t in range(8)),
+    # the bound's worth of 4x4 in one tile, on every pairing of lane,
+    # sublane and vreg bits: the most arithmetic and the most operands
+    "intile-32": tuple(_u4(a, a + 1) for a in range(0, 16, 2))
+    + tuple(_u4(a, a + 1) for a in range(1, 15, 2))
+    + tuple(_u4(a, a + 8) for a in range(8))
+    + tuple(_u4(a, 15 - a) for a in range(8)) + (_u4(0, 15),),
 }
 
 
@@ -362,7 +399,7 @@ def test_two_qubit_window_kernel(one_chip, window):
     plan, why = fu.kernel_lowering(W, structure, backend="tpu")
     assert why is None
     expected = {"intile-and-quad": (1, 0, 6), "cross-pair-quad": (0, 1, 1),
-                "intile-16": (1, 0, 0)}[window]
+                "intile-16": (1, 0, 0), "intile-32": (1, 0, 0)}[window]
     assert tuple(plan["twoq"][f"sweeps.{k}"]
                  for k in ("intile", "pair", "quad")) == expected
     t0 = time.perf_counter()
@@ -378,14 +415,15 @@ def test_two_qubit_window_kernel(one_chip, window):
 
 
 # the window programs of one application of the three dense cells at
-# w28, by the families' own gate lists: QFT's 26 windows, the Trotter
-# step's 7, and the 12 structures of a random circuit's 14 windows.  One
-# structure of each plan shape is compiled (27 of the 45: all 45 took the
-# file from 62 to 144 s): dropped are 18 of QFT's one-sweep windows of
-# 16 in-tile ops, windows 7-12, 14-16, 18, 19, 21, 22 and 24 in the
-# shape of window 6, and 17, 20, 23 and 25 in that of window 13
-CELL_WINDOWS = [("qft", i) for i in (0, 1, 2, 3, 4, 5, 12, 25)] \
-    + [("tfim", i) for i in range(7)] + [("rcs", i) for i in range(12)]
+# w28, by the families' own gate lists: QFT's 13 windows (7, 4, 3
+# launches, then ten of one), the Trotter step's 4 (1, 14, 12, 13) and a
+# random circuit's 4 (13, 15, 16, 7), every one a program of its own
+# and every one compiled (26, 7 and 12 structures in 14 windows at the
+# bound of 16, of which 27 were)
+CELL_WINDOWS = [("qft", i) for i in range(13)] \
+    + [("tfim", i) for i in range(4)] + [("rcs", i) for i in range(4)]
+CELL_SWEEPS = {"qft": [7, 4, 3] + [1] * 10, "tfim": [1, 14, 12, 13],
+               "rcs": [13, 15, 16, 7]}
 
 
 def _plan_shape(structure):
@@ -410,7 +448,7 @@ def cell_windows():
                    if w["path"] == "kernel"))
                for family in ("qft", "tfim", "rcs")}
     assert {f: len(structures) for f, structures in out.items()} \
-        == {"qft": 26, "tfim": 7, "rcs": 12}
+        == {f: len(sweeps) for f, sweeps in CELL_SWEEPS.items()}
     assert {_plan_shape(s) for structures in out.values() for s in structures} \
         == {_plan_shape(out[f][i]) for f, i in CELL_WINDOWS}
     return out
@@ -424,24 +462,53 @@ def test_cell_window_sweeps_its_ket_in_place(one_chip, cell_windows, family,
     a window program of a dense cell is its planned launches and
     nothing of the ket's size beside them (a one-sweep window held one
     ``copy`` of ``f32[2,268435456]`` back into the donated ket, 6.53 ms
-    on the chip: 21 of QFT's 26 windows, three of the Trotter step's 7,
-    one of a random circuit's)."""
+    on the chip: 21 of QFT's 26 windows of 16, three of the Trotter
+    step's 7, one of a random circuit's)."""
     structure = cell_windows[family][index]
     plan, why = fu.kernel_lowering(W, structure, backend="tpu")
-    assert why is None
+    assert why is None and plan["sweeps"] == CELL_SWEEPS[family][index]
     compiled = _compile(pk.make_window_fn(W, structure),
                         _dense_args(structure, one_chip))
     assert _launches(compiled) == plan["sweeps"]
     assert _in_place(compiled)
 
 
+SMEM_BYTES = 1 << 20  # a v5e core's scalar memory
+SMEM_ROW_BYTES = 512   # what one entry of an (N, 1) operand column pads to
+
+
+def test_window_of_32_u4_is_the_smem_ceiling(one_chip, cell_windows):
+    """Why the bound is 32 and not 64 (PR 46).  A window's float
+    operands reach every launch as one ``(N, 1)`` column in SMEM, which
+    pads each entry to a row of 128 words: a ``u4`` brings 32 floats, so
+    a random circuit's window of 32 ``u4`` is 1024 rows, half of SMEM,
+    and compiles (the case ``rcs-w01`` above); 64 ``u4`` are 2048 rows,
+    the whole of it, and the chip's compiler refuses the program (built
+    here by hand: the lowering itself keeps such a window on the chain,
+    ``fusion.SMEM_OPERAND_ROWS``).  When the column is packed as a row
+    (a kernel-layer change) the refusal goes, this case fails, and the
+    bound can be asked again."""
+    structure = cell_windows["rcs"][0]
+    assert [kind for kind, _, _ in structure] == ["u4"] * fu.DEFAULT_WINDOW
+    for window, share in ((structure, 2), (structure * 2, 1)):
+        iv, fv = fu.pack_operands(_ops(window), jnp.float32)
+        print(f"operand rows at {len(window)} u4: iv={iv.shape} fv={fv.shape}")
+        assert fv.shape == (32 * len(window), 1)
+        assert fv.shape[0] * SMEM_ROW_BYTES == SMEM_BYTES // share
+    with pytest.raises(Exception, match="(?i)smem"):
+        _compile(pk.make_window_fn(W, structure * 2),
+                 _dense_args(structure * 2, one_chip))
+
+
 # QFT's windows at w28 whose bodies hold its runs of controlled phases
-# (PR 42; the indices are cell_windows'): eight cphase, a gen and seven
-# more in one tile (the shape of 15 of the 26: one gen and fifteen
-# cphase, two runs), sixteen cphase (five of them: one run), and a
-# window of three launches, two of them led with phases behind the lead
-QFT_RUN_WINDOWS = {"gen+15cphase": (6, [(1, 2)]), "16cphase": (12, [(1, 1)]),
-                   "led": (4, [(0, 0), (2, 1), (2, 1)])}
+# (PR 42; the indices are cell_windows'): two gen among thirty cphase in
+# one tile (three runs), one gen among thirty-one (two runs: nine of the
+# ten one-launch windows are one or the other), the last window's gen
+# and twenty-one cphase (one run), and a window of three launches, two of
+# them led with phases behind the lead, the last with a gen between runs
+QFT_RUN_WINDOWS = {"2gen+30cphase": (3, [(1, 3)]), "gen+31cphase": (8, [(1, 2)]),
+                   "gen+21cphase": (12, [(1, 1)]),
+                   "led": (2, [(0, 0), (2, 1), (2, 2)])}
 
 
 @pytest.mark.parametrize("name", sorted(QFT_RUN_WINDOWS))
@@ -451,7 +518,8 @@ def test_qft_run_window_kernel(one_chip, cell_windows, name):
     double-buffered) and a led launch's two orbits they stay far under
     the limit the launch asks for, the program holds nothing of the
     ket's size beside the donated ket, and the compiler takes under a
-    second (the backend alone, this sandbox, 16cphase / gen+15cphase /
+    second (the backend alone, this sandbox, the windows of 16 ops this
+    case held until PR 46, sixteen cphase / gen and fifteen cphase /
     led: 0.82, 0.78 and 0.62 s without the run lowering, 0.11, 0.23 and
     0.23 s with it: a run's ops are a loop, a sixth of the code)."""
     from test_pallas_window import launches_of
@@ -482,19 +550,20 @@ def test_qft_run_window_kernel(one_chip, cell_windows, name):
 
 
 # the cells' windows whose launches hold a stretch of in-tile ops (PR 44;
-# the indices are cell_windows'): the random circuit's sixteen gen on
-# qubits 0-15 (seven on the whole tile, nine in two passes) and its
-# window of 15 u4 and one gen (six u4 in the tile, two of them on lane
-# bits; six leading four tiles, the last with a gen and three u4 behind
-# it, all on lane bits: no pass, no scratch but the orbits), the Trotter
-# step's first window (inv and diag alternating on qubits 1-6: every op
-# rolls lanes or stands behind one that does) and its inv-led launch with
-# 15 gen riding.  Each launch: (scratch operands, passes of its stretch)
+# the indices are cell_windows'): a random circuit's first window (eight
+# u4 in the tile in three passes, then two cycles' couplers leading four
+# tiles, the sixth with seven u4 behind it and the last with five: one
+# more scratch tile beside the orbits) and its last (six u4 in the tile,
+# six bare leads), the Trotter step's first window (inv and diag
+# alternating on qubits 1-11: one pass behind the ops that roll lanes)
+# and its third, whose last inv-led launch has 15 gen riding.  Each
+# launch: (scratch operands, passes of its stretch)
 STRETCH_WINDOWS = {
-    "rcs-16gen": ("rcs", 0, [(1, 2)]),
-    "rcs-15u4+gen": ("rcs", 5, [(1, 3)] + [(1, 0)] * 6),
-    "tfim-16op": ("tfim", 0, [(0, 0)]),
-    "tfim-inv-led-15gen": ("tfim", 5, [(2, 2)]),
+    "rcs-w1": ("rcs", 0, [(1, 3)] + [(1, 0)] * 5 + [(2, 3)] + [(1, 0)] * 5
+               + [(2, 1)]),
+    "rcs-w4": ("rcs", 3, [(1, 3)] + [(1, 0)] * 6),
+    "tfim-32op": ("tfim", 0, [(1, 1)]),
+    "tfim-inv-led-15gen": ("tfim", 2, [(0, 0)] + [(1, 0)] * 10 + [(2, 2)]),
 }
 
 
@@ -531,11 +600,13 @@ def test_stretch_window_kernel(one_chip, cell_windows, name):
     the launch (512 KiB), beside a led launch's two orbits; the program
     holds nothing of the ket's size beside the donated ket, its result
     takes the ket's buffer, and the compiler takes a fraction of a
-    second (the backend alone, this sandbox, parent / change:
-    rcs-15u4+gen 2.06 / 0.51 s, rcs-16gen 1.70 / 0.21, tfim-16op 1.28 /
-    0.20, tfim-inv-led-15gen 1.96 / 0.32, the w30 QFT window below 0.26
-    / 0.15; the three families' 45 programs 32.6 / 13.6 s: a pass is a
-    loop, its body a chunk's code and not a tile's)."""
+    second (the backend alone, this sandbox, PR 44's parent / change on
+    the windows of 16 ops this case held until PR 46: fifteen u4 and a
+    gen 2.06 / 0.51 s, sixteen gen 1.70 / 0.21, the step's first sixteen
+    1.28 / 0.20, its inv-led launch with 15 gen 1.96 / 0.32, the w30 QFT
+    window below 0.26 / 0.15; the three families' 45 programs 32.6 /
+    13.6 s: a pass is a loop, its body a chunk's code and not a
+    tile's)."""
     family, index, expected = STRETCH_WINDOWS[name]
     structure = cell_windows[family][index]
     fn = pk.make_window_fn(W, structure)
@@ -553,12 +624,14 @@ def test_stretch_window_kernel(one_chip, cell_windows, name):
 
 # -- w30: the widest ket one chip holds (PR 43) --------------------------------
 # An 8 GiB ket fits a 16 GB chip only while no program of the path holds
-# a second: the fill, each of QFT(0, 30)'s 30 windows (29 of sixteen ops
-# and the last H alone, a kernel window of one since the rule
-# ``single_op`` went) and the read.  SLACK stays the w28 cases' 32 MiB.
+# a second: the fill, each of QFT(0, 30)'s 15 windows (14 of 32 ops and
+# the last 17; at the bound of 16 the thirtieth was the last H alone, a
+# kernel window of one since the rule ``single_op`` went, which a case of
+# its own still compiles) and the read.  SLACK stays the w28 cases' 32 MiB.
 W30 = 30
 KET30_BYTES = 2 * 4 << W30
-QFT30_WINDOWS = 30
+QFT30_WINDOWS = 15
+QFT30_SWEEPS = [7, 4, 4, 2] + [1] * 11
 
 
 @pytest.fixture(scope="module")
@@ -570,8 +643,8 @@ def qft30_windows():
     with benchmark_plans(W30) as windows:
         out = [w["structure"] for w in windows("qft")]
     assert len(out) == QFT30_WINDOWS
-    assert [len(s) for s in out] == [16] * 29 + [1]
-    assert out[-1] == (("gen", 0, False),)
+    assert [len(s) for s in out] == [32] * 14 + [17]
+    assert out[-1][-1] == ("gen", 0, False)
     return out
 
 
@@ -608,13 +681,14 @@ def test_fill_writes_one_ket_w30(one_chip, owned):
 @pytest.mark.parametrize("index", range(QFT30_WINDOWS),
                          ids=[f"w{i + 1:02d}" for i in range(QFT30_WINDOWS)])
 def test_qft_w30_window_sweeps_its_ket_in_place(one_chip, qft30_windows, index):
-    """Every window of ``qft_w30.library``'s application, the lone last
-    ``H`` among them: its planned launches (2^14 tiles a plane, led
-    segments on targets 16 to 29), the result aliased to the donated
-    ket, no temporary and no copy of the ket's size."""
+    """Every window of ``qft_w30.library``'s application: its planned
+    launches (2^14 tiles a plane, led segments on targets 16 to 29),
+    the result aliased to the donated ket, no temporary and no copy of
+    the ket's size."""
     structure = qft30_windows[index]
     plan, why = fu.kernel_lowering(W30, structure, backend="tpu")
     assert why is None and not plan["interpret"]
+    assert plan["sweeps"] == QFT30_SWEEPS[index]
     compiled = _compile(pk.make_window_fn(W30, structure),
                         _dense_args(structure, one_chip, n=W30))
     assert _launches(compiled) == plan["sweeps"]
@@ -622,11 +696,29 @@ def test_qft_w30_window_sweeps_its_ket_in_place(one_chip, qft30_windows, index):
     assert _in_place(compiled, W30)
 
 
+@pytest.mark.parametrize("target,passes", [(0, 0), (12, 1)],
+                         ids=["lane", "vreg"])
+def test_one_op_window_w30_sweeps_in_place(one_chip, target, passes):
+    """A window of one op is a kernel window (PR 43: the eager program
+    of a lone gate holds one to three kets of temporaries, refused at
+    w30).  QFT(0, 30) ended in one, the lone ``H`` on qubit 0, until the
+    bound of 32 took it into the window before; a read behind a single
+    gate still flushes one."""
+    structure = (("gen", target, False),)
+    fn = pk.make_window_fn(W30, structure)
+    args = _dense_args(structure, one_chip, n=W30)
+    _stretch_launches(fn, args, structure, [(passes, passes)])
+    compiled = _compile(fn, args)
+    assert _launches(compiled) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes == KET30_BYTES
+    assert _in_place(compiled, W30)
+
+
 def test_qft_w30_stretch_window_kernel(one_chip, qft30_windows):
     """A window of ``qft_w30.library`` with a ``gen`` between two runs
-    of ``cphase`` (its seventh: eight ``cphase``, the ``gen`` on qubit
-    14, seven more): the stretch of one op is one pass on the tile the
-    runs hold the value in, one scratch of three tiles."""
+    of ``cphase`` (its ninth: the ``gen`` on qubit 7 among thirty-one
+    ``cphase``): the stretch of one op is one pass on the tile the runs
+    hold the value in, one scratch of three tiles."""
     structure = qft30_windows[8]
     kinds = [kind for kind, _, _ in structure]
     assert sorted(set(kinds)) == ["cphase", "gen"] and kinds.count("gen") == 1
@@ -642,11 +734,13 @@ def test_qft_w30_stretch_window_kernel(one_chip, qft30_windows):
     assert _in_place(compiled, W30)
 
 
-def test_qft_w30_plans_43_sweeps(qft30_windows):
+def test_qft_w30_plans_28_sweeps(qft30_windows):
+    """43 at the bound of 16: the 14 led launches stay, and of the
+    in-tile sweeps two windows' worth now ride in one."""
     plans = [fu.kernel_lowering(W30, s, backend="tpu")[0]
              for s in qft30_windows]
-    assert [p["sweeps"] for p in plans] == [5, 3, 3, 2, 3, 2, 2] + [1] * 23
-    assert sum(p["cross"] for p in plans) == 14
+    assert [p["sweeps"] for p in plans] == QFT30_SWEEPS
+    assert sum(QFT30_SWEEPS) == 28 and sum(p["cross"] for p in plans) == 14
 
 
 def test_amplitude_read_w30_holds_no_ket(one_chip):
@@ -667,31 +761,35 @@ def test_amplitude_read_w30_holds_no_ket(one_chip):
 # -- the first ket no one chip holds: ``qft_w31.pager4`` (PR 45) -------------
 # A w31 ket is 16 GiB, 4 GiB a page on the 2x2 mesh: whatever a program
 # of the application keeps beside the page has to fit the 15.75 GiB the
-# runtime gives a chip.  The fill, the one-amplitude read, and three of
-# QFT(0, 31)'s 31 windows: the first (the planner's first prologue, two
-# pairs whose victims sit below the carrier bits: a shuffle of the page
-# before and after the exchange), the second (the last prologue, on the
-# carrier bits themselves) and the third (no exchange: launches alone).
+# runtime gives a chip.  The fill, the one-amplitude read, and four of
+# QFT(0, 31)'s 19 windows: the three short ones that the ``H`` on 30, 29
+# and 28 head (the first brings 30 and 29 onto the carrier bits, the
+# third sends them back for 28 and 27; no shuffle of the page before or
+# after either exchange) and the first of the sixteen the bound cuts (no
+# exchange: launches alone, 32 ops).
 W31 = 31
 PAGE31_BYTES = (2 * 4 << W31) // 4
 HBM_BYTES = int(15.75 * 2 ** 30)
-QFT31_WINDOWS = {"w01-prologue-shuffled": 0, "w02-prologue-carriers": 1,
-                 "w03-plain": 2}
+QFT31_WINDOWS = {"w01-prologue": 0, "w02-plain": 1, "w03-prologue": 2,
+                 "w04-plain-32": 3}
 
 
 @pytest.fixture(scope="module")
 def pager31(topo):
     """A pager on the described chips with no planes (its programs are
     built, never run) after one application of the cell: ``windows``
-    holds what ``_plan_window`` decided for each of the 31."""
+    holds what ``_plan_window`` decided for each of the 19."""
     from helpers import plan_only_pager
 
     q = plan_only_pager(W31, devices=list(topo.devices[:4]))
     q.SetPermutation(5)
     q.QFT(0, W31)
     q.GetAmplitude(3)
-    assert len(q.windows) == 31
-    assert [bool(w.swaps) for w in q.windows] == [True, True] + [False] * 29
+    # H on 30 alone, H on 29, H on 28 (a page bit by then) each head a
+    # window; sixteen by the bound behind them
+    assert [len(w.tops) for w in q.windows] == [2, 3, 4] + [32] * 15 + [7]
+    assert [bool(w.swaps) for w in q.windows] \
+        == [True, False, True] + [False] * 16
     return q
 
 
@@ -751,7 +849,7 @@ def test_qft_w31_window_fits_beside_its_page(topo, pager31, name):
 
         exchange = shb.plan_exchange(W31 - 2, 2, window.swaps)
         assert exchange.k == 2 and exchange.page_dest is None
-        assert bool(exchange.pre) == (name == "w01-prologue-shuffled")
+        assert not exchange.pre  # both ride the carrier bits
     t0 = time.perf_counter()
     compiled = _compile_sharded(topo, window.structure, W31,
                                 remap=window.swaps, batched=window.batched,

@@ -255,8 +255,11 @@ def _qft_gates(width):
 
 def test_qft_whose_last_window_is_one_op_matches_the_reference(
         kernel_on, monkeypatch):
-    """QFT(0, 30) is 465 ops: 29 windows of 16 and the last ``H`` alone.
-    At w12 a window of 11 gives the same shape: 78 = 7 x 11 + 1.  Every
+    """A window whose bound divides the stream but for one op ends it on
+    a window of that op alone: QFT(0, 30)'s 465 ops at the bound of 16
+    (29 windows and the last ``H``; 15 windows and no lone op at the 32
+    of PR 46), QFT(0, 29)'s 435 at 31.  At w12 a window of 11 gives the
+    same shape: 78 = 7 x 11 + 1.  Every
     window, the lone op's too, is a kernel window, and the ket is the
     plain simulator's."""
     width, x = 12, 2741

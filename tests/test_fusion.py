@@ -231,8 +231,9 @@ def test_w16_qft_dispatch_count_drops_4x(monkeypatch):
         return _program_dispatches(counters)
 
     per_gate = run(1)
-    fused = run(16)
-    # 136 gates: per-gate pays ~one dispatch each; fused pays ~ceil(136/16)
+    fused = run(32)
+    # 136 gates: per-gate pays ~one dispatch each; fused pays
+    # ~ceil(136 / 32)
     assert per_gate >= 4 * fused, (per_gate, fused)
 
 

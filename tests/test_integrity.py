@@ -146,18 +146,18 @@ def test_quarantine_strikes_and_reset(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# detect-and-repair matrix: every flush envelope site, windows 1 and 16
+# detect-and-repair matrix: every flush envelope site, windows 1 and 32
 # ---------------------------------------------------------------------------
 
 _MATRIX = [
     ("tpu", 1, "tpu.compile", {}),
-    ("tpu", 16, "tpu.fuse.flush", {}),
+    ("tpu", 32, "tpu.fuse.flush", {}),
     # remap off: the placement planner would turn the lone global op
     # into a remapped local window (tpu.fuse.flush) and the pair
     # exchange under test would never dispatch (test_remap.py covers
     # the planner path)
     ("pager", 1, "pager.exchange", {"n_pages": 4, "remap": "off"}),
-    ("pager", 16, "tpu.fuse.flush", {"n_pages": 4}),
+    ("pager", 32, "tpu.fuse.flush", {"n_pages": 4}),
 ]
 
 

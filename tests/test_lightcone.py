@@ -165,7 +165,7 @@ def _random_shallow_qcircuit(n: int, n_gates: int, seed: int) -> QCircuit:
     return c
 
 
-@pytest.mark.parametrize("window", ["1", "16"])
+@pytest.mark.parametrize("window", ["1", "32"])
 @pytest.mark.parametrize("trial", [0, 1])
 def test_observable_surface_parity_vs_dense_oracle(window, trial,
                                                    monkeypatch):

@@ -75,10 +75,11 @@ _STACKS = [
 
 
 # ---------------------------------------------------------------------------
-# parity matrix: vocabulary stream, kernel ON, windows 1 and 16
+# parity matrix: vocabulary stream, kernel ON, windows 1, 16 and 32
+# (per gate, PR 5's bound, the bound since PR 46)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("window", [1, 16])
+@pytest.mark.parametrize("window", [1, 16, 32])
 @pytest.mark.parametrize("name,kw,floor", _STACKS,
                          ids=[s[0] for s in _STACKS])
 def test_kernel_parity_matrix(name, kw, floor, window, monkeypatch):
@@ -92,7 +93,7 @@ def test_kernel_parity_matrix(name, kw, floor, window, monkeypatch):
         getattr(o, op)(*args)
         getattr(s, op)(*args)
     assert _fidelity(s.GetQuantumState(), o.GetQuantumState()) > floor
-    if window == 16 and name in ("tpu", "pager"):
+    if window > 1 and name in ("tpu", "pager"):
         # the window really flushed through the kernel, not a fallback
         c = tele.snapshot(include_events=False)["counters"]
         assert c.get("fuse.kernel.windows", 0) >= 1, c
@@ -149,7 +150,7 @@ def test_fuzz_vocabulary_kernel_on(name, kw, floor, ptol, trial,
                          ids=["tpu", "pager"])
 def test_detect_and_repair_through_kernel_flush(stack, kw, monkeypatch):
     monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
-    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "16")
+    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "32")
     tele.enable()
     res.enable()
     o = QEngineCPU(N, rng=QrackRandom(3), rand_global_phase=False)
@@ -202,7 +203,7 @@ def test_pager_shrink_midwindow_kernel_on(monkeypatch):
     the mesh, the job finishes degraded, and the final state matches the
     oracle — the shrunk layout recompiles its own kernel programs."""
     monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
-    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "16")
+    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "32")
     tele.enable()
     res.enable()
     q = create_quantum_interface("pager", N, n_pages=4, rng=QrackRandom(3),
@@ -264,12 +265,14 @@ def test_kernel_off_is_pr5_xla_path_byte_for_byte(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# telemetry contract: a 16-gate diagonal window pays ONE HBM sweep
+# telemetry contract: a full window of in-tile gates pays ONE HBM sweep,
+# at PR 5's bound of 16 and at the 32 it is since PR 46
 # ---------------------------------------------------------------------------
 
-def test_sixteen_gate_window_records_one_sweep(monkeypatch):
+@pytest.mark.parametrize("gates", [16, 32])
+def test_full_window_records_one_sweep(gates, monkeypatch):
     monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
-    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "16")
+    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", str(gates))
     tele.enable()
     eng = QEngineTPU(N, rng=QrackRandom(8), rand_global_phase=False)
     for q in range(N):                     # amplitude everywhere first
@@ -277,17 +280,17 @@ def test_sixteen_gate_window_records_one_sweep(monkeypatch):
     eng.Prob(0)                            # flush the H window out of the way
     tele.reset()
     tele.enable()
-    # a 16-gate CNOT ladder: each gate's control is the previous gate's
+    # a CNOT ladder: each gate's control is the previous gate's
     # target, so nothing commutes past anything and no merge fires —
     # all in-tile inverts, ONE planned segment
-    for j in range(16):
+    for j in range(gates):
         t = j % N
         eng.CNOT(t, (t + 1) % N)
     eng.Prob(0)
     c = tele.snapshot(include_events=False)["counters"]
     assert c.get("fuse.kernel.windows", 0) == 1, c
-    assert c.get("fuse.kernel.ops", 0) == 16, c
-    assert c.get("fuse.kernel.sweeps", 0) == 1, c   # one HBM pass, 16 gates
+    assert c.get("fuse.kernel.ops", 0) == gates, c
+    assert c.get("fuse.kernel.sweeps", 0) == 1, c   # one HBM pass, a window
     # the XLA chain would have paid ~one sweep per op
     assert c.get("fuse.xla.windows", 0) == 0
 
@@ -356,6 +359,30 @@ def test_the_reasons_that_keep_the_chain(lower, mode, backend, targets,
     if mode is not None:
         monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", mode)
     assert lower(24, _gen_structure(targets), backend=backend) == (None, reason)
+
+
+@pytest.mark.parametrize("lower,kind,ops,fits", [
+    (fu.kernel_lowering, "u4", 32, True), (fu.kernel_lowering, "u4", 47, True),
+    (fu.kernel_lowering, "u4", 48, False), (fu.kernel_lowering, "u4", 64, False),
+    (fu.kernel_lowering, "gen", 64, True), (fu.kernel_lowering, "gen", 256, False),
+    # the pager queues no two-qubit op
+    (fu.sharded_kernel_lowering, "gen", 64, True),
+    (fu.sharded_kernel_lowering, "gen", 256, False)],
+    ids=lambda v: getattr(v, "__name__", str(v)))
+def test_a_window_too_wide_for_smem_keeps_the_chain(lower, kind, ops, fits):
+    """A window's operands reach a launch as two ``(N, 1)`` columns in
+    SMEM, 512 bytes an entry: the default bound's widest window (32
+    ``u4``, 1024 rows) is a kernel window, and one that only a
+    ``QRACK_TPU_FUSE_WINDOW`` above 32 builds takes the chain (reason
+    ``smem_operands``) before the chip's compiler refuses it
+    (``tests/test_chip_compile.py`` holds the refusal at 64 ``u4``).
+    The interpreter has no SMEM."""
+    structure = tuple((kind, (j % 20, 20 + j % 4) if kind == "u4" else j % 24,
+                       False) for j in range(ops))
+    plan, why = lower(24, structure, backend="tpu")
+    assert (why is None) == fits
+    assert why is None or (plan, why) == (None, "smem_operands")
+    assert fu.kernel_lowering(24, structure, backend="cpu")[1] == "cpu_backend"
 
 
 # (width, block_pow, targets): every target at or above block_pow; the
@@ -559,35 +586,39 @@ def lowered_counts(ops, bp, split_at=None):
 
 
 @pytest.mark.parametrize("family,sweeps,carry_ops,diag_runs,stretches", [
-    ("qft", 37, 37, (47, 373, 119), (9, 10, 9, 11)),
-    ("tfim", 41, 17, (0, 0, 0), (4, 36, 6, 37)),
-    ("rcs", 102, 20, (0, 0, 0), (13, 68, 33, 58))])
+    ("qft", 24, 24, (36, 375, 119), (9, 9, 9, 10)),
+    ("tfim", 40, 16, (0, 0, 0), (4, 36, 6, 37)),
+    ("rcs", 51, 10, (0, 0, 0), (9, 32, 25, 28))])
 def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
                                      carry_ops, diag_runs, stretches):
     """What ``fuse.kernel.sweeps.dense`` reads in a traced run of each
-    cell: every planned kernel segment of an application at w28.  Of
-    TFIM's 36 cross-tile segments 12 carry an in-tile op behind the mix
-    (11 a ``diag``, one a window's 15 ``gen``), 12 are the controlled
-    ``inv`` alone, whose select is what the dense tile shortens, and 12
-    the last window's bare ``gen`` (its 13th, on qubit 15, is in-tile).
+    cell: every planned kernel segment of an application at w28, at the
+    fuser's bound of 32 ops a window (37 / 41 / 102 at the 16 it had
+    until PR 46).  Of TFIM's 36 cross-tile segments 12 carry an in-tile
+    op behind the mix (11 a ``diag``, one 15 ``gen``), 12 are the
+    controlled ``inv`` alone, whose select is what the dense tile
+    shortens, and 12 the last window's bare ``gen`` (its 13th, on qubit
+    15, is in-tile).  A random
+    circuit's 108 ops are all ``u4`` (every root composed into the
+    coupler behind it on the host): 48 lead a launch, 60 ride in 10.
 
     And what ``fuse.kernel.diag_runs`` / ``.diag_run.ops`` /
     ``.diag_run.tile_ops`` read there (PR 42): QFT's 378 ``cphase`` sit
-    in 47 runs of two or more but for five, and 119 of those in runs
+    in 36 runs of two or more but for three, and 119 of those in runs
     have both bits in the tile; no segment of a Trotter step or of a
     random circuit holds two diagonal ops in a row.
 
     And ``fuse.kernel.stretches`` / ``.stretch.ops`` / ``.stretch.passes``
     / ``.whole_tile_ops`` (PR 44): every in-tile op outside a run is in
-    a stretch: the random circuit's 60 ``u4`` and 66 ``gen`` in 20
-    launches, the Trotter step's 73 ops in 17 (11 of them a lone
-    ``diag`` behind a led ``inv``), QFT's 16 ``gen`` and 5 lone
-    ``cphase`` in 19.  The ops that take a partner by lane rotation (a
-    target on qubits 0 to 6) are applied on the whole tile with the
-    diagonal ops behind them, and so is a diagonal op that is its
-    segment's only op: 58 / 37 / 11 of them; the others chunk by chunk,
-    68 / 36 / 10, a pass where a chunk holds every op's partners (all
-    of QFT's) and more where it does not."""
+    a stretch: the random circuit's 60 ``u4`` in 10 launches, the
+    Trotter step's 73 ops in 16 (11 of them a lone ``diag`` behind a led
+    ``inv``), QFT's 16 ``gen`` and 3 lone ``cphase`` in 19.  The ops
+    that take a partner by lane rotation (a target on qubits 0 to 6) are
+    applied on the whole tile with the diagonal ops behind them, and so
+    is a diagonal op that is its segment's only op: 28 / 37 / 10 of
+    them; the others chunk by chunk, 32 / 36 / 9, a pass where a chunk
+    holds every op's partners (all of QFT's) and more where it does
+    not."""
     dense = with_ops = 0
     runs, found = (0, 0, 0), (0, 0, 0, 0)
     for w in benchmark_plans(family):
@@ -615,28 +646,36 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
                          ids=["tfim_w30.pager4_noremap", "tfim_w30.pager4"])
 def test_paged_cells_hold_no_diag_run(kwargs):
     """The per-page kernel runs of the paged Trotter step at w30, on the
-    fixed placement and on the pager's own through its settled steps:
-    no run of two diagonal ops in any segment, and every in-tile op in
-    a stretch: 36 of a step's 75 applied chunk by chunk in 6 passes,
-    the 25 on qubits 0 to 6 and the 14 lone ``diag`` on the whole
-    tile."""
+    fixed placement (four windows a step at the bound of 32) and on the
+    pager's own through its settled steps (eleven: a gate that needs a
+    prologue heads its window; nine in the first step): no run of two
+    diagonal ops in any segment, and every in-tile op in a stretch: 36
+    of a step's 75 applied chunk by chunk in 6 passes (5 in 3 stretches
+    on the pager's own placement, whose first 75 ops are two windows of
+    32 and one of 11), the 25 on qubits 0 to 6 and the 14 lone ``diag``
+    on the whole tile."""
     from helpers import issue, plan_only_pager, trotter_step_gates
 
     q = plan_only_pager(30, **kwargs)
-    for _ in range(6):
+    for step in range(6):
         q.windows.clear()
         issue(q, trotter_step_gates(30))
         q.GetAmplitude(0)
-        assert len(q.windows) == 8
+        assert len(q.windows) == (4 if kwargs else 9 if step == 0 else 11)
         stretches = (0, 0, 0, 0)
         for w in q.windows:
+            if w.structure is None:  # a lone RX: the shared one-op program
+                continue
             plan, _ = fu.sharded_kernel_lowering(q.local_bits, w.structure,
                                                  backend="tpu")
             in_runs, in_stretches = lowered_counts(
                 w.tops, plan["block_pow"], split_at=q.local_bits)
             assert in_runs == (0, 0, 0)
             stretches = tuple(a + b for a, b in zip(stretches, in_stretches))
-        assert stretches == (4, 36, 6, 39)
+        if kwargs:
+            assert stretches == (4, 36, 6, 39)
+        else:
+            assert stretches == (3, 36, 5, 39)
 
 
 # ---------------------------------------------------------------------------
@@ -1586,21 +1625,22 @@ def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
     behind its lead lowers to the launch it has with the chunk path
     taken out, and that is the parent's: its orbit scratch, the two
     ``pl.when`` of its grid (read in, compute out) and no loop.  So
-    does a segment whose ops all roll lanes (the Trotter step's first
-    window) or whose one op is diagonal (the step's eleven ``diag``
-    behind a led ``inv``): they stay on the whole tile's value.  Every
-    other segment
-    of the two families holds its value in one more scratch tile and
-    applies the ops that roll no lane in one rolled loop a pass
-    (``stretch_passes``); no segment here holds a run, so those loops
-    are all its loops."""
+    does a segment whose ops all roll lanes (none here since the
+    Trotter step's first window holds 32 ops, on qubits 1 to 11; its 16
+    on qubits 1 to 6 were one until PR 46) or whose one op is diagonal
+    (the step's eleven ``diag`` behind a led ``inv`` and the one that
+    opens its third window): they stay on the whole tile's value.
+    Every other segment of the two families holds its value in one more
+    scratch tile and applies the ops that roll no lane in one rolled
+    loop a pass (``stretch_passes``); no segment here holds a run, so
+    those loops are all its loops."""
     import jax
     import jax.numpy as jnp
 
     structures = list(dict.fromkeys(
         w["structure"] for w in benchmark_plans(family)
         if w["path"] == "kernel"))
-    assert len(structures) == {"tfim": 7, "rcs": 12}[family]
+    assert len(structures) == {"tfim": 4, "rcs": 4}[family]
     planes = jax.ShapeDtypeStruct((2, 1 << 28), jnp.float32)
     bare = whole = chunked = 0
     for structure in structures:
@@ -1635,10 +1675,11 @@ def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
                            for slot in ops)
             else:
                 whole += 1
-    # the distinct structures' segments: 36 of the step's 41 launches
-    # are led, 24 of them bare; a sample's 14 windows are 12 structures
+    # the distinct structures' segments: 36 of the step's 40 launches
+    # are led, 24 of them bare; a sample's 4 windows are 4 structures,
+    # 48 of its 51 launches led and 41 of those bare
     assert (bare, whole, chunked) \
-        == {"tfim": (24, 13, 4), "rcs": (71, 6, 11)}[family]
+        == {"tfim": (24, 12, 4), "rcs": (41, 1, 9)}[family]
 
 
 def test_a_window_of_one_op_is_one_pass():

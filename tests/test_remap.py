@@ -50,7 +50,7 @@ def _op_skip_setbit(rng):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("collective", ["auto", "off"])
-@pytest.mark.parametrize("window", [1, 16])
+@pytest.mark.parametrize("window", [1, 32])
 @pytest.mark.parametrize("trial", range(3))
 def test_fuzz_parity_remap_on(trial, window, collective, monkeypatch):
     monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", str(window))
@@ -153,7 +153,7 @@ def test_shrink_mid_remapped_span_resets_table():
 # ---------------------------------------------------------------------------
 
 def _iqft_bytes(width, n_pages, remap_mode, monkeypatch):
-    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "16")
+    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "32")
     tele.reset()
     tele.enable()
     q = QPager(width, rng=QrackRandom(5), rand_global_phase=False,
@@ -289,7 +289,7 @@ def _iqft_qcircuit(width):
 
 
 def _measured_circuit_bytes(width, n_pages, collective, monkeypatch):
-    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "16")
+    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "32")
     circ = _iqft_qcircuit(width)
     o = QEngineCPU(width, rng=QrackRandom(3), rand_global_phase=False)
     o.SetPermutation(314)

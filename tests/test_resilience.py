@@ -520,7 +520,7 @@ _ELASTIC_CIRCUITS = {
 }
 
 
-@pytest.mark.parametrize("window", [1, 16])
+@pytest.mark.parametrize("window", [1, 32])
 @pytest.mark.parametrize("circ", sorted(_ELASTIC_CIRCUITS))
 def test_pager_shrink_midcircuit_matrix(circ, window, monkeypatch):
     """A flap mid-circuit (fused window mid-flight included) shrinks the

@@ -197,7 +197,7 @@ def test_knobs_from_env(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("window", ["1", "16"])
+@pytest.mark.parametrize("window", ["1", "32"])
 @pytest.mark.parametrize("trial", range(3))
 def test_routed_fuzz_vs_oracle(trial, window, monkeypatch):
     monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", window)

@@ -496,9 +496,11 @@ def test_flat_sweeps_are_not_counted_dense(small_tiles):
 
 def test_pager_dense_sweeps_are_its_kernel_segments(dense_tiles):
     """An exchange is a sweep and no launch: the pager's dense sweeps
-    are the planned segments of its local runs."""
+    are the planned segments of its local runs.  (The placement fixed:
+    the planner leaves no gate on a paged qubit.)"""
     tele.enable()
-    q = _pager()
+    q = QPager(W, rng=QrackRandom(7), rand_global_phase=False, n_pages=4,
+               remap="off")
     assert q.local_bits == 10
     _trotter(q)
     c = tele.snapshot()["counters"]

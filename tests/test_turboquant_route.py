@@ -108,7 +108,7 @@ def _fuzz_ops(rng):
             return name, args
 
 
-@pytest.mark.parametrize("window", [1, 16])
+@pytest.mark.parametrize("window", [1, 32])
 @pytest.mark.parametrize("trial", range(3))
 def test_routed_turboquant_fuzz_matches_oracle(monkeypatch, window, trial):
     monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", str(window))
@@ -156,12 +156,12 @@ def _sweep_count(window: int, monkeypatch) -> int:
 
 def test_fused_window_cuts_sweeps_at_least_4x(monkeypatch):
     per_gate = _sweep_count(1, monkeypatch)
-    fused = _sweep_count(16, monkeypatch)
+    fused = _sweep_count(32, monkeypatch)
     assert per_gate >= 4 * fused, (per_gate, fused)
 
 
 def test_fused_window_sweeps_saved_counter(monkeypatch):
-    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "16")
+    monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "32")
     tele.enable()
     tele.reset()
     eng = QEngineTurboQuant(N, rng=QrackRandom(2), rand_global_phase=False,
