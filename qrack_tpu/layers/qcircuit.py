@@ -25,6 +25,15 @@ def _is_unitary_2x2(m: np.ndarray, tol: float = 1e-9) -> bool:
     return bool(np.allclose(m.conj().T @ m, np.eye(2), atol=tol))
 
 
+def _is_phase_gate(m: np.ndarray, tol: float = 1e-9) -> bool:
+    """Diagonal with entries of modulus one.  A recorded measurement's
+    projector is diagonal too (lightcone/engine.py ForceM) and is not
+    one: the stacks factor a controlled diagonal by dividing its
+    entries (layers/qunit.py)."""
+    return (mat.is_phase(m) and abs(abs(m[0, 0]) - 1.0) <= tol
+            and abs(abs(m[1, 1]) - 1.0) <= tol)
+
+
 class QCircuitGate:
     __slots__ = ("target", "controls", "payloads")
 
@@ -46,14 +55,51 @@ class QCircuitGate:
     def qubits(self) -> Tuple[int, ...]:
         return (self.target,) + self.controls
 
-    def can_merge(self, other: "QCircuitGate") -> bool:
-        return (self.target == other.target and self.controls == other.controls)
+    def can_merge(self, later: "QCircuitGate") -> bool:
+        """Does ``later`` compose onto this gate?  Same target, and the
+        same controls, or (reference: include/qcircuit.hpp CanCombine /
+        AddControl) ``later``'s controls a subset of this gate's where
+        ``later`` is a phase gate (_is_phase_gate) and the merged gate
+        holds no more payloads than the two hold together.  So the RZ
+        between a bond's two CNOTs composes onto the first (``{1: X}`` +
+        ``{0: RZ}`` -> ``{0: RZ, 1: RZ X}``, two for two) and the second
+        CNOT makes the bond the diagonal operator it is: two controlled
+        ``diag``.  A gate that is no phase gate keeps the rule of equal
+        controls (an RX behind a bond would become two controlled
+        general gates), a phase behind a Toffoli would become four ops
+        for two, and an earlier gate never takes controls from a later
+        one (a QFT's H ahead of its cphase would).  By the gates' qubits
+        and by whether a payload is a phase, nothing else."""
+        if self.target != later.target:
+            return False
+        if self.controls == later.controls:
+            return True
+        if not set(later.controls) < set(self.controls) \
+                or not all(_is_phase_gate(m) for m in later.payloads.values()):
+            return False
+        merged = set(self.payloads) | set(self._over_my_controls(later))
+        return len(merged) <= len(self.payloads) + len(later.payloads)
+
+    def _over_my_controls(self, later: "QCircuitGate") -> Dict[int, np.ndarray]:
+        """``later``'s payloads over this gate's control perms (the
+        reference's AddControl): each at every perm that agrees with its
+        own on ``later``'s controls."""
+        if later.controls == self.controls:
+            return later.payloads
+        where = [self.controls.index(c) for c in later.controls]
+        out = {}
+        for perm in range(1 << len(self.controls)):
+            own = sum(((perm >> at) & 1) << j for j, at in enumerate(where))
+            if own in later.payloads:
+                out[perm] = later.payloads[own]
+        return out
 
     def merge(self, later: "QCircuitGate") -> None:
         """Compose `later`'s payloads after self's (matrix product)."""
-        for perm in set(self.payloads) | set(later.payloads):
+        behind = self._over_my_controls(later)
+        for perm in set(self.payloads) | set(behind):
             a = self.payloads.get(perm, mat.I2)
-            b = later.payloads.get(perm, mat.I2)
+            b = behind.get(perm, mat.I2)
             self.payloads[perm] = b @ a
         # drop only removable payloads: exact identity always; identity up
         # to global phase only when uncontrolled (a controlled e^{i0}I is a

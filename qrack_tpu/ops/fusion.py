@@ -254,12 +254,88 @@ def _payload(kind: str, m) -> tuple:
     return (*m.real.ravel(), *m.imag.ravel())  # mtrx_planes, row-major
 
 
+def _whole_ket_groups(ops: Sequence[FusedOp]) -> List[range]:
+    """The stretches of ``ops`` that each act on all of the ket alike:
+    an uncontrolled op, or the ``2**k`` ops that lower_gates makes of
+    one gate with a payload for every value of its ``k`` controls (a
+    bond's two ``diag``).  A ``cphase`` packs one entry of its matrix
+    and a lone controlled payload acts on the share of the ket that
+    only the ket knows: neither is in a group."""
+    groups: List[range] = []
+    at = 0
+    while at < len(ops):
+        op = ops[at]
+        values = 1 << bin(op.cmask).count("1")
+        span = ops[at:at + values]
+        if len({o.cval for o in span}) == values and all(
+                o.kind != "cphase" and o.target == op.target
+                and o.cmask == op.cmask for o in span):
+            groups.append(range(at, at + values))
+            at += values
+        else:
+            at += 1
+    return groups
+
+
+def _norm_kept_float32(ops: Sequence[FusedOp], payloads: List) -> List:
+    """``payloads`` (per op its floats, _payload) as a float32 window
+    holds them.
+
+    A unitary rounded to float32 is none: RX(0.2) and RZ(0.2) round to
+    ``1 + 2.3e-8`` times a unitary, the same way every time, so a w28
+    Trotter step (55 of them) multiplies the squared norm by
+    ``1 + 1.26e-6`` and an observable read off the planes after ``s``
+    steps is off by ``s`` times that (PERF.md section 6, PR 47).  Where
+    ops act on all of the ket alike (_whole_ket_groups) and are unitary
+    that gain is known here, from the rounded floats alone.  Such a
+    group is rounded from its floats times ``gain ** -0.5``, ``gain``
+    the product over the window's groups so far, wherever that leaves
+    the product nearer 1 than plain rounding does: upstream's
+    ``1 / sqrt(runningNorm)`` on the next gate's matrix
+    (``QEngine::Apply2x2``), computed and not reduced, and never carried
+    past the window.  The scale is within a few float32 ulp of 1, so an
+    exact gate stays exact until the gain passes half an ulp.  Every
+    other op is rounded to nearest and counts nothing; an op's kind was
+    decided on the matrix it came with (lower_gates)."""
+    groups = _whole_ket_groups(ops)
+    members = [i for group in groups for i in group]
+    unitary = dict.fromkeys(members, False)
+    for dim in (2, 4):  # one product for all the 2x2, one for the 4x4
+        idx = [i for i in members if len(ops[i].m) == dim]
+        if idx:
+            ms = np.stack([np.asarray(ops[i].m) for i in idx])
+            off = np.einsum("nji,njk->nik", ms.conj(), ms) - np.eye(dim)
+            unitary.update(zip(idx, np.abs(off).max(axis=(1, 2)) <= 1e-9))
+    out = list(payloads)
+    gain = 1.0
+    for group in groups:
+        if not all(unitary[i] for i in group):
+            continue
+        exact = np.array([v for i in group for v in payloads[i]])
+        dim = sum(len(ops[i].m) for i in group)
+        vals = exact.astype(np.float32).astype(np.float64)
+        by = float(vals @ vals) / dim
+        if gain != 1.0:
+            kept = (exact * gain ** -0.5).astype(np.float32).astype(
+                np.float64)
+            kept_by = float(kept @ kept) / dim
+            if abs(gain * kept_by - 1.0) < abs(gain * by - 1.0):
+                vals, by = kept, kept_by
+        gain *= by
+        at = 0
+        for i in group:
+            out[i] = vals[at:at + len(payloads[i])]
+            at += len(payloads[i])
+    return out
+
+
 def pack_operands(ops: Sequence[FusedOp], dtype, split_at: int = None):
     """``(iv, fv)``: the window's int32 masks and its float payloads in
     the planes' ``dtype``, numpy columns laid out by
     ``pallas_kernels._operand_slots``.  ``split_at`` gives the sharded
     layout: masks split at that many local bits, 'inv' folded into
-    'gen' (sharded_structure_of).  Host work only."""
+    'gen' (sharded_structure_of).  Float32 payloads are rounded with
+    the window's norm kept (_norm_kept_float32).  Host work only."""
     from . import pallas_kernels as pk
     from .sharded import split_masks
 
@@ -268,8 +344,12 @@ def pack_operands(ops: Sequence[FusedOp], dtype, split_at: int = None):
     slots, nf, ni = pk._operand_slots(structure, split)
     floats = [0.0] * nf
     ints = [0] * ni
-    for op, (kind, _, _), (f, i) in zip(ops, structure, slots):
-        vals = _payload(kind, np.asarray(op.m))
+    payloads = [_payload(kind, np.asarray(op.m))
+                for op, (kind, _, _) in zip(ops, structure)]
+    if np.dtype(dtype) == np.float32:
+        payloads = _norm_kept_float32(ops, payloads)
+    for op, vals, (kind, _, _), (f, i) in zip(ops, payloads, structure,
+                                             slots):
         floats[f:f + len(vals)] = vals
         if not op.cmask:
             continue
@@ -1127,13 +1207,19 @@ class GateStreamFuser:
 
     def _append_merge(self, gate) -> None:
         # QCircuit.AppendGate's peephole: walk back past disjoint-qubit
-        # gates; compose onto a same-target/controls partner, or onto a
-        # two-qubit gate whose pair holds every qubit of this one
+        # gates; compose onto a same-target partner (of the same controls,
+        # or a diagonal gate onto one whose controls hold its own), or
+        # onto a two-qubit gate whose pair holds every qubit of this one
         i = len(self.gates) - 1
         gset = set(gate.qubits())
         while i >= 0:
             g = self.gates[i]
             if g.can_merge(gate):
+                if _tele._ENABLED and not isinstance(g, TwoQubitGate) \
+                        and len(g.controls) > len(gate.controls):
+                    # onto a partner with a larger control set (a bond's
+                    # RZ onto its first CNOT: QCircuitGate.can_merge)
+                    _tele.inc(f"fuse.{self.engine._tele_name}.merged.nested")
                 g.merge(gate)
                 if g.is_identity():
                     del self.gates[i]

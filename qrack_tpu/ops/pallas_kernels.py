@@ -307,9 +307,15 @@ def tile_cphase(v, lidx, hi_id, clo, chi, fre, fim):
 def tile_diag(v, lidx, hi_id, target, L,
               d0re, d0im, d1re, d1im, lm, lv, gm, gv):
     """Diagonal on one (2, 2^L) tile, target anywhere: in-tile targets
-    select per element, higher targets per tile via hi_id's bit."""
-    tmask_lo = (1 << target) if target < L else 0
-    tb_hi = 0 if target < L else (1 << (target - L))
+    select per element, higher targets per tile via hi_id's bit.  The
+    target is a Python int or, for the ops of a run's group, which are
+    one traced body (_apply_run), a traced int32."""
+    if isinstance(target, int):
+        tmask_lo = (1 << target) if target < L else 0
+        tb_hi = 0 if target < L else (1 << (target - L))
+    else:
+        tbit = jnp.int32(1) << target
+        tmask_lo, tb_hi = tbit & ((1 << L) - 1), tbit >> L
     hi_bit = (hi_id & tb_hi) != 0
     bit = ((lidx & tmask_lo) != 0) | hi_bit
     fre = jnp.where(bit, d1re, d0re)
@@ -527,20 +533,23 @@ def _slot_masks(slot, slots, iv_ref, at=0):
 
 def _diag_operands(group, at, slots, iv_ref, fv_ref, bp):
     """The arguments of tile_cphase / tile_diag after ``(v, lidx,
-    hi_id)`` for the ``at``-th slot of ``group``: consecutive cphase
-    slots alike in having controls, whose operands lie a fixed stride
-    apart in both columns, so that ``at`` may be a loop's traced index
-    (_apply_run), or one diag slot (tile_diag wants its target static).
-    Masks are runtime scalars; the lo/hi split happens here (dense
-    widths are int32-safe: engines/tpu.py MAX_DENSE_QB)."""
-    idx, kind, target, _ = group[0]
+    hi_id)`` for the ``at``-th slot of ``group``: consecutive slots of
+    one kind alike in having controls (_run_groups), whose operands lie
+    a fixed stride apart in both columns, so that ``at`` may be a loop's
+    traced index (_apply_run); the target is then picked at that index
+    too (_static_pick), and is the slot's own Python int where ``at``
+    is one.  Masks are runtime scalars; the lo/hi split happens here
+    (dense widths are int32-safe: engines/tpu.py MAX_DENSE_QB)."""
+    idx, kind, _, _ = group[0]
     foff = slots[idx][0] + at * _NFLOATS[kind]
     lbits = (1 << bp) - 1
     cm, cv = _slot_masks(group[0], slots, iv_ref, at)
+    targets = [slot[2] for slot in group]
+    target = (targets[at] if isinstance(at, int)
+              else _static_pick(targets, at))
     if kind == "cphase":
-        targets = [slot[2] for slot in group]
-        tbit = (jnp.int32(1 << targets[at]) if isinstance(at, int)
-                else jnp.int32(1) << _static_pick(targets, at))
+        tbit = (jnp.int32(1 << target) if isinstance(at, int)
+                else jnp.int32(1) << target)
         comb = tbit | cm
         return (comb & lbits, comb >> bp,
                 fv_ref[foff, 0], fv_ref[foff + 1, 0])
@@ -862,17 +871,32 @@ def _chunk_to(ref, at, pieces, v) -> None:
             v, j * size, (j + 1) * size, axis=1)
 
 
+# the fewest consecutive diag ops that are traced as one loop.  The
+# per-page QFT's cphase onto the two page bits are diag in pairs, so a
+# loop from two on would leave none of its window programs the text they
+# were; and a loop's op picks its target and reads its operands at a
+# traced index, some two dozen cycles a tile more than a body of its own
+# (one pair of runs of that cell at w31: +0.79 % as loops of two,
+# PERF.md section 6, PR 47).  What a pair's two bodies cost to trace,
+# every set-up has always paid
+DIAG_GROUP_MIN = 3
+
+
 def _run_groups(run) -> List[list]:
-    """A run's slots as _diag_operands takes them: consecutive cphase
-    slots alike in having controls together, a diag alone."""
+    """A run's slots as _diag_operands takes them: consecutive slots of
+    one kind (cphase, diag) alike in having controls together; diag
+    slots fewer than DIAG_GROUP_MIN each alone."""
     groups = []
     for slot in run:
         last = groups[-1][-1] if groups else None
-        if last and slot[1] == last[1] == "cphase" and slot[3] == last[3]:
+        if last and slot[1] == last[1] and slot[3] == last[3]:
             groups[-1].append(slot)
         else:
             groups.append([slot])
-    return groups
+    return [part for group in groups for part in (
+        [[slot] for slot in group]
+        if group[0][1] == "diag" and len(group) < DIAG_GROUP_MIN
+        else [group])]
 
 
 def _apply_run(v, blk, run, slots, iv_ref, fv_ref, bp, first, run_ref, table):
@@ -914,7 +938,7 @@ def _apply_run(v, blk, run, slots, iv_ref, fv_ref, bp, first, run_ref, table):
             if kind == "cphase":
                 high, admits = args[1], (blk & args[1]) == args[1]   # chi
             else:
-                high = jnp.int32(group[0][2] >= bp) | args[-2] | args[-1]
+                high = jnp.int32(args[0] >= bp) | args[-2] | args[-1]
                 admits = (blk & args[-2]) == args[-1]                # gm, gv
             # on the table an op has no high part: any tile id admits it
             pl.when(jnp.where(high == 0, first, admits))(functools.partial(

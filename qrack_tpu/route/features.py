@@ -210,8 +210,11 @@ def extract_features(circuit, width: int,
             if qubits[0] < width and q < width:
                 uf.union(qubits[0], q)
         lo, hi = qubits[0], qubits[-1]
+        # one crossing a payload, as gate_count above: a bond merged
+        # into one gate of two controlled payloads (QCircuitGate.
+        # can_merge) is still two entangling dispatches across the cut
         for cut in range(lo, min(hi, len(crossings))):
-            crossings[cut] += 1
+            crossings[cut] += len(gate.payloads)
     f.depth = int(circuit.GetDepth()) if hasattr(circuit, "GetDepth") else 0
     f.distinct_pairs = len(pairs)
     f.max_degree = max(degree.values(), default=0)

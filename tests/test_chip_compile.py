@@ -115,9 +115,11 @@ def _in_place(compiled, n=W):
             and compiled.memory_analysis().temp_size_in_bytes <= SLACK)
 
 
-# the Trotter step's last window at w28 (RX on 15-27: 13 planned sweeps
-# for 13 ops, 12 of them cross-tile) and the paged step's at 2^28 pages
-# (RX on 25-29: 28 and 29 are paged); PR 35 sent both to the kernel
+# the tail of the Trotter step's last window at w28 (RX on 15-27: 13
+# planned sweeps for 13 ops, 12 of them cross-tile) and of the paged
+# step's at 2^28 pages (RX on 25-29: 28 and 29 are paged); PR 35 sent
+# both to the kernel, and they were whole windows until a bond became
+# one gate (PR 47: the windows now begin at the RX on 5 and on 3)
 TFIM_LAST = tuple(("gen", t, False) for t in range(15, 28))
 TFIM_LAST_PAGED = tuple(("gen", t, False) for t in range(25, 30))
 
@@ -257,43 +259,68 @@ def test_tfim_last_window_sharded_kernel(topo):
         <= 5 * KET_BYTES // 2 + SLACK
 
 
-def test_tfim_third_window_sharded_kernel(topo):
-    """The paged Trotter step's third window at w30 on the fixed
-    placement (its sixth of 16 ops until PR 46, with the fifth's led
-    launches ahead of it now): launches between the step's four
-    controlled exchanges (the CNOTs onto 28 and 29), the paged program
-    the compiler counts the most memory for.  3.63 pages of temporaries
-    before PR 39, 4.13 with the alias alone (the launches' results chain
-    through the donated page's buffer, which took one of the exchange's
-    halves before), 3.06 since the exchange keeps no (a, b) halves."""
+# the paged Trotter step's windows at w30 since a bond is one gate of two
+# controlled ``diag`` (PR 47): the fixed placement's two (58 ``diag`` and
+# the RX on 0-2 in one launch and no exchange: the bonds onto 28 and 29
+# are phases by page; then the RX on 3-29, 13 launches and the step's
+# two exchanges) and the settled planner step's first (50 ``diag``).
+# name -> (pager's keywords, window, ops, launches, exchanges?, pages of
+# temporaries in eighths)
+TFIM_PAGED_WINDOWS = {
+    "fixed-61op": ({"remap": "off"}, 0, 61, 1, False, 0),
+    "fixed-27op": ({"remap": "off"}, 1, 27, 13, True, 20),
+    "planner-50op": ({}, 0, 50, 1, False, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TFIM_PAGED_WINDOWS))
+def test_tfim_step_window_sharded_kernel(topo, name):
+    """Each compiles in seconds under ``shard_map`` at a 2 GiB page.  A
+    window of ``diag`` alone sweeps the donated page in place: no
+    collective, nothing of the page's size beside it (the fixed
+    placement's third window of 32, launches between the four controlled
+    exchanges of the CNOTs onto 28 and 29, held 3.06 pages of
+    temporaries until those CNOTs went).  The window with the step's two
+    exchanges keeps their two and a half pages."""
     from helpers import issue, plan_only_pager, trotter_step_gates
 
-    q = plan_only_pager(W + 2, remap="off")
-    issue(q, trotter_step_gates(W + 2))
-    q.GetAmplitude(0)
-    structure = q.windows[2].structure
-    controlled = [op for op in structure if op[1] >= W and op[0] == "gen"]
-    assert len(q.windows) == 4 and len(controlled) == 4
-    plan, why = fu.sharded_kernel_lowering(W, structure, backend="tpu")
-    assert why is None and plan["sweeps"] == 19
-    compiled = _compile_sharded(topo, structure, W + 2)
-    assert _launches(compiled) == 15
-    assert compiled.memory_analysis().temp_size_in_bytes \
-        <= 13 * KET_BYTES // 4 + SLACK
+    kwargs, index, ops, launches, exchanges, eighths = TFIM_PAGED_WINDOWS[name]
+    q = plan_only_pager(W + 2, **kwargs)
+    for _ in range(2):  # the planner's table recurs from the second step
+        q.windows.clear()
+        issue(q, trotter_step_gates(W + 2))
+        q.GetAmplitude(0)
+    window = q.windows[index]
+    assert len(window.structure) == ops and not window.swaps
+    assert exchanges == any(op[0] == "gen" and op[1] >= W
+                            for op in window.structure)
+    plan, why = fu.sharded_kernel_lowering(W, window.structure, backend="tpu")
+    assert why is None and plan["sweeps"] == launches + 2 * exchanges
+    t0 = time.perf_counter()
+    compiled = _compile_sharded(topo, window.structure, W + 2,
+                                exchanges=exchanges)
+    assert time.perf_counter() - t0 < 120
+    assert _launches(compiled) == launches
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == KET_BYTES
+    assert memory.temp_size_in_bytes <= eighths * KET_BYTES // 8 + SLACK
 
 
 # every window of the paged Trotter step at w30 that begins with a remap
 # prologue, on the pager's default placement.  A gate that needs one
 # heads its window (``GateStreamFuser._heads_a_window``, PR 46), so
 # these windows are short and their victims sit on the carrier bits:
-# step 0 brings 28 and 29 in ahead of the bond onto 28; from step 1 on
-# every step runs the same two programs twice each (a bond's three ops,
-# a lone RX), all of two pairs.  Beside them what other circuits make
+# step 0 brings 28 and 29 in ahead of the RX on 28; from step 1 on
+# every step runs the same program twice (a lone RX behind two pairs:
+# the bonds onto the page bits are diagonal since PR 47 and need none).
+# A step through ``QCircuit.Run`` primes the planner's lookahead, so
+# the count alone ends a window: its settled first window is 61 ops
+# behind two pairs.  Beside them what other circuits make
 # the planner emit: one pair whose victim sits on a sublane bit, two
 # pairs with victims on lane bits (shuffles of the whole page before and
 # after the exchange), one pair and two pairs on the carrier bits
 # themselves (no shuffle at all)
-REMAP_PROGRAMS = ("step0-bond", "settled-bond", "settled-rx", "k1-sublane",
+REMAP_PROGRAMS = ("step0-rx", "settled-rx", "qcircuit-61op", "k1-sublane",
                   "k2-lanes", "k1-carrier", "k2-carriers")
 PAGED_W = W + 2
 
@@ -315,12 +342,22 @@ def remap_programs():
     # the settled step: every step from the second on plans the same,
     # table and all
     assert steps[1] == steps[2] == steps[3]
-    settled = sorted({w for w in steps[1] if w[1]}, key=lambda w: -len(w[0]))
-    first = [w for w in steps[0] if w[1] and w not in steps[1]]
-    assert [len(w[0]) for w in settled] == [3, 1] and len(first) == 1
-    assert sum(bool(w[1]) for w in steps[1]) == 4
-    out = {"step0-bond": first[0], "settled-bond": settled[0],
-           "settled-rx": settled[1]}
+    settled, = {w for w in steps[1] if w[1]}
+    first, = [w for w in steps[0] if w[1]]
+    assert len(settled[0]) == len(first[0]) == 1 and first != settled
+    assert sum(bool(w[1]) for w in steps[1]) == 2
+    from qrack_tpu.models.algorithms import trotter_qcircuit
+
+    circuit = trotter_qcircuit(PAGED_W, steps=1)
+    q = plan_only_pager(PAGED_W)
+    for _ in range(3):
+        q.windows.clear()
+        circuit.Run(q)
+        q.GetAmplitude(0)
+    primed = q.windows[0]
+    assert len(primed.structure) == 61 and len(primed.swaps) == 2
+    out = {"step0-rx": first, "settled-rx": settled,
+           "qcircuit-61op": (primed.structure, primed.swaps)}
     local = tuple(("gen", t, False) for t in (3, 12, 20, 27))
     out["k1-sublane"] = (local, ((7, 29),))
     out["k2-lanes"] = (local, ((0, 28), (1, 29)))
@@ -416,13 +453,14 @@ def test_two_qubit_window_kernel(one_chip, window):
 
 # the window programs of one application of the three dense cells at
 # w28, by the families' own gate lists: QFT's 13 windows (7, 4, 3
-# launches, then ten of one), the Trotter step's 4 (1, 14, 12, 13) and a
-# random circuit's 4 (13, 15, 16, 7), every one a program of its own
-# and every one compiled (26, 7 and 12 structures in 14 windows at the
-# bound of 16, of which 27 were)
+# launches, then ten of one), the Trotter step's 2 (1, 13: 59 ops, 54
+# of them the bonds' ``diag``, and 23; 4 of 1, 14, 12, 13 until PR 47)
+# and a random circuit's 4 (13, 15, 16, 7), every one a program of its
+# own and every one compiled (26, 7 and 12 structures in 14 windows at
+# the bound of 16, of which 27 were)
 CELL_WINDOWS = [("qft", i) for i in range(13)] \
-    + [("tfim", i) for i in range(4)] + [("rcs", i) for i in range(4)]
-CELL_SWEEPS = {"qft": [7, 4, 3] + [1] * 10, "tfim": [1, 14, 12, 13],
+    + [("tfim", i) for i in range(2)] + [("rcs", i) for i in range(4)]
+CELL_SWEEPS = {"qft": [7, 4, 3] + [1] * 10, "tfim": [1, 13],
                "rcs": [13, 15, 16, 7]}
 
 
@@ -554,16 +592,17 @@ def test_qft_run_window_kernel(one_chip, cell_windows, name):
 # u4 in the tile in three passes, then two cycles' couplers leading four
 # tiles, the sixth with seven u4 behind it and the last with five: one
 # more scratch tile beside the orbits) and its last (six u4 in the tile,
-# six bare leads), the Trotter step's first window (inv and diag
-# alternating on qubits 1-11: one pass behind the ops that roll lanes)
-# and its third, whose last inv-led launch has 15 gen riding.  Each
-# launch: (scratch operands, passes of its stretch)
+# six bare leads), the Trotter step's first window (its run of 54 diag,
+# then the RX on qubits 0-4, which roll lanes: the run's scratch and no
+# pass) and its second (the RX on 5-15 in two passes behind the two that
+# roll lanes, then twelve bare leads).  Each launch: (scratch operands,
+# passes of its stretch)
 STRETCH_WINDOWS = {
     "rcs-w1": ("rcs", 0, [(1, 3)] + [(1, 0)] * 5 + [(2, 3)] + [(1, 0)] * 5
                + [(2, 1)]),
     "rcs-w4": ("rcs", 3, [(1, 3)] + [(1, 0)] * 6),
-    "tfim-32op": ("tfim", 0, [(1, 1)]),
-    "tfim-inv-led-15gen": ("tfim", 2, [(0, 0)] + [(1, 0)] * 10 + [(2, 2)]),
+    "tfim-54diag-5gen": ("tfim", 0, [(1, 0)]),
+    "tfim-23gen": ("tfim", 1, [(1, 2)] + [(1, 0)] * 12),
 }
 
 
@@ -620,6 +659,43 @@ def test_stretch_window_kernel(one_chip, cell_windows, name):
     assert memory.temp_size_in_bytes == 0
     assert memory.alias_size_in_bytes == KET_BYTES
     assert _in_place(compiled)
+
+
+def test_a_run_of_diag_is_one_traced_body():
+    """The Trotter step's first window is a run of 54 controlled
+    ``diag`` (PR 47).  The ops of a run's group are one traced body in a
+    loop over their operands (``pallas_kernels._run_groups``): its
+    launch traces to the equations of a run of three and the words of
+    the target pick (``_static_pick``: four targets a word, four
+    equations a word), where a body an op would be some fifty equations
+    each, 0.2 s an op to trace and lower in a benchmark run and a second
+    under ``shard_map`` (PERF.md section 6, PR 42).  A pair keeps its two
+    bodies (``DIAG_GROUP_MIN``: a loop's op is dearer on the device, and
+    the per-page QFT's ``diag`` come in pairs).  Nothing here needs the
+    chip's compiler."""
+    from test_pallas_window import launches_of
+
+    def count(jaxpr):
+        return sum(1 + sum(count(sub)
+                           for sub in jax.core.jaxprs_in_params(eqn.params))
+                   for eqn in jaxpr.eqns)
+
+    def equations(ops):
+        structure = tuple(("diag", 1 + j // 2, True) for j in range(ops))
+        assert [len(g) for g in pk._run_groups(
+            pk.plan_window(structure, pk.DEFAULT_BLOCK_POW)[0]["ops"])] \
+            == ([ops] if ops >= pk.DIAG_GROUP_MIN else [1] * ops)
+        args = [jax.ShapeDtypeStruct((2, 1 << W), jnp.float32)] + [
+            jax.ShapeDtypeStruct(np.shape(o), o.dtype)
+            for o in fu.pack_operands(_ops(structure), jnp.float32)]
+        launch, = launches_of(pk.make_window_fn(W, structure), *args)
+        return count(launch.params["jaxpr"])
+
+    alone, two, three, step = (equations(k) for k in (1, 2, 3, 54))
+    print(f"equations: one diag {alone}, a run of 2 {two}, of 3 {three}, "
+          f"of 54 {step}")
+    assert step - three == 4 * (-(-54 // 4) - 1)
+    assert step < 4 * alone and three < two
 
 
 # -- w30: the widest ket one chip holds (PR 43) --------------------------------

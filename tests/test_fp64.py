@@ -86,7 +86,9 @@ for eng in (f32, f64, ora):
 ref = ora.GetQuantumState()
 e32 = np.max(np.abs(np.asarray(f32.GetQuantumState()) - ref))
 e64 = np.max(np.abs(np.asarray(f64.GetQuantumState()) - ref))
-assert e32 > 1e-6, e32          # f32 demonstrably degraded at this depth
+# f32 demonstrably degraded at this depth (over 1e-6 while a window's
+# operands let the norm drift; 9.6e-7 since PR 47 keeps it a window)
+assert e32 > 5e-7, e32
 assert e64 < 1e-11, e64         # f64 stays at oracle precision
 assert e64 * 100 < e32, (e32, e64)
 print('DEEP_OK', e32, e64)

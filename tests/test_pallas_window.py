@@ -587,36 +587,37 @@ def lowered_counts(ops, bp, split_at=None):
 
 @pytest.mark.parametrize("family,sweeps,carry_ops,diag_runs,stretches", [
     ("qft", 24, 24, (36, 375, 119), (9, 9, 9, 10)),
-    ("tfim", 40, 16, (0, 0, 0), (4, 36, 6, 37)),
+    ("tfim", 14, 2, (1, 54, 30), (1, 9, 2, 7)),
     ("rcs", 51, 10, (0, 0, 0), (9, 32, 25, 28))])
 def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
                                      carry_ops, diag_runs, stretches):
     """What ``fuse.kernel.sweeps.dense`` reads in a traced run of each
     cell: every planned kernel segment of an application at w28, at the
     fuser's bound of 32 ops a window (37 / 41 / 102 at the 16 it had
-    until PR 46).  Of TFIM's 36 cross-tile segments 12 carry an in-tile
-    op behind the mix (11 a ``diag``, one 15 ``gen``), 12 are the
-    controlled ``inv`` alone, whose select is what the dense tile
-    shortens, and 12 the last window's bare ``gen`` (its 13th, on qubit
-    15, is in-tile).  A random
+    until PR 46).  A Trotter step is 14 since a bond is one gate of two
+    controlled ``diag`` (PR 47; 40 while its CNOTs led 24 launches): its
+    first window's 54 ``diag`` and the RX on qubits 0 to 4 in one unled
+    launch, then the RX on 5 to 15 in one and the bare ``gen`` on 16 to
+    27, twelve led launches.  A random
     circuit's 108 ops are all ``u4`` (every root composed into the
     coupler behind it on the host): 48 lead a launch, 60 ride in 10.
 
     And what ``fuse.kernel.diag_runs`` / ``.diag_run.ops`` /
     ``.diag_run.tile_ops`` read there (PR 42): QFT's 378 ``cphase`` sit
     in 36 runs of two or more but for three, and 119 of those in runs
-    have both bits in the tile; no segment of a Trotter step or of a
-    random circuit holds two diagonal ops in a row.
+    have both bits in the tile; the Trotter step's 54 ``diag`` are one
+    run, the 30 of the bonds up to qubit 15 in its phase tile; no
+    segment of a random circuit holds two diagonal ops in a row.
 
     And ``fuse.kernel.stretches`` / ``.stretch.ops`` / ``.stretch.passes``
     / ``.whole_tile_ops`` (PR 44): every in-tile op outside a run is in
     a stretch: the random circuit's 60 ``u4`` in 10 launches, the
-    Trotter step's 73 ops in 16 (11 of them a lone ``diag`` behind a led
-    ``inv``), QFT's 16 ``gen`` and 3 lone ``cphase`` in 19.  The ops
+    Trotter step's 16 in-tile RX in 2, QFT's 16 ``gen`` and 3 lone
+    ``cphase`` in 19.  The ops
     that take a partner by lane rotation (a target on qubits 0 to 6) are
     applied on the whole tile with the diagonal ops behind them, and so
-    is a diagonal op that is its segment's only op: 28 / 37 / 10 of
-    them; the others chunk by chunk, 32 / 36 / 9, a pass where a chunk
+    is a diagonal op that is its segment's only op: 28 / 7 / 10 of
+    them; the others chunk by chunk, 32 / 9 / 9, a pass where a chunk
     holds every op's partners (all of QFT's) and more where it does
     not."""
     dense = with_ops = 0
@@ -644,16 +645,17 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
 
 @pytest.mark.parametrize("kwargs", [{"remap": "off"}, {}],
                          ids=["tfim_w30.pager4_noremap", "tfim_w30.pager4"])
-def test_paged_cells_hold_no_diag_run(kwargs):
+def test_paged_cells_hold_their_bonds_in_runs(kwargs):
     """The per-page kernel runs of the paged Trotter step at w30, on the
-    fixed placement (four windows a step at the bound of 32) and on the
-    pager's own through its settled steps (eleven: a gate that needs a
-    prologue heads its window; nine in the first step): no run of two
-    diagonal ops in any segment, and every in-tile op in a stretch: 36
-    of a step's 75 applied chunk by chunk in 6 passes (5 in 3 stretches
-    on the pager's own placement, whose first 75 ops are two windows of
-    32 and one of 11), the 25 on qubits 0 to 6 and the 14 lone ``diag``
-    on the whole tile."""
+    fixed placement (two windows a step, 61 and 27 ops) and on the
+    pager's own through its settled steps (seven: a gate that needs a
+    prologue heads its window; five in the first step): a bond is one
+    gate of two controlled ``diag`` (PR 47), so the step's 58 ``diag``
+    sit in runs (one on the fixed placement; three where the first CNOT
+    of a bond onto a page bit still closes a window), 32 of them in a
+    phase tile; no run until then, every ``RZ`` between two CNOTs.  The
+    in-tile RX are the stretches: 9 applied chunk by chunk in 2 passes,
+    the 7 on qubits 0 to 6 on the whole tile."""
     from helpers import issue, plan_only_pager, trotter_step_gates
 
     q = plan_only_pager(30, **kwargs)
@@ -661,8 +663,8 @@ def test_paged_cells_hold_no_diag_run(kwargs):
         q.windows.clear()
         issue(q, trotter_step_gates(30))
         q.GetAmplitude(0)
-        assert len(q.windows) == (4 if kwargs else 9 if step == 0 else 11)
-        stretches = (0, 0, 0, 0)
+        assert len(q.windows) == (2 if kwargs else 5 if step == 0 else 7)
+        runs, stretches = (0, 0, 0), (0, 0, 0, 0)
         for w in q.windows:
             if w.structure is None:  # a lone RX: the shared one-op program
                 continue
@@ -670,12 +672,10 @@ def test_paged_cells_hold_no_diag_run(kwargs):
                                                  backend="tpu")
             in_runs, in_stretches = lowered_counts(
                 w.tops, plan["block_pow"], split_at=q.local_bits)
-            assert in_runs == (0, 0, 0)
+            runs = tuple(a + b for a, b in zip(runs, in_runs))
             stretches = tuple(a + b for a, b in zip(stretches, in_stretches))
-        if kwargs:
-            assert stretches == (4, 36, 6, 39)
-        else:
-            assert stretches == (3, 36, 5, 39)
+        assert runs == ((1, 58, 32) if kwargs else (3, 58, 32))
+        assert stretches == (1, 9, 2, 7)
 
 
 # ---------------------------------------------------------------------------
@@ -709,6 +709,16 @@ def random_ket(rng, n):
     return ket / np.sqrt((ket ** 2).sum(dtype=np.float32))
 
 
+def nearest_operands(ops, split_at=None):
+    """A window's operands with every float rounded to nearest, as the
+    numpy these tests hold the kernel's arithmetic to rounds them:
+    where ops act on all of the ket the packing keeps a float32
+    window's norm, so a float is what came before it in the window
+    too (fusion._norm_kept_float32, tests/test_norm_kept_operands.py)."""
+    iv, fv = fu.pack_operands(ops, np.float64, split_at=split_at)
+    return iv, fv.astype(np.float32)
+
+
 def run_window(n, bp, ops, ket, donate):
     """The kernel window of ``ops`` under the interpreter on a device
     copy of the numpy ``ket``.  Every launch aliases its planes to its
@@ -719,7 +729,7 @@ def run_window(n, bp, ops, ket, donate):
     fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
                            interpret=True)
     planes = jnp.array(ket, copy=True)
-    got = _exact(fn, planes, *fu.pack_operands(ops, jnp.float32),
+    got = _exact(fn, planes, *nearest_operands(ops),
                  donate=donate)
     if donate:
         assert planes.is_deleted()
@@ -1585,7 +1595,7 @@ def test_the_per_page_kernel_walks_a_stretch_chunk_by_chunk(donate):
                        out_specs=P(None, "pages"), check_vma=False)
     ket = random_ket(np.random.default_rng(bp), n)
     got = _exact(fn, jnp.array(ket, copy=True),
-                 *fu.pack_operands(ops, jnp.float32, split_at=L),
+                 *nearest_operands(ops, split_at=L),
                  donate=donate)
     for pid in range(npg):
         local = ket[:, pid * page:(pid + 1) * page]
@@ -1625,24 +1635,24 @@ def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
     behind its lead lowers to the launch it has with the chunk path
     taken out, and that is the parent's: its orbit scratch, the two
     ``pl.when`` of its grid (read in, compute out) and no loop.  So
-    does a segment whose ops all roll lanes (none here since the
-    Trotter step's first window holds 32 ops, on qubits 1 to 11; its 16
-    on qubits 1 to 6 were one until PR 46) or whose one op is diagonal
-    (the step's eleven ``diag`` behind a led ``inv`` and the one that
-    opens its third window): they stay on the whole tile's value.
-    Every other segment of the two families holds its value in one more
-    scratch tile and applies the ops that roll no lane in one rolled
-    loop a pass (``stretch_passes``); no segment here holds a run, so
-    those loops are all its loops."""
+    does a segment whose ops all roll lanes or whose one op is diagonal
+    (the Trotter step had such until a bond became one gate, PR 47):
+    they stay on the whole tile's value.  Every other segment of the
+    two families holds its value in one more scratch tile and applies
+    the ops that roll no lane in one rolled loop a pass
+    (``stretch_passes``).  One segment here holds a run, the Trotter
+    step's first window (54 ``diag``, then the RX on qubits 0 to 4 on
+    the whole tile): its loops are the run's, which the chunk path has
+    no part in; every other segment's loops are its passes."""
     import jax
     import jax.numpy as jnp
 
     structures = list(dict.fromkeys(
         w["structure"] for w in benchmark_plans(family)
         if w["path"] == "kernel"))
-    assert len(structures) == {"tfim": 4, "rcs": 4}[family]
+    assert len(structures) == {"tfim": 2, "rcs": 4}[family]
     planes = jax.ShapeDtypeStruct((2, 1 << 28), jnp.float32)
-    bare = whole = chunked = 0
+    bare = whole = chunked = with_run = 0
     for structure in structures:
         args = (planes, *fu.pack_operands(_placeholder_ops(structure),
                                           jnp.float32))
@@ -1661,8 +1671,11 @@ def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
             pieces = pk.segment_pieces(seg["ops"], tile)
             loops = sum(held is not None
                         for _, _, passes in pieces for _, held in passes)
-            assert shape == (led + bool(loops), 2 * led, loops)
             assert (str(eqn.params["jaxpr"]) == was) == (not loops)
+            if pk.diag_runs(seg["ops"]):
+                with_run += 1
+                continue
+            assert shape == (led + bool(loops), 2 * led, loops)
             if not seg["ops"]:
                 bare += 1
                 assert shape == (1, 2, 0)
@@ -1675,11 +1688,11 @@ def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
                            for slot in ops)
             else:
                 whole += 1
-    # the distinct structures' segments: 36 of the step's 40 launches
-    # are led, 24 of them bare; a sample's 4 windows are 4 structures,
+    # the distinct structures' segments: 12 of the step's 14 launches
+    # are led, all of them bare; a sample's 4 windows are 4 structures,
     # 48 of its 51 launches led and 41 of those bare
-    assert (bare, whole, chunked) \
-        == {"tfim": (24, 12, 4), "rcs": (41, 1, 9)}[family]
+    assert (bare, whole, chunked, with_run) \
+        == {"tfim": (12, 0, 1, 1), "rcs": (41, 1, 9, 0)}[family]
 
 
 def test_a_window_of_one_op_is_one_pass():

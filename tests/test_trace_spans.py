@@ -74,7 +74,9 @@ def _qft(q):
 
 
 def _trotter(q):
-    trotter_qcircuit(q.qubit_count, steps=1).Run(q)
+    # two steps: a step is 23 gates since a bond is one (PR 47), and a
+    # window holds 32
+    trotter_qcircuit(q.qubit_count, steps=2).Run(q)
     return q.GetAmplitude(3)
 
 
