@@ -1,22 +1,18 @@
 #!/usr/bin/env python3
-"""chip_smoke.py — the quickest proof that qrack_tpu still starts on the chip.
+"""chip_smoke.py — a check on the chip of the two paths that have no cell yet.
 
-One process drives the main path through the entry points a user calls,
-on one TPU v5e, and checks every result against a closed form that is
-itself checked against QEngineCPU at a small width first:
+The dense engine and the pager are the benchmark's (seven cells of
+BENCHMARK.json, through benchmarks/run.py).  One process drives what is
+left through the entry points a user calls, on one TPU v5e, and checks
+every result against a closed form that is itself checked against
+QEngineCPU at a small width first:
 
-1. dense engine, w28 (a 2 GiB ket): create_quantum_interface("tpu"),
-   SetPermutation(x), the engine's own gate-call QFT, 64 sampled
-   amplitudes, IQFT back to |x>;
-2. the default stack, w24: create_quantum_interface("optimal"), GHZ, a
+1. the default stack, w24: create_quantum_interface("optimal"), GHZ, a
    layer of RY and a CZ chain, so that the stabilizer layer hands the
-   ket to the dense engine, 64 sampled amplitudes;
-3. one in-process QrackService, w22, 8 sessions, each submitting the
+   ket to the dense engine, 64 sampled amplitudes (until ROADMAP B2);
+2. one in-process QrackService, w22, 8 sessions, each submitting the
    same X(k)-prepared QFT circuit inside one batch window, 16 sampled
-   amplitudes per session.
-
-``--chips 4`` runs instead, and alone, the 4-page QPager at w28 on four
-chips against the one-chip engine on device 0.
+   amplitudes per session (until ROADMAP B11).
 
 Every phase prints one JSON line.  The last line of standard output is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``
@@ -38,7 +34,6 @@ import numpy as np
 import jax
 
 # widths: (chip, rehearsal)
-W_DENSE = (28, 14)
 W_STACK = (24, 14)
 # served width: the batcher compiles the whole circuit as ONE vmapped
 # program (ROADMAP C3) — see SERVE_WIDTH_REASON, printed with the phase
@@ -186,78 +181,6 @@ def _assert_on(planes, devices):
     assert planes.devices() == set(devices), (planes.devices(), devices)
 
 
-def _qft_sampled(q, n, x, ys):
-    """SetPermutation(x), QFT, sampled amplitudes against the closed
-    form; returns (amplitudes, worst relative error)."""
-    q.SetPermutation(x)
-    q.QFT(0, n)
-    got = [q.GetAmplitude(y) for y in ys]
-    return got, _check_amps(got, [qft_amp(x, y, n) for y in ys], "QFT")
-
-
-def _iqft_back(q, n, x):
-    """IQFT a QFT|x> back; returns (P(x), norm)."""
-    import jax.numpy as jnp
-
-    q.IQFT(0, n)
-    p_x = q.ProbAll(x)
-    planes = q._state
-    norm = float(jnp.sum(planes * planes))
-    assert p_x > 1 - 1e-4, f"IQFT(QFT|x>) returned to |x> with P = {p_x}"
-    assert abs(norm - 1) < 1e-4, f"norm {norm} after QFT and IQFT"
-    return p_x, norm
-
-
-def phase_dense(n, seed, compiles, on_chip, roundtrip=True):
-    """The one-chip engine.  Without `roundtrip` (the four-chip option,
-    where this is only what the pager is compared with) it stops at the
-    sampled amplitudes: the IQFT would build 26 more window kernels."""
-    from qrack_tpu import create_quantum_interface
-    from qrack_tpu import telemetry as tele
-    from qrack_tpu.engines.tpu import QEngineTPU
-    from qrack_tpu.utils.rng import QrackRandom
-
-    device = jax.devices()[0]
-    mark, t0 = compiles.mark(), time.perf_counter()
-    rng = np.random.default_rng(seed)
-    x = int(rng.integers(1, 1 << n))
-    ys = [int(y) for y in rng.integers(0, 1 << n, 64)]
-    q = create_quantum_interface("tpu", n, rng=QrackRandom(seed),
-                                 rand_global_phase=False)
-    assert type(q) is QEngineTPU, type(q)  # no resilience wrapper, no failover
-    got, err = _qft_sampled(q, n, x, ys)
-    _assert_on(q._state, [device])
-    extra = {}
-    if roundtrip:
-        p_x, norm = _iqft_back(q, n, x)
-        # is block_until_ready a completion barrier here?  Queue one
-        # more QFT (no new program), wait on it, then time a
-        # one-amplitude read: if the wait was complete, the read that
-        # follows it has nothing left to wait for.
-        q.QFT(0, n)
-        planes = q._state
-        t1 = time.perf_counter()
-        planes.block_until_ready()
-        t2 = time.perf_counter()
-        q.GetAmplitude(0)
-        t3 = time.perf_counter()
-        extra = dict(prob_x_after_iqft=p_x, norm=norm,
-                     block_until_ready_seconds=t2 - t1,
-                     read_after_it_seconds=t3 - t2)
-    counters = tele.snapshot()["counters"]
-    if on_chip:
-        assert counters.get("fuse.kernel.windows", 0) > 0, counters
-        assert "fuse.kernel.fallback.cpu_backend" not in counters, counters
-    _emit("dense_engine", n, t0, compiles, mark, device,
-          x=x, max_rel_err=err,
-          kernel_windows=counters.get("fuse.kernel.windows", 0),
-          xla_windows=counters.get("fuse.xla.windows", 0),
-          kernel_fallbacks={k.rsplit(".", 1)[1]: v for k, v in counters.items()
-                            if k.startswith("fuse.kernel.fallback.")},
-          **extra)
-    return ys, got, x
-
-
 def phase_stack(n, seed, compiles):
     from qrack_tpu import create_quantum_interface
     from qrack_tpu.engines.tpu import QEngineTPU
@@ -322,43 +245,8 @@ def phase_served(n, seed, compiles):
           batch_jobs=counters.get("serve.batch.jobs", 0))
 
 
-def phase_pager(n, seed, compiles, on_chip):
-    """QPager over four pages against the one-chip engine on device 0."""
-    from qrack_tpu import create_quantum_interface
-    from qrack_tpu import telemetry as tele
-    from qrack_tpu.parallel.pager import QPager
-    from qrack_tpu.utils.rng import QrackRandom
-
-    devices = jax.devices()
-    assert len(devices) == 4, devices
-    ys, want, x = phase_dense(n, seed, compiles, on_chip, roundtrip=False)
-    mark, t0 = compiles.mark(), time.perf_counter()
-    q = create_quantum_interface("pager", n, n_pages=4, rng=QrackRandom(seed),
-                                 rand_global_phase=False)
-    assert type(q) is QPager and q.n_pages == 4, (type(q), q.n_pages)
-    got, err = _qft_sampled(q, n, x, ys)
-    vs_engine = _check_amps(got, want, "pager vs one-chip engine")
-    p_x, norm = _iqft_back(q, n, x)
-    planes = q._state
-    _assert_on(planes, devices)
-    shards = planes.addressable_shards
-    assert len({s.device for s in shards}) == 4, shards
-    assert all(s.data.nbytes == (2 * 4 << n) // 4 for s in shards), \
-        [s.data.nbytes for s in shards]
-    exchange = {key: v for key, v in tele.snapshot()["counters"].items()
-                if key.startswith("exchange.pager.")}
-    assert exchange and all(v > 0 for v in exchange.values()), exchange
-    _emit("pager_4_pages", n, t0, compiles, mark, devices[0],
-          max_rel_err=err, max_rel_err_vs_engine=vs_engine,
-          prob_x_after_iqft=p_x, norm=norm, shard_bytes=shards[0].data.nbytes,
-          peak_bytes_per_device=[_peak_bytes(d) for d in devices],
-          exchange=exchange)
-
-
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run only the 4-page pager against the one-chip engine")
     ap.add_argument("--seed", type=int, default=20260926)
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="run the phases on the CPU at tiny widths; never ok")
@@ -370,7 +258,6 @@ def main():
         print(f"chip_smoke: JAX found platform {platform!r}, not a TPU",
               file=sys.stderr)
         return 2
-    assert len(jax.devices()) == args.chips or not on_chip, jax.devices()
     pick = 0 if on_chip else 1
 
     from qrack_tpu import resilience
@@ -386,12 +273,8 @@ def main():
                       "rehearsal": not on_chip}), flush=True)
     check_closed_forms(args.seed)
     t0 = time.perf_counter()
-    if args.chips == 4:
-        phase_pager(W_DENSE[pick], args.seed, compiles, on_chip)
-    else:
-        phase_dense(W_DENSE[pick], args.seed, compiles, on_chip)
-        phase_stack(W_STACK[pick], args.seed, compiles)
-        phase_served(W_SERVE[pick], args.seed, compiles)
+    phase_stack(W_STACK[pick], args.seed, compiles)
+    phase_served(W_SERVE[pick], args.seed, compiles)
     print(json.dumps({"phase": "total", "seconds": time.perf_counter() - t0,
                       "compiles": compiles.count,
                       "compile_seconds": compiles.seconds}), flush=True)

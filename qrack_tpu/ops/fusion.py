@@ -688,23 +688,13 @@ def remap_mode() -> str:
     return v if v in ("auto", "on", "off") else "auto"
 
 
-def collective_mode() -> str:
-    """``QRACK_TPU_COLLECTIVE``: auto (default — lower each remap
-    prologue as ONE batched exchange collective, (1-2^-k)x bytes), on
-    (alias of auto), off (PR 10 pair-at-a-time lowering and planner,
-    kept for A/B measurement)."""
-    v = os.environ.get("QRACK_TPU_COLLECTIVE", "auto").strip().lower()
-    return v if v in ("auto", "on", "off") else "auto"
-
-
 #: exchange cost of one paged-target 2x2, in units of state nbytes
 #: (half a page out + half back, summed over pages)
 GEN_GLOBAL_COST = 1.0
 #: exchange cost of one remap transposition touching a page bit when it
-#: ships alone: one half-buffer (mixed) or half-the-pages whole-buffer
-#: (page-page) ppermute — half the traffic of a pair-exchange gate.
-#: Also the deferral ceiling in the batched planner: a hit that can wait
-#: for a later prologue is never worth more than this.
+#: ships alone (a 1-pair batch, or a page-page transposition: half the
+#: state).  The planner's deferral ceiling: a hit that can wait for a
+#: later prologue is never worth more than this.
 REMAP_PAIR_COST = 0.5
 
 
@@ -726,15 +716,15 @@ def batched_exchange_cost(gbits, weights=None) -> float:
 
 
 def plan_remaps(ops: Sequence[FusedOp], L: int, qmap: Sequence[int],
-                lookahead=None, weights=None, batched: bool = True):
+                lookahead=None, weights=None):
     """Score the pending window (+ multi-window lookahead) and pick
     placement swaps that turn globally-placed gen targets into local
     sweeps.  Returns ``(swaps, new_qmap)``: PHYSICAL transpositions for
     the window prologue and the table after them.  cphase/diag are
     collective-free at any placement, so only non-diagonal hits score.
 
-    Batched model (default; units of state nbytes, scaled by the
-    per-page-bit ``weights`` when the mesh spans DCN): all k mixed pairs
+    The cost model (units of state nbytes, scaled by the per-page-bit
+    ``weights`` when the mesh spans DCN): all k mixed pairs
     of one prologue ship together for ``batched_exchange_cost`` — the
     marginal pair is nearly free — so candidates are ranked jointly.  A
     hot global's benefit is its in-window hits (which MUST otherwise pay
@@ -743,7 +733,6 @@ def plan_remaps(ops: Sequence[FusedOp], L: int, qmap: Sequence[int],
     than a 1-pair batch).  A victim's charge is the same quantity for
     the hits it will pay from the inherited global slot.  The best
     hot-desc/cold-asc prefix with positive net fires as ONE batch.
-    ``batched=False`` keeps the PR 10 greedy pair-at-a-time rule.
 
     When ``weights`` are non-uniform (multi-host mesh: DCN bits cost
     more than ICI bits, parallel/cluster.py page_bit_weights) a second
@@ -770,24 +759,6 @@ def plan_remaps(ops: Sequence[FusedOp], L: int, qmap: Sequence[int],
 
     new_qmap = list(qmap)
     swaps = []
-    if not batched:
-        hits = [win[q] + look[q] for q in range(n)]
-        while True:
-            glob = [(hits[q], -q) for q in range(n)
-                    if new_qmap[q] >= L and hits[q] > 0]
-            loc = [(hits[q], q) for q in range(n) if new_qmap[q] < L]
-            if not glob or not loc:
-                break
-            gh, negg = max(glob)
-            vh, v = min(loc)
-            if gh <= vh + REMAP_PAIR_COST:
-                break
-            g = -negg
-            p_g, p_v = new_qmap[g], new_qmap[v]
-            swaps.append((p_v, p_g))
-            new_qmap[g], new_qmap[v] = p_v, p_g
-        return tuple(swaps), new_qmap
-
     def worth(q, pos):
         return (win[q] * GEN_GLOBAL_COST
                 + min(look[q], REMAP_PAIR_COST)) * wt(pos)
@@ -883,8 +854,7 @@ def sharded_structure_of(ops: Sequence[FusedOp]) -> Tuple:
                   op.target, op.cmask != 0) for op in ops)
 
 
-def sharded_window_body(L: int, npg: int, structure: Tuple, remap=(),
-                        batched: bool = True):
+def sharded_window_body(L: int, npg: int, structure: Tuple, remap=()):
     """Per-shard traced body fn(local, iv, fv) for one window, on the
     sharded layout of :func:`pack_operands`.  Masks arrive pre-split
     host-side into (local, page) int32 halves — same exact-past-int32
@@ -899,7 +869,7 @@ def sharded_window_body(L: int, npg: int, structure: Tuple, remap=(),
 
     def qrack_sharded_xla_window(local, iv, fv):  # the module's name
         if remap:
-            local = shb.apply_remap(local, npg, L, remap, batched=batched)
+            local = shb.apply_remap(local, npg, L, remap)
         views = operand_views(structure, iv, fv, split=True)
         for (kind, target, has_ctrl), (p, masks) in zip(structure, views):
             if kind == "cphase":
@@ -1066,8 +1036,7 @@ def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):
 
 def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
                                block_pow: int = None,
-                               interpret: bool = False, remap=(),
-                               batched: bool = True):
+                               interpret: bool = False, remap=()):
     """Per-shard traced body fn(local, iv, fv) — SAME sharded operand
     layout as :func:`sharded_window_body`, kernel-lowered local runs,
     with the optional remap prologue ahead of the first segment."""
@@ -1082,7 +1051,7 @@ def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
 
     def qrack_sharded_kernel_window(local, iv, fv):  # the module's name
         if remap:
-            local = shb.apply_remap(local, npg, L, remap, batched=batched)
+            local = shb.apply_remap(local, npg, L, remap)
         pid = shb.page_id()
         views = operand_views(structure, iv, fv, split=True)
         for seg in segments:

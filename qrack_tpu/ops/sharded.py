@@ -208,51 +208,16 @@ def compose_ring(a_local, b, npg: int, L_in: int, start: int, n1: int, n2: int):
     return out
 
 
-def page_swap(local, npg: int, g1: int, g2: int):
-    """Swap two page bits: pure page permutation over ICI (reference
-    MetaSwap, src/qpager.cpp:1314).  Only pages whose g1/g2 bits differ
-    move; the rest map to themselves (ppermute requires a total map)."""
-    def permute(j):
-        b1 = (j >> g1) & 1
-        b2 = (j >> g2) & 1
-        return j if b1 == b2 else j ^ ((1 << g1) | (1 << g2))
-
-    perm = [(j, permute(j)) for j in range(npg)]
-    return exchange(local, perm)
-
-
-def mixed_swap(local, npg: int, L: int, lpos: int, gpos: int):
-    """Swap one in-page bit against one page bit: half-buffer exchange.
-
-    Each page keeps the half of its slab whose l-bit equals its own
-    g-bit (those amplitudes don't move) and ships the other half to its
-    bit-flipped partner — whose shipped half is exactly the slab this
-    page needs.  One ppermute, half a page per payload: the same traffic
-    bound as a paged-target 2x2, but a pure relabeling (no arithmetic).
-
-    The halves are the two contiguous halves of the page (the top local
-    bit), so a lower ``lpos`` first trades places with the top bit in
-    the page and trades back afterwards: a ``(planes, hi, 2, lo)`` view
-    of the page is what the TPU compiler took 935 s over at a 2 GiB
-    page (PERF.md §6, PR 30 and PR 38)."""
-    top = L - 1
-    if lpos != top:
-        local = gk.swap_bits(local, L, lpos, top)
-    local = batched_mixed_swap(local, npg, 1, (gpos,))
-    if lpos != top:
-        local = gk.swap_bits(local, L, lpos, top)
-    return local
-
-
 # ---------------------------------------------------------------------------
 # batched exchange collectives: ANY sequence of physical bit-position
 # transpositions composes into one permutation, which lowers as
 #   L_post . page_perm . mixed_batch . L_pre
 # where L_pre/L_post are free in-page bit shuffles, mixed_batch moves the
 # k boundary-crossing sub-buffers in 2^k-1 sub-block ppermutes totalling
-# (1 - 2^-k) state volumes (vs k/2 for k sequential half-buffer swaps;
-# mpiQulacs' fused multi-qubit exchange, arXiv:2203.16044), and page_perm
-# is one whole-slab ppermute for any residual page-bit permutation.
+# (1 - 2^-k) state volumes (the sub-block on the diagonal of the k pair
+# axes never leaves its page; mpiQulacs' fused multi-qubit exchange,
+# arXiv:2203.16044), and page_perm is one whole-slab ppermute for any
+# residual page-bit permutation.
 # ---------------------------------------------------------------------------
 
 class ExchangePlan(NamedTuple):
@@ -397,7 +362,7 @@ def batched_mixed_swap(local, npg: int, k: int, gpos):
     sub-block its XOR-d partner needs, in one ppermute.  The d=0
     diagonal never moves, so total traffic is (1 - 2^-k) state volumes
     and all 2^k - 1 transfers are independent (one collective round on
-    hardware that overlaps them, vs k serialized half-buffer swaps).
+    hardware that overlaps them).
 
     The sub-blocks are static slices of the minor axis, chosen by
     selects on this page's bits and joined with ``concatenate``: no
@@ -424,29 +389,15 @@ def batched_mixed_swap(local, npg: int, k: int, gpos):
     return jnp.concatenate(out, axis=-1)
 
 
-def apply_remap(local, npg: int, L: int, swaps, batched: bool = True):
+def apply_remap(local, npg: int, L: int, swaps):
     """Batched placement change: apply a sequence of PHYSICAL bit-position
     transpositions (p1, p2).  The planner (ops/fusion.py plan_remaps)
     emits these as the prologue of a fused window program, so remap +
     window is ONE dispatch.
 
-    ``batched`` (default) composes the whole sequence into one
-    permutation and lowers it through :func:`plan_exchange` — free local
-    shuffles, one (1-2^-k)-volume mixed batch, one residual page
-    ppermute.  ``batched=False`` keeps the PR 10 pair-at-a-time lowering
-    (one half-buffer collective per page-touching pair) for A/B runs
-    (QRACK_TPU_COLLECTIVE=off)."""
-    if not batched:
-        for p1, p2 in swaps:
-            if p1 > p2:
-                p1, p2 = p2, p1
-            if p2 < L:
-                local = gk.swap_bits(local, L, p1, p2)
-            elif p1 >= L:
-                local = page_swap(local, npg, p1 - L, p2 - L)
-            else:
-                local = mixed_swap(local, npg, L, p1, p2 - L)
-        return local
+    The whole sequence composes into one permutation and lowers through
+    :func:`plan_exchange` — free local shuffles, one (1-2^-k)-volume
+    mixed batch, one residual page ppermute."""
     g = npg.bit_length() - 1
     plan = plan_exchange(L, g, swaps)
     if plan is None:
@@ -462,8 +413,7 @@ def apply_remap(local, npg: int, L: int, swaps, batched: bool = True):
     return local
 
 
-def exchange_cost(L: int, g: int, swaps, weights=None,
-                  batched: bool = True) -> float:
+def exchange_cost(L: int, g: int, swaps, weights=None) -> float:
     """Host-side accounting twin of :func:`apply_remap`: the fraction of
     state nbytes the lowering ships.  ``weights`` (per page bit, e.g.
     DCN > ICI from parallel/cluster.py) turn bytes into planner cost
@@ -473,14 +423,6 @@ def exchange_cost(L: int, g: int, swaps, weights=None,
             return 1.0
         return max(weights[b] for b in bits)
 
-    if not batched:
-        tot = 0.0
-        for p1, p2 in swaps:
-            lo, hi = min(p1, p2), max(p1, p2)
-            if hi < L:
-                continue
-            tot += 0.5 * w([b - L for b in (lo, hi) if b >= L])
-        return tot
     plan = plan_exchange(L, g, swaps)
     if plan is None:
         return 0.0

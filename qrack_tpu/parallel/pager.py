@@ -140,10 +140,12 @@ class WindowPlan(NamedTuple):
     swaps: tuple          # the planner's physical transpositions (prologue)
     new_qmap: Sequence    # the placement table after them
     tops: Sequence        # the window's ops on that table
-    batched: bool         # the prologue's lowering
     structure: Optional[tuple]  # None: one op, the shared eager programs
     kernel: Optional[dict]      # the per-page kernel lowering, or None
     why: Optional[str]          # why not, where it is None
+
+    # benchmarks/tests/test_qft_w31.py still reads it (ROADMAP C14)
+    batched = property(lambda self: True)
 
 
 class QPager(QEngine):
@@ -156,7 +158,6 @@ class QPager(QEngine):
     def __init__(self, qubit_count: int, init_state: int = 0, devices=None,
                  n_pages: Optional[int] = None, dtype=None,
                  remap: Optional[str] = None,
-                 collective: Optional[str] = None,
                  dcn_bits: Optional[int] = None, **kwargs):
         super().__init__(qubit_count, init_state=init_state, **kwargs)
         if dtype is None:
@@ -205,10 +206,8 @@ class QPager(QEngine):
         # per-instance remap-planner override (None = QRACK_TPU_REMAP):
         # soaks/tests arm the placement table without touching process env
         self._remap = remap
-        # per-instance batched-collective override (None =
-        # QRACK_TPU_COLLECTIVE) and DCN stand-in (None =
-        # QRACK_TPU_DCN_BITS / mesh process topology) — same discipline
-        self._collective = collective
+        # per-instance DCN stand-in (None = QRACK_TPU_DCN_BITS / mesh
+        # process topology) — same discipline
         self._dcn_bits = dcn_bits
         self._xw_mesh = None
         self._map_reset()
@@ -321,16 +320,6 @@ class QPager(QEngine):
         mode = self._remap if self._remap is not None else fu.remap_mode()
         return mode != "off" and self.n_pages > 1
 
-    def _collective_batched(self) -> bool:
-        """True when remap prologues lower as ONE batched exchange
-        collective (QRACK_TPU_COLLECTIVE / per-instance override);
-        False restores the PR 10 pair-at-a-time lowering for A/B."""
-        from ..ops import fusion as fu
-
-        mode = (self._collective if self._collective is not None
-                else fu.collective_mode())
-        return mode != "off"
-
     @property
     def _exchange_weights(self):
         """Per-page-bit planner weights (DCN > ICI) for the CURRENT
@@ -345,7 +334,7 @@ class QPager(QEngine):
             self._xw_mesh = mesh
         return self._xw
 
-    def _p_remap(self, swaps, batched: bool = True):
+    def _p_remap(self, swaps):
         """One program applying a batch of physical transpositions —
         free local axis shuffles, one batched mixed exchange and one
         composed page permutation (ops/sharded.py plan_exchange), all
@@ -356,22 +345,20 @@ class QPager(QEngine):
 
         def build():
             def f(local):
-                return shb.apply_remap(local, npg, L, swaps,
-                                       batched=batched)
+                return shb.apply_remap(local, npg, L, swaps)
 
             return jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P(None, "pages"),
                 out_specs=P(None, "pages")), donate_argnums=(0,))
 
-        return _program(self._key("remap", swaps, batched), build,
+        return _program(self._key("remap", swaps), build,
                         site="pager.exchange")
 
-    def _tele_remap(self, swaps, batched: bool = True) -> None:
+    def _tele_remap(self, swaps) -> None:
         """Count placement-transposition traffic, mirroring the lowering
-        exactly (ops/sharded.py exchange_cost): batched prologues ship
+        exactly (ops/sharded.py exchange_cost): a prologue ships
         (1-2^-k) of the state for k mixed pairs plus the displaced-page
-        fraction of any composed page permutation; pair-at-a-time ships
-        half the state per page-touching pair."""
+        fraction of any composed page permutation."""
         if not (_tele._ENABLED and swaps):
             return
         from ..ops import sharded as shb
@@ -379,20 +366,19 @@ class QPager(QEngine):
         L = self.local_bits
         nb = self._state_raw.nbytes
         _tele.inc("remap.pager.pairs", len(swaps))
-        frac = shb.exchange_cost(L, self.g_bits, swaps, batched=batched)
+        frac = shb.exchange_cost(L, self.g_bits, swaps)
         if frac <= 0:
             return
-        if batched:
-            if sum(1 for p1, p2 in swaps if max(p1, p2) >= L) >= 2:
-                _tele.inc("remap.pager.batched")
-            _tele.inc("exchange.pager.collective_bytes", frac * nb)
-            # the prologue by what its lowering sends: k pairs across the
-            # page boundary in one batch, then whole pages where a
-            # permutation of the page bits is left over
-            plan = shb.plan_exchange(L, self.g_bits, swaps)
-            _tele.inc(f"remap.pager.prologues.k{plan.k}")
-            if plan.page_dest is not None:
-                _tele.inc("remap.pager.page_perms")
+        if sum(1 for p1, p2 in swaps if max(p1, p2) >= L) >= 2:
+            _tele.inc("remap.pager.batched")
+        _tele.inc("exchange.pager.collective_bytes", frac * nb)
+        # the prologue by what its lowering sends: k pairs across the
+        # page boundary in one batch, then whole pages where a
+        # permutation of the page bits is left over
+        plan = shb.plan_exchange(L, self.g_bits, swaps)
+        _tele.inc(f"remap.pager.prologues.k{plan.k}")
+        if plan.page_dest is not None:
+            _tele.inc("remap.pager.page_perms")
         self._tele_exchange("remap", frac * nb)
 
     def _unmap(self) -> None:
@@ -416,10 +402,8 @@ class QPager(QEngine):
             qinv[l], qinv[p] = l, o
         if _tele._ENABLED:
             _tele.inc("remap.pager.unmap")
-        batched = self._collective_batched()
-        self._tele_remap(tuple(swaps), batched=batched)
-        self._state = self._p_remap(tuple(swaps),
-                                    batched=batched)(self._state)
+        self._tele_remap(tuple(swaps))
+        self._state = self._p_remap(tuple(swaps))(self._state)
         self._map_reset()
 
     @property
@@ -754,8 +738,7 @@ class QPager(QEngine):
         # targets included (the pair exchange runs inside the program)
         return True
 
-    def _p_fuse_window(self, structure, kernel_plan=None, remap=(),
-                       batched: bool = True):
+    def _p_fuse_window(self, structure, kernel_plan=None, remap=()):
         """The window's shard_map program: the paged state, then the two
         packed operand columns (fusion.pack_operands), replicated."""
         from ..ops import fusion as fu
@@ -764,8 +747,7 @@ class QPager(QEngine):
 
         if kernel_plan is None:
             def build():
-                body = fu.sharded_window_body(L, npg, structure, remap=remap,
-                                              batched=batched)
+                body = fu.sharded_window_body(L, npg, structure, remap=remap)
                 return _tele.instrument_jit("fuse.window", jax.jit(
                     jax.shard_map(body, mesh=mesh,
                                       in_specs=_state_specs(2),
@@ -773,7 +755,7 @@ class QPager(QEngine):
                     donate_argnums=(0,)))
 
             return _program(self._key("fusewin", str(self.dtype), structure,
-                                      remap, batched),
+                                      remap),
                             fu.timed_build(build), site="tpu.fuse.flush")
 
         interpret = kernel_plan["interpret"]
@@ -783,8 +765,7 @@ class QPager(QEngine):
             body = fu.sharded_kernel_window_body(L, npg, structure,
                                                  block_pow=bp,
                                                  interpret=interpret,
-                                                 remap=remap,
-                                                 batched=batched)
+                                                 remap=remap)
             # pallas_call inside shard_map trips the replication checker
             # on per-shard refs; the body is manifestly per-page, so the
             # check is safely off for this one program
@@ -797,8 +778,7 @@ class QPager(QEngine):
 
         return _program(self._key("fusewin-k",
                                   "interp" if interpret else "mosaic", bp,
-                                  str(self.dtype), structure, remap,
-                                  batched),
+                                  str(self.dtype), structure, remap),
                         fu.timed_build(build), site="tpu.fuse.flush")
 
     def _fuse_flush(self, gates) -> int:
@@ -827,23 +807,21 @@ class QPager(QEngine):
         L = self.local_bits
         swaps = ()
         new_qmap = self._qmap
-        batched = self._collective_batched()
         if self._remap_active():
             with _tele.span("remap.plan"):
                 swaps, new_qmap = fu.plan_remaps(
                     ops, L, self._qmap, lookahead,
-                    weights=self._exchange_weights, batched=batched)
+                    weights=self._exchange_weights)
         tops = (fu.translate_ops(ops, new_qmap)
                 if (swaps or self._map_nonid()) else ops)
         # merged down to one op on the current placement: the shared
         # eager programs already exist and are cheaper than a fresh
         # one-op window structure
         if len(tops) == 1 and not swaps:
-            return WindowPlan(swaps, new_qmap, tops, batched, None, None, None)
+            return WindowPlan(swaps, new_qmap, tops, None, None, None)
         structure = fu.sharded_structure_of(tops)
         kernel, why = fu.sharded_kernel_lowering(L, structure)
-        return WindowPlan(swaps, new_qmap, tops, batched, structure, kernel,
-                          why)
+        return WindowPlan(swaps, new_qmap, tops, structure, kernel, why)
 
     def _dispatch_ops(self, ops, lookahead=None) -> int:
         """Lower + dispatch one window of LOGICAL ops: plan placement
@@ -857,12 +835,12 @@ class QPager(QEngine):
 
         L = self.local_bits
         with _tele.span("fuse.lower"):
-            swaps, new_qmap, tops, batched, structure, plan, why = \
+            swaps, new_qmap, tops, structure, plan, why = \
                 self._plan_window(ops, lookahead)
             one_op = structure is None
             if not one_op:
                 prog = self._p_fuse_window(structure, kernel_plan=plan,
-                                           remap=swaps, batched=batched)
+                                           remap=swaps)
         with _tele.span("fuse.operands"):
             if one_op:
                 prog, operands = self._one_op_program(tops[0])
@@ -879,7 +857,7 @@ class QPager(QEngine):
                         self._tele_exchange("global_2x2", nb)
                 if swaps:
                     _tele.inc("remap.pager.windows")
-                self._tele_remap(swaps, batched=batched)
+                self._tele_remap(swaps)
         with _tele.span("fuse.dispatch"):
             self._state = prog(self._state, *operands)
         if one_op:
@@ -946,9 +924,7 @@ class QPager(QEngine):
             if _tele._ENABLED:
                 _tele.inc("remap.pager.swap")
                 self._tele_exchange("remap", self._state.nbytes / 2)
-            self._state = self._p_remap(
-                ((p1, p2),),
-                batched=self._collective_batched())(self._state)
+            self._state = self._p_remap(((p1, p2),))(self._state)
 
     def _global_iota(self):
         """Sharded full-width index vector (int32-safe only to 31 qubits)."""

@@ -65,7 +65,12 @@ blocks on a JobHandle.  Env knobs (constructor args override):
                                    the engine is seeded from the shared
                                    planes, and only the per-tenant
                                    suffix executes
-                                   (serve/prefix_cache.py)
+                                   (serve/prefix_cache.py).  Identical
+                                   circuits from pristine sessions are
+                                   the cache's, not the co-batcher's:
+                                   the second splits at its whole
+                                   length, so they share no vmapped
+                                   dispatch (docs/SERVING.md)
 * ``QRACK_SERVE_PREFIX_BYTES``     resident prefix-cache budget
                                    (default 256 MiB; evicts by
                                    bytes×recency, spilling to the

@@ -42,8 +42,8 @@ Three surfaces:
 The hardware-truth profiling plane lives in two sibling modules:
 :mod:`~qrack_tpu.telemetry.roofline` (per-dispatch planned-bytes ledger,
 device-class fingerprints, the implied-bandwidth honesty clamp) and
-:mod:`~qrack_tpu.telemetry.sentinel` (stdlib-only shared formula, peak
-table, and the perf-regression sentinel over committed evidence) —
+:mod:`~qrack_tpu.telemetry.sentinel` (stdlib-only shared formula and
+peak table) —
 import them explicitly (``from qrack_tpu.telemetry import roofline``);
 they are deliberately not re-exported here so this module stays
 importable without touching them.

@@ -15,8 +15,7 @@ Timing honesty is structural: a sample whose implied bandwidth exceeds the
 device-class peak is the dispatch-ack signature (dispatch acked, completion
 never timed).  Such samples bump ``roofline.honesty.clamped`` (counter +
 event) and ``roofline.<site>.clamped``, and are **excluded** from the
-histogram and gauges — they can flag a campaign stage as failed but never
-enter committed evidence.
+histogram and gauges.
 
 The device-class fingerprint (kind, HBM bytes, peak GB/s) is captured from an
 *already-initialized* jax backend only — this module never triggers backend
@@ -200,9 +199,3 @@ def record(site: str, nbytes: float, wall_s: float,
         facet = f"{stack}.w{width}" if stack else f"w{width}"
         _tele.gauge(f"roofline.{site}.{facet}.peak_frac", round(frac, 4))
     return sample
-
-
-def note_verdict(v: str) -> None:
-    """Count a sentinel verdict (better/same/worse/new/replay)."""
-    if _tele._ENABLED and v:
-        _tele.inc(f"roofline.sentinel.{v}")

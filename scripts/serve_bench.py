@@ -1291,8 +1291,7 @@ def main(argv=None) -> int:
                   f"forced-dense refused): "
                   f"{'PASS' if res['pass_shallow'] else 'FAIL'}")
         # campaign evidence: one flat metric line + the OK marker
-        # (scripts/tpu_campaign.sh greps ^{"metric" and _OK$;
-        # perf_sentinel stamps the line into docs/tpu_results.jsonl)
+        # (one ^{"metric" line and an _OK$ marker)
         print(json.dumps({
             "metric": f"lightcone_w{res['shallow_width']}_serve",
             "value": res["shallow_jobs_per_s"], "unit": "jobs/s",

@@ -368,8 +368,8 @@ def report(snap: dict, top: int) -> dict:
     # roofline: achieved bandwidth per guarded dispatch site — GB/s
     # percentiles from the implied-bandwidth histograms (merged hists
     # under --all/--fleet report merged percentiles, same as SLO),
-    # peak-fraction gauges, clamped-sample counts and sentinel verdicts
-    # (the roofline.* counters collected above)
+    # peak-fraction gauges and clamped-sample counts (the roofline.*
+    # counters collected above)
     for name, d in sorted((snap.get("hists") or {}).items()):
         if not name.startswith("roofline."):
             continue

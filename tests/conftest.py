@@ -7,7 +7,7 @@ driver entry point) and must run before any backend init.  The driver
 runs this suite with JAX_PLATFORMS=cpu; the chip is exercised by
 benchmarks/run.py (the cells of BENCHMARK.json: every time comes from
 there) and by chip_smoke.py (the served batch and the default stack,
-which have no cell yet), never from here."""
+which have no cell yet, and nothing else), never from here."""
 
 import faulthandler
 import os
@@ -34,6 +34,19 @@ try:
     faulthandler.register(signal.SIGTERM, chain=True)
 except (AttributeError, ValueError):
     pass  # platform without SIGTERM registration (e.g. non-main thread)
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def no_prefix_cache(monkeypatch):
+    """For tests of the batcher, the pipeline and the program cache: with
+    ``QRACK_SERVE_PREFIX`` at its default the prefix cache claims
+    identical circuits from pristine sessions before the co-batcher sees
+    them (docs/SERVING.md), so such a test would watch the wrong
+    mechanism."""
+    monkeypatch.setenv("QRACK_SERVE_PREFIX", "0")
 
 
 def pytest_configure(config):

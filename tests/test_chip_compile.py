@@ -203,15 +203,14 @@ def test_tfim_last_window_kernel(one_chip):
     assert _in_place(compiled)
 
 
-def _compile_sharded(topo, structure, n, npg=4, remap=(), batched=True,
-                     exchanges=True):
+def _compile_sharded(topo, structure, n, npg=4, remap=(), exchanges=True):
     """The pager's per-page kernel body of a window on a 2x2 mesh, with
     the planner's transpositions ``remap`` as its prologue."""
     L = n - 2
     mesh = Mesh(np.array(topo.devices[:npg]), ("pages",))
     ops = _ops(structure)
     body = fu.sharded_kernel_window_body(L, npg, fu.sharded_structure_of(ops),
-                                         remap=remap, batched=batched)
+                                         remap=remap)
     args = _args(fu.pack_operands(ops, jnp.float32, split_at=L),
                  NamedSharding(mesh, P(None, "pages")),
                  NamedSharding(mesh, P()), n=n)
@@ -263,13 +262,18 @@ def test_tfim_last_window_sharded_kernel(topo):
 # controlled ``diag`` (PR 47): the fixed placement's two (58 ``diag`` and
 # the RX on 0-2 in one launch and no exchange: the bonds onto 28 and 29
 # are phases by page; then the RX on 3-29, 13 launches and the step's
-# two exchanges) and the settled planner step's first (50 ``diag``).
+# two exchanges) and the settled planner step's three that begin with
+# no prologue (50 ``diag``; the two ``diag`` a bond onto a page bit
+# leaves behind the window its first CNOT closed; the RX on 0-25 and the
+# bonds between them, 11 launches).
 # name -> (pager's keywords, window, ops, launches, exchanges?, pages of
 # temporaries in eighths)
 TFIM_PAGED_WINDOWS = {
     "fixed-61op": ({"remap": "off"}, 0, 61, 1, False, 0),
     "fixed-27op": ({"remap": "off"}, 1, 27, 13, True, 20),
     "planner-50op": ({}, 0, 50, 1, False, 0),
+    "planner-2op": ({}, 1, 2, 1, False, 0),
+    "planner-32op": ({}, 2, 32, 11, False, 0),
 }
 
 
@@ -367,14 +371,12 @@ def remap_programs():
     return out
 
 
-@pytest.mark.parametrize("batched", [True, False], ids=["batched", "pairs"])
 @pytest.mark.parametrize("name", REMAP_PROGRAMS)
-def test_remap_prologue_sharded_kernel(topo, remap_programs, name, batched):
-    """A remap prologue ahead of the window's launches, at a 2 GiB page,
-    in both lowerings: seconds to compile (935 s while ``mixed_swap``
-    and ``batched_mixed_swap`` viewed the page as ``(planes, hi, 2, lo)``
-    and ``(planes, 2^k, -1)``: PERF.md §6, PR 30; 1.5 to 3.7 s with the
-    sub-blocks sliced off the minor axis, PR 38) and at most three
+def test_remap_prologue_sharded_kernel(topo, remap_programs, name):
+    """A remap prologue ahead of the window's launches, at a 2 GiB page:
+    seconds to compile (935 s while ``batched_mixed_swap`` viewed the
+    page as ``(planes, 2^k, -1)``: PERF.md §6, PR 30; 1.5 to 3.7 s with
+    the sub-blocks sliced off the minor axis, PR 38) and at most three
     pages of temporaries beside the donated page (three and a half
     until the launches wrote in place, PR 39)."""
     from qrack_tpu.ops import sharded as shb
@@ -390,8 +392,7 @@ def test_remap_prologue_sharded_kernel(topo, remap_programs, name, batched):
         # page before the exchange or after
         assert plan.k == 2 and plan.page_dest is None and not plan.pre
     t0 = time.perf_counter()
-    compiled = _compile_sharded(topo, structure, PAGED_W, remap=swaps,
-                                batched=batched)
+    compiled = _compile_sharded(topo, structure, PAGED_W, remap=swaps)
     assert time.perf_counter() - t0 < 120
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= 3 * KET_BYTES + SLACK
@@ -837,17 +838,21 @@ def test_amplitude_read_w30_holds_no_ket(one_chip):
 # -- the first ket no one chip holds: ``qft_w31.pager4`` (PR 45) -------------
 # A w31 ket is 16 GiB, 4 GiB a page on the 2x2 mesh: whatever a program
 # of the application keeps beside the page has to fit the 15.75 GiB the
-# runtime gives a chip.  The fill, the one-amplitude read, and four of
+# runtime gives a chip.  The fill, the one-amplitude read, and nine of
 # QFT(0, 31)'s 19 windows: the three short ones that the ``H`` on 30, 29
 # and 28 head (the first brings 30 and 29 onto the carrier bits, the
 # third sends them back for 28 and 27; no shuffle of the page before or
-# after either exchange) and the first of the sixteen the bound cuts (no
-# exchange: launches alone, 32 ops).
+# after either exchange), the first four of the sixteen the bound cuts
+# (no exchange: launches alone, 32 ops; 5, 4, 4 and 2 launches, the
+# leads above the tile), the first of one launch (``H`` on 14, in the
+# tile) and the last (7 ops).  The ten between hold one or two ``H`` on
+# lower bits of the tile and differ by those targets alone.
 W31 = 31
 PAGE31_BYTES = (2 * 4 << W31) // 4
 HBM_BYTES = int(15.75 * 2 ** 30)
 QFT31_WINDOWS = {"w01-prologue": 0, "w02-plain": 1, "w03-prologue": 2,
-                 "w04-plain-32": 3}
+                 "w04-plain-32": 3, "w05-plain-32": 4, "w06-plain-32": 5,
+                 "w07-plain-32": 6, "w08-intile-32": 7, "w19-last-7": 18}
 
 
 @pytest.fixture(scope="module")
@@ -928,7 +933,7 @@ def test_qft_w31_window_fits_beside_its_page(topo, pager31, name):
         assert not exchange.pre  # both ride the carrier bits
     t0 = time.perf_counter()
     compiled = _compile_sharded(topo, window.structure, W31,
-                                remap=window.swaps, batched=window.batched,
+                                remap=window.swaps,
                                 exchanges=bool(window.swaps))
     assert time.perf_counter() - t0 < 120
     assert _launches(compiled) == plan["sweeps"]

@@ -74,7 +74,7 @@ def test_the_table_recurs_and_no_later_step_plans_anew(width):
         issue(q, gates)
         q.GetAmplitude(0)
         tables.append(q.placement())
-        keys = {(w.structure, w.swaps, w.batched) for w in q.windows}
+        keys = {(w.structure, w.swaps) for w in q.windows}
         new.append(len(keys - planned))
         planned |= keys
         # no gate is left on a paged qubit, at any width: one that lands
@@ -198,10 +198,11 @@ def _state():
 @pytest.mark.parametrize("gpos", range(G))
 @pytest.mark.parametrize("lpos", range(L))
 def test_mixed_swap_is_a_permutation(lpos, gpos):
-    got = _sharded(lambda x: shb.mixed_swap(x, 1 << G, L, lpos, gpos),
-                   _state())
-    np.testing.assert_array_equal(
-        got, _relabelled(_state(), ((lpos, L + gpos),)))
+    """One in-page bit against one page bit: the k = 1 plan, with the
+    shuffle onto the carrier bit and back where ``lpos`` is below it."""
+    swaps = ((lpos, L + gpos),)
+    got = _sharded(lambda x: shb.apply_remap(x, 1 << G, L, swaps), _state())
+    np.testing.assert_array_equal(got, _relabelled(_state(), swaps))
 
 
 @pytest.mark.parametrize("gpos", [(0,), (1,), (0, 1), (1, 0)],
@@ -232,17 +233,19 @@ def _planner_swaps():
     return sorted(found)
 
 
-@pytest.mark.parametrize("batched", [True, False], ids=["batched", "pairs"])
-def test_the_planners_prologues_are_permutations(batched):
-    swaps_seen = _planner_swaps()
-    # a prologue's window begins at the gate that needs it, so its
-    # victims are the carrier bits; elsewhere by hand
+# a prologue's window begins at the gate that needs it, so the planner's
+# victims are the carrier bits; elsewhere by hand
+_BY_HAND = [((0, L), (1, L + 1)), ((2, L + 1),), ((L - 3, L + 1), (1, L)),
+            ((L - 1, L + 1),), ((L - 1, L), (L - 2, L + 1))]
+
+
+@pytest.mark.parametrize("source", ["planner", "by_hand"])
+def test_the_planners_prologues_are_permutations(source):
+    swaps_seen = _planner_swaps() if source == "planner" else _BY_HAND
     assert len(swaps_seen) >= 2
-    for swaps in swaps_seen + [((0, L), (1, L + 1)), ((2, L + 1),),
-                               ((L - 3, L + 1), (1, L)), ((L - 1, L + 1),),
-                               ((L - 1, L), (L - 2, L + 1))]:
-        got = _sharded(lambda x: shb.apply_remap(x, 1 << G, L, swaps,
-                                                 batched=batched), _state())
+    for swaps in swaps_seen:
+        got = _sharded(lambda x: shb.apply_remap(x, 1 << G, L, swaps),
+                       _state())
         np.testing.assert_array_equal(got, _relabelled(_state(), swaps),
                                       err_msg=str(swaps))
 

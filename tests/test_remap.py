@@ -49,15 +49,14 @@ def _op_skip_setbit(rng):
 # fuzz parity: the whole non-measuring op vocabulary on a remap-on pager
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("collective", ["auto", "off"])
+@pytest.mark.parametrize("n_pages", [4, 8])   # the cells' layout, and 3 page bits
 @pytest.mark.parametrize("window", [1, 32])
 @pytest.mark.parametrize("trial", range(3))
-def test_fuzz_parity_remap_on(trial, window, collective, monkeypatch):
+def test_fuzz_parity_remap_on(trial, window, n_pages, monkeypatch):
     monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", str(window))
     rng = np.random.Generator(np.random.PCG64(7000 + trial))
     o = QEngineCPU(N, rng=QrackRandom(trial), rand_global_phase=False)
-    s = create_quantum_interface("pager", N, n_pages=8, remap="on",
-                                 collective=collective,
+    s = create_quantum_interface("pager", N, n_pages=n_pages, remap="on",
                                  rng=QrackRandom(trial),
                                  rand_global_phase=False)
     for step in range(25):
@@ -68,7 +67,7 @@ def test_fuzz_parity_remap_on(trial, window, collective, monkeypatch):
             qb = int(rng.integers(0, N))
             assert abs(o.Prob(qb) - s.Prob(qb)) < 3e-5, (trial, step, name)
     f = _fidelity(o.GetQuantumState(), s.GetQuantumState())
-    assert f > 1 - 1e-6, (trial, window, f)
+    assert f > 1 - 1e-6, (trial, window, n_pages, f)
 
 
 # ---------------------------------------------------------------------------
@@ -202,7 +201,7 @@ def _circuit_ops(width, kind):
     return ops
 
 
-def _account(ops, width, L, window, remap_on, batched=True):
+def _account(ops, width, L, window, remap_on):
     """Replay the _dispatch_ops cost accounting host-side: window at a
     time, prologue swaps priced by the lowering's own accounting twin
     (ops/sharded.py exchange_cost — mirrors _tele_remap exactly),
@@ -219,11 +218,9 @@ def _account(ops, width, L, window, remap_on, batched=True):
         rest = [("gen" if op.kind in ("gen", "inv") else "diag", op.target)
                 for op in ops[s + window:]]
         if remap_on:
-            swaps, qmap = fu.plan_remaps(win, L, qmap, rest,
-                                         batched=batched)
+            swaps, qmap = fu.plan_remaps(win, L, qmap, rest)
             pairs += len(swaps)
-            total += shb.exchange_cost(L, width - L, swaps,
-                                       batched=batched) * nb
+            total += shb.exchange_cost(L, width - L, swaps) * nb
         for op in fu.translate_ops(win, qmap):
             if op.kind in ("gen", "inv") and op.target >= L:
                 total += nb
@@ -232,27 +229,22 @@ def _account(ops, width, L, window, remap_on, batched=True):
 
 def test_w26_iqft_accounting_batched_collective():
     """The acceptance-scale claim without the 2 GiB ket: w26 on 16
-    pages (k=4).  Per-pair prologues ship nb/2 per paged qubit (the PR
-    10 2x-halving baseline); the batched collective ships all four in
-    one exchange at (1 - 2^-4) x nb — under 0.47x the per-pair bytes,
-    0.55x required."""
+    pages (k=4).  The fixed placement pays a whole-state exchange per
+    paged qubit; the batched collective ships all four home in one
+    exchange at (1 - 2^-4) x nb."""
     w, L = 26, 22
     ops = _circuit_ops(w, "iqft")
     nb = 2 * (1 << w) * 4
     off, _ = _account(ops, w, L, 16, remap_on=False)
-    per_pair, pp_pairs = _account(ops, w, L, 16, remap_on=True,
-                                  batched=False)
-    batch, b_pairs = _account(ops, w, L, 16, remap_on=True, batched=True)
+    batch, b_pairs = _account(ops, w, L, 16, remap_on=True)
     assert off == 4 * nb
-    assert pp_pairs == 4 and per_pair == 2 * nb, (per_pair, pp_pairs)
     assert b_pairs == 4 and batch == (1 - 2.0 ** -4) * nb, (batch, b_pairs)
-    assert batch <= 0.55 * per_pair, (batch, per_pair)
 
 
 def test_w26_qft_accounting_delivery_ratio():
-    """Descending-gen QFT: every per-pair remap victim still owes a gen,
-    so PR 10 prologues were bound at 2g/(g+1) and never fired (per-pair
-    == remap-off == 3nb at w26/8 pages).  The batched collective breaks
+    """Descending-gen QFT: every remap victim still owes a gen, so a
+    prologue priced pair by pair is bound at 2g/(g+1) and never fires
+    (remap-off is 3nb at w26/8 pages).  The batched collective breaks
     the bound: two k=3 batches (hot trio in window 1, pay-back trio once
     its victims are gen-done) ship 2 x (1 - 2^-3) x nb = 1.75nb — a
     12/7 ~ 1.71x delivery ratio vs remap-off, >= 1.6x required."""
@@ -260,9 +252,8 @@ def test_w26_qft_accounting_delivery_ratio():
     ops = _circuit_ops(w, "qft")
     nb = 2 * (1 << w) * 4
     off, _ = _account(ops, w, L, 16, remap_on=False)
-    per_pair, _ = _account(ops, w, L, 16, remap_on=True, batched=False)
-    batch, _ = _account(ops, w, L, 16, remap_on=True, batched=True)
-    assert off == 3 * nb and per_pair == off, (off, per_pair)
+    batch, _ = _account(ops, w, L, 16, remap_on=True)
+    assert off == 3 * nb, off
     assert batch == 2 * (1 - 2.0 ** -3) * nb, batch
     assert off / batch >= 1.6, (off, batch)
 
@@ -288,7 +279,7 @@ def _iqft_qcircuit(width):
     return c
 
 
-def _measured_circuit_bytes(width, n_pages, collective, monkeypatch):
+def _measured_circuit_bytes(width, n_pages, remap, monkeypatch):
     monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", "32")
     circ = _iqft_qcircuit(width)
     o = QEngineCPU(width, rng=QrackRandom(3), rand_global_phase=False)
@@ -297,7 +288,7 @@ def _measured_circuit_bytes(width, n_pages, collective, monkeypatch):
     tele.reset()
     tele.enable()
     q = QPager(width, rng=QrackRandom(3), rand_global_phase=False,
-               n_pages=n_pages, remap="auto", collective=collective)
+               n_pages=n_pages, remap=remap)
     q.SetPermutation(314)
     circ.Run(q)
     _ = q.GetAmplitude(0)  # read boundary: flush the fused window
@@ -309,9 +300,10 @@ def _measured_circuit_bytes(width, n_pages, collective, monkeypatch):
 
 
 def test_collective_measured_w10(monkeypatch):
-    """w10 IQFT / 8 pages, measured: the batched lowering ships exactly
-    (1 - 2^-3) x nb in ONE collective where per-pair ships 3 x nb/2 —
-    the (1 - 2^-k)x ratio of mpiQulacs' fused exchange, on the wire."""
+    """w10 IQFT / 8 pages, measured: the prologue ships exactly
+    (1 - 2^-3) x nb in ONE collective — the (1 - 2^-k)x ratio of
+    mpiQulacs' fused exchange, on the wire — where the fixed placement
+    exchanges the whole state under each of the 3 paged H."""
     nb = 2 * (1 << 10) * 4
     on, f_on = _measured_circuit_bytes(10, 8, "auto", monkeypatch)
     off, f_off = _measured_circuit_bytes(10, 8, "off", monkeypatch)
@@ -320,7 +312,7 @@ def test_collective_measured_w10(monkeypatch):
     assert on.get("exchange.pager.collective_bytes", 0) \
         == on["exchange.pager.bytes"]
     assert on.get("remap.pager.batched", 0) >= 1
-    assert off.get("exchange.pager.bytes", 0) == 1.5 * nb, off
+    assert off.get("exchange.pager.bytes", 0) == 3 * nb, off
     assert off.get("remap.pager.batched", 0) == 0
     assert off.get("exchange.pager.collective_bytes", 0) == 0
 
@@ -331,7 +323,7 @@ def test_collective_measured_w10(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_apply_remap_random_oracle():
-    """apply_remap (batched AND per-pair) must realize the composed bit
+    """apply_remap must realize the composed bit
     permutation of any transposition sequence — local, mixed and
     page-page, including the page-bit swaps the DCN pass emits."""
     import jax
@@ -354,16 +346,13 @@ def test_apply_remap_random_oracle():
         for p in range(n):
             j |= ((np.arange(1 << n) >> p) & 1) << src[p]
         want = state[:, j]
-        for batched in (True, False):
-            prog = jax.jit(jax.shard_map(
-                lambda local: shb.apply_remap(local, 1 << g, L, swaps,
-                                              batched=batched),
-                mesh=mesh, in_specs=P(None, "pages"),
-                out_specs=P(None, "pages")))
-            got = np.asarray(prog(jax.device_put(state, sh)))
-            np.testing.assert_array_equal(got, want,
-                                          err_msg=f"{trial} {batched} "
-                                                  f"{swaps}")
+        prog = jax.jit(jax.shard_map(
+            lambda local: shb.apply_remap(local, 1 << g, L, swaps),
+            mesh=mesh, in_specs=P(None, "pages"),
+            out_specs=P(None, "pages")))
+        got = np.asarray(prog(jax.device_put(state, sh)))
+        np.testing.assert_array_equal(got, want,
+                                      err_msg=f"{trial} {swaps}")
 
 
 # ---------------------------------------------------------------------------
@@ -381,12 +370,12 @@ def test_plan_remaps_dcn_weights_prefer_ici():
     ops = [fu.FusedOp("gen", 5, 0, 0, eye)]
     look = [("gen", q) for q in range(L)]  # every local still owes one
     swaps, qmap = fu.plan_remaps(ops, L, list(range(n)), look,
-                                 weights=weights, batched=True)
+                                 weights=weights)
     assert swaps == ((4, 5),), swaps       # page-page, off the DCN bit
     assert qmap[5] == 4 and qmap[4] == 5
     # uniform weights: same window fires nothing (net-zero local swap)
     swaps_u, qmap_u = fu.plan_remaps(ops, L, list(range(n)), look,
-                                     weights=None, batched=True)
+                                     weights=None)
     assert swaps_u == () and qmap_u == list(range(n))
 
 

@@ -94,7 +94,8 @@ def test_eight_concurrent_cpu_sessions_match_oracles():
 # batching
 # ---------------------------------------------------------------------------
 
-def test_same_shape_jobs_from_different_tenants_cobatch():
+def test_same_shape_jobs_from_different_tenants_cobatch(no_prefix_cache):
+    # the batcher's test: the cache would serve three of the four QFTs
     tele.enable()
     tele.reset()
     with _svc(engine_layers="tpu", batch_window_ms=500.0,
@@ -117,9 +118,10 @@ def test_same_shape_jobs_from_different_tenants_cobatch():
         assert _fidelity(expect, st) > 1 - 1e-6
 
 
-def test_program_cache_reused_across_sessions():
+def test_program_cache_reused_across_sessions(no_prefix_cache):
     """Satellite: two sessions, identical circuit shape -> exactly one
     compile (miss) and one cache hit, even submitted sequentially."""
+    # the program cache's test: the prefix cache would split the second QFT
     tele.enable()
     tele.reset()
     with _svc(engine_layers="tpu") as svc:

@@ -175,10 +175,11 @@ def test_idle_eviction_under_sustained_load():
 # in-flight batch joining
 # ---------------------------------------------------------------------------
 
-def test_inflight_join_matches_solo_submit(monkeypatch):
+def test_inflight_join_matches_solo_submit(monkeypatch, no_prefix_cache):
     """Same-shape jobs that arrive while the previous batch's sync is
     in flight join the STAGED batch (one dispatch for all three) and
     land states identical to a solo submit."""
+    # the pipeline's test: the prefix cache would split the identical walls
     tele.enable()
     tele.reset()
     entered, release = threading.Event(), threading.Event()
@@ -231,11 +232,14 @@ def test_inflight_join_matches_solo_submit(monkeypatch):
 
 @pytest.mark.parametrize("window", [1, 16])
 @pytest.mark.parametrize("kind", ["timeout", "raise"])
-def test_pipelined_sync_fault_exactly_once(kind, window, monkeypatch):
+def test_pipelined_sync_fault_exactly_once(kind, window, monkeypatch,
+                                           no_prefix_cache):
     """The in-flight batch's honest sync escalates while a staged batch
     waits: the in-flight jobs must roll back and fail over exactly
     once, and the staged batch must dispatch against settled engines —
     every session's final state matches its CPU oracle."""
+    # the pipeline's test: the prefix cache would take b's QFT and d's wall
+    # out of the two batches whose overlap is under test
     monkeypatch.setenv("QRACK_TPU_FUSE_WINDOW", str(window))
     tele.enable()
     tele.reset()
