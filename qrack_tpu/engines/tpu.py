@@ -30,10 +30,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..interface.alu import AluMixin
 from ..ops import gatekernels as gk
 from .qengine import QEngine
 from .. import matrices as mat
 from .. import telemetry as _tele
+from ..telemetry import roofline as _roofline
 from .. import resilience as _res
 
 
@@ -130,6 +132,54 @@ def qrack_fill(planes, perm, phase, n, dtype):
 # keep_unused: the donated ket is a parameter the result can alias
 _j_fill = _jit("fill", qrack_fill, static_argnums=(3, 4),
                donate_argnums=(0,), keep_unused=True)
+
+def qrack_alu_rotate(into, planes, shift, block_bits):
+    """The ket rotated by ``shift`` inside every aligned block of
+    ``2^block_bits`` amplitudes: ``out[b + m] = planes[b + (m - shift)
+    mod 2^block_bits]``, ``0 <= shift < 2^block_bits``.  That is an add
+    of a constant on a contiguous register (``INC(a, start, L)``:
+    ``block_bits = start + L``, ``shift = a << start``): one read and
+    one write of the ket, no index array.  ``shift`` is a runtime
+    operand: every constant shares the program of its register's top
+    bit.  The name is the compiled module's (``jit_qrack_alu_rotate``).
+
+    A dynamic slice of the ket laid end to end with itself, which XLA
+    lowers as one loop fusion that reads at the offset (compiled for a
+    v5e at w28: no temporary, no ``gather``).  Where the block is not
+    the whole ket the amplitudes that wrap inside their block come from
+    a second slice, ``block`` further on; its ket sits behind a barrier
+    so that the two slices are not one doubled ket, which XLA would
+    write out (4 GiB at w28).
+
+    A rotation cannot write the planes it reads (donated, XLA guards
+    the aliased result with a whole-ket copy: two more passes).  It
+    writes ``into``, a second ket that is donated, never read, and whose
+    buffer the result takes, or a fresh ket where ``into`` is None."""
+    size = planes.shape[-1]
+    block = 1 << block_bits
+
+    def doubled_from(x, offset):
+        return jax.lax.dynamic_slice_in_dim(
+            jax.lax.concatenate((x, x), 1), offset, size, axis=1)
+
+    out = doubled_from(planes, size - shift)
+    if block < size:
+        idx = jax.lax.broadcasted_iota(gk.IDX_DTYPE, planes.shape, 1)
+        wrapped = doubled_from(jax.lax.optimization_barrier(planes),
+                               block - shift)
+        out = jnp.where((idx & (block - 1)) >= shift, out, wrapped)
+    return out
+
+
+# keep_unused: the donated destination is a parameter the result can alias
+_j_alu_rotate = _jit("alu_rotate", qrack_alu_rotate, static_argnums=(3,),
+                     donate_argnums=(0,), keep_unused=True)
+
+# below 2^7 amplitudes a block is narrower than the chip's 128 lanes and
+# the index gather keeps it; at 30 qubits the doubled axis would pass
+# int32 (and a ket beside its result the chip: PERF.md section 7)
+ROTATE_MIN_BITS = 7
+ROTATE_MAX_QB = 29
 
 # A fresh ket is allocated behind a spacer of this many bytes, let go
 # at once.  An engine is as a rule the first thing a process puts on
@@ -567,26 +617,135 @@ class QEngineTPU(QEngine):
                                 target, tuple(controls))
         self._drift_tick()
 
+    # ------------------------------------------------------------------
+    # the ALU (docs/OBSERVABILITY.md): every call that runs a whole-ket
+    # program of its own is a span ``engine.alu`` and one of the counters
+    # ``alu.tpu.rotate`` / ``.gather`` / ``.out_of_place`` / ``.phase_fn``;
+    # a comparator flip that rides the pending window instead counts
+    # ``alu.tpu.phase_queued`` and runs no program
+    # ------------------------------------------------------------------
+
+    # the add family as a rotation of the planes, the comparator flips as
+    # ops of the window: the compressed subclass holds codes, not planes
+    _alu_on_planes = True
+
+    def _alu(self, kind: str, split=None):
+        if not _tele._ENABLED:
+            return _tele._NULL_SPAN
+        _tele.inc(f"alu.{self._tele_name}.{kind}")
+        return _tele.span("engine.alu", arg=split[0][0] if split else kind)
+
+    # the planes the last rotation read, kept as the next one's
+    # destination until a host read proves the device done
+    _alu_spare = None
+
+    def _k_rotate(self, shift: int, block_bits: int) -> None:
+        """``qrack_alu_rotate`` on the resident planes (the read of
+        ``_state`` flushes the pending window: an ALU call is a
+        barrier).  The planes it read are the next rotation's
+        destination: the host runs ahead of the device, and a result
+        allocated at every dispatch stood a third ket beside the two of
+        ``DEC`` before ``INC`` had run (6.0 GiB at w28; PERF.md, PR 49).
+        Planes the prefix cache pinned as shared are never a destination."""
+        with self._alu("rotate"):
+            planes = self._state
+            into, self._alu_spare = self._alu_spare, None
+            # the whole-register form alone: written over a second ket a
+            # block rotation took 219 ms where a fresh result takes 15.9
+            # (w28; PERF.md section 7)
+            whole = block_bits == self.qubit_count
+            if into is not None and (
+                    not whole or into.shape != planes.shape
+                    or into.dtype != planes.dtype
+                    or into.is_deleted() or planes_pinned(into)):
+                into = None
+            self._state = _j_alu_rotate(into, planes, np.int32(shift),
+                                        block_bits)
+            if whole and not planes_pinned(planes):
+                self._alu_spare = planes
+        if _tele._ENABLED:
+            # one read and one write of the planes (roofline.tpu.alu.rotate.*)
+            _roofline.note_bytes("tpu.alu.rotate", _roofline.plane_pass_bytes(
+                self.qubit_count, jnp.dtype(self.dtype).itemsize))
+
+    def _rotates(self, block_bits: int) -> bool:
+        return (self._alu_on_planes and block_bits >= ROTATE_MIN_BITS
+                and self.qubit_count <= ROTATE_MAX_QB)
+
+    def INC(self, to_add: int, start: int, length: int) -> None:
+        """An add of a constant on a contiguous register rotates the ket
+        along the register's axis (``DEC`` arrives here as the add of
+        the complement, interface/alu.py)."""
+        if not length or not self._rotates(start + length):
+            return super().INC(to_add, start, length)
+        self._check_range(start, length)
+        to_add &= (1 << length) - 1
+        if to_add:
+            self._k_rotate(to_add << start, start + length)
+
+    def INCDECC(self, to_add: int, start: int, length: int,
+                carry_index: int) -> None:
+        # a carry that sits on top of its register is the register's top bit
+        if length and carry_index == start + length:
+            return self.INC(to_add, start, length + 1)
+        super().INCDECC(to_add, start, length, carry_index)
+
+    def _comparator_flip(self, name: str, *args) -> None:
+        """A comparator's phase flip is a few multi-controlled phase
+        gates (interface/alu.py's synthesis: at most 2 L cubes), which
+        the window takes with runtime masks: no flush, no whole-ket
+        factor arrays.  Without a fuser it keeps ``_k_phase_fn``."""
+        queued = self._fuser is not None and self._alu_on_planes
+        if queued and _tele._ENABLED:
+            _tele.inc(f"alu.{self._tele_name}.phase_queued")
+        getattr(AluMixin if queued else QEngine, name)(self, *args)
+
+    def ZeroPhaseFlip(self, start: int, length: int) -> None:
+        self._comparator_flip("ZeroPhaseFlip", start, length)
+
+    def PhaseFlipIfLess(self, greater_perm: int, start: int, length: int) -> None:
+        self._comparator_flip("PhaseFlipIfLess", greater_perm, start, length)
+
+    def CPhaseFlipIfLess(self, greater_perm: int, start: int, length: int,
+                         flag_index: int) -> None:
+        self._comparator_flip("CPhaseFlipIfLess", greater_perm, start, length,
+                              flag_index)
+
+    def PhaseFlip(self) -> None:
+        self._comparator_flip("PhaseFlip")
+
     def _k_gather(self, src_fn, split=None) -> None:
-        st = self._owned_state()
-        src = src_fn(gk.iota_for(st))
-        self._state = _j_gather(st, src)
+        with self._alu("gather", split):
+            st = self._owned_state()
+            src = src_fn(gk.iota_for(st))
+            self._state = _j_gather(st, src)
 
     def _k_out_of_place(self, src_idx, dst_idx, passthrough_cmask) -> None:
-        src_idx = jnp.asarray(src_idx, dtype=gk.IDX_DTYPE)
-        dst_idx = jnp.asarray(dst_idx, dtype=gk.IDX_DTYPE)
-        new = jnp.zeros_like(self._state)
-        if passthrough_cmask is not None:
-            idx = gk.iota_for(self._state)
-            keep = (idx & passthrough_cmask) != passthrough_cmask
-            new = jnp.where(keep, self._state, new)
-        new = new.at[:, dst_idx].set(self._state[:, src_idx])
-        self._state = new
+        with self._alu("out_of_place"):
+            src_idx = jnp.asarray(src_idx, dtype=gk.IDX_DTYPE)
+            dst_idx = jnp.asarray(dst_idx, dtype=gk.IDX_DTYPE)
+            new = jnp.zeros_like(self._state)
+            if passthrough_cmask is not None:
+                idx = gk.iota_for(self._state)
+                keep = (idx & passthrough_cmask) != passthrough_cmask
+                new = jnp.where(keep, self._state, new)
+            new = new.at[:, dst_idx].set(self._state[:, src_idx])
+            self._state = new
 
     def _k_phase_fn(self, fn, split=None) -> None:
-        st = self._owned_state()
-        fre, fim = fn(jnp, gk.iota_for(st))
-        self._state = _j_phase_apply(st, fre, fim)
+        with self._alu("phase_fn", split):
+            st = self._owned_state()
+            fre, fim = fn(jnp, gk.iota_for(st))
+            self._state = _j_phase_apply(st, fre, fim)
+
+    def _host_read(self, fn):
+        """``fn`` of the resident planes, read on the host (site
+        ``tpu.device_get``, span ``engine.read``).  The read proves the
+        device done, so the ket a rotation kept as its next destination
+        is let go behind it."""
+        out = _device_get(fn, self._state)
+        self._alu_spare = None
+        return out
 
     def _k_probs(self) -> np.ndarray:
         return np.asarray(_j_probs(self._state), dtype=np.float64)
@@ -602,7 +761,7 @@ class QEngineTPU(QEngine):
         """Device-side categorical sample; no 2^n host transfer
         (reference MAll ships probabilities to host)."""
         r = float(self.Rand())
-        result = _device_get(lambda st: int(_j_sample(st, r)), self._state)
+        result = self._host_read(lambda st: int(_j_sample(st, r)))
         self.SetPermutation(result)
         return result
 
@@ -680,7 +839,7 @@ class QEngineTPU(QEngine):
     # ------------------------------------------------------------------
 
     def GetQuantumState(self) -> np.ndarray:
-        return _device_get(gk.from_planes, self._state)
+        return self._host_read(gk.from_planes)
 
     def SetQuantumState(self, state) -> None:
         st = np.asarray(state).reshape(-1)
@@ -689,8 +848,8 @@ class QEngineTPU(QEngine):
         self._state = self._put(gk.to_planes(st, self.dtype))
 
     def GetAmplitude(self, perm: int) -> complex:
-        amp = _device_get(
-            lambda st: np.asarray(st[:, perm], dtype=np.float64), self._state)
+        amp = self._host_read(
+            lambda st: np.asarray(st[:, perm], dtype=np.float64))
         return complex(amp[0], amp[1])
 
     def SetAmplitude(self, perm: int, amp: complex) -> None:
@@ -750,7 +909,7 @@ class QEngineTPU(QEngine):
 
     def Finish(self) -> None:
         if self._state is not None:
-            _device_get(self._state.block_until_ready)
+            self._host_read(lambda st: st.block_until_ready())
 
     # -- device placement (reference: SetDevice, opencl.cpp:535) --
 
@@ -773,9 +932,8 @@ class QEngineTPU(QEngine):
         return not bool(jnp.any(self._state != 0))
 
     def GetAmplitudePage(self, offset: int, length: int) -> np.ndarray:
-        return _device_get(
-            lambda st: gk.from_planes(st[:, offset:offset + length]),
-            self._state)
+        return self._host_read(
+            lambda st: gk.from_planes(st[:, offset:offset + length]))
 
     def SetAmplitudePage(self, page, offset: int) -> None:
         self._state = self._state.at[:, offset:offset + len(page)].set(

@@ -406,6 +406,9 @@ class QEngineTurboQuant(QEngineTPU):
     """Dense ket resident as rotated b-bit block codes (lossy)."""
 
     _tele_name = "turboquant"
+    # codes, not planes: the add family keeps the gather over the
+    # decompressed ket and the comparator flips their chunked phase pass
+    _alu_on_planes = False
 
     # the chunk and tile window bodies hold no two-target op: the
     # two-qubit gates keep the base engine's routes, not QEngineTPU's

@@ -482,6 +482,13 @@ class AluMixin:
         self._phase_flip_if_in_range(0, greater_perm, start, length,
                                      extra_controls=(flag_index,), extra_perm=1)
 
+    def ZeroPhaseFlip(self, start: int, length: int) -> None:
+        """-1 phase on every basis state whose register reads 0
+        (reference: QInterface::ZeroPhaseFlip; what test_grover and
+        examples/grovers.cpp call between DEC and INC): one
+        multi-controlled phase, every control at 0."""
+        self._phase_flip_if_in_range(0, 1, start, length)
+
     def PhaseFlip(self) -> None:
         """Global -1 phase (reference: include/qinterface.hpp PhaseFlip)."""
         self._phase_flip_if_in_range(0, 2, 0, 1)

@@ -22,22 +22,32 @@ def ghz(qsim, n: Optional[int] = None) -> None:
 
 
 def grover_search(qsim, target: int, n: Optional[int] = None) -> int:
-    """Grover search for |target> via phase-flip oracle (reference:
-    examples/grovers.cpp:1-68 — same oracle construction from
-    PhaseFlipIfLess pairs). Returns the measured index."""
+    """Grover search for |target> with the source's arithmetic oracle
+    (reference: test/benchmarks.cpp:542 test_grover and
+    examples/grovers.cpp: DEC, ZeroPhaseFlip, INC, then H,
+    ZeroPhaseFlip, H, PhaseFlip). Returns the measured index."""
     n = n if n is not None else qsim.GetQubitCount()
     for i in range(n):
         qsim.H(i)
     iters = int(math.floor(math.pi / 4 * math.sqrt(1 << n)))
     for _ in range(iters):
-        qsim.PhaseFlipIfLess(target + 1, 0, n)
-        qsim.PhaseFlipIfLess(target, 0, n)
-        for i in range(n):
-            qsim.H(i)
-        qsim.PhaseFlipIfLess(1, 0, n)
-        for i in range(n):
-            qsim.H(i)
+        grover_iteration(qsim, target, n)
     return qsim.MAll()
+
+
+def grover_iteration(qsim, target: int, n: int) -> None:
+    """One Grover iteration as the source writes it: the oracle is true
+    for |target> (the register is moved down by it, the state that reads
+    0 flipped, the register moved back), then the diffusion."""
+    qsim.DEC(target, 0, n)
+    qsim.ZeroPhaseFlip(0, n)
+    qsim.INC(target, 0, n)
+    for i in range(n):
+        qsim.H(i)
+    qsim.ZeroPhaseFlip(0, n)
+    for i in range(n):
+        qsim.H(i)
+    qsim.PhaseFlip()
 
 
 def teleport(qsim, prepare=None) -> Tuple[float, float]:

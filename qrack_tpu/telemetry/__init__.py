@@ -268,7 +268,7 @@ _SPAN_IDS = itertools.count(1)
 _ANNOTATION = None
 
 
-def _annotation(name: str, span_id: int):
+def _annotation(name: str, span_id: int, arg=None):
     global _ANNOTATION
     if _ANNOTATION is None:
         from jax.profiler import TraceAnnotation
@@ -277,6 +277,8 @@ def _annotation(name: str, span_id: int):
         _listen()  # the environment gate, before jax was imported
     # the id is a statistic of the host-plane event: what ties a ring
     # entry (perf_counter) to the trace's clock
+    if arg is not None:
+        return _ANNOTATION("qrack." + name, id=span_id, arg=arg)
     return _ANNOTATION("qrack." + name, id=span_id)
 
 
@@ -300,11 +302,14 @@ def _record(entry: dict) -> None:
 
 
 class _Span:
-    __slots__ = ("name", "t0", "depth", "trace", "id", "parent", "_ann")
+    __slots__ = ("name", "t0", "depth", "trace", "id", "parent", "_ann",
+                 "arg")
 
-    def __init__(self, name: str, trace=None, annotate: bool = True):
+    def __init__(self, name: str, trace=None, annotate: bool = True,
+                 arg=None):
         self.name = name
         self.trace = trace
+        self.arg = arg
         self._ann = None if annotate else _NULL_SPAN
 
     def __enter__(self):
@@ -319,7 +324,7 @@ class _Span:
         # host plane too, on the device trace's clock; without one the
         # annotation costs a flag test
         if self._ann is None:
-            self._ann = _annotation(self.name, self.id)
+            self._ann = _annotation(self.name, self.id, self.arg)
         self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
@@ -341,20 +346,24 @@ class _Span:
         }
         if trace is not None:
             entry["trace"] = trace
+        if self.arg is not None:
+            entry["arg"] = self.arg
         _record(entry)
         return False
 
 
-def span(name: str, trace=None):
+def span(name: str, trace=None, arg=None):
     """Nestable timer of host time (see the module docstring: device
     time comes from a profiler trace, where this span is an event
     named ``"qrack." + name``).  `trace` pins a distributed-trace id on
     the recorded span (defaults to the thread's :func:`current_trace` —
     pass it explicitly when the span runs on a different thread than
-    the one that minted the id, e.g. the executor's dispatch owner)."""
+    the one that minted the id, e.g. the executor's dispatch owner).
+    `arg` is one word the span carries into its ring entry and its trace
+    event (``engine.alu``: the ALU call's name)."""
     if not _ENABLED:
         return _NULL_SPAN
-    return _Span(name, trace)
+    return _Span(name, trace, arg=arg)
 
 
 def self_seconds(entries) -> Dict[int, float]:

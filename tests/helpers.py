@@ -105,6 +105,12 @@ def benchmark_plans(width: int = 28):
                 self.windows[-1]["ops"] = fu.lower_gates(gates)
                 return dispatched
 
+            def _k_rotate(self, shift, block_bits):
+                # an ALU call is a barrier: the pending window flushes
+                # as at a read of the planes; there are none to rotate
+                if self._fuser.gates:
+                    self._fuser.flush("read")
+
         def windows(name):
             planner, structure.PlanOnlyEngine = structure.PlanOnlyEngine, WithOps
             try:

@@ -1027,3 +1027,74 @@ def test_compile_cache_key_does_not_hold_the_call_stack(
     finally:
         for k, v in saved.items():
             jax.config.update(k, v)
+
+
+# ---------------------------------------------------------------------------
+# PR 49: Grover's search with an arithmetic oracle at w28 (the cell
+# ``grover_w28.library``): the ALU's add as one rotation of the ket, and
+# the windows its barriers cut
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("destination", [True, False],
+                         ids=["written-over", "fresh"])
+@pytest.mark.parametrize("block_bits", [28, 24, 7],
+                         ids=["whole-ket", "block-2^24", "block-2^7"])
+def test_alu_rotate_w28(one_chip, block_bits, destination):
+    """``INC``/``DEC`` on a contiguous register: one program, the amount a
+    runtime operand, no ``gather`` and no index array of the ket's length;
+    the whole-register form (the deployment's) is one fusion with no
+    temporary, a block form keeps a predicate of the ket's length
+    (256 MiB).  Its result takes the buffer of a second ket, donated and
+    never read (the planes the rotation before it read), or a fresh one;
+    never that of the planes it reads, which would cost a whole-ket
+    copy."""
+    from qrack_tpu.engines import tpu as tpu_engine
+
+    planes = jax.ShapeDtypeStruct((2, 1 << W), jnp.float32, sharding=one_chip)
+    shift = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        tpu_engine.qrack_alu_rotate, static_argnums=(3,), donate_argnums=(0,),
+        keep_unused=True).lower(planes if destination else None, planes,
+                                shift, block_bits).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    print(f"temp_bytes={memory.temp_size_in_bytes}")
+    assert text.startswith("HloModule jit_qrack_alu_rotate")
+    assert "gather" not in text and "scatter" not in text
+    assert not re.findall(r"f32\[2,%d\]\S* copy\(" % (1 << W), text)
+    assert memory.temp_size_in_bytes <= (KET_BYTES >> 3) + (1 << 20)
+    assert memory.output_size_in_bytes == KET_BYTES
+    assert memory.alias_size_in_bytes == (KET_BYTES if destination else 0)
+    if block_bits == W:
+        assert memory.temp_size_in_bytes <= 1 << 20
+        assert not re.findall(r"s32\[(?:2,)?%d\]" % (1 << W), text)
+
+
+@pytest.fixture(scope="module")
+def grover_windows():
+    """The windows of one Grover iteration at w28, as the fuser flushes
+    them between the rotations (``helpers.benchmark_plans``)."""
+    from helpers import benchmark_plans
+
+    with benchmark_plans(W) as windows:
+        return [w["structure"] for w in windows("grover")]
+
+
+@pytest.mark.parametrize("index,sweeps", [(0, 1), (1, 13), (2, 13)],
+                         ids=["zero-phase-flip-alone", "diffusion-first-32",
+                              "diffusion-rest"])
+def test_grover_window_sweeps_its_ket_in_place(one_chip, grover_windows,
+                                               index, sweeps):
+    """The oracle's ``ZeroPhaseFlip`` (one ``diag`` under 27 controls,
+    every one at 0: its masks are runtime operands) stands alone between
+    ``DEC`` and ``INC`` and is one in-tile sweep; the diffusion's first
+    window holds a layer of ``H``, the second flip and three more ``H``."""
+    assert [len(s) for s in grover_windows] == [1, 32, 26]
+    structure = grover_windows[index]
+    if index == 0:
+        assert structure == (("diag", 0, True),)
+    plan, why = fu.kernel_lowering(W, structure, backend="tpu")
+    assert why is None and plan["sweeps"] == sweeps
+    compiled = _compile(pk.make_window_fn(W, structure),
+                        _dense_args(structure, one_chip))
+    assert _launches(compiled) == sweeps
+    assert _in_place(compiled)

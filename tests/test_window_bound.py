@@ -1,7 +1,7 @@
 """The fuser's pending-window bound (``ops/fusion.DEFAULT_WINDOW``: 32
 ops since PR 46, 16 before), the gate that opens a window on a paged
 engine (``GateStreamFuser._heads_a_window``) and what the two plan for
-the benchmark's seven cells, held without a chip.
+the benchmark's eight cells, held without a chip.
 
 The bound sets how many launches an application is: a window that fills
 at 16 flushes a random circuit's roots bare before the coupler they
@@ -46,6 +46,11 @@ PLANS = {
     # 36 of them led, until then; on the pager 117 / 11 / 43 / 40 and, on
     # the fixed placement, 117 / 4 / 49 / 36
     "tfim_w28.library": {16: (82, 4, 15, 12), 32: (82, 2, 14, 12)},
+    # a Grover iteration (PR 49): 59 ops (56 H, two ZeroPhaseFlip as one
+    # controlled ``diag`` each, PhaseFlip as a ``diag``) in the windows
+    # its two ALU rotations and the bound cut: the lone ZeroPhaseFlip
+    # between DEC and INC, then the diffusion, 12 led launches a layer
+    "grover_w28.library": {16: (59, 5, 27, 24), 32: (59, 3, 27, 24)},
     "qft_w31.pager4": {16: (496, 34, 45, 15), 32: (496, 19, 30, 15)},
     "tfim_w30.pager4": {16: (88, 9, 17, 12), 32: (88, 7, 15, 12)},
     "tfim_w30.pager4_noremap": {16: (88, 4, 17, 12), 32: (88, 2, 16, 12)},
@@ -71,11 +76,13 @@ EXCHANGES = {
 SIZES = {
     "qft_w31.pager4": [2, 3, 4] + [32] * 15 + [7],
     "tfim_w28.library": [59, 23],
+    "grover_w28.library": [1, 32, 26],
     "tfim_w30.pager4": [50, 2, 32, 1, 1, 1, 1],
     "tfim_w30.pager4_noremap": [61, 27],
 }
 DENSE = {"rcs_w28.library": ("rcs", 28), "qft_w28.library": ("qft", 28),
-         "qft_w30.library": ("qft", 30), "tfim_w28.library": ("tfim", 28)}
+         "qft_w30.library": ("qft", 30), "tfim_w28.library": ("tfim", 28),
+         "grover_w28.library": ("grover", 28)}
 
 
 def _dense_plan(family, width):
@@ -141,6 +148,12 @@ def test_cell_plans_at_the_bound(cell, bound, monkeypatch):
         if family == "tfim":
             # the bound counts gates: 27 bonds of two ops and 5 RX
             assert bound < max(sizes) <= 2 * bound
+            assert bound != fu.DEFAULT_WINDOW or sizes == SIZES[cell]
+        elif family == "grover":
+            # the circuit cuts the windows before the bound does: an ALU
+            # call is a barrier, and the oracle's flip stands alone
+            assert sizes[0] == 1 and max(sizes) == bound
+            assert planned[0]["structure"] == (("diag", 0, True),)
             assert bound != fu.DEFAULT_WINDOW or sizes == SIZES[cell]
         else:
             assert max(sizes) == bound and set(sizes[:-1]) == {bound}
