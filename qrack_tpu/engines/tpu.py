@@ -512,7 +512,8 @@ class QEngineTPU(QEngine):
         if plan is not None:
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
                                    width=n, esize=esize, cross=plan["cross"],
-                                   dense=plan["dense"], twoq=plan["twoq"],
+                                   dense=plan["dense"], paired=plan["paired"],
+                                   twoq=plan["twoq"],
                                    lowered=lambda: fu.count_kernel_window(
                                        ops, plan["block_pow"]))
         else:

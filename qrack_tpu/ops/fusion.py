@@ -487,11 +487,13 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
 
     Returns ``(plan, fallback_reason)`` — exactly one is non-None.
     ``plan`` is ``{"interpret": bool, "block_pow": int, "sweeps": int,
-    "cross": int, "dense": int, "twoq": dict}`` (``cross``: the
-    cross-tile segments a 2x2 leads among the sweeps; ``dense``: the
-    sweeps whose kernel body computes on the dense ``(rows, 128)`` tile,
-    pallas_kernels.dense_tile; ``twoq``: pallas_kernels.twoq_counts,
-    the window's two-target ops and the sweeps that carry them).
+    "cross": int, "dense": int, "paired": int, "twoq": dict}``
+    (``cross``: the cross-tile segments 2x2s lead among the sweeps;
+    ``dense``: the sweeps whose kernel body computes on the dense
+    ``(rows, 128)`` tile, pallas_kernels.dense_tile; ``paired``: the
+    second leads that joined a segment, pallas_kernels.plan_window;
+    ``twoq``: pallas_kernels.twoq_counts, the window's two-target ops
+    and the sweeps that carry them).
 
     The decision inputs are the mode, the backend and the window length
     (the plan's counts depend on the op mix, width and block_pow, the
@@ -530,7 +532,7 @@ SMEM_OPERAND_ROWS = 1536
 def _lowering(structure: Tuple, backend, bp: int, counts,
               split: bool = False):
     """The choice both lowerings share; ``counts(structure, bp)`` gives
-    the plan's ``(sweeps, cross, dense)``.  A window whose operand
+    the plan's ``(sweeps, cross, dense, paired)``.  A window whose operand
     columns (``split``: in the sharded layout) would not fit a chip's
     SMEM takes the chain (reason ``smem_operands``) where the chip's
     compiler would refuse its program."""
@@ -545,10 +547,10 @@ def _lowering(structure: Tuple, backend, bp: int, counts,
         _, floats, ints = pk._operand_slots(structure, split)
         if floats + ints > SMEM_OPERAND_ROWS:
             return None, "smem_operands"
-    sweeps, cross, dense = counts(structure, bp)
+    sweeps, cross, dense, paired = counts(structure, bp)
     plan = {"interpret": backend != "tpu", "block_pow": bp,
             "sweeps": sweeps, "cross": cross, "dense": dense,
-            "twoq": pk.twoq_counts(structure, bp)}
+            "paired": paired, "twoq": pk.twoq_counts(structure, bp)}
     if mode == "on":
         return plan, None
     if backend != "tpu":
@@ -620,11 +622,13 @@ def count_kernel_window(ops: Sequence[FusedOp], block_pow: int,
 
 def record_kernel_flush(name: str, nops: int, sweeps: int,
                         width=None, esize: int = 4, cross: int = 0,
-                        dense: int = 0, twoq=None, lowered=None) -> None:
+                        dense: int = 0, paired: int = 0, twoq=None,
+                        lowered=None) -> None:
     """A window flushed through the Pallas kernel: count it, the HBM
     sweeps it actually paid (telemetry_report derives sweeps/window),
-    how many of them were cross-tile pair segments and how many
-    computed on the dense tile; with ``twoq`` (the plan's), its
+    how many of them were cross-tile pair segments, how many computed
+    on the dense tile and how many second leads joined a segment (each
+    a sweep not paid); with ``twoq`` (the plan's), its
     two-target ops and the sweeps that carried them, by placement; with
     ``lowered`` (a call that gives :func:`count_kernel_window`, made
     only while telemetry is on), the runs of diagonal ops the kernel
@@ -638,6 +642,7 @@ def record_kernel_flush(name: str, nops: int, sweeps: int,
         _tele.inc("fuse.kernel.sweeps", sweeps)
         _tele.inc("fuse.kernel.sweeps.cross", cross)
         _tele.inc("fuse.kernel.sweeps.dense", dense)
+        _tele.inc("fuse.kernel.leads.paired", paired)
         for key, count in (twoq or {}).items():
             if count:
                 _tele.inc(f"fuse.kernel.twoq.{key}", count)
@@ -1003,25 +1008,22 @@ def _sharded_run_operands(run, L: int, views, pid, dtype):
 
 
 def sharded_kernel_counts(structure: Tuple, L: int,
-                          block_pow: int) -> Tuple[int, int, int]:
-    """``(sweeps, cross, dense)`` of the per-page kernel lowering: one
-    sweep per planned kernel segment inside each local run and one per
-    ppermute exchange; ``cross`` counts the runs' cross-tile pair
-    segments and ``dense`` their dense-tile ones (an exchange is no
-    kernel launch)."""
+                          block_pow: int) -> Tuple[int, int, int, int]:
+    """``(sweeps, cross, dense, paired)`` of the per-page kernel
+    lowering: one sweep per planned kernel segment inside each local run
+    and one per ppermute exchange; ``cross`` counts the runs' cross-tile
+    pair segments, ``dense`` their dense-tile ones and ``paired`` the
+    second leads that joined one (an exchange is no kernel launch)."""
     from . import pallas_kernels as pk
 
-    total = cross = dense = 0
+    total = [0, 0, 0, 0]
     for seg in _sharded_segments(structure, L):
         if seg[0] == "global":
-            total += 1
+            total[0] += 1
         else:
-            s, x, d = pk.plan_counts(_sharded_run_structure(seg[1], L),
-                                     block_pow)
-            total += s
-            cross += x
-            dense += d
-    return total, cross, dense
+            total = list(map(sum, zip(total, pk.plan_counts(
+                _sharded_run_structure(seg[1], L), block_pow))))
+    return tuple(total)
 
 
 def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):

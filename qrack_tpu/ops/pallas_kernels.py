@@ -28,10 +28,11 @@ Vocabulary (everything the fuser emits):
   reads its partner 2^target amplitudes away through two rotations
   (tile_partner); controls anywhere (runtime mask split).
 * inv / gen with target >= block_pow — CROSS-TILE: the planner starts a
-  new segment led by the op, and the segment's grid walks an ORBIT at a
-  time.  An orbit is the set of tiles the lead mixes, here the two
-  tiles ``{base, base | 1 << (target - block_pow)}`` (orbit_tile); the
-  grid is ``(orbits + 1, members)``, the member innermost.  A step moves
+  new segment led by the op, unless the op joins a bare lead (below),
+  and the segment's grid walks an ORBIT at a time.  An orbit is the set
+  of tiles the lead mixes, here the two tiles ``{base, base | 1 <<
+  (target - block_pow)}`` (orbit_tile); the grid is ``(orbits + 1,
+  members)``, the member innermost.  A step moves
   one tile in and one tile out, as an unled step does: step ``(o, j)``
   is handed tile ``j`` of orbit ``o``, casts it to the dense tile
   (below) into one half of a VMEM scratch, and computes tile ``j`` of
@@ -43,6 +44,23 @@ Vocabulary (everything the fuser emits):
   that the second's then write with what belongs there).  The tile id
   that high-bit masks and the riding in-tile ops read is
   ``orbit_tile(lead bits, o - 1, j)``, not a grid index.
+  TWO LEADS A LAUNCH: a cross-tile inv/gen that directly follows a
+  cross-tile inv/gen on another qubit with no op behind it yet joins
+  that segment as its second lead (plan_window; nothing is reordered,
+  a u4 lead neither joins nor is joined, and a third lead opens the
+  next segment).  The segment's orbits are the four tiles over both
+  targets, its grid and scratch those of a u4 above the tile, and
+  member ``j`` computes its tile of ``second . first`` in their order:
+  the first lead's row on tile ``j`` and on the tile across the
+  second's bit, the second's row over those two (six complex
+  multiply-adds an amplitude where two launches make four, and one
+  pass over HBM where they make two).  Each lead keeps its own control,
+  its high half tested on the id of the tile the lead computes, so a
+  lead controlled by the other's target pairs like any other; the
+  arithmetic and its order are two launches', the result theirs bit
+  for bit but for the sign of a zero (the value between the leads
+  stays in VMEM and meets no ``+ 0.0`` cast).  The ops behind the
+  second lead ride as behind a single one.
 * u4 — the two-target op, a 4x4 on ``target = (lo, hi)``, never
   controlled: each amplitude reads the four members of its (lo, hi)
   quad and applies its own row of the matrix (tile_quad_mix).  Three
@@ -64,7 +82,9 @@ w28) per launch for it, and a view with a short minor axis is padded
 (32 GiB at w28: PERF.md section 6, PR 25).
 
 ``sweeps == len(segments)``: a window with no cross-tile non-diagonal
-op is exactly one sweep; each cross-tile op opens one more.
+op is exactly one sweep; each cross-tile op opens one more unless it
+joins a bare lead (telemetry counts those as
+``fuse.kernel.leads.paired``: sweeps the window does not pay).
 
 The dense tile.  The refs hold a block as ``(2, 2^block_pow)``: two
 rows in a vreg's eight sublanes, every vreg a quarter full.  From
@@ -187,25 +207,43 @@ def segment_compatible(kind: str, target, block_pow: int) -> bool:
     return kind in ("cphase", "diag") or target < block_pow
 
 
+def _pairs_with(cur: dict, slot) -> bool:
+    """Does the cross-tile ``slot`` join the segment ``cur`` as its
+    second lead?  Where ``cur`` is led by one cross-tile inv/gen on
+    another qubit and has no op behind that lead yet: the two mix one
+    orbit of four tiles, in their order, in one sweep.  A u4 neither
+    joins nor is joined, and the rule stops at two leads.  From the
+    structure alone: the program key does not know it."""
+    leads = cur["leads"]
+    return (slot[1] in ("inv", "gen") and len(leads) == 1 and not cur["ops"]
+            and leads[0][1] in ("inv", "gen") and leads[0][2] != slot[2])
+
+
 def plan_window(structure: Tuple, block_pow: int) -> List[dict]:
     """Split a window structure into single-sweep segments.
 
-    Returns a list of ``{"xgen": slot | None, "ops": [slot, ...]}``
-    where each slot is ``(op_index, kind, target, has_ctrl)``.  A
-    cross-tile inv/gen (target >= block_pow) or u4 (hi >= block_pow)
-    leads its own segment — the grid walks the lead's orbits of two or
-    four tiles and mixes them for exactly one op, then the rest of the
-    segment applies in-tile."""
+    Returns a list of ``{"xgen": slot | None, "leads": (slot, ...),
+    "ops": [slot, ...]}`` where each slot is ``(op_index, kind, target,
+    has_ctrl)``.  A cross-tile inv/gen (target >= block_pow) or u4
+    (hi >= block_pow) leads a segment: the grid walks the lead's orbits
+    of two or four tiles and mixes them, then the rest of the segment
+    applies in-tile.  ``leads`` are the ops that lead the segment, in
+    their order, and ``xgen`` is the first of them: one, or the two
+    cross-tile inv/gen of a PAIR, where the second directly follows a
+    bare first on another qubit (_pairs_with) and the segment's orbits
+    are the four tiles over both targets."""
     segs: List[dict] = []
-    cur = {"xgen": None, "ops": []}
+    cur = {"xgen": None, "leads": (), "ops": []}
     for idx, (kind, target, has_ctrl) in enumerate(structure):
         slot = (idx, kind, target, has_ctrl)
-        if not segment_compatible(kind, target, block_pow):
-            if cur["ops"] or cur["xgen"] is not None:
-                segs.append(cur)
-            cur = {"xgen": slot, "ops": []}
-        else:
+        if segment_compatible(kind, target, block_pow):
             cur["ops"].append(slot)
+        elif _pairs_with(cur, slot):
+            cur["leads"] += (slot,)
+        else:
+            if cur["ops"] or cur["leads"]:
+                segs.append(cur)
+            cur = {"xgen": slot, "leads": (slot,), "ops": []}
     segs.append(cur)
     return segs
 
@@ -220,16 +258,19 @@ def dense_tile(block_pow: int) -> Optional[Tuple[int, int]]:
     return (1 << (block_pow - _LANE_POW), 1 << _LANE_POW)
 
 
-def plan_counts(structure: Tuple, block_pow: int) -> Tuple[int, int, int]:
-    """``(sweeps, cross, dense)``: the HBM sweeps the kernel lowering
-    pays for this window (the XLA window chain pays ~len(structure)),
-    how many of them are cross-tile pair segments led by a 2x2, and how
-    many compute on the dense tile (all, where the block has one)."""
+def plan_counts(structure: Tuple, block_pow: int) -> Tuple[int, int, int, int]:
+    """``(sweeps, cross, dense, paired)``: the HBM sweeps the kernel
+    lowering pays for this window (the XLA window chain pays
+    ~len(structure)), how many of them are cross-tile segments led by
+    2x2s (a pair of leads is one), how many compute on the dense tile
+    (all, where the block has one), and the second leads that joined a
+    segment (plan_window): each is a sweep the window does not pay."""
     segs = plan_window(structure, block_pow)
     return (len(segs),
             sum(segment_kernel_name(seg, block_pow) == CROSS_KERNEL_NAME
                 for seg in segs),
-            len(segs) if dense_tile(block_pow) else 0)
+            len(segs) if dense_tile(block_pow) else 0,
+            sum(len(seg["leads"]) - 1 for seg in segs if seg["leads"]))
 
 
 def segment_kernel_name(seg: dict, block_pow: int) -> str:
@@ -1168,16 +1209,14 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
 
         return launch(kernel, lead_bits)
 
-    idx, kind, target, has_ctrl = xgen
-    foff_x, ioff_x = slots[idx]
-
-    if kind == "u4":
+    if xgen[1] == "u4":
         # the two-target op above the tile: the quad's members are the
         # orbit's tiles in the order this member meets them, itself
         # first (the order tile_quad_mix sums in), and the low partner
         # inside each where the low target is in the tile
-        lo, hi = target
-        lead_bits = tuple(t - bp for t in target if t >= bp)
+        lo, hi = xgen[2]
+        foff_x = slots[xgen[0]][0]
+        lead_bits = tuple(t - bp for t in xgen[2] if t >= bp)
 
         def mix(tiles, member, blk, iv_ref, fv_ref):
             seen = [tiles(member ^ x) for x in range(1 << len(lead_bits))]
@@ -1195,40 +1234,73 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
 
         return led_kernel(mix, lead_bits)
 
-    # cross-tile segment: the leading inv/gen mixes the orbit's two tiles
-    def mix(tiles, b, blk, iv_ref, fv_ref):
-        # the target-bit-0 / target-bit-1 operands of the 2x2
-        lo, hi = tiles(0), tiles(1)
-        if kind == "gen":
+    # cross-tile segment: the leading inv/gen, one or a pair, mix the
+    # orbit's two or four tiles, in their order
+    leads = seg["leads"]
+    lead_bits = tuple(sorted(slot[2] - bp for slot in leads))
+
+    def lead_row(slot, b, fv_ref):
+        """``(m0r, m0i, m1r, m1i)``: the lead's row for the tiles whose
+        target bit is ``b``."""
+        foff = slots[slot[0]][0]
+        if slot[1] == "gen":
             # my row of the matrix: row b -> (m[b,0], m[b,1]);
             # fv holds mtrx_planes flat: [re00,re01,re10,re11,im...]
-            m0r = jnp.where(b == 0, fv_ref[foff_x + 0, 0],
-                            fv_ref[foff_x + 2, 0])
-            m0i = jnp.where(b == 0, fv_ref[foff_x + 4, 0],
-                            fv_ref[foff_x + 6, 0])
-            m1r = jnp.where(b == 0, fv_ref[foff_x + 1, 0],
-                            fv_ref[foff_x + 3, 0])
-            m1i = jnp.where(b == 0, fv_ref[foff_x + 5, 0],
-                            fv_ref[foff_x + 7, 0])
-        else:  # inv rows: (0, tr) and (bl, 0); fv holds [tr.re,tr.im,bl...]
-            zero = jnp.zeros((), lo.dtype)
-            m0r = jnp.where(b == 0, zero, fv_ref[foff_x + 2, 0])
-            m0i = jnp.where(b == 0, zero, fv_ref[foff_x + 3, 0])
-            m1r = jnp.where(b == 0, fv_ref[foff_x + 0, 0], zero)
-            m1i = jnp.where(b == 0, fv_ref[foff_x + 1, 0], zero)
-        nr = m0r * lo[0] - m0i * lo[1] + m1r * hi[0] - m1i * hi[1]
-        nim = m0r * lo[1] + m0i * lo[0] + m1r * hi[1] + m1i * hi[0]
-        nv = jnp.stack([nr, nim])
-        if has_ctrl:
-            cm = iv_ref[ioff_x, 0]
-            cv = iv_ref[ioff_x + 1, 0]
-            lidx = _tile_index(tile)
-            sel = (((lidx & (cm & lbits)) == (cv & lbits))
-                   & ((blk & (cm >> bp)) == (cv >> bp)))
-            nv = jnp.where(sel, nv, tiles(b))
-        return nv
+            return (jnp.where(b == 0, fv_ref[foff + 0, 0], fv_ref[foff + 2, 0]),
+                    jnp.where(b == 0, fv_ref[foff + 4, 0], fv_ref[foff + 6, 0]),
+                    jnp.where(b == 0, fv_ref[foff + 1, 0], fv_ref[foff + 3, 0]),
+                    jnp.where(b == 0, fv_ref[foff + 5, 0], fv_ref[foff + 7, 0]))
+        # inv rows: (0, tr) and (bl, 0); fv holds [tr.re,tr.im,bl...]
+        zero = jnp.zeros((), fv_ref.dtype)
+        return (jnp.where(b == 0, zero, fv_ref[foff + 2, 0]),
+                jnp.where(b == 0, zero, fv_ref[foff + 3, 0]),
+                jnp.where(b == 0, fv_ref[foff + 0, 0], zero),
+                jnp.where(b == 0, fv_ref[foff + 1, 0], zero))
 
-    return led_kernel(mix, (target - bp,))
+    def mix(tiles, member, blk, iv_ref, fv_ref):
+        # a lead's row, read once: a tile that a later lead visits
+        # differs from this member's own only in the later lead's bit,
+        # so a lead applies the row of the member's own bit wherever it
+        # is applied
+        rows = {}
+
+        def after(upto, k, tile_id):
+            """Tile ``k`` of the orbit (its id ``tile_id``) behind the
+            first ``upto`` leads: the later lead's row over the two
+            tiles across its bit, each behind the leads ahead of it (for
+            a pair: the first lead's row on two tiles, the second's over
+            those, six complex multiply-adds an amplitude in the order
+            two launches make them).  A lead's control is tested on the
+            tile it computes, so a lead controlled by the other's target
+            finds that bit in the tile's id."""
+            if not upto:
+                return tiles(k)
+            _, _, target, has_ctrl = leads[upto - 1]
+            if len(leads) == 1:  # the orbit's two tiles, by name
+                b, lo, hi = k, tiles(0), tiles(1)
+            else:
+                at = lead_bits.index(target - bp)
+                b, bit, high = (member >> at) & 1, 1 << at, 1 << (target - bp)
+                lo = after(upto - 1, k & ~bit, tile_id & ~high)
+                hi = after(upto - 1, k | bit, tile_id | high)
+            if upto not in rows:
+                rows[upto] = lead_row(leads[upto - 1], b, fv_ref)
+            m0r, m0i, m1r, m1i = rows[upto]
+            nr = m0r * lo[0] - m0i * lo[1] + m1r * hi[0] - m1i * hi[1]
+            nim = m0r * lo[1] + m0i * lo[0] + m1r * hi[1] + m1i * hi[0]
+            nv = jnp.stack([nr, nim])
+            if has_ctrl:
+                cm, cv = _slot_masks(leads[upto - 1], slots, iv_ref)
+                lidx = _tile_index(tile)
+                sel = (((lidx & (cm & lbits)) == (cv & lbits))
+                       & ((tile_id & (cm >> bp)) == (cv >> bp)))
+                nv = jnp.where(sel, nv, tiles(k) if upto == 1
+                               else jnp.where(b == 0, lo, hi))
+            return nv
+
+        return after(len(leads), member, blk)
+
+    return led_kernel(mix, lead_bits)
 
 
 def make_window_fn(n: int, structure: Tuple,

@@ -867,6 +867,7 @@ class QPager(QEngine):
             fu.record_kernel_flush(self._tele_name, len(ops), plan["sweeps"],
                                    width=self.qubit_count,
                                    cross=plan["cross"], dense=plan["dense"],
+                                   paired=plan["paired"],
                                    lowered=lambda: fu.count_kernel_window(
                                        tops, plan["block_pow"], split_at=L))
         else:

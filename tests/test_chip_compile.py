@@ -115,11 +115,12 @@ def _in_place(compiled, n=W):
             and compiled.memory_analysis().temp_size_in_bytes <= SLACK)
 
 
-# the tail of the Trotter step's last window at w28 (RX on 15-27: 13
-# planned sweeps for 13 ops, 12 of them cross-tile) and of the paged
-# step's at 2^28 pages (RX on 25-29: 28 and 29 are paged); PR 35 sent
-# both to the kernel, and they were whole windows until a bond became
-# one gate (PR 47: the windows now begin at the RX on 5 and on 3)
+# the tail of the Trotter step's last window at w28 (RX on 15-27: 7
+# planned sweeps for 13 ops, 6 of them cross-tile, each led by two RX
+# since PR 50; 13 and 12 until then) and of the paged step's at 2^28
+# pages (RX on 25-29: 28 and 29 are paged); PR 35 sent both to the
+# kernel, and they were whole windows until a bond became one gate
+# (PR 47: the windows now begin at the RX on 5 and on 3)
 TFIM_LAST = tuple(("gen", t, False) for t in range(15, 28))
 TFIM_LAST_PAGED = tuple(("gen", t, False) for t in range(25, 30))
 
@@ -192,14 +193,15 @@ def test_qft_window_kernel(one_chip, n):
 
 
 def test_tfim_last_window_kernel(one_chip):
-    """13 launches in one program, each on the result of the one
-    before: none beside the donated ket (two kets in flight until
-    PR 39)."""
+    """7 launches in one program (13 until two leads shared one,
+    PR 50), each on the result of the one before: none beside the
+    donated ket (two kets in flight until PR 39)."""
     plan, why = fu.kernel_lowering(W, TFIM_LAST, backend="tpu")
-    assert why is None and (plan["sweeps"], plan["cross"]) == (13, 12)
+    assert why is None
+    assert (plan["sweeps"], plan["cross"], plan["paired"]) == (7, 6, 6)
     compiled = _compile(pk.make_window_fn(W, TFIM_LAST),
                         _dense_args(TFIM_LAST, one_chip))
-    assert _launches(compiled) == 13
+    assert _launches(compiled) == 7
     assert _in_place(compiled)
 
 
@@ -241,16 +243,18 @@ def test_sharded_kernel_window_four_pages(topo):
 
 
 def test_tfim_last_window_sharded_kernel(topo):
-    """The paged Trotter step's last window at w30: three local
-    cross-tile launches on a 2 GiB page, then two exchanges."""
+    """The paged Trotter step's last window at w30: two local
+    cross-tile launches on a 2 GiB page (the RX on 25 and 26 share one
+    since PR 50), then two exchanges."""
     plan, why = fu.sharded_kernel_lowering(W, TFIM_LAST_PAGED, backend="tpu")
-    assert why is None and (plan["sweeps"], plan["cross"]) == (5, 3)
+    assert why is None
+    assert (plan["sweeps"], plan["cross"], plan["paired"]) == (4, 2, 1)
     t0 = time.perf_counter()
     compiled = _compile_sharded(topo, TFIM_LAST_PAGED, W + 2)
     # 2 s with the exchange's halves sliced off the minor axis; 1172 s
     # with a (planes, 2, half) view of a launch's result (PR 35)
     assert time.perf_counter() - t0 < 120
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert _launches(compiled) == 2
     # a page is a w28 ket here: two and a half by the compiler's count
     # (three and a half until PR 39; the step's sixth window, launches
     # between controlled exchanges, reads 3.06, 3.63 before)
@@ -261,19 +265,21 @@ def test_tfim_last_window_sharded_kernel(topo):
 # the paged Trotter step's windows at w30 since a bond is one gate of two
 # controlled ``diag`` (PR 47): the fixed placement's two (58 ``diag`` and
 # the RX on 0-2 in one launch and no exchange: the bonds onto 28 and 29
-# are phases by page; then the RX on 3-29, 13 launches and the step's
+# are phases by page; then the RX on 3-29, 7 launches and the step's
 # two exchanges) and the settled planner step's three that begin with
 # no prologue (50 ``diag``; the two ``diag`` a bond onto a page bit
 # leaves behind the window its first CNOT closed; the RX on 0-25 and the
-# bonds between them, 11 launches).
+# bonds between them, 6 launches).  The RX on 16-27 (16-25) reach the
+# per-page kernel controlled (``fusion._sharded_run_structure``) and
+# share a launch two by two since PR 50: 13 and 11 launches until then.
 # name -> (pager's keywords, window, ops, launches, exchanges?, pages of
 # temporaries in eighths)
 TFIM_PAGED_WINDOWS = {
     "fixed-61op": ({"remap": "off"}, 0, 61, 1, False, 0),
-    "fixed-27op": ({"remap": "off"}, 1, 27, 13, True, 20),
+    "fixed-27op": ({"remap": "off"}, 1, 27, 7, True, 20),
     "planner-50op": ({}, 0, 50, 1, False, 0),
     "planner-2op": ({}, 1, 2, 1, False, 0),
-    "planner-32op": ({}, 2, 32, 11, False, 0),
+    "planner-32op": ({}, 2, 32, 6, False, 0),
 }
 
 
@@ -454,14 +460,15 @@ def test_two_qubit_window_kernel(one_chip, window):
 
 # the window programs of one application of the three dense cells at
 # w28, by the families' own gate lists: QFT's 13 windows (7, 4, 3
-# launches, then ten of one), the Trotter step's 2 (1, 13: 59 ops, 54
-# of them the bonds' ``diag``, and 23; 4 of 1, 14, 12, 13 until PR 47)
+# launches, then ten of one), the Trotter step's 2 (1, 7: 59 ops, 54
+# of them the bonds' ``diag``, and 23, the RX on 16-27 two a launch
+# since PR 50; 1, 13 until then and 4 of 1, 14, 12, 13 until PR 47)
 # and a random circuit's 4 (13, 15, 16, 7), every one a program of its
 # own and every one compiled (26, 7 and 12 structures in 14 windows at
 # the bound of 16, of which 27 were)
 CELL_WINDOWS = [("qft", i) for i in range(13)] \
     + [("tfim", i) for i in range(2)] + [("rcs", i) for i in range(4)]
-CELL_SWEEPS = {"qft": [7, 4, 3] + [1] * 10, "tfim": [1, 13],
+CELL_SWEEPS = {"qft": [7, 4, 3] + [1] * 10, "tfim": [1, 7],
                "rcs": [13, 15, 16, 7]}
 
 
@@ -470,7 +477,7 @@ def _plan_shape(structure):
     segment's kernel, its lead and the kinds that ride behind it."""
     return tuple(
         (pk.segment_kernel_name(seg, pk.DEFAULT_BLOCK_POW),
-         seg["xgen"] and (seg["xgen"][1], seg["xgen"][3]),
+         tuple((kind, ctrl) for _, kind, _, ctrl in seg["leads"]),
          tuple(sorted((kind, ctrl) for _, kind, _, ctrl in seg["ops"])))
         for seg in pk.plan_window(structure, pk.DEFAULT_BLOCK_POW))
 
@@ -485,7 +492,7 @@ def cell_windows():
         out = {family: list(dict.fromkeys(
                    w["structure"] for w in windows(family)
                    if w["path"] == "kernel"))
-               for family in ("qft", "tfim", "rcs")}
+               for family in CELL_SWEEPS}
     assert {f: len(structures) for f, structures in out.items()} \
         == {f: len(sweeps) for f, sweeps in CELL_SWEEPS.items()}
     assert {_plan_shape(s) for structures in out.values() for s in structures} \
@@ -596,14 +603,14 @@ def test_qft_run_window_kernel(one_chip, cell_windows, name):
 # six bare leads), the Trotter step's first window (its run of 54 diag,
 # then the RX on qubits 0-4, which roll lanes: the run's scratch and no
 # pass) and its second (the RX on 5-15 in two passes behind the two that
-# roll lanes, then twelve bare leads).  Each launch: (scratch operands,
-# passes of its stretch)
+# roll lanes, then twelve bare leads in six launches).  Each launch:
+# (scratch operands, passes of its stretch)
 STRETCH_WINDOWS = {
     "rcs-w1": ("rcs", 0, [(1, 3)] + [(1, 0)] * 5 + [(2, 3)] + [(1, 0)] * 5
                + [(2, 1)]),
     "rcs-w4": ("rcs", 3, [(1, 3)] + [(1, 0)] * 6),
     "tfim-54diag-5gen": ("tfim", 0, [(1, 0)]),
-    "tfim-23gen": ("tfim", 1, [(1, 2)] + [(1, 0)] * 12),
+    "tfim-23gen": ("tfim", 1, [(1, 2)] + [(1, 0)] * 6),
 }
 
 
@@ -1079,7 +1086,7 @@ def grover_windows():
         return [w["structure"] for w in windows("grover")]
 
 
-@pytest.mark.parametrize("index,sweeps", [(0, 1), (1, 13), (2, 13)],
+@pytest.mark.parametrize("index,sweeps", [(0, 1), (1, 7), (2, 7)],
                          ids=["zero-phase-flip-alone", "diffusion-first-32",
                               "diffusion-rest"])
 def test_grover_window_sweeps_its_ket_in_place(one_chip, grover_windows,
@@ -1087,7 +1094,9 @@ def test_grover_window_sweeps_its_ket_in_place(one_chip, grover_windows,
     """The oracle's ``ZeroPhaseFlip`` (one ``diag`` under 27 controls,
     every one at 0: its masks are runtime operands) stands alone between
     ``DEC`` and ``INC`` and is one in-tile sweep; the diffusion's first
-    window holds a layer of ``H``, the second flip and three more ``H``."""
+    window holds a layer of ``H``, the second flip and three more ``H``.
+    The twelve ``H`` above the tile of a layer are six launches since
+    PR 50 (13 sweeps a window until then)."""
     assert [len(s) for s in grover_windows] == [1, 32, 26]
     structure = grover_windows[index]
     if index == 0:
@@ -1097,4 +1106,71 @@ def test_grover_window_sweeps_its_ket_in_place(one_chip, grover_windows,
     compiled = _compile(pk.make_window_fn(W, structure),
                         _dense_args(structure, one_chip))
     assert _launches(compiled) == sweeps
+    assert _in_place(compiled)
+
+
+# ---------------------------------------------------------------------------
+# PR 50: two leads a launch.  A cross-tile 2 x 2 that directly follows a
+# bare one on another qubit shares its launch: the quad's grid and its
+# scratch of two orbits of four cast tiles (4 MiB), the mix the first
+# lead's row on two tiles and the second's over those.  The programs the
+# four gaining cells launch, at w28 (the per-page run of a w30 ket over
+# four pages is a w28 ket, every op controlled)
+# ---------------------------------------------------------------------------
+
+def _gens(*targets, ctrl=False):
+    return tuple(("gen", t, ctrl) for t in targets)
+
+
+PAIRED_WINDOWS = {
+    # the Trotter step's and the Grover layer's first pair, bare
+    "bare-16-17": (_gens(16, 17), 1),
+    "bare-apart-16-27": (_gens(16, 27), 1),
+    "bare-descending-27-16": (_gens(27, 16), 1),
+    # a Grover layer's last pair with the second flip and the next
+    # layer's first ``H`` behind it (its window's ``Lgen27+4``)
+    "riders-26-27": (_gens(26, 27) + (("diag", 27, True),) + _gens(0, 1, 2), 1),
+    # as the pager's per-page run hands them over: every op controlled
+    "controlled-16-17": (_gens(16, 17, ctrl=True), 1),
+    "controlled-26-27-riders": (_gens(26, 27, ctrl=True)
+                                + (("diag", 3, True), ("diag", 4, True),
+                                   ("gen", 5, True)), 1),
+    "inv-gen-20-18": ((("inv", 20, True), ("gen", 18, False)), 1),
+    # three bare leads: a pair and a single
+    "three-bare": (_gens(16, 17, 18), 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PAIRED_WINDOWS))
+def test_paired_leads_window_kernel(one_chip, name):
+    """Each compiles for the chip in seconds, its launch's VMEM (the
+    blocks, one in and one out, each double-buffered; two orbits of four
+    cast tiles; a tile or two for the riders) stays under a quarter of
+    the limit the launch asks for, and the program sweeps the donated
+    ket in place."""
+    from test_pallas_window import launches_of
+
+    structure, sweeps = PAIRED_WINDOWS[name]
+    plan, why = fu.kernel_lowering(W, structure, backend="tpu")
+    assert why is None and plan["sweeps"] == plan["cross"] == sweeps
+    assert plan["paired"] == 1
+    fn = pk.make_window_fn(W, structure)
+    args = _dense_args(structure, one_chip)
+    block = 2 * 4 << pk.DEFAULT_BLOCK_POW
+    first = launches_of(fn, *args)[0]
+    count = first.params["grid_mapping"].num_scratch_operands
+    scratch = [v.aval for v in first.params["jaxpr"].invars[-count:]]
+    assert scratch[0].shape == (2, 4, 2) + pk.dense_tile(pk.DEFAULT_BLOCK_POW)
+    assert tuple(first.params["grid_mapping"].grid) == ((1 << (W - 18)) + 1, 4)
+    vmem = 4 * block + sum(4 * int(np.prod(a.shape)) for a in scratch)
+    print(f"vmem_bytes={vmem}")
+    assert vmem <= pk._VMEM_LIMIT_BYTES // 4
+    t0 = time.perf_counter()
+    compiled = _compile(fn, args)
+    assert time.perf_counter() - t0 < 60
+    assert _launches(compiled) == sweeps
+    assert pk.CROSS_KERNEL_NAME in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes == KET_BYTES
     assert _in_place(compiled)

@@ -1,5 +1,6 @@
 """Pallas fused gate-segment sweep (interpret mode on CPU): parity with
-the XLA compile_fn path on random circuits."""
+the XLA compile_fn path on random circuits, and of a segment led by two
+cross-tile 2 x 2s (PR 50) with the two segments it stands for."""
 
 import numpy as np
 import pytest
@@ -10,6 +11,9 @@ from qrack_tpu import matrices as mat
 from qrack_tpu.ops import fusion as fu
 from qrack_tpu.ops import pallas_kernels as pk
 from qrack_tpu.utils.rng import QrackRandom
+
+from test_pallas_window import (_su, lead_in_numpy, random_ket, riders,
+                                run_window)
 
 
 def through_window_kernel(circ, n, planes, block_pow):
@@ -152,3 +156,114 @@ def test_tq_pallas_untouched_tiles_exact(monkeypatch):
             assert np.array_equal(before[sl], after[sl]), t
             untouched += 1
     assert untouched == tiles // 2
+
+
+# ---------------------------------------------------------------------------
+# two leads a launch (PR 50): a cross-tile inv/gen that directly follows
+# a bare cross-tile inv/gen on another qubit joins its segment
+# (plan_window; the planner's cases are tests/test_pallas_window.py's),
+# whose orbits are the four tiles over both targets; a member computes
+# the first lead's row on the two tiles across the second's bit and the
+# second's row over those: the arithmetic of two launches, in their
+# order, in one sweep.
+# ---------------------------------------------------------------------------
+
+# (width, block_pow): sixteen tiles, on the flat tile and the dense
+PAIR_SHAPES = [(11, 7), (14, 10)]
+PAIR_KINDS = [("gen", "gen"), ("gen", "inv"), ("inv", "gen")]
+
+
+def _pair_targets(n, bp):
+    """The two leads' targets: on adjacent bits of the tile id, on its
+    lowest and its highest, and the second lead's below the first's."""
+    return {"adjacent": (bp, bp + 1), "apart": (bp, n - 1),
+            "descending": (n - 1, bp + 1)}
+
+
+def _pair_masks(n, bp, targets, control):
+    """``[(cmask, cval), ...]`` of the two leads: no control; a bit
+    inside the tile (the second lead's an anti-control); the highest
+    qubit above the tile that is neither target; each lead controlled by
+    the other's target (the first an anti-control: it acts where the
+    second's bit is 0), which no composed 4 x 4 of the two gets right."""
+    first, second = targets
+    if control == "none":
+        return [(0, 0), (0, 0)]
+    if control == "tile":
+        return [(1 << 1, 1 << 1), (1 << 2, 0)]
+    if control == "above":
+        high = 1 << [q for q in range(bp, n) if q not in targets][-1]
+        return [(high | 1, high | 1), (high, high)]
+    assert control == "other"
+    return [(1 << second, 0), (1 << first, 1 << first)]
+
+
+def _pair_of_leads(n, bp, layout, kinds, control, seed=5):
+    rng = np.random.default_rng(seed)
+    targets = _pair_targets(n, bp)[layout]
+    return [fu.FusedOp(kind, target, cmask, cval,
+                       _su(rng, 2) if kind == "gen" else
+                       np.fliplr(np.diag(np.exp(1j * rng.uniform(0, 6, 2)))))
+            for kind, target, (cmask, cval)
+            in zip(kinds, targets, _pair_masks(n, bp, targets, control))]
+
+
+def _pair_cases():
+    cases = []
+    for n, bp in PAIR_SHAPES:
+        for layout in _pair_targets(n, bp):
+            for kinds in PAIR_KINDS:
+                for control in ("none", "tile", "above", "other"):
+                    for behind in (False, True):
+                        cases.append(pytest.param(
+                            n, bp, layout, kinds, control, behind,
+                            id=f"w{n}-bp{bp}-{layout}-{'-'.join(kinds)}-"
+                               f"{control}" + ("-riders" if behind else "-bare")))
+    return cases
+
+
+def _dense_2x2(ket, op, n):
+    """A controlled 2 x 2 on a complex128 ket, index by index."""
+    idx = np.arange(1 << n)
+    bit = 1 << op.target
+    b = (idx >> op.target) & 1
+    m = np.asarray(op.m)
+    new = m[b, 0] * ket[idx & ~bit] + m[b, 1] * ket[idx | bit]
+    return np.where((idx & op.cmask) == op.cval, new, ket)
+
+
+@pytest.mark.parametrize("n,bp,layout,kinds,control,behind", _pair_cases())
+def test_a_pair_of_leads_is_two_launches_bit_for_bit(n, bp, layout, kinds,
+                                                     control, behind):
+    """One launch led by both against the first lead's launch and then
+    the second's (with the riders behind it): the same bits, but for the
+    sign of a zero (a launch's cast writes ``+ 0.0``, which a value that
+    stays in VMEM between the two leads does not pass); against the two
+    leads in numpy, one IEEE operation at a time; and against the dense
+    complex128 reference to float32 rounding."""
+    leads = _pair_of_leads(n, bp, layout, kinds, control)
+    after = riders(n, bp) if behind else []
+    segment, = pk.plan_window(fu.structure_of(leads + after), bp)
+    assert [slot[0] for slot in segment["leads"]] == [0, 1]
+    assert segment["xgen"] == segment["leads"][0]
+    assert pk.segment_kernel_name(segment, bp) == pk.CROSS_KERNEL_NAME
+    assert pk.plan_counts(fu.structure_of(leads + after), bp)[::3] == (1, 1)
+    ket = random_ket(np.random.default_rng(n + bp), n)
+    got = run_window(n, bp, leads + after, ket, donate=True)
+
+    one_by_one = run_window(n, bp, leads[:1], ket, donate=False)
+    assert len(pk.plan_window(fu.structure_of(leads[1:] + after), bp)) == 1
+    one_by_one = run_window(n, bp, leads[1:] + after, one_by_one, donate=True)
+    assert np.array_equal(got, one_by_one), \
+        float(np.max(np.abs(got - one_by_one)))
+
+    want = lead_in_numpy(lead_in_numpy(ket, leads[0], n), leads[1], n)
+    if after:
+        want = run_window(n, bp, after, want, donate=True)
+    assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
+
+    if not after:
+        dense = (ket[0] + 1j * ket[1]).astype(np.complex128)
+        for op in leads:
+            dense = _dense_2x2(dense, op, n)
+        assert np.max(np.abs(got[0] + 1j * got[1] - dense)) < 1e-6

@@ -309,15 +309,17 @@ def _gen_structure(targets):
 # (lowering, width or local bits, targets, sweeps, cross-tile): the last
 # window of the dense Trotter step at w28 (RX on 15-27: q15 in-tile),
 # of the paged one (RX on 25-29 at 2^28 pages: 28 and 29 exchange),
-# and bare cross-tile windows of 2, 3 and 13 ops
+# and bare cross-tile windows of 2, 3 and 13 ops.  Two bare leads share
+# a launch since PR 50 (13 / 12, 5 / 3, 2 / 2, 2 / 2, 3 / 3, 13 / 13 and
+# 2 / 2 sweeps and led sweeps until then: a sweep an op)
 _TPU_WINDOWS = [
-    ("dense", 28, range(15, 28), 13, 12),
-    ("paged", 28, range(25, 30), 5, 3),
-    ("dense", 28, (16, 27), 2, 2),
-    ("dense", 18, (16, 17), 2, 2),
-    ("dense", 22, (19, 20, 21), 3, 3),
-    ("dense", 30, range(16, 29), 13, 13),
-    ("paged", 28, (26, 27), 2, 2),
+    ("dense", 28, range(15, 28), 7, 6),
+    ("paged", 28, range(25, 30), 4, 2),
+    ("dense", 28, (16, 27), 1, 1),
+    ("dense", 18, (16, 17), 1, 1),
+    ("dense", 22, (19, 20, 21), 2, 2),
+    ("dense", 30, range(16, 29), 7, 7),
+    ("paged", 28, (26, 27), 1, 1),
 ]
 
 
@@ -331,7 +333,9 @@ def test_tpu_takes_the_kernel_for_bare_cross_tile_windows(lowering, n, targets,
     plan, why = lower(n, _gen_structure(targets), backend="tpu")
     assert why is None and not plan["interpret"]
     assert (plan["sweeps"], plan["cross"]) == (sweeps, cross)
-    assert sweeps == len(targets)       # the window the old rule refused
+    # the window the old rule refused: a sweep a lead then, a sweep for
+    # two now
+    assert sweeps + plan["paired"] == len(targets)
 
 
 @pytest.mark.parametrize("lower", [fu.kernel_lowering,
@@ -397,8 +401,10 @@ _BARE_CROSS = [(12, 10, (11, 10)), (13, 10, (10, 12, 11)),
                          ids=[f"w{n}-{len(t)}gen" for n, _, t in _BARE_CROSS])
 def test_bare_cross_tile_gen_window_matches_cpu(n, bp, targets, monkeypatch):
     """The window the rule kept on the chain, through the engine's gate
-    calls and the forced kernel: one window, a sweep an op, all
-    cross-tile, nothing on the chain, the CPU engine's amplitudes."""
+    calls and the forced kernel: one window, a sweep for every two ops
+    since two bare leads share a launch (PR 50; a sweep an op until
+    then) and one for the odd one left, all cross-tile, nothing on the
+    chain, the CPU engine's amplitudes."""
     monkeypatch.setenv("QRACK_TPU_FUSE_KERNEL", "on")
     monkeypatch.setattr(pk, "DEFAULT_BLOCK_POW", bp)
     tele.enable()
@@ -413,8 +419,9 @@ def test_bare_cross_tile_gen_window_matches_cpu(n, bp, targets, monkeypatch):
     c = tele.snapshot(include_events=False)["counters"]
     k = len(targets)
     assert (c["fuse.kernel.windows"], c["fuse.kernel.ops"],
-            c["fuse.kernel.sweeps"], c["fuse.kernel.sweeps.cross"]) \
-        == (1, k, k, k), c
+            c["fuse.kernel.sweeps"], c["fuse.kernel.sweeps.cross"],
+            c["fuse.kernel.leads.paired"]) \
+        == (1, k, k - k // 2, k - k // 2, k // 2), c
     assert c.get("fuse.xla.windows", 0) == 0
     assert not [name for name in c if name.startswith("fuse.kernel.fallback")]
 
@@ -546,7 +553,7 @@ def test_dense_tile_parity(n, bp, kind, target, cmask, cval, behind):
     assert fu.classify(ops[0].m, cmask, cval) == kind
     structure, err = _window_against_chain(n, bp, ops, seed=target + cmask)
     cross = kind in ("inv", "gen") and target >= bp
-    assert pk.plan_counts(structure, bp) == (1, cross, 1)
+    assert pk.plan_counts(structure, bp) == (1, cross, 1, 0)
     assert err < 2e-7
 
 
@@ -560,7 +567,7 @@ def test_flat_tile_below_block_pow_10():
            fu.FusedOp("inv", 10, 1 << 7, 0, _DENSE_MATRICES["inv"]),
            fu.FusedOp("cphase", 3, 1 << 9, 1 << 9, _DENSE_MATRICES["cphase"])]
     structure, err = _window_against_chain(11, 9, ops, seed=5)
-    assert pk.plan_counts(structure, 9) == (2, 1, 0)
+    assert pk.plan_counts(structure, 9) == (2, 1, 0, 0)
     assert err < 2e-7
 
 
@@ -587,18 +594,19 @@ def lowered_counts(ops, bp, split_at=None):
 
 @pytest.mark.parametrize("family,sweeps,carry_ops,diag_runs,stretches", [
     ("qft", 24, 24, (36, 375, 119), (9, 9, 9, 10)),
-    ("tfim", 14, 2, (1, 54, 30), (1, 9, 2, 7)),
+    ("tfim", 8, 2, (1, 54, 30), (1, 9, 2, 7)),
     ("rcs", 51, 10, (0, 0, 0), (9, 32, 25, 28))])
 def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
                                      carry_ops, diag_runs, stretches):
     """What ``fuse.kernel.sweeps.dense`` reads in a traced run of each
     cell: every planned kernel segment of an application at w28, at the
     fuser's bound of 32 ops a window (37 / 41 / 102 at the 16 it had
-    until PR 46).  A Trotter step is 14 since a bond is one gate of two
-    controlled ``diag`` (PR 47; 40 while its CNOTs led 24 launches): its
-    first window's 54 ``diag`` and the RX on qubits 0 to 4 in one unled
+    until PR 46).  A Trotter step is 8 since two bare leads share a
+    launch (PR 50; 14 since a bond is one gate of two controlled
+    ``diag``, PR 47; 40 while its CNOTs led 24 launches): its first
+    window's 54 ``diag`` and the RX on qubits 0 to 4 in one unled
     launch, then the RX on 5 to 15 in one and the bare ``gen`` on 16 to
-    27, twelve led launches.  A random
+    27, six launches led by two each.  A random
     circuit's 108 ops are all ``u4`` (every root composed into the
     coupler behind it on the host): 48 lead a launch, 60 ride in 10.
 
@@ -838,6 +846,78 @@ def test_led_2x2_segment_is_numpy_bit_for_bit(n, bp, kind, target, cmask,
     assert np.array_equal(got, want), float(np.max(np.abs(got - want)))
 
 
+# ---------------------------------------------------------------------------
+# two leads a launch (PR 50): a cross-tile inv/gen that directly follows
+# a bare cross-tile inv/gen on another qubit joins its segment
+# (plan_window), whose orbits are the four tiles over both targets.
+# Which leads pair is held here; that a pair is the two launches it
+# stands for, bit for bit, in tests/test_pallas_kernels.py.
+# ---------------------------------------------------------------------------
+
+_G, _I, _U = "gen", "inv", "u4"
+# structure (kind, target, controlled?) at block_pow 8 -> the leads of
+# every segment, by op index
+PAIR_PLANS = {
+    "bare-pair": ([(_G, 8, False), (_G, 9, False)], [(0, 1)]),
+    "controlled-pair": ([(_I, 10, True), (_G, 8, True)], [(0, 1)]),
+    # riders ride behind the second lead as behind a single one
+    "pair-with-riders": ([(_G, 8, False), (_G, 9, False), (_G, 3, False),
+                          ("cphase", 9, True)], [(0, 1)]),
+    # the fuser merges two gates on one qubit upstream; the planner
+    # does not assume it
+    "same-qubit": ([(_G, 8, False), (_G, 8, False)], [(0,), (1,)]),
+    "same-qubit-inv": ([(_I, 9, False), (_G, 9, True)], [(0,), (1,)]),
+    # only an op that directly follows a bare lead joins it
+    "lead-with-riders": ([(_G, 8, False), (_G, 3, False), (_G, 9, False)],
+                         [(0,), (2,)]),
+    "lead-with-a-diag-behind": ([(_G, 8, False), ("diag", 9, False),
+                                 (_G, 9, False)], [(0,), (2,)]),
+    # a u4 lead neither joins nor is joined, over two tiles or four
+    "u4-then-gen": ([(_U, (8, 9), False), (_G, 10, False)], [(0,), (1,)]),
+    "gen-then-u4": ([(_G, 10, False), (_U, (8, 9), False)], [(0,), (1,)]),
+    "gen-then-u4-pair": ([(_G, 10, False), (_U, (3, 9), False)], [(0,), (1,)]),
+    "u4-pair-then-inv": ([(_U, (3, 9), False), (_I, 10, False)], [(0,), (1,)]),
+    # the rule stops at two: three bare leads are a pair and a single,
+    # four are two pairs
+    "three-bare": ([(_G, 8, False), (_G, 9, False), (_G, 10, False)],
+                   [(0, 1), (2,)]),
+    "four-bare": ([(_G, 8, False), (_I, 9, False), (_G, 10, False),
+                   (_G, 11, True)], [(0, 1), (2, 3)]),
+    # in-tile ops ahead of the pair are a segment of their own
+    "unled-then-pair": ([(_G, 2, False), (_G, 8, False), (_G, 9, False)],
+                        [(), (1, 2)]),
+    # a pair behind a lead with riders; then the third lead stands alone
+    "riders-pair-single": ([(_G, 11, False), (_G, 1, False), (_G, 8, False),
+                            (_G, 9, False), ("cphase", 2, True),
+                            (_G, 10, False)], [(0,), (2, 3), (5,)]),
+    # an in-tile inv/gen is no lead whatever stands ahead of it
+    "in-tile-behind-a-bare-lead": ([(_G, 8, False), (_G, 7, False)], [(0,)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAIR_PLANS))
+def test_which_leads_pair(case):
+    structure, leads = PAIR_PLANS[case]
+    structure = tuple(structure)
+    segments = pk.plan_window(structure, 8)
+    assert [tuple(slot[0] for slot in seg["leads"]) for seg in segments] \
+        == leads
+    for seg in segments:
+        assert seg["xgen"] == (seg["leads"][0] if seg["leads"] else None)
+        assert all(slot == (slot[0],) + structure[slot[0]]
+                   for slot in list(seg["leads"]) + seg["ops"])
+    # every op in one segment, in the window's order
+    assert [slot[0] for seg in segments
+            for slot in list(seg["leads"]) + seg["ops"]] \
+        == list(range(len(structure)))
+    paired = sum(len(pair) == 2 for pair in leads)
+    assert pk.plan_counts(structure, 8) == (
+        len(segments), sum(bool(pair) and structure[pair[0]][0] != _U
+                           for pair in leads), 0, paired)
+    fn = pk.make_window_fn(12, structure, block_pow=8, interpret=True)
+    assert fn.sweeps == len(segments)
+
+
 def launches_of(fn, *args):
     """The equation of every pallas_call ``fn`` traces to, in order."""
     import jax
@@ -891,6 +971,13 @@ _ORBIT_LEADS = [
     pytest.param(12, 8, _u4(3, 11), (3,), id="16tiles-pair-u4"),
     pytest.param(12, 8, _u4(9, 11), (1, 3), id="16tiles-quad"),
     pytest.param(14, 10, _u4(10, 13), (0, 3), id="16tiles-dense-quad"),
+    # a pair of 2 x 2 leads: the quad's grid, whichever target came first
+    pytest.param(12, 8, [fu.FusedOp("gen", 9, 0, 0, np.eye(2)),
+                         fu.FusedOp("gen", 11, 0, 0, np.eye(2))], (1, 3),
+                 id="16tiles-two-leads"),
+    pytest.param(14, 10, [fu.FusedOp("inv", 13, 1, 1, np.eye(2)),
+                          fu.FusedOp("gen", 10, 2, 0, np.eye(2))], (0, 3),
+                 id="16tiles-dense-two-leads-descending"),
 ]
 
 
@@ -905,8 +992,9 @@ def test_a_led_segment_moves_one_tile_in_and_one_out_a_step(n, bp, lead,
     the steps that own them)."""
     import jax.numpy as jnp
 
-    ops = [lead, fu.FusedOp("cphase", 2, 1 << (n - 1), 1 << (n - 1),
-                            _DENSE_MATRICES["cphase"])]
+    leads = lead if isinstance(lead, list) else [lead]
+    ops = leads + [fu.FusedOp("cphase", 2, 1 << (n - 1), 1 << (n - 1),
+                              _DENSE_MATRICES["cphase"])]
     fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
                            interpret=True)
     (grid, (read,), write), = grids_of(fn, jnp.zeros((2, 1 << n), jnp.float32),
@@ -965,6 +1053,10 @@ def test_an_unled_segment_keeps_its_grid():
                  id="pair"),
     pytest.param(12, 8, [_u4(3, 11)], [2], id="pair-u4"),
     pytest.param(14, 10, [_u4(10, 13)], [2], id="four-tiles"),
+    pytest.param(14, 10, [fu.FusedOp("gen", 12, 0, 0, np.eye(2)),
+                          fu.FusedOp("inv", 10, 1, 1, np.eye(2)),
+                          fu.FusedOp("gen", 2, 0, 0, np.eye(2))], [2],
+                 id="four-tiles-two-leads"),
     # a window of three launches: each takes the one before's result
     pytest.param(12, 8, [fu.FusedOp("gen", 5, 0, 0, np.eye(2)),
                          fu.FusedOp("diag", 5, 2, 2, np.eye(2)),
@@ -1688,11 +1780,12 @@ def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
                            for slot in ops)
             else:
                 whole += 1
-    # the distinct structures' segments: 12 of the step's 14 launches
-    # are led, all of them bare; a sample's 4 windows are 4 structures,
-    # 48 of its 51 launches led and 41 of those bare
+    # the distinct structures' segments: 6 of the step's 8 launches
+    # are led (by two ``gen`` each, PR 50: the quad's scratch and the
+    # same two ``pl.when``), all of them bare; a sample's 4 windows are
+    # 4 structures, 48 of its 51 launches led and 41 of those bare
     assert (bare, whole, chunked, with_run) \
-        == {"tfim": (12, 0, 1, 1), "rcs": (41, 1, 9, 0)}[family]
+        == {"tfim": (6, 0, 1, 1), "rcs": (41, 1, 9, 0)}[family]
 
 
 def test_a_window_of_one_op_is_one_pass():

@@ -591,10 +591,11 @@ def _noremap_pager():
 
 
 # (engine, RX targets, exchanges): at tiles of 2^6 every target from 6
-# on is cross-tile; as many planned sweeps as ops, the window the fuser
-# kept on the XLA chain until PR 35.  The last is the paged Trotter
-# step's last window in small: three local cross-tile gen, then two on
-# paged qubits
+# on is cross-tile; the window the fuser kept on the XLA chain until
+# PR 35, as many planned sweeps as ops until two bare leads shared a
+# launch (PR 50: a sweep for every two local ones and one for the odd
+# one left).  The last is the paged Trotter step's last window in small:
+# three local cross-tile gen, then two on paged qubits
 _BARE_CROSS_WINDOWS = [(_dense, (7, 11), 0), (_dense, (6, 9, 11), 0),
                        (_dense, tuple(range(6, 12)), 0),
                        (_noremap_pager, (8, 9), 0),
@@ -621,9 +622,12 @@ def test_bare_cross_tile_window_is_a_kernel_window(small_tiles, make, targets,
     c = tele.snapshot()["counters"]
     k = len(targets)
     assert (c["fuse.kernel.windows"], c["fuse.kernel.ops"]) == (1, k)
-    # an exchange is counted a sweep and is no launch
-    assert c["fuse.kernel.sweeps"] == k
-    assert c["fuse.kernel.sweeps.cross"] == k - exchanges
+    # an exchange is counted a sweep and is no launch; the local leads
+    # (the pager hands them to its per-page kernel controlled) pair
+    paired = (k - exchanges) // 2
+    assert c["fuse.kernel.leads.paired"] == paired
+    assert c["fuse.kernel.sweeps"] == k - paired
+    assert c["fuse.kernel.sweeps.cross"] == k - exchanges - paired
     assert c.get("exchange.pager.global_2x2", 0) == exchanges
     assert c.get("fuse.xla.windows", 0) == c.get("fuse.xla.sweeps", 0) == 0
     assert not [name for name in c if name.startswith("fuse.kernel.fallback")]

@@ -36,24 +36,48 @@ PAGES = 4
 # cell -> bound -> (ops, windows, sweeps, cross-tile sweeps), an
 # application (a chip, where the ket is paged; there a window of one op
 # and no prologue is no kernel window and no sweep: the pager's shared
-# one-op program runs it)
+# one-op program runs it).  Since PR 50 a cross-tile 2 x 2 that directly
+# follows a bare one on another qubit shares its launch
+# (``pallas_kernels.plan_window``; PAIRED below): the Trotter step's
+# twelve ``RX`` launches on qubits 16-27 are six (14 sweeps, 12 of them
+# led, until then; 16 / 12 and 15 / 12 on the pager), a Grover
+# iteration's 24 ``H`` launches twelve (27 / 24); a QFT's leads carry
+# their ``cphase`` and a random circuit's are ``u4``: not one pairs
 PLANS = {
-    "rcs_w28.library": {16: (218, 14, 102, 92), 32: (108, 4, 51, 48)},
+    # at 16 a cycle's roots flush bare ahead of their coupler, and 21 of
+    # those ``gen`` leads pair (102 sweeps, 92 of them led, until PR 50)
+    "rcs_w28.library": {16: (218, 14, 81, 71), 32: (108, 4, 51, 48)},
     "qft_w28.library": {16: (406, 26, 37, 12), 32: (406, 13, 24, 12)},
     "qft_w30.library": {16: (465, 30, 43, 14), 32: (465, 15, 28, 14)},
     # a bond (CNOT, RZ, CNOT) is one gate of two controlled ``diag`` since
     # PR 47 (``QCircuitGate.can_merge``): 109 ops, 4 windows, 40 sweeps,
     # 36 of them led, until then; on the pager 117 / 11 / 43 / 40 and, on
     # the fixed placement, 117 / 4 / 49 / 36
-    "tfim_w28.library": {16: (82, 4, 15, 12), 32: (82, 2, 14, 12)},
+    "tfim_w28.library": {16: (82, 4, 10, 7), 32: (82, 2, 8, 6)},
     # a Grover iteration (PR 49): 59 ops (56 H, two ZeroPhaseFlip as one
     # controlled ``diag`` each, PhaseFlip as a ``diag``) in the windows
     # its two ALU rotations and the bound cut: the lone ZeroPhaseFlip
-    # between DEC and INC, then the diffusion, 12 led launches a layer
-    "grover_w28.library": {16: (59, 5, 27, 24), 32: (59, 3, 27, 24)},
+    # between DEC and INC, then the diffusion, 12 leads a layer in six
+    # launches (at 16 a window's edge leaves one lead single)
+    "grover_w28.library": {16: (59, 5, 16, 13), 32: (59, 3, 15, 12)},
     "qft_w31.pager4": {16: (496, 34, 45, 15), 32: (496, 19, 30, 15)},
-    "tfim_w30.pager4": {16: (88, 9, 17, 12), 32: (88, 7, 15, 12)},
-    "tfim_w30.pager4_noremap": {16: (88, 4, 17, 12), 32: (88, 2, 16, 12)},
+    # the pager's own placement pairs ten of its twelve: the ``RX`` on
+    # 26 and 27 stand in one-op windows behind their prologues
+    "tfim_w30.pager4": {16: (88, 9, 12, 7), 32: (88, 7, 10, 7)},
+    "tfim_w30.pager4_noremap": {16: (88, 4, 12, 7), 32: (88, 2, 10, 6)},
+}
+# cell -> bound -> the second leads that joined a segment, an
+# application: what ``fuse.kernel.leads.paired`` counts and
+# ``kernel.paired_leads_per_circuit`` reads on the chip
+PAIRED = {
+    "rcs_w28.library": {16: 21, 32: 0},
+    "qft_w28.library": {16: 0, 32: 0},
+    "qft_w30.library": {16: 0, 32: 0},
+    "tfim_w28.library": {16: 5, 32: 6},
+    "grover_w28.library": {16: 11, 32: 12},
+    "qft_w31.pager4": {16: 0, 32: 0},
+    "tfim_w30.pager4": {16: 5, 32: 5},
+    "tfim_w30.pager4_noremap": {16: 5, 32: 6},
 }
 # paged cell -> bound -> (prologues, pairs, pages sent a chip, prologues
 # with a shuffle of the page before and after, gates left on a paged
@@ -130,9 +154,9 @@ def _paged_windows(cell):
 @pytest.mark.parametrize("bound", [16, 32])
 @pytest.mark.parametrize("cell", sorted(PLANS))
 def test_cell_plans_at_the_bound(cell, bound, monkeypatch):
-    """Ops, windows, sweeps and cross-tile sweeps of one application of
-    every cell and, where the ket is paged, its prologues, pairs, pages
-    sent and paged gates.  The committed bound is the last column: what
+    """Ops, windows, sweeps, cross-tile sweeps and paired leads of one
+    application of every cell and, where the ket is paged, its
+    prologues, pairs, pages sent and paged gates.  The committed bound is the last column: what
     ``fuser.sweeps_per_circuit`` (``kernel.twoq_sweeps_per_circuit``,
     ``remap.prologues_per_circuit``, ``remap.pages_sent_per_circuit``)
     read on the chip."""
@@ -144,6 +168,9 @@ def test_cell_plans_at_the_bound(cell, bound, monkeypatch):
         family, width = DENSE[cell]
         planned, counts = _dense_plan(family, width)
         assert counts == PLANS[cell][bound]
+        assert sum(fu.kernel_lowering(width, w["structure"],
+                                      backend="tpu")[0]["paired"]
+                   for w in planned) == PAIRED[cell][bound]
         sizes = [len(w["structure"]) for w in planned]
         if family == "tfim":
             # the bound counts gates: 27 bonds of two ops and 5 RX
@@ -176,6 +203,7 @@ def test_cell_plans_at_the_bound(cell, bound, monkeypatch):
     assert (sum(len(w.tops) for w in windows), len(windows),
             sum(p["sweeps"] for p, _ in plans),
             sum(p["cross"] for p, _ in plans)) == PLANS[cell][bound]
+    assert sum(p["paired"] for p, _ in plans) == PAIRED[cell][bound]
     exchanges = [shb.plan_exchange(local, 2, w.swaps)
                  for w in windows if w.swaps]
     paged_gates = sum(op.kind in ("gen", "inv") and op.target >= local
