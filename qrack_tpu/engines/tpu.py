@@ -884,10 +884,15 @@ class QEngineTPU(QEngine):
     def _fresh_ket(self, args):
         """The fill where the engine owns no ket: the one ket allocated,
         behind a spacer that is let go when this returns."""
-        spacer = jnp.zeros((_KET_STAGGER_BYTES,), jnp.uint8,  # noqa: F841
+        spacer = jnp.zeros((_KET_STAGGER_BYTES,), jnp.uint8,
                            device=self._device)
-        if self._device is not None:  # the ket goes where its operands are
-            args = jax.device_put(args, self._device)
+        # the ket goes where its operands are, and is committed there
+        # from its first write: a stored window program's result is
+        # (checkpoint/warmstart.stored_program: jax.export's call commits
+        # what it returns), and a ket that turned committed in mid-stream
+        # would meet every program after it as a new argument signature,
+        # compiled a second time
+        args = jax.device_put(args, next(iter(spacer.devices())))
         return _j_fill(None, *args, self.qubit_count, self.dtype)
 
     def Clone(self) -> "QEngineTPU":

@@ -438,6 +438,16 @@ def window_fn(n: int, structure: Tuple):
     return qrack_xla_window
 
 
+def stored_program(key, make_fn, **jit_kw):
+    """``jax.jit(make_fn(), **jit_kw)`` through the program store beside
+    the compile cache (checkpoint/warmstart.py): where a cache directory
+    is configured, a process that finds the program there traces no
+    window body."""
+    from ..checkpoint import warmstart
+
+    return warmstart.stored_program(key, make_fn, **jit_kw)
+
+
 def timed_build(build):
     """A window program's builder under the span ``fuse.build``: what a
     ``ProgramCache`` miss costs inside ``fuse.lower`` (planning and
@@ -457,9 +467,8 @@ def dense_window_program(n: int, structure: Tuple, dtype):
     def build():
         return _res.instrument_dispatch(
             "tpu.fuse.flush",
-            _tele.instrument_jit(
-                "fuse.window", jax.jit(window_fn(n, structure),
-                                       donate_argnums=(0,))))
+            _tele.instrument_jit("fuse.window", stored_program(
+                key, lambda: window_fn(n, structure), donate_argnums=(0,))))
 
     return PROGRAMS.get_or_build(key, timed_build(build))
 
@@ -572,12 +581,12 @@ def kernel_window_program(n: int, structure: Tuple, dtype,
            str(jnp.dtype(dtype)), structure)
 
     def build():
-        fn = pk.make_window_fn(n, structure, block_pow=bp,
-                               interpret=interpret)
         return _res.instrument_dispatch(
             "tpu.fuse.flush",
-            _tele.instrument_jit("fuse.window", jax.jit(fn,
-                                                        donate_argnums=(0,))))
+            _tele.instrument_jit("fuse.window", stored_program(
+                key, lambda: pk.make_window_fn(n, structure, block_pow=bp,
+                                               interpret=interpret),
+                donate_argnums=(0,))))
 
     return PROGRAMS.get_or_build(key, timed_build(build))
 

@@ -746,40 +746,39 @@ class QPager(QEngine):
         L, mesh, npg = self.local_bits, self.mesh, self.n_pages
 
         if kernel_plan is None:
-            def build():
+            parts = ("fusewin", str(self.dtype), structure, remap)
+
+            def make():
                 body = fu.sharded_window_body(L, npg, structure, remap=remap)
-                return _tele.instrument_jit("fuse.window", jax.jit(
-                    jax.shard_map(body, mesh=mesh,
-                                      in_specs=_state_specs(2),
-                                      out_specs=P(None, "pages")),
-                    donate_argnums=(0,)))
+                return jax.shard_map(body, mesh=mesh,
+                                     in_specs=_state_specs(2),
+                                     out_specs=P(None, "pages"))
+        else:
+            interpret = kernel_plan["interpret"]
+            bp = kernel_plan["block_pow"]
+            parts = ("fusewin-k", "interp" if interpret else "mosaic", bp,
+                     str(self.dtype), structure, remap)
 
-            return _program(self._key("fusewin", str(self.dtype), structure,
-                                      remap),
-                            fu.timed_build(build), site="tpu.fuse.flush")
-
-        interpret = kernel_plan["interpret"]
-        bp = kernel_plan["block_pow"]
+            def make():
+                body = fu.sharded_kernel_window_body(L, npg, structure,
+                                                     block_pow=bp,
+                                                     interpret=interpret,
+                                                     remap=remap)
+                # pallas_call inside shard_map trips the replication
+                # checker on per-shard refs; the body is manifestly
+                # per-page, so the check is safely off for this one program
+                return jax.shard_map(body, mesh=mesh,
+                                     in_specs=_state_specs(2),
+                                     out_specs=P(None, "pages"),
+                                     check_vma=False)
 
         def build():
-            body = fu.sharded_kernel_window_body(L, npg, structure,
-                                                 block_pow=bp,
-                                                 interpret=interpret,
-                                                 remap=remap)
-            # pallas_call inside shard_map trips the replication checker
-            # on per-shard refs; the body is manifestly per-page, so the
-            # check is safely off for this one program
-            return _tele.instrument_jit("fuse.window", jax.jit(
-                jax.shard_map(body, mesh=mesh,
-                                  in_specs=_state_specs(2),
-                                  out_specs=P(None, "pages"),
-                                  check_vma=False),
-                donate_argnums=(0,)))
+            # the store's key: the in-process one less the mesh's id
+            return _tele.instrument_jit("fuse.window", fu.stored_program(
+                (npg, L) + parts, make, donate_argnums=(0,)))
 
-        return _program(self._key("fusewin-k",
-                                  "interp" if interpret else "mosaic", bp,
-                                  str(self.dtype), structure, remap),
-                        fu.timed_build(build), site="tpu.fuse.flush")
+        return _program(self._key(*parts), fu.timed_build(build),
+                        site="tpu.fuse.flush")
 
     def _fuse_flush(self, gates) -> int:
         from ..ops import fusion as fu

@@ -53,3 +53,47 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: long-running soak/benchmark tests (tier-1 runs -m 'not slow')")
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache_left_behind():
+    """A test that configures a compilation cache directory (a
+    ``QrackService`` with a checkpoint directory does, through
+    ``checkpoint.warmstart.enable_compile_cache``) does not leave it to
+    the tests its worker runs next: beside XLA's executables the
+    directory holds the window programs themselves since PR 52
+    (``warmstart.stored_program``), so a later test that rebuilds a
+    program, patched or not, would be handed the stored one."""
+    import jax
+
+    from qrack_tpu.checkpoint import warmstart
+
+    was = jax.config.jax_compilation_cache_dir, warmstart._ENABLED_DIR
+    yield
+    if (jax.config.jax_compilation_cache_dir, warmstart._ENABLED_DIR) != was:
+        jax.config.update("jax_compilation_cache_dir", was[0])
+        warmstart._ENABLED_DIR = was[1]
+
+
+@pytest.fixture
+def program_store(tmp_path):
+    """A compilation cache directory configured, as the benchmark's
+    harness and ``enable_compile_cache`` configure one on a TPU, so that
+    window programs are stored beside it
+    (``checkpoint/warmstart.stored_program``); yields the programs'
+    directory.  XLA's own cache stays off: the store is what such a
+    test watches."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from qrack_tpu.checkpoint import warmstart
+
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_enable_compilation_cache)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    cc.reset_cache()
+    yield str(tmp_path / warmstart.PROGRAM_DIR)
+    jax.config.update("jax_compilation_cache_dir", was[0])
+    jax.config.update("jax_enable_compilation_cache", was[1])
+    cc.reset_cache()

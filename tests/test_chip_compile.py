@@ -205,9 +205,10 @@ def test_tfim_last_window_kernel(one_chip):
     assert _in_place(compiled)
 
 
-def _compile_sharded(topo, structure, n, npg=4, remap=(), exchanges=True):
-    """The pager's per-page kernel body of a window on a 2x2 mesh, with
-    the planner's transpositions ``remap`` as its prologue."""
+def _sharded_program(topo, structure, n, npg=4, remap=()):
+    """``(fn, args)``: the pager's per-page kernel body of a window
+    under ``shard_map`` on a 2x2 mesh, with the planner's transpositions
+    ``remap`` as its prologue, and the shapes of its arguments."""
     L = n - 2
     mesh = Mesh(np.array(topo.devices[:npg]), ("pages",))
     ops = _ops(structure)
@@ -219,7 +220,12 @@ def _compile_sharded(topo, structure, n, npg=4, remap=(), exchanges=True):
     fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P(None, "pages"),) + (P(),) * (len(args) - 1),
                        out_specs=P(None, "pages"), check_vma=False)
-    compiled = _compile(fn, args)
+    return fn, args
+
+
+def _compile_sharded(topo, structure, n, npg=4, remap=(), exchanges=True):
+    """That program compiled for the described chips."""
+    compiled = _compile(*_sharded_program(topo, structure, n, npg, remap))
     text = compiled.as_text()
     assert ("collective-permute" in text) == exchanges
     assert "tpu_custom_call" in text
@@ -1174,3 +1180,79 @@ def test_paired_leads_window_kernel(one_chip, name):
     assert memory.temp_size_in_bytes == 0
     assert memory.alias_size_in_bytes == KET_BYTES
     assert _in_place(compiled)
+
+
+# -- the program store: a reloaded program is the direct one -------------------------
+
+# a window of each cell family the store serves (checkpoint/warmstart.
+# stored_program, PR 52): name -> (family, index into cell_windows), and
+# a window of ``qft_w31.pager4`` by its name in QFT31_WINDOWS
+STORED_WINDOWS = {"qft-3-launches": ("qft", 2), "tfim-59-ops": ("tfim", 0),
+                  "rcs-7-launches": ("rcs", 3), "qft31-prologue": None}
+KERNEL_NAMES = (pk.INTILE_KERNEL_NAME, pk.CROSS_KERNEL_NAME,
+                pk.TWOQ_INTILE_KERNEL_NAME, pk.TWOQ_PAIR_KERNEL_NAME,
+                pk.TWOQ_QUAD_KERNEL_NAME)
+
+
+@pytest.mark.parametrize("name", sorted(STORED_WINDOWS))
+def test_a_reloaded_program_compiles_as_the_direct_one(request, topo, name):
+    """Exported for the TPU, serialized, read back and jitted as the
+    store jits it (``warmstart._jit_exported``: the name and the
+    donation stated again, a paged program's result on the arguments'
+    mesh), a window program compiles for the described v5e to what the
+    direct ``jax.jit(fn)`` compiles to: the module's name, the launches
+    and their kernels' names, the donated ket swept in place, the same
+    bytes of temporaries (none where launches alone sweep the ket; the
+    exchange's where a prologue runs) and the collective."""
+    from jax import export
+
+    from qrack_tpu.checkpoint import warmstart
+
+    if STORED_WINDOWS[name] is None:
+        q = request.getfixturevalue("pager31")
+        window = q.windows[QFT31_WINDOWS["w01-prologue"]]
+        assert window.swaps
+        fn, args = _sharded_program(topo, window.structure, W31,
+                                    remap=window.swaps)
+        n, module = W31 - 2, "jit_qrack_sharded_kernel_window"
+    else:
+        family, index = STORED_WINDOWS[name]
+        structure = request.getfixturevalue("cell_windows")[family][index]
+        assert len(structure) == {"qft-3-launches": 32, "tfim-59-ops": 59,
+                                  "rcs-7-launches": 12}[name]
+        fn = pk.make_window_fn(W, structure)
+        args = _dense_args(structure,
+                           request.getfixturevalue("one_chip"))
+        n, module = W, "jit_qrack_kernel_window"
+    direct = _compile(fn, args)
+    t0 = time.perf_counter()
+    exported = export.export(jax.jit(fn, donate_argnums=(0,)),
+                             platforms=["tpu"])(*args)
+    blob = exported.serialize()
+    reloaded = export.deserialize(blob)
+    lowered = warmstart._jit_exported(
+        reloaded, args, {"donate_argnums": (0,)}).lower(*args)
+    print(f"export_and_reload_s={time.perf_counter() - t0:.2f} "
+          f"bytes={len(blob)} devices={reloaded.nr_devices}")
+    stored = lowered.compile()
+    assert reloaded.nr_devices == (4 if STORED_WINDOWS[name] is None else 1)
+    texts = direct.as_text(), stored.as_text()
+    for text in texts:
+        assert text.startswith("HloModule " + module)
+    assert _launches(stored) == _launches(direct) >= 1
+    for kernel in KERNEL_NAMES:
+        assert texts[0].count(f'"qrack_kernel":"{kernel}"') \
+            == texts[1].count(f'"qrack_kernel":"{kernel}"')
+    assert ("collective-permute" in texts[1]) \
+        == ("collective-permute" in texts[0]) \
+        == (STORED_WINDOWS[name] is None)
+    was, now = direct.memory_analysis(), stored.memory_analysis()
+    assert now.temp_size_in_bytes == was.temp_size_in_bytes
+    assert now.alias_size_in_bytes == was.alias_size_in_bytes \
+        == (PAGE31_BYTES if STORED_WINDOWS[name] is None else KET_BYTES)
+    assert now.output_size_in_bytes == was.output_size_in_bytes
+    if STORED_WINDOWS[name] is not None:
+        assert _in_place(stored, n) and now.temp_size_in_bytes == 0
+    # the result lies over the pages as the direct program's does: the
+    # next window's program meets the sharding it was compiled for
+    assert stored.output_shardings == direct.output_shardings

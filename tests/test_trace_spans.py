@@ -10,6 +10,8 @@ is (tests/test_chip_compile.py)."""
 
 import ast
 import os
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -798,3 +800,90 @@ def test_served_batch_program_is_lowered_under_its_scope():
     prog = batcher.batch_program(qft_qcircuit(4), 4, 2)
     text = prog.lower([jnp.zeros((2, 16), jnp.float32)] * 2).as_text()
     assert "module @jit_qrack_serve_dispatch" in text
+
+
+# -- the program store's spans, and the benchmark's reader of them ---------------------
+
+@pytest.fixture
+def benchmark_modules():
+    """``load(metric)``: a per-layer reader of the benchmark, and
+    ``setup_spans``; the benchmark's modules are imported for the test
+    alone."""
+    import sys
+
+    bench = os.path.join(REPO, "benchmarks")
+    before, path = set(sys.modules), list(sys.path)
+    sys.path.insert(0, bench)
+    try:
+        import harness
+        import setup_spans
+
+        yield lambda metric: harness.load_module("per_layer", metric).read, \
+            setup_spans
+    finally:
+        sys.path[:] = path
+        for name in set(sys.modules) - before:
+            if getattr(sys.modules[name], "__file__", "").startswith(bench):
+                del sys.modules[name]
+
+
+def _setup_of_this_thread(setup_spans):
+    """The ring as a traced run's set-up: the window opens now, and one
+    host event of the trace carries the id of a ring entry."""
+    ring = _recorded()
+    offset = 1000.0
+    tie = ring[0]
+    events = [("qrack." + tie["name"], tie["id"],
+               int((tie["ts_s"] + offset) * 1e9))]
+    now = time.perf_counter() - tele._EPOCH
+    return setup_spans.SetupSpans(ring, events, int((now + offset) * 1e9),
+                                  threading.get_ident())
+
+
+@pytest.mark.parametrize("start", ["cold", "warm"])
+def test_programs_loaded_counts_the_programs_read_back(
+        small_tiles, program_store, benchmark_modules, start):
+    load, setup_spans = benchmark_modules
+    read = load("setup.programs_loaded")
+    if start == "warm":  # an earlier process stored them
+        _qft(_dense())
+        fu.PROGRAMS.clear()
+    tele.enable()
+    _qft(_dense())
+    counters = tele.snapshot()["counters"]
+    windows = counters["compile.fuse.miss"]
+    found = _setup_of_this_thread(setup_spans)
+    names = [e["name"] for e in found.program]
+    assert read({"setup_spans": found}) == (windows if start == "warm" else 0)
+    assert names.count("warmstart.program.export") == (
+        0 if start == "warm" else windows)
+    assert counters.get("warmstart.program.hit", 0) == (
+        windows if start == "warm" else 0)
+    assert counters.get("warmstart.program.miss", 0) == (
+        0 if start == "warm" else windows)
+    # one span a program, inside the dispatch that first called it: the
+    # table of self seconds still adds up to the program's seconds
+    by_id = {e["id"]: e for e in found.program}
+    for e in found.program:
+        if e["name"].startswith("warmstart.program."):
+            assert by_id[e["parent"]]["name"] == "fuse.dispatch"
+    assert sum(found.self_seconds_by_name().values()) == pytest.approx(
+        found.program_s)
+
+
+def test_programs_loaded_reads_nothing_without_a_trace_or_a_store(
+        benchmark_modules, monkeypatch):
+    load, setup_spans = benchmark_modules
+    read = load("setup.programs_loaded")
+    assert read({"trace": None, "setup_seconds": 17.0}) is None
+    assert read({"setup_seconds": 17.0}) is None
+    tele.enable()
+    _qft(_dense())
+    found = _setup_of_this_thread(setup_spans)
+    assert read({"setup_spans": found}) == 0  # no directory: none loaded
+    # a program older than the store (the parent the driver lays this
+    # reader over) leaves the metric out
+    from qrack_tpu.checkpoint import warmstart
+
+    monkeypatch.delattr(warmstart, "stored_program")
+    assert read({"setup_spans": found}) is None
