@@ -125,6 +125,14 @@ class QEngineCPU(QEngine):
         p = self._k_probs()[sel].sum()
         return float(min(max(p, 0.0), 1.0))
 
+    def _reduces_register(self, start, length) -> bool:
+        return True
+
+    def _k_prob_reg_all(self, start, length) -> np.ndarray:
+        n = self.qubit_count
+        return self._k_probs().reshape(
+            1 << (n - start - length), 1 << length, 1 << start).sum(axis=(0, 2))
+
     def _k_collapse(self, mask, val, nrm_sq) -> None:
         sel = (self._idx & mask) == val
         nrm = 1.0 / math.sqrt(nrm_sq)

@@ -19,6 +19,8 @@ include/common/oclapi.hpp:19-99):
                                                 fn(xp, idx) -> (re, im)
   _k_probs()                                    |amp|^2 vector (host numpy)
   _k_prob_mask(mask, perm)                      masked-probability reduce
+  _k_prob_reg_all(start, length)                a register's 2^length probabilities
+                                                (probregall), where _reduces_register
   _k_collapse(mask, val, nrm_sq)                projective collapse (applym/applymreg)
   _k_compose(other, start)                      tensor product (compose kernel)
   _k_decompose(start, length) -> dest_state     split separable subsystem
@@ -191,22 +193,81 @@ class QEngine(QInterface):
     def ProbMask(self, mask: int, perm: int) -> float:
         return self._k_prob_mask(mask, perm)
 
+    # a measurement is a span ``engine.measure`` (docs/OBSERVABILITY.md)
+    # and counts ``measure.<engine>.bit`` (one qubit) or ``.reg`` (a
+    # register by one reduction), and under ``.passes`` the whole-ket
+    # programs it dispatched: the reduction, and the collapse if applied
+
+    def _reduces_register(self, start: int, length: int) -> bool:
+        """Whether ``_k_prob_reg_all`` has a form for this register: the
+        pager and the compressed engine measure a qubit at a time."""
+        return False
+
+    def _measuring(self, name: str, kind: str, do_apply: bool):
+        if not _tele._ENABLED:
+            return _tele._NULL_SPAN
+        _tele.inc(f"measure.{self._tele_name}.{kind}")
+        _tele.inc(f"measure.{self._tele_name}.passes", 2 if do_apply else 1)
+        return _tele.span("engine.measure", arg=name)
+
     def ForceM(self, q: int, result: bool, do_force: bool = True, do_apply: bool = True) -> bool:
         self._check_qubit(q)
-        prob_one = self.Prob(q)
+        with self._measuring("ForceM" if do_force else "M", "bit", do_apply):
+            prob_one = self.Prob(q)
+            if do_force:
+                res = bool(result)
+            elif prob_one >= 1.0 - FP_NORM_EPSILON:
+                res = True   # deterministic: no RNG draw (keeps streams
+            elif prob_one <= FP_NORM_EPSILON:
+                res = False  # aligned with the tableau engines)
+            else:
+                res = self.Rand() <= prob_one
+            nrm_sq = prob_one if res else (1.0 - prob_one)
+            if nrm_sq <= 0.0:
+                raise RuntimeError("ForceM: forced result has zero probability")
+            if do_apply:
+                self._k_collapse(1 << q, (1 << q) if res else 0, nrm_sq)
+        return res
+
+    def ForceMReg(self, start: int, length: int, result: int,
+                  do_force: bool = True, do_apply: bool = True) -> int:
+        """A register is measured as upstream's dense engine measures it
+        (``probregall``, one draw, ``applymreg``): one reduction to its
+        ``2^length`` probabilities, one draw on the host from their
+        running sum (or the forced result), one collapse with the
+        register's mask.  An outcome that is certain draws nothing, as
+        ``ForceM``'s.  A qubit at a time where the engine has no
+        reduction for the register."""
+        self._check_range(start, length)
+        if length <= 1 or not self._reduces_register(start, length):
+            return super().ForceMReg(start, length, result, do_force, do_apply)
+        with self._measuring("ForceMReg" if do_force else "MReg", "reg",
+                             do_apply):
+            probs = self._k_prob_reg_all(start, length)
+            with _tele.span("engine.measure.sample"):
+                res = self._draw_reg(probs, result, do_force)
+            if do_apply:
+                self._k_collapse(bit_reg_mask(start, length), res << start,
+                                 float(probs[res]))
+        return res
+
+    def _draw_reg(self, probs: np.ndarray, result: int, do_force: bool) -> int:
+        """The host's part of a register's measurement: its value."""
         if do_force:
-            res = bool(result)
-        elif prob_one >= 1.0 - FP_NORM_EPSILON:
-            res = True   # deterministic: no RNG draw (keeps streams
-        elif prob_one <= FP_NORM_EPSILON:
-            res = False  # aligned with the tableau engines)
+            res = int(result) & (probs.shape[0] - 1)
         else:
-            res = self.Rand() <= prob_one
-        nrm_sq = prob_one if res else (1.0 - prob_one)
-        if nrm_sq <= 0.0:
-            raise RuntimeError("ForceM: forced result has zero probability")
-        if do_apply:
-            self._k_collapse(1 << q, (1 << q) if res else 0, nrm_sq)
+            res = int(np.argmax(probs))
+            total = float(probs.sum())
+            if probs[res] < total * (1.0 - FP_NORM_EPSILON):
+                # the first value whose running sum passes the draw: one
+                # of probability 0 adds nothing and is never that
+                running = np.cumsum(probs)
+                res = int(np.searchsorted(running, self.Rand() * running[-1],
+                                          side="right"))
+                res = min(res, int(np.flatnonzero(probs)[-1]))
+        if probs[res] <= 0.0:
+            raise RuntimeError(
+                "ForceMReg: forced result has zero probability")
         return res
 
     def ForceMParity(self, mask: int, result: bool, do_force: bool = True) -> bool:
@@ -258,6 +319,18 @@ class QEngine(QInterface):
 
     def GetProbs(self) -> np.ndarray:
         return self._k_probs()
+
+    def ProbMaskAll(self, mask: int) -> np.ndarray:
+        """A mask that is one contiguous register takes the register's
+        reduction: ``2^length`` numbers reach the host, not the ket's
+        ``2^n`` probabilities."""
+        start = (mask & -mask).bit_length() - 1
+        length = (mask >> start).bit_length() if mask > 0 else 0
+        if (length and mask == bit_reg_mask(start, length)
+                and start + length <= self.qubit_count
+                and self._reduces_register(start, length)):
+            return self._k_prob_reg_all(start, length)
+        return super().ProbMaskAll(mask)
 
     # ------------------------------------------------------------------
     # ALU overrides: vectorized index-map kernels
@@ -808,6 +881,9 @@ class QEngine(QInterface):
         raise NotImplementedError
 
     def _k_prob_mask(self, mask, perm) -> float:
+        raise NotImplementedError
+
+    def _k_prob_reg_all(self, start, length) -> np.ndarray:
         raise NotImplementedError
 
     def _k_collapse(self, mask, val, nrm_sq) -> None:

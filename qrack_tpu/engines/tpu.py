@@ -31,7 +31,9 @@ import jax
 import jax.numpy as jnp
 
 from ..interface.alu import AluMixin
+from ..ops import alu_kernels as alu
 from ..ops import gatekernels as gk
+from ..ops import register_kernels as rk
 from .qengine import QEngine
 from .. import matrices as mat
 from .. import telemetry as _tele
@@ -103,7 +105,6 @@ _j_swap_bits = _jit("swap_bits", gk.swap_bits, static_argnums=(1, 2, 3), donate_
 _j_gather = _jit("gather", gk.gather, donate_argnums=(0,))
 _j_phase_apply = _jit("phase_apply", gk.phase_factor_apply, donate_argnums=(0,))
 _j_prob_mask = _jit("prob_mask", gk.prob_mask_sum)
-_j_collapse = _jit("collapse", gk.collapse, donate_argnums=(0,))
 _j_normalize = _jit("normalize", gk.normalize, donate_argnums=(0,))
 _j_probs = _jit("probs", gk.probs)
 _j_sum_sqr_diff = _jit("sum_sqr_diff", gk.sum_sqr_diff)
@@ -180,6 +181,79 @@ _j_alu_rotate = _jit("alu_rotate", qrack_alu_rotate, static_argnums=(3,),
 # int32 (and a ket beside its result the chip: PERF.md section 7)
 ROTATE_MIN_BITS = 7
 ROTATE_MAX_QB = 29
+
+def qrack_alu_modn_slice(planes, table, n, in_start, length, out_start, ol):
+    """What an out-of-place modular call reads of the ket, ``2^(n - ol)``
+    amplitudes as ``(2, ...)`` planes in the ket's own order: with
+    ``table`` None the amplitudes whose out register reads 0 (the
+    forward calls), else, for every other bit of the index, the one
+    amplitude at out register ``table[in register]`` (``IMULModNOut``).
+    Where the out register is the ket's top bits the forward slice is
+    the ket's first rows; elsewhere a gather of the slice's size."""
+    if table is None and out_start + ol == n:
+        return planes[:, :1 << out_start]
+    j = jax.lax.iota(gk.IDX_DTYPE, 1 << (n - ol))
+    src = ((j >> out_start) << (out_start + ol)) | (j & ((1 << out_start) - 1))
+    if table is not None:
+        src |= table[(src >> in_start) & ((1 << length) - 1)] << out_start
+    return planes[:, src]
+
+
+def qrack_alu_modn(planes, sl, table, n, in_start, length, out_start, ol,
+                   kernel):
+    """``out[rest, v, x] = sl[rest, x] where v == table[x], else 0``: an
+    out-of-place modular call (``POWModNOut``, ``MULModNOut``; with a
+    table of zeros ``IMULModNOut``) as one write of the ket from the
+    slice ``qrack_alu_modn_slice`` took and a table of ``2^length``
+    int32, a runtime operand: every base and modulus share the program
+    of their registers.  ``planes`` is the ket to write over: donated,
+    never read, its buffer the result's, as ``qrack_fill``'s.  The name
+    is the compiled module's (``jit_qrack_alu_modn``).  ``kernel``: the
+    body (ops/register_kernels.py), None for the view, else whether the
+    Pallas one runs under the interpreter."""
+    if kernel is None:
+        dims, out_axis, in_axis = rk.modn_view(n, in_start, length,
+                                               out_start, ol)
+        return rk.modn_write_view(sl, table, planes.shape, dims, out_axis,
+                                  in_axis)
+    return rk.modn_write_kernel(n, out_start, ol, interpret=kernel)(
+        planes, sl, rk.modn_row_table(table, in_start, length, out_start))
+
+
+def qrack_prob_reg(planes, n, start, length, kernel):
+    """The ``2^length`` probabilities of a contiguous register: one read
+    of the planes, summed in float32 over every other bit.  The name is
+    the compiled module's (``jit_qrack_prob_reg``); ``kernel`` as
+    ``qrack_alu_modn``'s."""
+    if kernel is None:
+        return rk.prob_reg_view(planes, n, start, length)
+    return rk.prob_reg_kernel(n, start, length, interpret=kernel)(planes)
+
+
+def qrack_collapse(planes, mask, val, nrm_sq):
+    """Projective collapse and renormalisation (reference kernels
+    applym/applymreg, qengine.cl:1013-1045): the amplitudes whose masked
+    bits read ``val`` scaled by ``1 / sqrt(nrm_sq)``, the rest 0, as one
+    fusion over the donated ket.  The plane's number rides the index's
+    sign bit, which no mask holds: a predicate of the index alone XLA
+    computes once for both planes and keeps beside the ket (256 MiB at
+    w28, 1 GiB at w30, compiled for a described v5e)."""
+    idx = jax.lax.broadcasted_iota(gk.IDX_DTYPE, planes.shape, 1)
+    plane = jax.lax.broadcasted_iota(gk.IDX_DTYPE, planes.shape, 0)
+    keep = ((idx | (plane << 31)) & mask) == val
+    scale = (1.0 / jnp.sqrt(nrm_sq)).astype(planes.dtype)
+    return jnp.where(keep, planes * scale, jnp.zeros((), planes.dtype))
+
+
+_j_alu_modn_slice = _jit("alu_modn_slice", qrack_alu_modn_slice,
+                         static_argnums=(2, 3, 4, 5, 6))
+# keep_unused: the donated ket is a parameter the result can alias
+_j_alu_modn = _jit("alu_modn", qrack_alu_modn,
+                   static_argnums=(3, 4, 5, 6, 7, 8), donate_argnums=(0,),
+                   keep_unused=True)
+_j_prob_reg = _jit("prob_reg", qrack_prob_reg, static_argnums=(1, 2, 3, 4))
+_j_collapse = _jit("collapse", qrack_collapse, donate_argnums=(0,))
+
 
 # A fresh ket is allocated behind a spacer of this many bytes, let go
 # at once.  An engine is as a rule the first thing a process puts on
@@ -627,7 +701,9 @@ class QEngineTPU(QEngine):
     # ------------------------------------------------------------------
 
     # the add family as a rotation of the planes, the comparator flips as
-    # ops of the window: the compressed subclass holds codes, not planes
+    # ops of the window, the out-of-place modular calls as a table write,
+    # a register measured by one reduction: the compressed subclass holds
+    # codes, not planes
     _alu_on_planes = True
 
     def _alu(self, kind: str, split=None):
@@ -721,6 +797,88 @@ class QEngineTPU(QEngine):
             src = src_fn(gk.iota_for(st))
             self._state = _j_gather(st, src)
 
+    def _register_kernel(self, fits: bool):
+        """The body a register program takes (ops/register_kernels.py):
+        None for the view, False for the Pallas kernel (True, which only
+        tests ask for, runs it under the interpreter).  The kernel is the
+        chip's, on float32 planes, where ``QRACK_TPU_FUSE_KERNEL`` has
+        not switched kernels off."""
+        from ..ops import fusion as fu
+
+        if (fits and jax.default_backend() == "tpu"
+                and fu.kernel_mode() != "off"
+                and self.dtype == jnp.dtype("float32")):
+            return False
+        return None
+
+    def _takes_register(self, fits: bool) -> bool:
+        """Whether a register program runs here at all.  On the chip only
+        where the kernel body takes the register: the view body would
+        stand a ket or two of temporaries beside the ket there, and the
+        call keeps the lowering it had."""
+        return self._alu_on_planes and (
+            jax.default_backend() != "tpu"
+            or self._register_kernel(fits) is False)
+
+    def _modn_writes(self, in_start, length, out_start, ol) -> bool:
+        """Whether an uncontrolled out-of-place modular call on these
+        registers is the table write: two registers inside the ket that
+        do not overlap (else the scatter, and its errors)."""
+        n = self.qubit_count
+        return (length > 0 and ol > 0 and 0 <= in_start and 0 <= out_start
+                and in_start + length <= n and out_start + ol <= n
+                and (in_start + length <= out_start
+                     or out_start + ol <= in_start)
+                and self._takes_register(
+                    rk.modn_kernel_fits(n, in_start, length, out_start, ol)))
+
+    def _k_modn(self, name, table, in_start, length, out_start, ol,
+                inverse=False) -> None:
+        """``qrack_alu_modn`` over the resident planes: the slice first,
+        by a small program, then the write over the donated ket.  The
+        inverse (``IMULModNOut``) takes the table into the slice, one
+        amplitude a column, and writes it where the out register is 0."""
+        n = self.qubit_count
+        geom = (n, in_start, length, out_start, ol)
+        kernel = self._register_kernel(rk.modn_kernel_fits(*geom))
+        with self._alu("modn", ((name,),)):
+            planes = self._owned_state()
+            sl = _j_alu_modn_slice(planes, table if inverse else None, *geom)
+            if inverse:
+                table = np.zeros_like(table)
+            self._state = _j_alu_modn(planes, sl, table, *geom, kernel)
+        if _tele._ENABLED:
+            # one write of the planes (roofline.tpu.alu.modn.*)
+            _roofline.note_bytes("tpu.alu.modn", _roofline.plane_pass_bytes(
+                n, jnp.dtype(self.dtype).itemsize) // 2)
+
+    def _modn_out(self, name, table_of, in_start, out_start, length, mod_n,
+                  inverse=False) -> bool:
+        ol = self._mod_out_len(mod_n)
+        if not self._modn_writes(in_start, length, out_start, ol):
+            return False
+        self._k_modn(name, table_of(), in_start, length, out_start, ol,
+                     inverse)
+        return True
+
+    def MULModNOut(self, to_mul, mod_n, in_start, out_start, length) -> None:
+        if not self._modn_out(
+                "MULModNOut", lambda: alu.mulmod_table(to_mul, mod_n, length),
+                in_start, out_start, length, mod_n):
+            super().MULModNOut(to_mul, mod_n, in_start, out_start, length)
+
+    def IMULModNOut(self, to_mul, mod_n, in_start, out_start, length) -> None:
+        if not self._modn_out(
+                "IMULModNOut", lambda: alu.mulmod_table(to_mul, mod_n, length),
+                in_start, out_start, length, mod_n, inverse=True):
+            super().IMULModNOut(to_mul, mod_n, in_start, out_start, length)
+
+    def POWModNOut(self, base, mod_n, in_start, out_start, length) -> None:
+        if not self._modn_out(
+                "POWModNOut", lambda: alu.powmod_table(base, mod_n, length),
+                in_start, out_start, length, mod_n):
+            super().POWModNOut(base, mod_n, in_start, out_start, length)
+
     def _k_out_of_place(self, src_idx, dst_idx, passthrough_cmask) -> None:
         with self._alu("out_of_place"):
             src_idx = jnp.asarray(src_idx, dtype=gk.IDX_DTYPE)
@@ -757,6 +915,27 @@ class QEngineTPU(QEngine):
 
     def _k_collapse(self, mask, val, nrm_sq) -> None:
         self._state = _j_collapse(self._owned_state(), mask, val, nrm_sq)
+        if _tele._ENABLED:
+            # one read and one write of the planes: with a register's
+            # reduction the measurement's ledger (roofline.tpu.measure.*)
+            _roofline.note_bytes("tpu.measure", _roofline.plane_pass_bytes(
+                self.qubit_count, jnp.dtype(self.dtype).itemsize))
+
+    def _reduces_register(self, start, length) -> bool:
+        # on the chip a row of 2^10 to 2^18 amplitudes, float32 planes
+        return self._takes_register(
+            rk.prob_kernel_fits(self.qubit_count, start, length))
+
+    def _k_prob_reg_all(self, start, length) -> np.ndarray:
+        """``qrack_prob_reg``: the register's probabilities reduced on
+        the device, ``2^length`` float32 to the host."""
+        n = self.qubit_count
+        kernel = self._register_kernel(rk.prob_kernel_fits(n, start, length))
+        if _tele._ENABLED:  # one read of the planes
+            _roofline.note_bytes("tpu.measure", _roofline.plane_pass_bytes(
+                n, jnp.dtype(self.dtype).itemsize) // 2)
+        return self._host_read(lambda st: np.asarray(
+            _j_prob_reg(st, n, start, length, kernel), dtype=np.float64))
 
     def MAll(self) -> int:
         """Device-side categorical sample; no 2^n host transfer

@@ -69,16 +69,30 @@ def teleport(qsim, prepare=None) -> Tuple[float, float]:
     return before, qsim.Prob(2)
 
 
-def shor_order_find(qsim, base: int, to_factor: int, width: int) -> Optional[int]:
-    """One period-finding round of Shor's algorithm (reference:
-    examples/shors_factoring.cpp:98-160). Needs 2*width qubits.
-    Returns a nontrivial factor or None."""
+def shor_period_state(qsim, base: int, to_factor: int, width: int) -> None:
+    """The quantum half of an order-finding attempt up to its
+    measurement (reference: examples/shors_factoring.cpp:98-160), on
+    2*width qubits: ``|x>|base^x mod N>`` over every ``x``, the input
+    register transformed."""
     qsim.SetPermutation(0)
     for i in range(width):
         qsim.H(i)
     qsim.POWModNOut(base, to_factor, 0, width, width)
     qsim.IQFT(0, width)
-    y = qsim.MReg(0, width)
+
+
+def shor_period_measure(qsim, base: int, to_factor: int, width: int) -> int:
+    """The quantum half of an attempt: the measured input register,
+    ``y / 2^width`` near a multiple of ``1 / order``."""
+    shor_period_state(qsim, base, to_factor, width)
+    return qsim.MReg(0, width)
+
+
+def shor_order_find(qsim, base: int, to_factor: int, width: int) -> Optional[int]:
+    """One period-finding round of Shor's algorithm (reference:
+    examples/shors_factoring.cpp:98-160). Needs 2*width qubits.
+    Returns a nontrivial factor or None."""
+    y = shor_period_measure(qsim, base, to_factor, width)
     if y == 0:
         return None
     # continued-fraction reconstruction of the order

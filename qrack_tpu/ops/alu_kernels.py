@@ -166,6 +166,35 @@ def powmodnout_pair(xp, n_qubits, base_int, mod_n, in_start, out_start, length, 
     return xp.asarray(base_idx), xp.asarray(dst)
 
 
+def mulmod_table(to_mul: int, mod_n: int, length: int):
+    """``(x * to_mul) mod N`` for every ``x`` of a register of ``length``
+    bits, int32: the table of ``MULModNOut`` / ``IMULModNOut`` where the
+    call is a table write (``engines/tpu.py _k_modn``).  ``N <= 2^31``:
+    the products stay under 2^62."""
+    import numpy as np
+
+    x = np.arange(1 << length, dtype=np.int64)
+    return ((x % mod_n) * (to_mul % mod_n) % mod_n).astype(np.int32)
+
+
+def powmod_table(base_int: int, mod_n: int, length: int):
+    """``base^x mod N`` for every ``x`` of a register of ``length`` bits,
+    int32, by doubling: the entries of ``[2^k, 2^(k+1))`` are those of
+    ``[0, 2^k)`` times ``base^(2^k)``: ``length`` numpy products and no
+    Python loop over the entries (16 384 ``pow()`` calls an application
+    of the order-finding cell before)."""
+    import numpy as np
+
+    table = np.empty(1 << length, dtype=np.int64)
+    table[0] = 1 % mod_n
+    square = base_int % mod_n  # base^(2^k)
+    for k in range(length):
+        half = 1 << k
+        table[half:2 * half] = table[:half] * square % mod_n
+        square = square * square % mod_n
+    return table.astype(np.int32)
+
+
 def indexed_lda_src(xp, idx, index_start, index_length, value_start, value_length, table):
     """IndexedLDA: value reg ^= table[index reg] (reference kernel
     indexedLda, qheader_alu.cl:~600). XOR form makes it a bijection."""

@@ -253,15 +253,6 @@ def prob_mask_sum(planes, mask, val):
     return jnp.sum(jnp.where((idx & mask) == val, p, 0.0))
 
 
-def collapse(planes, mask, val, nrm_sq):
-    """Projective collapse + renorm (reference kernels applym/applymreg,
-    qengine.cl:1013-1045)."""
-    idx = iota_for(planes)
-    keep = (idx & mask) == val
-    scale = (1.0 / jnp.sqrt(nrm_sq)).astype(planes.dtype)
-    return jnp.where(keep, planes * scale, jnp.zeros((), planes.dtype))
-
-
 def normalize(planes, nrm_sq):
     return planes * (1.0 / jnp.sqrt(nrm_sq)).astype(planes.dtype)
 

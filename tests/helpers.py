@@ -105,11 +105,26 @@ def benchmark_plans(width: int = 28):
                 self.windows[-1]["ops"] = fu.lower_gates(gates)
                 return dispatched
 
-            def _k_rotate(self, shift, block_bits):
-                # an ALU call is a barrier: the pending window flushes
-                # as at a read of the planes; there are none to rotate
+            def _barrier(self):
+                # an ALU call or a register's reduction is a barrier: the
+                # pending window flushes as at a read of the planes
                 if self._fuser.gates:
                     self._fuser.flush("read")
+
+            def _k_rotate(self, shift, block_bits):
+                self._barrier()  # there are no planes to rotate
+
+            def _k_modn(self, name, table, *registers):
+                self._barrier()  # nor to write
+
+            def _k_prob_reg_all(self, start, length):
+                # with no planes every value is as likely, and the draw
+                # is the engine's own
+                self._barrier()
+                return np.full(1 << length, 1.0 / (1 << length))
+
+            def _k_collapse(self, mask, val, nrm_sq):
+                pass
 
         def windows(name):
             planner, structure.PlanOnlyEngine = structure.PlanOnlyEngine, WithOps

@@ -1,7 +1,7 @@
 """The fuser's pending-window bound (``ops/fusion.DEFAULT_WINDOW``: 32
 ops since PR 46, 16 before), the gate that opens a window on a paged
 engine (``GateStreamFuser._heads_a_window``) and what the two plan for
-the benchmark's eight cells, held without a chip.
+the benchmark's nine cells, held without a chip.
 
 The bound sets how many launches an application is: a window that fills
 at 16 flushes a random circuit's roots bare before the coupler they
@@ -60,6 +60,11 @@ PLANS = {
     # between DEC and INC, then the diffusion, 12 leads a layer in six
     # launches (at 16 a window's edge leaves one lead single)
     "grover_w28.library": {16: (59, 5, 16, 13), 32: (59, 3, 15, 12)},
+    # an order-finding attempt (PR 53): 14 H, which the table write's
+    # barrier flushes as one window, and IQFT(0, 14)'s 14 H and 91 cphase,
+    # which the measurement's reduction flushes: every target under the
+    # tile's 16 bits, so every window is one in-tile sweep and none is led
+    "shor_w28.library": {16: (119, 8, 8, 0), 32: (119, 5, 5, 0)},
     "qft_w31.pager4": {16: (496, 34, 45, 15), 32: (496, 19, 30, 15)},
     # the pager's own placement pairs ten of its twelve: the ``RX`` on
     # 26 and 27 stand in one-op windows behind their prologues
@@ -75,6 +80,7 @@ PAIRED = {
     "qft_w30.library": {16: 0, 32: 0},
     "tfim_w28.library": {16: 5, 32: 6},
     "grover_w28.library": {16: 11, 32: 12},
+    "shor_w28.library": {16: 0, 32: 0},
     "qft_w31.pager4": {16: 0, 32: 0},
     "tfim_w30.pager4": {16: 5, 32: 5},
     "tfim_w30.pager4_noremap": {16: 5, 32: 6},
@@ -101,12 +107,14 @@ SIZES = {
     "qft_w31.pager4": [2, 3, 4] + [32] * 15 + [7],
     "tfim_w28.library": [59, 23],
     "grover_w28.library": [1, 32, 26],
+    "shor_w28.library": [14, 32, 32, 32, 9],
     "tfim_w30.pager4": [50, 2, 32, 1, 1, 1, 1],
     "tfim_w30.pager4_noremap": [61, 27],
 }
 DENSE = {"rcs_w28.library": ("rcs", 28), "qft_w28.library": ("qft", 28),
          "qft_w30.library": ("qft", 30), "tfim_w28.library": ("tfim", 28),
-         "grover_w28.library": ("grover", 28)}
+         "grover_w28.library": ("grover", 28),
+         "shor_w28.library": ("shor", 28)}
 
 
 def _dense_plan(family, width):
@@ -181,6 +189,12 @@ def test_cell_plans_at_the_bound(cell, bound, monkeypatch):
             # call is a barrier, and the oracle's flip stands alone
             assert sizes[0] == 1 and max(sizes) == bound
             assert planned[0]["structure"] == (("diag", 0, True),)
+            assert bound != fu.DEFAULT_WINDOW or sizes == SIZES[cell]
+        elif family == "shor":
+            # the table write cuts the H layer off; the bound then cuts
+            # the IQFT; no window has a target above the tile
+            assert sizes[0] == 14 and set(sizes[1:-1]) == {bound}
+            assert all(t < 16 for w in planned for _, t, _ in w["structure"])
             assert bound != fu.DEFAULT_WINDOW or sizes == SIZES[cell]
         else:
             assert max(sizes) == bound and set(sizes[:-1]) == {bound}
