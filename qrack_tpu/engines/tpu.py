@@ -573,7 +573,8 @@ class QEngineTPU(QEngine):
             else:
                 # two host columns, whatever the window holds: the
                 # dispatch puts them on the device with the program
-                operands = fu.pack_operands(ops, self.dtype)
+                operands = fu.pack_operands(
+                    ops, self.dtype, runs=plan and plan["runs"])
         with _tele.span("fuse.dispatch"):
             self._state = prog(self._owned_state(), *operands)
         if _tele._ENABLED:

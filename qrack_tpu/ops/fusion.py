@@ -329,13 +329,17 @@ def _norm_kept_float32(ops: Sequence[FusedOp], payloads: List) -> List:
     return out
 
 
-def pack_operands(ops: Sequence[FusedOp], dtype, split_at: int = None):
+def pack_operands(ops: Sequence[FusedOp], dtype, split_at: int = None,
+                  runs=None):
     """``(iv, fv)``: the window's int32 masks and its float payloads in
     the planes' ``dtype``, numpy columns laid out by
     ``pallas_kernels._operand_slots``.  ``split_at`` gives the sharded
     layout: masks split at that many local bits, 'inv' folded into
-    'gen' (sharded_structure_of).  Float32 payloads are rounded with
-    the window's norm kept (_norm_kept_float32).  Host work only."""
+    'gen' (sharded_structure_of).  ``runs`` gives the kernel lowering's
+    ``iv``: behind the masks, the plan of every run of diagonal ops the
+    lowering found (its plan's ``"runs"``: _run_plan_rows).  Float32
+    payloads are rounded with the window's norm kept
+    (_norm_kept_float32).  Host work only."""
     from . import pallas_kernels as pk
     from .sharded import split_masks
 
@@ -361,6 +365,8 @@ def pack_operands(ops: Sequence[FusedOp], dtype, split_at: int = None):
         else:
             masks = split_masks(op.cmask, op.cval, split_at)
         ints[i:i + len(masks)] = masks
+    if runs is not None:
+        ints += _run_plan_rows(ops, runs, split_at)
     # from Python ints: a mask past int32 raises, as it always did
     iv = np.array(ints, dtype=np.int32).reshape(-1, 1)
     fv = np.array(floats, dtype=np.float64).astype(dtype).reshape(-1, 1)
@@ -496,13 +502,14 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
 
     Returns ``(plan, fallback_reason)`` — exactly one is non-None.
     ``plan`` is ``{"interpret": bool, "block_pow": int, "sweeps": int,
-    "cross": int, "dense": int, "paired": int, "twoq": dict}``
-    (``cross``: the cross-tile segments 2x2s lead among the sweeps;
+    "cross": int, "dense": int, "paired": int, "twoq": dict, "runs":
+    list}`` (``cross``: the cross-tile segments 2x2s lead among the sweeps;
     ``dense``: the sweeps whose kernel body computes on the dense
     ``(rows, 128)`` tile, pallas_kernels.dense_tile; ``paired``: the
     second leads that joined a segment, pallas_kernels.plan_window;
     ``twoq``: pallas_kernels.twoq_counts, the window's two-target ops
-    and the sweeps that carry them).
+    and the sweeps that carry them; ``runs``: kernel_runs, the window's
+    runs of diagonal ops, which pack_operands plans from the masks).
 
     The decision inputs are the mode, the backend and the window length
     (the plan's counts depend on the op mix, width and block_pow, the
@@ -538,13 +545,53 @@ def kernel_lowering(n: int, structure: Tuple, backend: str = None):
 SMEM_OPERAND_ROWS = 1536
 
 
+def kernel_runs(structure: Tuple, bp: int, split_at: int = None) -> List:
+    """``[(bp, local, run), ...]``: the runs of diagonal ops the kernel
+    lowering applies as one operator each for a window
+    (``pallas_kernels.window_runs`` for tiles of ``bp`` bits), in the
+    order their plans lie behind the masks in ``iv``.  ``local`` is
+    None in the dense layout; with ``split_at`` (``structure`` then the
+    sharded one) it is the local run whose per-page kernel holds the
+    run, whose slots are that kernel's ops (_sharded_run_structure)."""
+    from . import pallas_kernels as pk
+
+    if split_at is None:
+        return [(bp, None, run) for run in pk.window_runs(structure, bp)]
+    return [(bp, local, run)
+            for kind, local in _sharded_segments(structure, split_at)
+            if kind == "run"
+            for run in pk.window_runs(
+                _sharded_run_structure(local, split_at), bp)]
+
+
+def _run_plan_rows(ops: Sequence[FusedOp], runs, split_at: int) -> List[int]:
+    """The rows behind a kernel window's masks in ``iv``: the plan of
+    each of ``runs`` (kernel_runs; ``pallas_kernels.run_planner``).
+    Which ops of a run share a slot depends on where their controls
+    lie, so the host plans from the masks as the run's kernel reads
+    them: a per-page kernel's are host values though its payloads are
+    not (_sharded_run_masks), and its plans ride the window's ``iv``
+    through to it (_sharded_run_operands)."""
+    from . import pallas_kernels as pk
+
+    rows, masks = [], {}
+    for bp, local, run in runs:
+        if id(local) not in masks:
+            masks[id(local)] = (
+                [(op.cmask, op.cval) for op in ops] if local is None
+                else _sharded_run_masks(ops, local, split_at))
+        rows += pk.run_planner(run, masks[id(local)], bp)[0]
+    return rows
+
+
 def _lowering(structure: Tuple, backend, bp: int, counts,
-              split: bool = False):
+              split_at: int = None):
     """The choice both lowerings share; ``counts(structure, bp)`` gives
     the plan's ``(sweeps, cross, dense, paired)``.  A window whose operand
-    columns (``split``: in the sharded layout) would not fit a chip's
-    SMEM takes the chain (reason ``smem_operands``) where the chip's
-    compiler would refuse its program."""
+    columns (``split_at``: in the sharded layout), its runs' plans
+    among them, would not fit a chip's SMEM takes the chain (reason
+    ``smem_operands``) where the chip's compiler would refuse its
+    program."""
     mode = kernel_mode()
     if mode == "off":
         return None, "mode_off"
@@ -552,14 +599,17 @@ def _lowering(structure: Tuple, backend, bp: int, counts,
         backend = jax.default_backend()
     from . import pallas_kernels as pk
 
+    runs = kernel_runs(structure, bp, split_at)
     if backend == "tpu":
-        _, floats, ints = pk._operand_slots(structure, split)
-        if floats + ints > SMEM_OPERAND_ROWS:
+        _, floats, ints = pk._operand_slots(structure, split_at is not None)
+        plans = sum(pk._PLAN_HEAD + len(run) for _, _, run in runs)
+        if floats + ints + plans > SMEM_OPERAND_ROWS:
             return None, "smem_operands"
     sweeps, cross, dense, paired = counts(structure, bp)
     plan = {"interpret": backend != "tpu", "block_pow": bp,
             "sweeps": sweeps, "cross": cross, "dense": dense,
-            "paired": paired, "twoq": pk.twoq_counts(structure, bp)}
+            "paired": paired, "twoq": pk.twoq_counts(structure, bp),
+            "runs": runs}
     if mode == "on":
         return plan, None
     if backend != "tpu":
@@ -594,6 +644,7 @@ def kernel_window_program(n: int, structure: Tuple, dtype,
 # what the kernel lowered for a flushed window, by counter under
 # ``fuse.kernel.``: pallas_kernels.diag_run_counts, then .stretch_counts
 KERNEL_WINDOW_COUNTERS = ("diag_runs", "diag_run.ops", "diag_run.tile_ops",
+                          "diag_run.folded_ops",
                           "stretches", "stretch.ops", "stretch.passes",
                           "whole_tile_ops")
 
@@ -602,13 +653,13 @@ def count_kernel_window(ops: Sequence[FusedOp], block_pow: int,
                         split_at: int = None) -> dict:
     """``{counter: count}`` over KERNEL_WINDOW_COUNTERS for a flushed
     window: ``pallas_kernels.diag_run_counts`` (the runs of diagonal ops
-    the kernel applies through a phase tile) and ``.stretch_counts``
-    (the stretches of in-tile ops between runs that it applies chunk by
-    chunk, their ops and passes, and the ops it still applies on a whole
-    tile), from the structure and the masks the host packed: the dense
-    layout's, or with ``split_at`` those of each per-page kernel run
-    (local halves of the masks: page-level tests are in its payload,
-    _sharded_run_operands).  Host work only."""
+    the kernel applies through a phase tile, and those of their ops
+    with a high part that it folds into a slot's accumulators) and
+    ``.stretch_counts`` (the stretches of in-tile ops between runs that
+    it applies chunk by chunk, their ops and passes, and the ops it
+    still applies on a whole tile), from the structure and the masks the
+    host packed: the dense layout's, or with ``split_at`` those of each
+    per-page kernel run (_sharded_run_masks).  Host work only."""
     from . import pallas_kernels as pk
 
     def counts(structure, masks):
@@ -618,14 +669,12 @@ def count_kernel_window(ops: Sequence[FusedOp], block_pow: int,
     if split_at is None:
         total = counts(structure_of(ops), [(op.cmask, op.cval) for op in ops])
     else:
-        lbits = (1 << split_at) - 1
         total = (0,) * len(KERNEL_WINDOW_COUNTERS)
         for kind, run in _sharded_segments(sharded_structure_of(ops), split_at):
             if kind == "run":
-                masks = [(ops[idx].cmask & lbits, ops[idx].cval & lbits)
-                         for idx, _, _, _ in run]
                 total = tuple(map(sum, zip(total, counts(
-                    _sharded_run_structure(run, split_at), masks))))
+                    _sharded_run_structure(run, split_at),
+                    _sharded_run_masks(ops, run, split_at)))))
     return dict(zip(KERNEL_WINDOW_COUNTERS, total))
 
 
@@ -957,13 +1006,24 @@ def _sharded_run_structure(run, L: int) -> Tuple:
     return tuple(out)
 
 
-def _sharded_run_operands(run, L: int, views, pid, dtype):
+def _sharded_run_masks(ops: Sequence[FusedOp], run, L: int) -> List:
+    """The ``(cmask, cval)`` of a local run's ops as its kernel reads
+    them (_sharded_run_operands), on the host: the local halves, the
+    page-level tests being in the payload."""
+    lbits = (1 << L) - 1
+    return [(ops[idx].cmask & lbits, ops[idx].cval & lbits)
+            for idx, _, _, _ in run]
+
+
+def _sharded_run_operands(run, L: int, views, pid, dtype, plan):
     """Traced per-shard ``(iv, fv)`` of one local run, in the dense
     layout of its kernel (_sharded_run_structure: every op controlled):
     local masks pass through, page-level tests collapse into the payload
     (identity payload when this page misses the page-mask).  This
     rewrite depends on ``page_id`` and so stays inside the program; the
-    window's own columns (``views``) came packed from the host."""
+    window's own columns (``views``) came packed from the host, and so
+    did ``plan``, the rows of the run's plans (_run_plan_rows), which
+    go behind its masks as they came."""
     lbits = (1 << L) - 1
     one = jnp.ones((), dtype)
     zero = jnp.zeros((), dtype)
@@ -1013,7 +1073,7 @@ def _sharded_run_operands(run, L: int, views, pid, dtype):
         ints += [lm, lv]
     iv = jnp.stack([jnp.asarray(x, jnp.int32) for x in ints])
     fv = jnp.concatenate([q.reshape(-1) for q in payloads])
-    return iv.reshape(-1, 1), fv.reshape(-1, 1)
+    return jnp.concatenate([iv.reshape(-1, 1), plan]), fv.reshape(-1, 1)
 
 
 def sharded_kernel_counts(structure: Tuple, L: int,
@@ -1042,7 +1102,7 @@ def sharded_kernel_lowering(L: int, structure: Tuple, backend: str = None):
 
     return _lowering(structure, backend, min(pk.DEFAULT_BLOCK_POW, L),
                      lambda st, bp: sharded_kernel_counts(st, L, bp),
-                     split=True)
+                     split_at=L)
 
 
 def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
@@ -1054,11 +1114,19 @@ def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
     from . import pallas_kernels as pk
     from . import sharded as shb
 
-    bp = min(pk.DEFAULT_BLOCK_POW, L) if block_pow is None else block_pow
+    bp = min(pk.DEFAULT_BLOCK_POW if block_pow is None else block_pow, L)
     segments = _sharded_segments(structure, L)
     runs = {id(seg): pk.make_window_fn(L, _sharded_run_structure(seg[1], L),
                                        block_pow=bp, interpret=interpret)
             for seg in segments if seg[0] == "run"}
+    # where each run's plans lie in the window's iv: behind its masks,
+    # in the runs' order (pack_operands)
+    plans, at = {}, pk._operand_slots(structure, split=True)[2]
+    for seg in segments:
+        if seg[0] == "run":
+            rows = pk.run_plan_len(_sharded_run_structure(seg[1], L), bp)
+            plans[id(seg)] = (at, at + rows)
+            at += rows
 
     def qrack_sharded_kernel_window(local, iv, fv):  # the module's name
         if remap:
@@ -1073,8 +1141,9 @@ def sharded_kernel_window_body(L: int, npg: int, structure: Tuple,
                 local = shb.apply_global_2x2(local, p, npg, target - L,
                                              lm, lv, gm, gv)
             else:
+                start, stop = plans[id(seg)]
                 local = runs[id(seg)](local, *_sharded_run_operands(
-                    seg[1], L, views, pid, local.dtype))
+                    seg[1], L, views, pid, local.dtype, iv[start:stop]))
         return local
 
     return qrack_sharded_kernel_window

@@ -17,13 +17,27 @@ Vocabulary (everything the fuser emits):
   against the in-tile index and a high part tested against the grid
   block id, so high targets cost one scalar compare per tile.  Two or
   more of them in a row are a RUN, applied as the one diagonal operator
-  they are (_apply_run): the ops with no high part are multiplied
-  together once a launch into a phase tile in VMEM, which every step
-  multiplies by once, and an op with a high part is applied only on the
-  tiles whose id admits it, in a pass over the value held in VMEM.
-  Which of the two an op is follows from its runtime masks, so the
-  program key stays the structure.  One that stands alone is an op of
-  a stretch (below) like any other.
+  they are (_apply_run) in ONE pass over the tile.  The ops with no
+  high part are multiplied together once a launch into a phase tile in
+  VMEM.  An op with a high part is ``1 + 0i`` on a tile whose id does
+  not admit it, and on one that does a complex scalar (two, by the
+  target's bit, for a diag with its target in the tile) wherever the
+  in-tile index matches a mask: its SIGNATURE (fold_signature).  Ops of
+  one signature multiply scalar by scalar, so a step folds the ops its
+  tile admits into a FOLD SLOT a signature (a sublane of one vreg a
+  plane, from operands laid out for the vector unit at the launch's
+  first step: a step does no scalar work an op), and its one pass
+  multiplies the value by the phase tile and by each used slot's
+  factor where the index matches; a run none of whose ops folds
+  branches to the one multiply by the phase tile.  Which ops share a
+  signature depends on where their controls lie, which the program key
+  leaves out: the host plans (run_planner: the first FOLD_SLOTS
+  signatures of a run get a slot, an op of a later one keeps a pass of
+  its own over the value, on the tiles that admit it) and the kernel
+  reads the plan as runtime rows at the tail of ``iv``.  Table, folded
+  or passed follows from the runtime masks and the plan, so the program
+  key stays the structure.  One that stands alone is an op of a stretch
+  (below) like any other.
 * inv / gen with target < block_pow — in-tile pair mix: each element
   reads its partner 2^target amplitudes away through two rotations
   (tile_partner); controls anywhere (runtime mask split).
@@ -670,26 +684,139 @@ def in_tile(kind: str, target: int, cmask: int, cval: int, bp: int) -> bool:
     its factor is the same on every tile of a launch, and a run folds it
     into its phase tile.  On Python ints, for the host's counters; the
     kernel decides the same from its runtime masks (_apply_run)."""
+    return fold_signature(kind, target, cmask, cval, bp) is None
+
+
+# the in-tile signatures a run folds its high-part ops by: a QFT run
+# needs one (the cphase behind a gen share that gen's qubit, so its
+# bit where it lies in the tile and the empty mask where it does not),
+# the Trotter step's three (the bonds above the tile share the empty
+# mask, the bond across the tile's edge is one for either value of its
+# control).  An op of a fourth signature keeps a pass of its own
+FOLD_SLOTS = 3
+# a run's rows at the tail of ``iv``, _PLAN_HEAD of them and one an op:
+# (mask, value, pick) a slot; how many of its ops fold, and how many
+# with a high part fold into no slot and keep a pass of their own; then
+# a row an op of the run: its slot id (FOLD_SLOTS: the op folds into
+# none)
+_SLOT_ROWS = 3
+_PLAN_HEAD = _SLOT_ROWS * FOLD_SLOTS + 2
+# the sublanes of the accumulators' vreg that hold an op's first entry,
+# a slot each and the sink; as many more hold its second
+_FOLD_ROWS = 1 << FOLD_SLOTS.bit_length()
+
+
+def fold_signature(kind: str, target: int, cmask: int, cval: int, bp: int):
+    """``(mask, value, pick)`` of a diagonal op that reads a bit above
+    the tile, None for one that reads none (in_tile).  On one tile the
+    op's factor is ``1 + 0i`` unless the tile id admits it, else a
+    function of the in-tile index ``lidx`` alone: one complex scalar
+    where ``(lidx & mask) == value`` (a cphase's phase; a diag's entry
+    by the tile id's bit, its target above the tile: ``pick`` 0), or
+    for a diag with its target in the tile and a control above, its
+    two entries by the target's bit of ``lidx`` (``pick`` that bit's
+    mask).  Ops of one signature multiply scalar by scalar (_apply_run).
+    On Python ints, the masks as the kernel reads them."""
+    lbits = (1 << bp) - 1
     if kind == "cphase":
-        return ((1 << target) | cmask) >> bp == 0
-    return target < bp and (cmask | cval) >> bp == 0
+        comb = (1 << target) | cmask
+        return (comb & lbits, comb & lbits, 0) if comb >> bp else None
+    if target >= bp:
+        return (cmask & lbits, cval & lbits, 0)
+    if (cmask | cval) >> bp:
+        return (cmask & lbits, cval & lbits, 1 << target)
+    return None
 
 
-def diag_run_counts(structure: Tuple, masks, bp: int) -> Tuple[int, int, int]:
-    """``(runs, ops, tile_ops)``: the runs of diagonal ops the kernel
-    lowers to a phase tile for this window, the ops inside them, and
-    those of them whose factor goes into the tile (in_tile).  ``masks``
-    are the ops' ``(cmask, cval)`` as the kernel reads them: the
-    telemetry counters ``fuse.kernel.diag_runs``, ``.diag_run.ops`` and
-    ``.diag_run.tile_ops``."""
-    runs = ops = tile_ops = 0
-    for seg in plan_window(structure, bp):
-        for start, stop in diag_runs(seg["ops"]):
-            runs += 1
-            ops += stop - start
-            tile_ops += sum(in_tile(kind, target, *masks[idx], bp)
-                            for idx, kind, target, _ in seg["ops"][start:stop])
-    return runs, ops, tile_ops
+def _may_fold(run, bp: int) -> int:
+    """How many of a run's slots may fold whatever their masks hold: all
+    but a diag in the tile with no control, which has no high part."""
+    return sum(kind == "cphase" or target >= bp or has_ctrl
+               for _, kind, target, has_ctrl in run)
+
+
+def window_runs(structure: Tuple, bp: int) -> List[list]:
+    """The runs of diagonal ops of a window's segments (plan_window,
+    diag_runs), each the list of its slots, in the window's order."""
+    return [seg["ops"][start:stop] for seg in plan_window(structure, bp)
+            for start, stop in diag_runs(seg["ops"])]
+
+
+def run_planner(run, masks, bp: int) -> Tuple[List[int], int]:
+    """``(rows, folded)``: one run's plan as the kernel reads it from
+    the tail of ``iv``, and how many of its ops it folds.
+
+    Which ops of a run share a signature depends on where their
+    controls lie, which the structure, and so the program, does not
+    know: the host plans from the masks it packs (``masks[idx]`` the
+    ``(cmask, cval)`` of the slot with op index ``idx``, as the kernel
+    reads them) and the kernel reads the plan as runtime rows.  The
+    first FOLD_SLOTS signatures in op order get a FOLD SLOT each:
+    ``(mask, value, pick)``, ``(0, -1, 0)`` for a slot no op uses (no
+    index has the value -1).  An op that picks nothing goes with the
+    ops of its mask and value that pick by some bit, and they with it:
+    its scalar is both of their two (the pager's op on a page bit
+    reaches its kernel as a diag on qubit 0 with two equal entries).
+    Then how many ops fold and how many keep a pass (a step that has
+    none of the second loops over the run's ops at the launch's first
+    step alone), and a row an op: its slot, or FOLD_SLOTS for an op
+    that folds into none, one with no high part (it goes to the phase
+    tile) or of a later signature (it keeps its pass)."""
+    folds: List[List[int]] = []
+    ids, passed = [], 0
+    for idx, kind, target, _ in run:
+        sig = fold_signature(kind, target, *masks[idx], bp)
+        at = FOLD_SLOTS
+        if sig is not None:
+            at = next((s for s, fold in enumerate(folds)
+                       if fold[:2] == list(sig[:2])
+                       and (fold[2] == sig[2] or not (fold[2] and sig[2]))),
+                      len(folds))
+            if at == len(folds) and at < FOLD_SLOTS:
+                folds.append(list(sig))
+            if at < FOLD_SLOTS:
+                folds[at][2] |= sig[2]
+            passed += at == FOLD_SLOTS
+        ids.append(at)
+    folded = sum(at < FOLD_SLOTS for at in ids)
+    rows = [row for fold in folds for row in fold]
+    rows += [0, -1, 0] * (FOLD_SLOTS - len(folds))
+    return rows + [folded, passed] + ids, folded
+
+
+def _run_plan_slots(structure: Tuple, bp: int) -> Tuple[dict, int]:
+    """``({op index of a run's first slot: offset of its plan's rows in
+    iv}, I)``: the runs' plans (run_planner) lie behind the ops' masks
+    (_operand_slots), in the window's order, and ``I`` is the column's
+    length with them."""
+    _, _, at = _operand_slots(structure)
+    offsets = {}
+    for run in window_runs(structure, bp):
+        offsets[run[0][0]] = at
+        at += _PLAN_HEAD + len(run)
+    return offsets, at
+
+
+def run_plan_len(structure: Tuple, bp: int) -> int:
+    """The rows a window's runs' plans take behind its masks in ``iv``."""
+    return _run_plan_slots(structure, bp)[1] - _operand_slots(structure)[2]
+
+
+def diag_run_counts(structure: Tuple, masks,
+                    bp: int) -> Tuple[int, int, int, int]:
+    """``(runs, ops, tile_ops, folded_ops)``: the runs of diagonal ops
+    the kernel lowers to a phase tile for this window, the ops inside
+    them, those of them whose factor goes into the tile (in_tile) and
+    those with a high part that fold into a slot's scalars
+    (run_planner); the rest keep a pass each.  ``masks`` are the ops'
+    ``(cmask, cval)`` as the kernel reads them: the telemetry counters
+    ``fuse.kernel.diag_runs``, ``.diag_run.ops``, ``.diag_run.tile_ops``
+    and ``.diag_run.folded_ops``."""
+    runs = window_runs(structure, bp)
+    return (len(runs), sum(map(len, runs)),
+            sum(in_tile(kind, target, *masks[idx], bp)
+                for run in runs for idx, kind, target, _ in run),
+            sum(run_planner(run, masks, bp)[1] for run in runs))
 
 
 # rows of the dense tile a step of a pass takes: eight vreg pairs of the
@@ -940,28 +1067,54 @@ def _run_groups(run) -> List[list]:
         else [group])]
 
 
-def _apply_run(v, blk, run, slots, iv_ref, fv_ref, bp, first, run_ref, table):
+def _apply_run(v, blk, run, slots, plan_at, iv_ref, fv_ref, bp, first,
+               run_ref, fold_ref, mask_ref, table, fold_at):
     """A run of diagonal ops on the tile value held in ``run_ref[0]``,
     in place, as one operator; ``v`` is the value where the scratch does
     not hold it yet (the segment begins with the run), else None.
 
-    The run's factor at amplitude ``(blk, lidx)`` is ``R(blk, lidx) *
-    T(lidx)``.  ``T`` is the product of the ops that read no bit above
+    The run's factor at amplitude ``(blk, lidx)`` is ``T(lidx) * R(blk,
+    lidx)``.  ``T`` is the product of the ops that read no bit above
     the tile: the same for every tile, so it is built once a launch, at
     ``first`` (the launch's first computing step), in ``run_ref[table]``
-    from a tile of ``1 + 0i``.  ``R`` is the rest: each op with a high
-    part, by the code it has alone, in a pass over the value, and only
-    on a tile whose id admits it; on any other tile its factor is
-    exactly ``1 + 0i`` and the step branches past it.
-    An op is one or the other by its runtime masks, never both, so it is
-    traced once, under one ``pl.when``: onto the table at ``first``, or
-    onto the value where the tile admits it.  The run ends with the
-    one multiply of the value by the table, complete by then on the
-    first step too.  The ops of a group (_run_groups) are one
-    traced body in a loop over their operands' offsets: what a window
-    program costs to trace and lower is part of every set-up (PERF.md
-    section 6, PR 42)."""
+    from a tile of ``1 + 0i``.  ``R`` is the rest, the ops with a high
+    part: on a tile whose id does not admit one its factor is exactly
+    ``1 + 0i``, and on one that does it is a function of ``lidx`` with
+    a signature of three ints (fold_signature), so ops of one signature
+    multiply scalar by scalar.  The host's plan (run_planner; its rows
+    in ``iv_ref`` from ``plan_at``) gives the first FOLD_SLOTS
+    signatures a slot each and every op its slot.
+
+    What a step pays an op is what counts: a scalar read, a float
+    operation on the scalar core and a scalar handed to the vector unit
+    each take tens of cycles, and a loop's iterations do not overlap
+    (PERF.md section 6, PR 54).  So the ops of the run are walked, one
+    traced body a group (_run_groups) in a loop over the operands'
+    offsets, at the launch's first step alone, unless the plan says an
+    op keeps a pass: there a table op is multiplied onto the table, and
+    a folded op's operands are laid out for the vector unit, in
+    ``fold_ref`` and ``mask_ref`` at the op's place among the folded
+    (from ``fold_at`` on), a vreg each: sublane ``s`` is slot ``s``
+    (the last of the first _FOLD_ROWS a sink), the sublanes from
+    _FOLD_ROWS on the second entry of an op that picks by its target's
+    bit of ``lidx``.  Every step then folds, in a loop of as many
+    iterations as the plan says ops fold and no scalar work, the
+    factors of the ops its tile admits into the slots' accumulators,
+    one vreg a plane, in op order from ``1 + 0i``; and makes ONE pass
+    over the tile: value times table times the first slot's factor
+    where ``lidx`` matches its mask and ``1 + 0i`` elsewhere (the plan
+    fills the slots in their order), then each further slot the plan
+    uses (one it does not use is a scalar branch a chunk).  A run the
+    plan folds no op of branches past all that, to the one multiply by
+    the table it always was.  An op of a fourth signature keeps the pass
+    it had, by the code it has alone over the value, under ``pl.when``
+    on the tile id."""
     tile, dtype = run_ref.shape[2:], run_ref.dtype
+    one, zero = jnp.ones((), dtype), jnp.zeros((), dtype)
+    ids_at = plan_at + _PLAN_HEAD
+    folded, passed = (iv_ref[ids_at - 2, 0], iv_ref[ids_at - 1, 0])
+    vreg = fold_ref.shape[2:]
+    row_of = jax.lax.broadcasted_iota(jnp.int32, vreg, 0)
 
     def a_pass(at, kind, args):
         """The op on ``run_ref[at]``, in place."""
@@ -970,26 +1123,56 @@ def _apply_run(v, blk, run, slots, iv_ref, fv_ref, bp, first, run_ref, table):
                 _chunk_of(run_ref, at, pieces), lidx, blk, *args)[0])
         _for_tile_chunks(tile, chunk)
 
-    def for_ops(group):
-        """Each op of the group onto the table or onto the value."""
+    def lay_out(at, kind, slot, args):
+        """A folded op's operands as the fold's loop reads them, the
+        ``at``-th: the tile id's mask and value that admit it (the
+        value -1, which no id has, on the sublanes of other slots), its
+        target's bit of the tile id, and its factor by that bit."""
+        mine = (row_of & (_FOLD_ROWS - 1)) == slot
+        if kind == "cphase":  # one entry, and no bit of the tile id picks
+            gm = gv = args[1]
+            entries, high = [args[2:], args[2:]], 0
+        else:
+            target, gm, gv = args[0], args[-2], args[-1]
+            high = jnp.where(target >= bp,
+                             jnp.int32(1) << jnp.maximum(target - bp, 0), 0)
+            # the second entry's sublanes of an op with its target in
+            # the tile hold its second entry whatever the tile id
+            second = (row_of >= _FOLD_ROWS) & (high == 0)
+            entries = [tuple(jnp.where(second, b, a)
+                             for a, b in zip(args[2:4], args[4:6])),
+                       args[4:6]]
+        for k, value in enumerate((gm, jnp.where(mine, gv, -1), high)):
+            mask_ref[at, k] = jnp.broadcast_to(value, vreg).astype(jnp.int32)
+        for k, value in enumerate(entries[0] + entries[1]):
+            fold_ref[at, k] = jnp.broadcast_to(value, vreg).astype(dtype)
+
+    def for_ops(group, at_run, at_fold):
+        """Each op of the group onto the table, laid out for the fold
+        or onto the value; the group's ops are the ``at_run``-th on of
+        the run, and ``at_fold`` of the run's ops ahead of them fold."""
         kind = group[0][1]
 
-        def op(at, carry=0):
+        def op(at, at_fold):
+            slot = iv_ref[ids_at + at_run + at, 0]
             args = _diag_operands(group, at, slots, iv_ref, fv_ref, bp)
             if kind == "cphase":
                 high, admits = args[1], (blk & args[1]) == args[1]   # chi
             else:
                 high = jnp.int32(args[0] >= bp) | args[-2] | args[-1]
                 admits = (blk & args[-2]) == args[-1]                # gm, gv
+            folds = slot < FOLD_SLOTS
             # on the table an op has no high part: any tile id admits it
-            pl.when(jnp.where(high == 0, first, admits))(functools.partial(
-                a_pass, jnp.where(high == 0, table, 0), kind, args))
-            return carry
+            pl.when(jnp.where(high == 0, first, admits & ~folds))(
+                functools.partial(a_pass, jnp.where(high == 0, table, 0),
+                                  kind, args))
+            pl.when(first & folds)(functools.partial(
+                lay_out, fold_at + at_fold, kind, slot, args))
+            return at_fold + folds.astype(jnp.int32)
 
         if len(group) == 1:
-            op(0)
-        else:
-            jax.lax.fori_loop(0, len(group), op, 0)
+            return op(0, at_fold)
+        return jax.lax.fori_loop(0, len(group), op, at_fold)
 
     @pl.when(first)
     def _():
@@ -1000,17 +1183,87 @@ def _apply_run(v, blk, run, slots, iv_ref, fv_ref, bp, first, run_ref, table):
         # + 0.0: a cast that feeds a store alone is made by strided stores
         # (led_kernel); it turns a -0.0 into 0.0 and nothing else
         run_ref[0] = v + 0.0
-    for group in _run_groups(run):
-        for_ops(group)
 
-    def by_table(pieces, _):
-        rows, = pieces
-        t_re, t_im = run_ref[table, 0, rows], run_ref[table, 1, rows]
+    @pl.when(first | (passed > 0))
+    def _():
+        at_run, at_fold = 0, jnp.int32(0)
+        for group in _run_groups(run):
+            at_fold = for_ops(group, at_run, at_fold)
+            at_run += len(group)
+
+    def times(rows, *factors):
+        """The chunk's value times each factor in turn."""
         re, im = run_ref[0, 0, rows], run_ref[0, 1, rows]
-        run_ref[0, 0, rows] = re * t_re - im * t_im
-        run_ref[0, 1, rows] = re * t_im + im * t_re
+        for f_re, f_im in factors:
+            re, im = re * f_re - im * f_im, re * f_im + im * f_re
+        run_ref[0, 0, rows], run_ref[0, 1, rows] = re, im
 
-    _for_tile_chunks(tile, by_table)
+    def of_table(rows):
+        return run_ref[table, 0, rows], run_ref[table, 1, rows]
+
+    # a run that is all table pays the one multiply it always did
+    @pl.when(folded == 0)
+    def _():
+        _for_tile_chunks(tile, lambda pieces, _: times(
+            pieces[0], of_table(pieces[0])))
+
+    # the accumulators go to the scratch's last place, whose sublanes
+    # the pass reads a slot at a time
+    acc_at = fold_ref.shape[0] - 1
+    # may an op pick by its target's bit: a diag with its target in the
+    # tile and a control (where above the tile, the plan says)
+    picks = any(kind == "diag" and t < bp and c for _, kind, t, c in run)
+
+    @pl.when(folded > 0)
+    def _():
+        blk_of = jnp.broadcast_to(blk, vreg).astype(jnp.int32)
+
+        def fold(at, acc):
+            at = fold_at + at
+            admits = (blk_of & mask_ref[at, 0]) == mask_ref[at, 1]
+            up = (blk_of & mask_ref[at, 2]) != 0
+            f_re = jnp.where(up, fold_ref[at, 2], fold_ref[at, 0])
+            f_im = jnp.where(up, fold_ref[at, 3], fold_ref[at, 1])
+            f_re = jnp.where(admits, f_re, one)
+            f_im = jnp.where(admits, f_im, zero)
+            return (acc[0] * f_re - acc[1] * f_im,
+                    acc[0] * f_im + acc[1] * f_re)
+
+        fold_ref[acc_at, 0], fold_ref[acc_at, 1] = jax.lax.fori_loop(
+            0, folded, fold, (jnp.ones(vreg, dtype), jnp.zeros(vreg, dtype)))
+        folds = [tuple(iv_ref[plan_at + _SLOT_ROWS * s + row, 0]
+                       for row in range(_SLOT_ROWS))
+                 for s in range(FOLD_SLOTS)]
+
+        def of_slot(s, lidx):
+            """Slot ``s``'s scalar, a sublane of the accumulators, where
+            the chunk's index matches its mask and ``1 + 0i`` elsewhere."""
+            mask, value, pick = folds[s]
+
+            def factor(plane):
+                rows = [fold_ref[acc_at, plane, pl.ds(at, 1)] for at in
+                        ((s, _FOLD_ROWS + s) if picks else (s,))]
+                if len(tile) == 1:
+                    rows = [row.reshape(tile) for row in rows]
+                if picks:
+                    return jnp.where((lidx & pick) != 0, rows[1], rows[0])
+                return rows[0]
+            hit = (lidx & mask) == value
+            return jnp.where(hit, factor(0), one), jnp.where(hit, factor(1), zero)
+
+        def by_table_and_slots(pieces, lidx):
+            rows, = pieces
+            # the plan fills the slots in their order: where an op folds
+            # the first is used, and rides the table's multiply
+            times(rows, of_table(rows), of_slot(0, lidx))
+            for s in range(1, FOLD_SLOTS):
+                # a branch that hands no value on: a chunk carried through
+                # a cond is stored and loaded again whichever side runs
+                # (3.3 ms a launch of an all-table run: PERF.md s.6, PR 54)
+                pl.when(folds[s][1] >= 0)(
+                    lambda s=s: times(rows, of_slot(s, lidx)))
+
+        _for_tile_chunks(tile, by_table_and_slots)
 
 
 def _u4_scalars(fv_ref, foff):
@@ -1036,8 +1289,10 @@ def orbit_tile(lead_bits: Tuple[int, ...], orbit, member):
     return orbit
 
 
-def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
-    """One pl.pallas_call for one segment: run(planes, iv, fv)."""
+def _segment_program(n: int, bp: int, seg: dict, slots, plans,
+                     interpret: bool):
+    """One pl.pallas_call for one segment: run(planes, iv, fv); ``slots``
+    and ``plans`` the window's _operand_slots and _run_plan_slots."""
     block = 1 << bp
     nblk = 1 << (n - bp)
     lbits = block - 1
@@ -1066,6 +1321,12 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
                    if any(kind == "run" or any(held is not None
                                                for _, held in passes)
                           for kind, _, passes in pieces) else [])
+    # and what a run's fold reads (_apply_run): a place an op of the
+    # segment's runs that may fold and one for the accumulators, four
+    # float vregs a place, and three int32 ones an op
+    may_fold = [_may_fold(slots_, bp) if kind == "run" else 0
+                for kind, slots_, _ in pieces]
+    fold_vreg = (2 * _FOLD_ROWS, tile[-1])
 
     def in_tile_ops(v, blk, iv_ref, fv_ref, first, run_refs):
         """The segment's in-tile ops on a loaded (or mixed) tile value,
@@ -1096,11 +1357,13 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
                     v = jax.lax.optimization_barrier(v)
             return v
 
-        for kind, in_piece, passes in pieces:
+        for (kind, in_piece, passes), fold_at in zip(
+                pieces, itertools.accumulate(may_fold, initial=0)):
             if kind == "run":
                 table += 1
-                _apply_run(None if held else v, blk, in_piece, slots, iv_ref,
-                           fv_ref, bp, first(), *run_refs, table)
+                _apply_run(None if held else v, blk, in_piece, slots,
+                           plans[in_piece[0][0]], iv_ref, fv_ref, bp, first(),
+                           *run_refs, table, fold_at)
                 held = True
             for in_pass, bits in passes:
                 if bits is None:  # on the whole tile's value
@@ -1152,6 +1415,12 @@ def _segment_program(n: int, bp: int, seg: dict, slots, interpret: bool):
             # two orbits of cast tiles: the one read and the one written
             scratch = [pltpu.VMEM(shape, planes.dtype) for shape in
                        ([(2, m, 2) + tile] if lead_bits else []) + run_scratch]
+            if runs:
+                scratch += [
+                    pltpu.VMEM((sum(may_fold) + 1, 4) + fold_vreg,
+                               planes.dtype),
+                    pltpu.VMEM((max(sum(may_fold), 1), 3) + fold_vreg,
+                               jnp.int32)]
             return pl.pallas_call(
                 kernel,
                 out_shape=jax.ShapeDtypeStruct((2, 1 << n), planes.dtype),
@@ -1308,19 +1577,26 @@ def make_window_fn(n: int, structure: Tuple,
                    interpret: bool = False):
     """The parametric window kernel: fn(planes, iv, fv) on the packed
     scalar columns of fusion.pack_operands (``fv`` in the planes'
-    dtype), lowering to ``fn.sweeps`` Pallas sweeps (one per planned
-    segment).  Trace it under jit exactly like fusion.window_fn —
+    dtype; ``iv`` with the runs' plans behind the masks, as
+    ``pack_operands`` lays them out for ``fusion.kernel_runs`` of this
+    ``block_pow``), lowering to ``fn.sweeps`` Pallas sweeps (one per
+    planned segment).  Trace it under jit exactly like fusion.window_fn —
     fusion.kernel_window_program does, with the shared structure-only
     cache key."""
     bp = min(block_pow, n)
     segments = plan_window(structure, bp)
     slots, _, _ = _operand_slots(structure)
-    programs = [_segment_program(n, bp, seg, slots, interpret)
+    plans, rows = _run_plan_slots(structure, bp)
+    programs = [_segment_program(n, bp, seg, slots, plans, interpret)
                 for seg in segments]
 
     # named for the compiled module (jit_qrack_kernel_window), as
     # fusion.window_fn's is; the columns go to the launches as they came
     def qrack_kernel_window(planes, iv, fv):
+        if iv.shape[0] != rows:
+            raise ValueError(
+                f"iv has {iv.shape[0]} rows where the window's masks and "
+                f"its runs' plans are {rows}: pack_operands(runs=...)")
         with jax.named_scope("qrack.fuse.kernel_window"):
             for run in programs:
                 planes = run(planes, iv, fv)

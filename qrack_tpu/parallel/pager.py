@@ -844,7 +844,9 @@ class QPager(QEngine):
             if one_op:
                 prog, operands = self._one_op_program(tops[0])
             else:
-                operands = fu.pack_operands(tops, self.dtype, split_at=L)
+                operands = fu.pack_operands(
+                    tops, self.dtype, split_at=L,
+                    runs=plan and plan["runs"])
         if _tele._ENABLED:
             # a window issues one put per operand column and its program
             _tele.inc(f"fuse.{self._tele_name}.programs",

@@ -81,9 +81,17 @@ def _args(operands, state_sharding, operand_sharding, n=W):
         for o in operands]
 
 
+def _kernel_operands(structure, split_at=None):
+    """A kernel window's two columns, the runs' plans behind the masks."""
+    ops, bp = _ops(structure), pk.DEFAULT_BLOCK_POW
+    if split_at is not None:
+        structure, bp = fu.sharded_structure_of(ops), min(bp, split_at)
+    return fu.pack_operands(ops, jnp.float32, split_at=split_at,
+                            runs=fu.kernel_runs(structure, bp, split_at))
+
+
 def _dense_args(structure, sharding, n=W):
-    return _args(fu.pack_operands(_ops(structure), jnp.float32),
-                 sharding, sharding, n=n)
+    return _args(_kernel_operands(structure), sharding, sharding, n=n)
 
 
 def _compile(fn, args):
@@ -214,7 +222,7 @@ def _sharded_program(topo, structure, n, npg=4, remap=()):
     ops = _ops(structure)
     body = fu.sharded_kernel_window_body(L, npg, fu.sharded_structure_of(ops),
                                          remap=remap)
-    args = _args(fu.pack_operands(ops, jnp.float32, split_at=L),
+    args = _args(_kernel_operands(structure, split_at=L),
                  NamedSharding(mesh, P(None, "pages")),
                  NamedSharding(mesh, P()), n=n)
     fn = jax.shard_map(body, mesh=mesh,
@@ -558,15 +566,16 @@ def test_window_of_32_u4_is_the_smem_ceiling(one_chip, cell_windows):
 # ten one-launch windows are one or the other), the last window's gen
 # and twenty-one cphase (one run), and a window of three launches, two of
 # them led with phases behind the lead, the last with a gen between runs
-QFT_RUN_WINDOWS = {"2gen+30cphase": (3, [(1, 3)]), "gen+31cphase": (8, [(1, 2)]),
-                   "gen+21cphase": (12, [(1, 1)]),
-                   "led": (2, [(0, 0), (2, 1), (2, 2)])}
+QFT_RUN_WINDOWS = {"2gen+30cphase": (3, [(3, 3)]), "gen+31cphase": (8, [(3, 2)]),
+                   "gen+21cphase": (12, [(3, 1)]),
+                   "led": (2, [(0, 0), (4, 1), (4, 2)])}
 
 
 @pytest.mark.parametrize("name", sorted(QFT_RUN_WINDOWS))
 def test_qft_run_window_kernel(one_chip, cell_windows, name):
     """A run's phase tile and the tile its value is held in are one
-    VMEM scratch of the launch: beside the blocks (one in, one out, each
+    VMEM scratch of the launch, what the runs' folds read two more (PR 54:
+    seven vregs an op of a run, 28 KiB): beside the blocks (one in, one out, each
     double-buffered) and a led launch's two orbits they stay far under
     the limit the launch asks for, the program holds nothing of the
     ket's size beside the donated ket, and the compiler takes under a
@@ -588,17 +597,59 @@ def test_qft_run_window_kernel(one_chip, cell_windows, name):
         count = eqn.params["grid_mapping"].num_scratch_operands
         scratch = [v.aval for v in eqn.params["jaxpr"].invars[-count:]] \
             if count else []
-        assert all(a.dtype == jnp.float32 for a in scratch)
+        assert all(a.dtype in (jnp.float32, jnp.int32) for a in scratch)
         vmem = 4 * block + sum(4 * int(np.prod(a.shape)) for a in scratch)
         assert vmem <= pk._VMEM_LIMIT_BYTES // 4
         found.append((count, len(pk.diag_runs(seg["ops"]))))
     assert found == expected
+    # the runs' plans ride the tail of iv (PR 54: the slots' rows and a
+    # slot id an op), inside the SMEM budget with the masks and floats
+    iv, fv = _kernel_operands(structure)
+    assert len(iv) == pk._run_plan_slots(structure, pk.DEFAULT_BLOCK_POW)[1]
+    assert len(iv) - pk._operand_slots(structure)[2] == sum(
+        pk._PLAN_HEAD + stop - start
+        for seg in pk.plan_window(structure, pk.DEFAULT_BLOCK_POW)
+        for start, stop in pk.diag_runs(seg["ops"]))
+    assert len(iv) + len(fv) <= fu.SMEM_OPERAND_ROWS // 4
     t0 = time.perf_counter()
     compiled = _compile(fn, args)
     assert time.perf_counter() - t0 < 60
     assert _launches(compiled) == len(expected)
     assert compiled.memory_analysis().temp_size_in_bytes == 0
     assert _in_place(compiled)
+
+
+# jaxpr equations of qft_w28.library's window programs, nested bodies
+# counted: 13 522 over the 13 structures and 2 413 the widest (the first
+# window: seven launches) since a run folds its high-part ops (PR 54:
+# ~180 equations a run more, 36 runs); 7 006 and 1 327 before, 16 540
+# over 26 programs before a run's ops were a loop (PR 42).  What a
+# program costs to trace and lower a cold machine pays once
+# (``first_setup_s``: PERF.md section 6, PR 54); a kernel change that
+# passes a ceiling should know it does
+QFT_EQUATIONS = {"widest": 2500, "all": 14000}
+
+
+def test_qft_w28_programs_stay_under_their_equation_ceiling(cell_windows):
+    """Nothing here needs the chip's compiler."""
+    def count(jaxpr):
+        return sum(1 + sum(count(sub)
+                           for sub in jax.core.jaxprs_in_params(eqn.params))
+                   for eqn in jaxpr.eqns)
+
+    found = []
+    for structure in dict.fromkeys(cell_windows["qft"]):
+        args = [jax.ShapeDtypeStruct((2, 1 << W), jnp.float32)] + [
+            jax.ShapeDtypeStruct(np.shape(o), o.dtype)
+            for o in _kernel_operands(structure)]
+        found.append(count(jax.make_jaxpr(
+            pk.make_window_fn(W, structure))(*args).jaxpr))
+    print(f"equations: {sum(found)} in {len(found)} programs, {found}")
+    assert len(found) == 13
+    assert max(found) <= QFT_EQUATIONS["widest"]
+    assert sum(found) <= QFT_EQUATIONS["all"]
+    # and the ceiling is not slack: within a tenth of what is
+    assert max(found) > 0.9 * QFT_EQUATIONS["widest"]
 
 
 # the cells' windows whose launches hold a stretch of in-tile ops (PR 44;
@@ -615,7 +666,7 @@ STRETCH_WINDOWS = {
     "rcs-w1": ("rcs", 0, [(1, 3)] + [(1, 0)] * 5 + [(2, 3)] + [(1, 0)] * 5
                + [(2, 1)]),
     "rcs-w4": ("rcs", 3, [(1, 3)] + [(1, 0)] * 6),
-    "tfim-54diag-5gen": ("tfim", 0, [(1, 0)]),
+    "tfim-54diag-5gen": ("tfim", 0, [(3, 0)]),  # + what the run's fold reads
     "tfim-23gen": ("tfim", 1, [(1, 2)] + [(1, 0)] * 6),
 }
 
@@ -635,7 +686,7 @@ def _stretch_launches(fn, args, structure, expected):
         count = eqn.params["grid_mapping"].num_scratch_operands
         scratch = [v.aval for v in eqn.params["jaxpr"].invars[-count:]] \
             if count else []
-        assert all(a.dtype == jnp.float32 for a in scratch)
+        assert all(a.dtype in (jnp.float32, jnp.int32) for a in scratch)
         vmem = 4 * block + sum(4 * int(np.prod(a.shape)) for a in scratch)
         assert vmem <= pk._VMEM_LIMIT_BYTES // 4
         pieces = pk.segment_pieces(seg["ops"], pk.dense_tile(bp))
@@ -701,7 +752,7 @@ def test_a_run_of_diag_is_one_traced_body():
             == ([ops] if ops >= pk.DIAG_GROUP_MIN else [1] * ops)
         args = [jax.ShapeDtypeStruct((2, 1 << W), jnp.float32)] + [
             jax.ShapeDtypeStruct(np.shape(o), o.dtype)
-            for o in fu.pack_operands(_ops(structure), jnp.float32)]
+            for o in _kernel_operands(structure)]
         launch, = launches_of(pk.make_window_fn(W, structure), *args)
         return count(launch.params["jaxpr"])
 
@@ -709,7 +760,16 @@ def test_a_run_of_diag_is_one_traced_body():
     print(f"equations: one diag {alone}, a run of 2 {two}, of 3 {three}, "
           f"of 54 {step}")
     assert step - three == 4 * (-(-54 // 4) - 1)
-    assert step < 4 * alone and three < two
+    # the parent's bound was ``step < 4 * alone`` (60: a run of 54 under
+    # 240 equations).  Since PR 54 a run also lays its folded ops out
+    # for the fold, folds them and ends with one of two passes, by the
+    # table alone or by the table and the slots: ~300 equations a run
+    # whatever its length (one diag 60, a run of 2 472, of 3 373, of 54
+    # 425), so the bound that still says "one body, not one an op" is
+    # that 54 ops are fewer equations than the two bodies of a pair,
+    # and within a body's length of a run of three
+    assert step < two and three < two
+    assert step - three < alone
 
 
 # -- w30: the widest ket one chip holds (PR 43) --------------------------------
@@ -808,14 +868,15 @@ def test_qft_w30_stretch_window_kernel(one_chip, qft30_windows):
     """A window of ``qft_w30.library`` with a ``gen`` between two runs
     of ``cphase`` (its ninth: the ``gen`` on qubit 7 among thirty-one
     ``cphase``): the stretch of one op is one pass on the tile the runs
-    hold the value in, one scratch of three tiles."""
+    hold the value in, one scratch of three tiles (and what the runs'
+    folds read, two scratches of vregs: PR 54)."""
     structure = qft30_windows[8]
     kinds = [kind for kind, _, _ in structure]
     assert sorted(set(kinds)) == ["cphase", "gen"] and kinds.count("gen") == 1
     assert all(t < pk.DEFAULT_BLOCK_POW for k, t, _ in structure if k == "gen")
     fn = pk.make_window_fn(W30, structure)
     args = _dense_args(structure, one_chip, n=W30)
-    _stretch_launches(fn, args, structure, [(1, 1)])
+    _stretch_launches(fn, args, structure, [(3, 1)])
     assert pk.stretch_counts(structure, fn.block_pow) == (1, 1, 1, 0)
     compiled = _compile(fn, args)
     memory = compiled.memory_analysis()
@@ -1017,8 +1078,7 @@ def test_compile_cache_key_does_not_hold_the_call_stack(
     from qrack_tpu.checkpoint import warmstart
 
     structure = (("gen", 19, False), ("cphase", 3, True))
-    args = _args(fu.pack_operands(_ops(structure), jnp.float32),
-                 one_chip, one_chip, n=20)
+    args = _args(_kernel_operands(structure), one_chip, one_chip, n=20)
     saved = {k: getattr(jax.config, k) for k in (
         "jax_traceback_in_locations_limit",
         "jax_compilation_cache_dir",

@@ -219,9 +219,9 @@ def test_dense_and_pager_agree(lowering, monkeypatch):
     packed = []
     real = fu.pack_operands
 
-    def spy(ops, dtype, split_at=None):
+    def spy(ops, dtype, split_at=None, runs=None):
         packed.extend((split_at, op.kind, bool(op.cmask)) for op in ops)
-        return real(ops, dtype, split_at)
+        return real(ops, dtype, split_at, runs)
 
     monkeypatch.setattr(fu, "pack_operands", spy)
     a = _all_four_kinds(_engine("dense"))

@@ -12,8 +12,8 @@ from qrack_tpu.ops import fusion as fu
 from qrack_tpu.ops import pallas_kernels as pk
 from qrack_tpu.utils.rng import QrackRandom
 
-from test_pallas_window import (_su, lead_in_numpy, random_ket, riders,
-                                run_window)
+from test_pallas_window import (_su, kernel_operands, lead_in_numpy,
+                                random_ket, riders, run_window)
 
 
 def through_window_kernel(circ, n, planes, block_pow):
@@ -22,7 +22,8 @@ def through_window_kernel(circ, n, planes, block_pow):
     ops = fu.lower_gates(circ.gates)
     wfn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=block_pow,
                             interpret=True)
-    return np.asarray(wfn(planes, *fu.pack_operands(ops, planes.dtype)))
+    return np.asarray(wfn(planes, *kernel_operands(
+        ops, planes.dtype, wfn.block_pow)))
 
 
 def build_circuit(n, seed, gates=30):

@@ -49,6 +49,17 @@ def _clean_layers(monkeypatch):
     tele.reset()
 
 
+def kernel_operands(ops, dtype, bp, split_at=None):
+    """A kernel window's two columns as the engines' flush packs them:
+    the plans of its runs of diagonal ops, for tiles of ``bp`` bits,
+    behind the masks."""
+    structure = (fu.structure_of(ops) if split_at is None
+                 else fu.sharded_structure_of(ops))
+    bp = bp if split_at is None else min(bp, split_at)
+    return fu.pack_operands(ops, dtype, split_at=split_at,
+                            runs=fu.kernel_runs(structure, bp, split_at))
+
+
 def _fidelity(a, b):
     a, b = np.asarray(a), np.asarray(b)
     return abs(np.vdot(a, b)) ** 2 / (np.vdot(a, a).real * np.vdot(b, b).real)
@@ -462,7 +473,8 @@ def test_w12_qft_block_pow8_numeric_parity():
     circ = qft_qcircuit(12)
     ops = fu.lower_gates(circ.gates)
     structure = fu.structure_of(ops)
-    operands = fu.pack_operands(ops, jnp.float32)
+    # the chain reads the masks by offset: the plans behind them ride along
+    operands = kernel_operands(ops, jnp.float32, 8)
     planes = jnp.asarray(basis_planes(12, 1234 & ((1 << 12) - 1)))
     want = np.asarray(fu.window_fn(12, structure)(planes, *operands))
     fn = pk.make_window_fn(12, structure, block_pow=8, interpret=True)
@@ -534,7 +546,7 @@ def _window_against_chain(n, bp, ops, seed):
     import jax.numpy as jnp
 
     structure = fu.structure_of(ops)
-    operands = fu.pack_operands(ops, jnp.float32)
+    operands = kernel_operands(ops, jnp.float32, bp)
     rng = np.random.default_rng(seed)
     ket = rng.standard_normal((2, 1 << n)).astype(np.float32)
     ket /= np.sqrt((ket ** 2).sum())
@@ -582,20 +594,21 @@ def benchmark_plans():
 
 
 def lowered_counts(ops, bp, split_at=None):
-    """``fusion.count_kernel_window`` as ``(runs' three, stretches'
+    """``fusion.count_kernel_window`` as ``(runs' four, stretches'
     four)``: what ``fuse.kernel.diag_runs`` / ``.diag_run.ops`` /
-    ``.diag_run.tile_ops`` and ``fuse.kernel.stretches`` /
+    ``.diag_run.tile_ops`` / ``.diag_run.folded_ops`` and
+    ``fuse.kernel.stretches`` /
     ``.stretch.ops`` / ``.stretch.passes`` / ``.whole_tile_ops`` read."""
     counts = fu.count_kernel_window(ops, bp, split_at=split_at)
     assert tuple(counts) == fu.KERNEL_WINDOW_COUNTERS
     values = tuple(counts.values())
-    return values[:3], values[3:]
+    return values[:4], values[4:]
 
 
 @pytest.mark.parametrize("family,sweeps,carry_ops,diag_runs,stretches", [
-    ("qft", 24, 24, (36, 375, 119), (9, 9, 9, 10)),
-    ("tfim", 8, 2, (1, 54, 30), (1, 9, 2, 7)),
-    ("rcs", 51, 10, (0, 0, 0), (9, 32, 25, 28))])
+    ("qft", 24, 24, (36, 375, 119, 256), (9, 9, 9, 10)),
+    ("tfim", 8, 2, (1, 54, 30, 24), (1, 9, 2, 7)),
+    ("rcs", 51, 10, (0, 0, 0, 0), (9, 32, 25, 28))])
 def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
                                      carry_ops, diag_runs, stretches):
     """What ``fuse.kernel.sweeps.dense`` reads in a traced run of each
@@ -629,7 +642,7 @@ def test_benchmark_cells_sweep_dense(benchmark_plans, family, sweeps,
     holds every op's partners (all of QFT's) and more where it does
     not."""
     dense = with_ops = 0
-    runs, found = (0, 0, 0), (0, 0, 0, 0)
+    runs, found = (0, 0, 0, 0), (0, 0, 0, 0)
     for w in benchmark_plans(family):
         if w["path"] != "kernel":
             continue
@@ -672,7 +685,7 @@ def test_paged_cells_hold_their_bonds_in_runs(kwargs):
         issue(q, trotter_step_gates(30))
         q.GetAmplitude(0)
         assert len(q.windows) == (2 if kwargs else 5 if step == 0 else 7)
-        runs, stretches = (0, 0, 0), (0, 0, 0, 0)
+        runs, stretches = (0, 0, 0, 0), (0, 0, 0, 0)
         for w in q.windows:
             if w.structure is None:  # a lone RX: the shared one-op program
                 continue
@@ -682,7 +695,7 @@ def test_paged_cells_hold_their_bonds_in_runs(kwargs):
                 w.tops, plan["block_pow"], split_at=q.local_bits)
             runs = tuple(a + b for a, b in zip(runs, in_runs))
             stretches = tuple(a + b for a, b in zip(stretches, in_stretches))
-        assert runs == ((1, 58, 32) if kwargs else (3, 58, 32))
+        assert runs == ((1, 58, 32, 26) if kwargs else (3, 58, 32, 26))
         assert stretches == (1, 9, 2, 7)
 
 
@@ -717,13 +730,13 @@ def random_ket(rng, n):
     return ket / np.sqrt((ket ** 2).sum(dtype=np.float32))
 
 
-def nearest_operands(ops, split_at=None):
-    """A window's operands with every float rounded to nearest, as the
-    numpy these tests hold the kernel's arithmetic to rounds them:
-    where ops act on all of the ket the packing keeps a float32
+def nearest_operands(ops, bp, split_at=None):
+    """A kernel window's operands with every float rounded to nearest,
+    as the numpy these tests hold the kernel's arithmetic to rounds
+    them: where ops act on all of the ket the packing keeps a float32
     window's norm, so a float is what came before it in the window
     too (fusion._norm_kept_float32, tests/test_norm_kept_operands.py)."""
-    iv, fv = fu.pack_operands(ops, np.float64, split_at=split_at)
+    iv, fv = kernel_operands(ops, np.float64, bp, split_at=split_at)
     return iv, fv.astype(np.float32)
 
 
@@ -737,8 +750,7 @@ def run_window(n, bp, ops, ket, donate):
     fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
                            interpret=True)
     planes = jnp.array(ket, copy=True)
-    got = _exact(fn, planes, *nearest_operands(ops),
-                 donate=donate)
+    got = _exact(fn, planes, *nearest_operands(ops, bp), donate=donate)
     if donate:
         assert planes.is_deleted()
     else:
@@ -998,7 +1010,7 @@ def test_a_led_segment_moves_one_tile_in_and_one_out_a_step(n, bp, lead,
     fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
                            interpret=True)
     (grid, (read,), write), = grids_of(fn, jnp.zeros((2, 1 << n), jnp.float32),
-                                       *fu.pack_operands(ops, jnp.float32))
+                                       *kernel_operands(ops, jnp.float32, bp))
     nblk, m = 1 << (n - bp), 1 << len(lead_bits)
     orbits = nblk // m
     assert grid == (orbits + 1, m)
@@ -1031,7 +1043,7 @@ def test_an_unled_segment_keeps_its_grid():
     fn = pk.make_window_fn(12, fu.structure_of(ops), block_pow=8,
                            interpret=True)
     (grid, (tile,), out), = grids_of(fn, jnp.zeros((2, 1 << 12), jnp.float32),
-                                     *fu.pack_operands(ops, jnp.float32))
+                                     *kernel_operands(ops, jnp.float32, 8))
     assert grid == (16,)
     assert [tile(i) for i in range(16)] == [out(i) for i in range(16)] \
         == list(range(16))
@@ -1070,7 +1082,7 @@ def test_every_launch_aliases_its_planes_to_its_result(n, bp, ops, grids):
                            interpret=True)
     assert fn.sweeps == len(grids)
     launches = launches_of(fn, jnp.zeros((2, 1 << n), jnp.float32),
-                           *fu.pack_operands(ops, jnp.float32))
+                           *kernel_operands(ops, jnp.float32, bp))
     assert [len(eqn.params["grid_mapping"].grid) for eqn in launches] == grids
     for eqn in launches:
         # iv, fv, planes -> the one result, of the planes' shape and type
@@ -1173,21 +1185,67 @@ def _cmul(v, f):
     return np.stack([v[0] * f[0] - v[1] * f[1], v[0] * f[1] + v[1] * f[0]])
 
 
+def _scalar_cmul(a, f):
+    """``a * f`` on float32 scalars, one IEEE operation at a time."""
+    return (np.float32(a[0] * f[0]) - np.float32(a[1] * f[1]),
+            np.float32(a[0] * f[1]) + np.float32(a[1] * f[0]))
+
+
 def run_in_numpy(ket, run, n, bp):
     """A run of diagonal ops on the whole ``(2, 2^n)`` float32 ket in
-    the kernel's order (``_apply_run``): the ops with a bit above the
-    tile, in their order (on a tile that does not admit one its factor
-    is ``1 + 0i``, and multiplying by that changes no bit); then one
-    multiply by the phase tile, built from the ops with every bit in
-    the tile, in their order, on a tile of ``1 + 0i``."""
+    the kernel's order (``_apply_run``, the host's ``run_planner``).
+    An op with every bit in the tile goes, in its order, onto the phase
+    tile, built on a tile of ``1 + 0i``.  An op with a bit above the
+    tile has a signature (``fold_signature``); the first ``FOLD_SLOTS``
+    signatures of the run are its slots, and on every tile a slot's
+    factor is the product, in op order from ``1 + 0i`` and in float32
+    scalars, of its ops that the tile id admits: one scalar, or two
+    where an op picks an entry by its target's bit of the in-tile
+    index.  An op of a later signature is applied to the ket by its own
+    code, in its order (on a tile that does not admit it its factor is
+    ``1 + 0i``, and multiplying by that changes no bit).  Then one
+    pass: the ket times the table, times each used slot's factor where
+    the in-tile index matches its mask and ``1 + 0i`` elsewhere."""
+    one, zero = np.float32(1), np.float32(0)
     table = np.stack([np.ones(1 << bp, np.float32),
                       np.zeros(1 << bp, np.float32)])
-    for op in run:
+    slots = [(i, op.kind, op.target, op.cmask != 0) for i, op in enumerate(run)]
+    rows, _ = pk.run_planner(slots, [(op.cmask, op.cval) for op in run], bp)
+    ids = rows[pk._PLAN_HEAD:]
+    for op, slot in zip(run, ids):
         if pk.in_tile(op.kind, op.target, op.cmask, op.cval, bp):
+            assert slot == pk.FOLD_SLOTS
             table = unled_in_numpy(table, op, bp)
-        else:
+        elif slot == pk.FOLD_SLOTS:
             ket = unled_in_numpy(ket, op, n)
-    return _cmul(ket, np.tile(table, (1, 1 << (n - bp))))
+    ket = _cmul(ket, np.tile(table, (1, 1 << (n - bp))))
+    lidx = np.arange(1 << bp)
+    for s in range(pk.FOLD_SLOTS):
+        mask, value, pick = rows[3 * s:3 * s + 3]
+        if value < 0:
+            continue
+        hit = (lidx & mask) == value
+        odd = (lidx & pick) != 0
+        for blk in range(1 << (n - bp)):
+            acc = [(one, zero), (one, zero)]
+            for op, slot in zip(run, ids):
+                m = np.asarray(op.m)
+                d = [(np.float32(m[b, b].real), np.float32(m[b, b].imag))
+                     for b in (0, 1)]
+                if op.kind == "cphase":
+                    high = ((1 << op.target) | op.cmask) >> bp
+                    admits, by = (blk & high) == high, (d[1], d[1])
+                else:
+                    admits = (blk & (op.cmask >> bp)) == op.cval >> bp
+                    by = d if op.target < bp \
+                        else (d[(blk >> (op.target - bp)) & 1],) * 2
+                if slot == s and admits:
+                    acc = [_scalar_cmul(a, f) for a, f in zip(acc, by)]
+            f_re = np.where(hit, np.where(odd, acc[1][0], acc[0][0]), one)
+            f_im = np.where(hit, np.where(odd, acc[1][1], acc[0][1]), zero)
+            tile = slice(blk << bp, (blk + 1) << bp)
+            ket[:, tile] = _cmul(ket[:, tile], (f_re, f_im))
+    return ket
 
 
 def segment_in_numpy(ket, ops, n, bp):
@@ -1246,6 +1304,10 @@ def diagonal_ops(n, bp):
                            _two_phases(-0.77, 0.66)),
         "diag.mixed.anti2": ("diag", 0, (low << 1) | 4, low << 1,
                              _two_phases(0.23, -0.12)),
+        # an in-tile target under a control above the tile, with the
+        # in-tile mask and value of "diag.high.anti"
+        "diag.mixed.pick": ("diag", 5, low | 8, low | 8,
+                            _two_phases(-1.1, 0.31)),
     }
     out = {}
     for name, (kind, target, cmask, cval, m) in ops.items():
@@ -1255,14 +1317,36 @@ def diagonal_ops(n, bp):
 
 
 # (name, the run's ops): runs of 2, 3 and 16, a run that is all table
-# (no op may read the tile id: no value scratch), one that is all rest
+# (no op may read the tile id: no slot engages), one that is all rest;
+# and by the fold (PR 54): four signatures (the fourth keeps its pass,
+# the op behind it shares the third's slot), ops that pick one of two
+# entries by their target's bit under a control above the tile (two
+# accumulators a slot; one of the controls an anti-control above the
+# tile), and an op that picks nothing in the slot of one that picks
 DIAG_RUNS = {
     "2": ("cphase.tile", "cphase.mixed"),
     "2-table": ("cphase.tile.bare", "diag.tile.bare"),
     "2-rest": ("cphase.high", "diag.high.bare"),
     "3": ("diag.tile", "cphase.high", "diag.mixed.anti"),
     "3-anti": ("diag.tile.anti", "diag.high.anti", "diag.mixed.anti2"),
-    "16": tuple(diagonal_ops(12, 10)),
+    "16": tuple(diagonal_ops(12, 10))[:16],
+    "4-signatures": ("cphase.mixed", "cphase.mixed2", "cphase.high",
+                     "diag.high", "cphase.high.bare", "cphase.tile"),
+    "3-twin": ("diag.mixed.anti", "diag.mixed.anti2", "cphase.mixed.2c",
+               "diag.mixed.anti"),
+    "2-shared": ("diag.high.anti", "diag.mixed.pick", "diag.high.anti"),
+}
+# what the host plans for them: the slots' (mask, value, pick) and the
+
+
+RUN_PLANS = {
+    "2-table": ([], [3, 3]),
+    "2-rest": ([(0, 0, 0)], [0, 0]),
+    "4-signatures": ([(1 << 3, 1 << 3, 0), (1 << 6, 1 << 6, 0), (0, 0, 0)],
+                     [0, 1, 2, 3, 2, 3]),
+    "3-twin": ([(1, 1, 1 << 2), (4, 0, 1), ((1 << 4) | 2, (1 << 4) | 2, 0)],
+               [0, 1, 2, 0]),
+    "2-shared": ([(8, 8, 1 << 5)], [0, 0, 0]),
 }
 RUN_SHAPES = [(10, 8), (12, 10), (18, 16)]
 
@@ -1281,6 +1365,76 @@ def _diag_run_case(n, bp, name):
 _RUN_CASES = pytest.mark.parametrize(
     "n,bp,name", [pytest.param(n, bp, name, id=f"w{n}-bp{bp}-run{name}")
                   for n, bp in RUN_SHAPES for name in DIAG_RUNS])
+
+
+@pytest.mark.parametrize("n,bp", RUN_SHAPES, ids=lambda v: str(v))
+@pytest.mark.parametrize("name", sorted(RUN_PLANS))
+def test_the_host_plans_a_runs_slots(n, bp, name):
+    """``run_planner`` on the masks the host packs: the first
+    ``FOLD_SLOTS`` signatures in op order get a slot, an op of a fourth
+    keeps its pass, an op with every bit in the tile goes to the table;
+    and the rows ride the tail of ``iv`` at the offset the kernel reads
+    them from."""
+    ops = [diagonal_ops(n, bp)[key] for key in DIAG_RUNS[name]]
+    structure = fu.structure_of(ops)
+    segment, = pk.plan_window(structure, bp)
+    masks = [(op.cmask, op.cval) for op in ops]
+    rows, folded = pk.run_planner(segment["ops"], masks, bp)
+    slots, ids = RUN_PLANS[name]
+    want = [row for slot in slots for row in slot] \
+        + [0, -1, 0] * (pk.FOLD_SLOTS - len(slots))
+    passed = sum(at == pk.FOLD_SLOTS and not pk.in_tile(
+        op.kind, op.target, op.cmask, op.cval, bp) for op, at in zip(ops, ids))
+    assert rows == want + [folded, passed] + ids   # an op's row: its slot
+    assert passed == (name == "4-signatures")
+    assert folded == sum(at < pk.FOLD_SLOTS for at in ids)
+    assert pk.diag_run_counts(structure, masks, bp) == (
+        1, len(ops), sum(pk.in_tile(op.kind, op.target, op.cmask, op.cval, bp)
+                         for op in ops), folded)
+    iv, _ = kernel_operands(ops, np.float32, bp)
+    offsets, length = pk._run_plan_slots(structure, bp)
+    assert length == len(iv) and pk.run_plan_len(structure, bp) == len(rows)
+    assert iv[offsets[0]:, 0].tolist() == rows
+    # without the runs the columns are the chain's: no plan behind them
+    assert len(fu.pack_operands(ops, np.float32)[0]) == offsets[0]
+
+
+@pytest.mark.parametrize("family", ["qft", "tfim", "rcs", "grover"])
+def test_the_host_plan_covers_every_run_of_a_family(benchmark_plans, family):
+    """Every window of an application at w28: the plans behind the masks
+    are one a run of ``diag_runs``, at the offsets the kernel reads, and
+    every op of a run is table or folded: none keeps a pass of its own
+    (a QFT run needs one slot, the Trotter step's three)."""
+    runs = slots_used = 0
+    for w in benchmark_plans(family):
+        if w["path"] != "kernel":
+            continue
+        structure, ops = w["structure"], w["ops"]
+        plan, _ = fu.kernel_lowering(28, structure, backend="tpu")
+        bp = plan["block_pow"]
+        masks = [(op.cmask, op.cval) for op in ops]
+        # the lowering plans the window once: the packer takes its runs
+        iv, _ = fu.pack_operands(ops, np.float32, runs=plan["runs"])
+        assert plan["runs"] == fu.kernel_runs(structure, bp)
+        offsets, length = pk._run_plan_slots(structure, bp)
+        assert length == len(iv)
+        found = [seg["ops"][a:b] for seg in pk.plan_window(structure, bp)
+                 for a, b in pk.diag_runs(seg["ops"])]
+        assert found == pk.window_runs(structure, bp)
+        assert sorted(offsets) == [run[0][0] for run in found]
+        for run in found:
+            rows, folded = pk.run_planner(run, masks, bp)
+            at = offsets[run[0][0]]
+            assert iv[at:at + len(rows), 0].tolist() == rows
+            ids = rows[pk._PLAN_HEAD:]
+            for (idx, kind, target, _), slot in zip(run, ids):
+                assert (slot == pk.FOLD_SLOTS) == pk.in_tile(
+                    kind, target, *masks[idx], bp)
+            runs += 1
+            slots_used = max(slots_used, sum(
+                rows[3 * s + 1] >= 0 for s in range(pk.FOLD_SLOTS)))
+    assert (runs, slots_used) == {"qft": (36, 1), "tfim": (1, 3),
+                                  "rcs": (0, 0), "grover": (0, 0)}[family]
 
 
 @_RUN_CASES
@@ -1445,7 +1599,7 @@ def test_the_per_page_kernel_runs_a_diag_run_in_the_new_order(bp):
     assert kind == "run"
     segment, = pk.plan_window(fu._sharded_run_structure(run, L), bp)
     assert pk.diag_runs(segment["ops"]) == [(0, 6), (7, 9)]
-    expected = {8: (2, 8, 5), 10: (2, 8, 8)}[bp]
+    expected = {8: (2, 8, 5, 3), 10: (2, 8, 8, 0)}[bp]
     assert lowered_counts(ops, bp, split_at=L)[0] == expected
     body = fu.sharded_kernel_window_body(L, npg, structure, block_pow=bp,
                                          interpret=True)
@@ -1455,7 +1609,7 @@ def test_the_per_page_kernel_runs_a_diag_run_in_the_new_order(bp):
                        out_specs=P(None, "pages"), check_vma=False)
     ket = random_ket(np.random.default_rng(bp), n)
     got = _exact(fn, jnp.asarray(ket),
-                 *fu.pack_operands(ops, jnp.float32, split_at=L))
+                 *kernel_operands(ops, jnp.float32, bp, split_at=L))
     for pid in range(npg):
         local = ket[:, pid * page:(pid + 1) * page]
         want = segment_in_numpy(local, _page_ops(ops, L, pid), L, bp)
@@ -1678,7 +1832,7 @@ def test_the_per_page_kernel_walks_a_stretch_chunk_by_chunk(donate):
     assert kind == "run"
     segment, = pk.plan_window(fu._sharded_run_structure(run, L), bp)
     assert not pk.diag_runs(segment["ops"])
-    assert lowered_counts(ops, bp, split_at=L) == ((0, 0, 0), (1, 7, 3, 2))
+    assert lowered_counts(ops, bp, split_at=L) == ((0, 0, 0, 0), (1, 7, 3, 2))
     body = fu.sharded_kernel_window_body(L, npg, structure, block_pow=bp,
                                          interpret=True)
     mesh = Mesh(np.array(jax.devices()[:npg]), ("pages",))
@@ -1687,7 +1841,7 @@ def test_the_per_page_kernel_walks_a_stretch_chunk_by_chunk(donate):
                        out_specs=P(None, "pages"), check_vma=False)
     ket = random_ket(np.random.default_rng(bp), n)
     got = _exact(fn, jnp.array(ket, copy=True),
-                 *nearest_operands(ops, split_at=L),
+                 *nearest_operands(ops, bp, split_at=L),
                  donate=donate)
     for pid in range(npg):
         local = ket[:, pid * page:(pid + 1) * page]
@@ -1746,8 +1900,8 @@ def test_a_window_without_a_run_traces_as_before(benchmark_plans, family,
     planes = jax.ShapeDtypeStruct((2, 1 << 28), jnp.float32)
     bare = whole = chunked = with_run = 0
     for structure in structures:
-        args = (planes, *fu.pack_operands(_placeholder_ops(structure),
-                                          jnp.float32))
+        args = (planes, *kernel_operands(_placeholder_ops(structure),
+                                         jnp.float32, pk.DEFAULT_BLOCK_POW))
         with monkeypatch.context() as patch:
             patch.setattr(pk, "chunked", lambda tile: False)
             unchunked = [str(eqn.params["jaxpr"]) for eqn in launches_of(
@@ -1834,15 +1988,23 @@ def _chunked_cases():
         # for three
         ("split-u4", [_u4(9, 10), _u4(11, 12)], [(1, 0, 2)], 1),
         # a stretch between two runs works on the tile the runs hold the
-        # value in: the runs' five conds and six loops (the first run's
-        # two like cphase are one loop over one traced pass, the second
-        # run's two unlike ops a pass each, and a multiply by the table
-        # each), and the stretch's one
-        ("between-runs", mixed + [gen[12]] + table_only, [(1, 5, 7)], 3),
+        # value in, and the stretch's one loop.  A run's conds: the
+        # table's start, the walk over its ops (at the first step, or
+        # where an op keeps a pass), in it two a group of like ops (onto
+        # the table or the value; laid out for the fold), the branch
+        # to the pass by the table alone or to the fold and its pass,
+        # and in that pass a scalar branch a fold slot behind the first:
+        # 3 + 2 x groups + FOLD_SLOTS, 8 for the first run's two like
+        # cphase and 10 for the second's two unlike ops.  Its rolled
+        # loops: one over a group's ops and the pass traced in it, and
+        # the two passes, by the table alone and by the table and the
+        # slots (the fold's loop has the plan's trip count: a ``while``)
+        ("between-runs", mixed + [gen[12]] + table_only, [(3, 18, 9)], 3),
         # an op that rolls lanes between them is applied on the value,
         # loaded behind the first run and stored again by the second
-        ("lanes-between-runs", mixed + [gen[3]] + table_only, [(1, 5, 6)], 3),
-        ("run-then-stretch", mixed + [gen[3], gen[13]], [(1, 2, 4)], 2),
+        ("lanes-between-runs", mixed + [gen[3]] + table_only,
+         [(3, 18, 8)], 3),
+        ("run-then-stretch", mixed + [gen[3], gen[13]], [(3, 8, 5)], 2),
     ]
     for name, lead in leads.items():
         # behind a lead: the orbits, the value's tile, the grid's two conds
@@ -1865,27 +2027,50 @@ def test_a_stretch_holds_its_value_in_the_scratch(ops, shapes, tiles):
     fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
                            interpret=True)
     args = (jnp.zeros((2, 1 << n), jnp.float32),
-            *fu.pack_operands(ops, jnp.float32))
+            *kernel_operands(ops, jnp.float32, bp))
     assert _scratch_conds_loops(fn, *args) == shapes
     eqn, = launches_of(fn, *args)
     assert tuple(eqn.params["input_output_aliases"]) == ((2, 0),)
     if tiles:
-        assert eqn.params["jaxpr"].invars[-1].aval.shape \
-            == (tiles, 2) + pk.dense_tile(bp)
+        # behind the tiles where the segment holds a run: the fold
+        # slots' accumulators, a vreg a plane
+        scratch = [v.aval.shape
+                   for v in eqn.params["jaxpr"].invars[-shapes[0][0]:]]
+        segment, = pk.plan_window(fu.structure_of(ops), bp)
+        runs = [segment["ops"][a:b] for a, b in pk.diag_runs(segment["ops"])]
+        # behind the tiles where the segment holds a run: what its fold
+        # reads, a place an op of the runs that may fold, whatever its
+        # masks hold (four float vregs, three int32: all but a bare
+        # diag in the tile), and one more for the slots' accumulators
+        if runs:
+            may_fold = sum(kind == "cphase" or target >= bp or ctrl
+                           for run in runs for _, kind, target, ctrl in run)
+            assert scratch[-2:] == [(may_fold + 1, 4, 8, 128),
+                                    (max(may_fold, 1), 3, 8, 128)]
+            scratch = scratch[:-2]
+        assert scratch[-1] == (tiles, 2) + pk.dense_tile(bp)
 
 
 def test_a_window_with_a_run_holds_its_scratch_in_vmem():
-    """One scratch of tiles: the value's and a phase tile a run; the
-    planes stay the launch's one result
-    (``test_every_launch_aliases_its_planes_to_its_result``).  A
-    ``pl.when`` for the table's start, and one for each stretch of a run
-    (consecutive ``cphase`` alike in having controls, or one ``diag``:
-    one traced body in a loop over its ops, onto the table at the first
-    step where the op has no high part, onto the value where it has one
-    and the tile admits it).  The tile here is one chunk (eight rows):
-    a pass is its body, not a loop, the only loops are those over a
-    run's like ops, and the ops outside runs are applied on the tile's
-    value (the last case: no scratch at all)."""
+    """One scratch of tiles: the value's and a phase tile a run, and
+    two of vregs for what the runs' folds read; the planes stay the
+    launch's one result
+    (``test_every_launch_aliases_its_planes_to_its_result``).  A run's
+    ``pl.when``: one for the table's start, one around the walk over
+    its ops (the launch's first step, or where the plan says an op
+    keeps a pass), in it two a group (consecutive ``cphase`` alike in
+    having controls, or one ``diag``: one traced body in a loop over
+    its ops, onto the table at the first step where the op has no high
+    part, onto the value where it has one that folds into no slot and
+    the tile admits it; and laid out for the fold at the first step
+    where it folds), the branch to the pass by the table alone (the
+    plan folds no op) or to the fold and its pass, and in that pass a
+    scalar branch a fold slot behind the first (a slot the plan does
+    not use costs no vector work): 3 + 2 x groups + ``FOLD_SLOTS`` a
+    run.  The tile here is one chunk (eight rows): a pass is
+    its body, not a loop, the only rolled loops are those over a run's
+    like ops, and the ops outside runs are applied on the tile's value
+    (the last case: no scratch at all)."""
     import jax
     import jax.numpy as jnp
 
@@ -1896,15 +2081,16 @@ def test_a_window_with_a_run_holds_its_scratch_in_vmem():
     mixed = [named["cphase.tile"], named["cphase.mixed"]]
     lead = fu.FusedOp("gen", 11, 0, 0, _DENSE_MATRICES["gen"])
     for ops, expected in [
-            (table_only, [(1, 3, 0)]),
-            (mixed, [(1, 2, 1)]),
-            (mixed + [gen] + table_only, [(1, 5, 1)]),
-            ([lead] + mixed, [(2, 4, 1)]),           # + the orbits, the grid's two
+            (table_only, [(3, 10, 0)]),
+            (mixed, [(3, 8, 1)]),
+            (mixed + [gen] + table_only, [(3, 18, 1)]),
+            # + the orbits, the grid's two
+            ([lead] + mixed, [(4, 10, 1)]),
             ([named["cphase.mixed"], gen, named["cphase.tile"]], [(0, 0, 0)])]:
         fn = pk.make_window_fn(n, fu.structure_of(ops), block_pow=bp,
                                interpret=True)
         args = (jnp.zeros((2, 1 << n), jnp.float32),
-                *fu.pack_operands(ops, jnp.float32))
+                *kernel_operands(ops, jnp.float32, bp))
         assert _scratch_conds_loops(fn, *args) == expected
         assert pk.stretch_counts(fu.structure_of(ops), bp)[:3] == (0, 0, 0)
         for eqn in launches_of(fn, *args):
